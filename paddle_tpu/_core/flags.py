@@ -418,8 +418,10 @@ watch_flag("FLAGS_async_flush", _sync_async_flush_gate)
 
 # ---- kernels / pallas
 define_flag("FLAGS_flash_interpret", False,
-            "Force Pallas flash kernels into interpret mode (CPU mesh "
-            "tests; PT_FLASH_INTERPRET env is the legacy spelling).")
+            "Off a TPU, let the compiled GPT trainer use the Pallas flash "
+            "kernel in interpret mode instead of the einsum path (CPU "
+            "mesh tests, multichip dryrun). No effect on a TPU, where "
+            "the kernel is always Mosaic-compiled.")
 define_flag("FLAGS_moe_capacity_factor", 1.25,
             "Default MoE gating capacity factor.")
 
@@ -526,17 +528,17 @@ define_flag("FLAGS_compute_telemetry", False,
             "analysis work (bench row 14).")
 define_flag("FLAGS_device_peak_flops", 0.0,
             "Per-chip peak FLOP/s the MFU column divides by. 0 = "
-            "autodetect per backend: TPU from the device_kind table "
-            "(v2 45T .. v6e 918T bf16), CPU falls back to a nominal "
-            "cores x 2.5 GHz x 16 fp32-FLOPs/cycle AVX2-FMA envelope "
-            "(documented in README — CPU MFU is a relative meter, not "
-            "an absolute one).")
+            "the device's published peak: a TPU from the device_kind "
+            "table in _core/device.py (an unknown kind is an error), "
+            "the CPU backend from a nominal cores x 2.5 GHz x 16 "
+            "fp32-FLOPs/cycle AVX2-FMA envelope (documented in README "
+            "— CPU MFU is a relative meter, not an absolute one).")
 define_flag("FLAGS_device_peak_membw", 0.0,
             "Per-chip peak memory bandwidth in bytes/s for the "
             "roofline ridge point (peak_flops / peak_membw). 0 = "
-            "autodetect: TPU from the device_kind table (v4 1.2TB/s, "
-            "v5p 2.8TB/s, ...), CPU falls back to a nominal 25.6 GB/s "
-            "two-channel DDR4 envelope.")
+            "the device's published peak: a TPU from the device_kind "
+            "table in _core/device.py, the CPU backend from a nominal "
+            "25.6 GB/s two-channel DDR4 envelope.")
 define_flag("FLAGS_memory_budget_bytes", 0,
             "Per-device HBM budget in bytes for the cross-rank memory "
             "column: budget --distributed flags the rank whose peak is "

@@ -9,40 +9,65 @@ plugin ABI, paddle/phi/backends/device_ext.h:96)."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
+import sys
 import threading
-from typing import Optional
+
+_LOG = logging.getLogger(__name__)
 
 _lib = None
 _lib_lock = threading.Lock()
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_SO = os.path.join(_CSRC, "build", "libpaddle_tpu_rt.so")
-def _sources():
-    # derived, not duplicated: every .cc/.h under csrc/ participates in
-    # staleness so build.sh and this list cannot silently diverge
+_BUILD = os.path.join(_CSRC, "build")
+_SO = os.path.join(_BUILD, "libpaddle_tpu_rt.so")
+_STAMP = os.path.join(_BUILD, "sources.sha256")
+_BUILD_LOG = os.path.join(_BUILD, "build.log")
+
+
+def _sources_digest() -> str:
+    """Digest of what the build is made FROM: every .cc/.h under csrc/
+    (derived, so build.sh and this list cannot diverge), build.sh and the
+    interpreter the extension is built against. csrc/build/ is git-ignored
+    and may be a copy from another machine, so staleness is decided by
+    content, never by an mtime inside it."""
     import glob
-    return (glob.glob(os.path.join(_CSRC, "*.cc"))
-            + glob.glob(os.path.join(_CSRC, "*.h")))
+    h = hashlib.sha256(repr(sys.version_info[:2]).encode())
+    for p in sorted(glob.glob(os.path.join(_CSRC, "*.cc"))
+                    + glob.glob(os.path.join(_CSRC, "*.h"))
+                    + [os.path.join(_CSRC, "build.sh")]):
+        h.update(os.path.basename(p).encode())
+        with open(p, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
 
 
 def _needs_build() -> bool:
-    if not os.path.exists(_SO):
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip() != _sources_digest() \
+                or not os.path.exists(_SO)
+    except OSError:
         return True
-    so_mtime = os.path.getmtime(_SO)
-    for p in _sources():
-        if os.path.getmtime(p) > so_mtime:
-            return True
-    return False
 
 
 def _build():
-    import sys
     env = dict(os.environ)
     env["PT_PYTHON"] = sys.executable   # ABI-match the extension build
-    subprocess.run(["sh", os.path.join(_CSRC, "build.sh")], check=True,
-                   capture_output=True, env=env)
+    proc = subprocess.run(["sh", os.path.join(_CSRC, "build.sh")],
+                          stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+                          text=True, env=env)
+    os.makedirs(_BUILD, exist_ok=True)
+    with open(_BUILD_LOG, "w") as f:
+        f.write(proc.stdout)
+    if proc.returncode:
+        raise RuntimeError(
+            f"csrc/build.sh exited {proc.returncode}:\n{proc.stdout[-2000:]}")
+    with open(_STAMP, "w") as f:
+        f.write(_sources_digest())
 
 
 def _bind(lib):
@@ -154,29 +179,22 @@ def _bind(lib):
     return lib
 
 
-def get_lib(required: bool = False) -> Optional[ctypes.CDLL]:
-    """Load (building if stale) the native runtime; None when the
-    toolchain is unavailable and required=False."""
+def get_lib() -> ctypes.CDLL:
+    """Load (building if stale) the native runtime. Raises when the
+    toolchain is unavailable: every caller needs the library."""
     global _lib
     if _lib is not None:
         return _lib
     with _lib_lock:
-        if _lib is not None:
-            return _lib
-        try:
+        if _lib is None:
             if _needs_build():
                 _build()
             _lib = _bind(ctypes.CDLL(_SO))
-        except Exception:
-            if required:
-                raise
-            return None
     return _lib
 
 
 def last_error() -> str:
-    lib = get_lib()
-    return lib.pt_last_error().decode() if lib is not None else ""
+    return get_lib().pt_last_error().decode()
 
 
 def bind_jit(lib):
@@ -216,7 +234,7 @@ def host_pool():
     global _HOST_POOL
     if _HOST_POOL is None:
         from . import flags
-        lib = get_lib(required=True)
+        lib = get_lib()
         _HOST_POOL = lib.pt_alloc_create(
             int(flags.flag_value("FLAGS_host_alloc_chunk_kb")) * 1024)
     return _HOST_POOL
@@ -224,6 +242,7 @@ def host_pool():
 
 _EAGER_CORE = None
 _EAGER_CORE_TRIED = False
+_EAGER_CORE_WHY_NOT = "not asked for yet"
 
 
 def get_eager_core():
@@ -234,25 +253,39 @@ def get_eager_core():
     trace-stable skeleton matcher ``skel_record`` that replays one
     recorded op per C call (lazy.py arms/validates the skeleton and
     stands alone in pure python when this returns None). Returns None
-    when unavailable (python fallbacks stay correct); set
+    when unavailable: the python record path is a supported path
+    (correct, slower per op), so a failed build is logged once and
+    `eager_core_status()` says which path this process is on; set
     PT_DISABLE_NATIVE_EAGER=1 to force the python path. Consumers
     cache their own resolution (dispatch._EAGER_CORE, lazy._NC) so
     bench row 17 and the fallback tests can force either prong
     in-process."""
-    global _EAGER_CORE, _EAGER_CORE_TRIED
+    global _EAGER_CORE, _EAGER_CORE_TRIED, _EAGER_CORE_WHY_NOT
     if _EAGER_CORE_TRIED:
         return _EAGER_CORE
     _EAGER_CORE_TRIED = True
     if os.environ.get("PT_DISABLE_NATIVE_EAGER") == "1":
+        _EAGER_CORE_WHY_NOT = "PT_DISABLE_NATIVE_EAGER=1"
         return None
     try:
-        get_lib(required=True)   # builds csrc (including the extension)
+        get_lib()   # builds csrc (including the extension)
         import importlib.util
-        so = os.path.join(_CSRC, "build", "pt_eager_core.so")
+        so = os.path.join(_BUILD, "pt_eager_core.so")
         spec = importlib.util.spec_from_file_location("pt_eager_core", so)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         _EAGER_CORE = mod
-    except Exception:
-        _EAGER_CORE = None
+    except Exception as e:   # any build/load failure selects the python path
+        _EAGER_CORE_WHY_NOT = (f"{type(e).__name__}: {e}; build log: "
+                               f"{_BUILD_LOG}")
+        _LOG.warning("native eager core unavailable, recording in python: "
+                     "%s", _EAGER_CORE_WHY_NOT)
     return _EAGER_CORE
+
+
+def eager_core_status() -> str:
+    """Which eager record path this process is on: "native", or
+    "python (<why the native core is not loaded>)"."""
+    if get_eager_core() is not None:
+        return "native"
+    return f"python ({_EAGER_CORE_WHY_NOT})"

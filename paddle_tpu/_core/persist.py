@@ -43,7 +43,7 @@ from ..observability import _state as _OBS
 
 _LOG = logging.getLogger(__name__)
 
-VERSION = 1
+VERSION = 2    # 2: payload carries the executable's device ids
 MAGIC = b"PTXC1\n"
 _SUFFIX = ".ptxc"
 
@@ -113,6 +113,10 @@ def store(kind: str, norm_key, compiled, extra: Optional[Dict] = None):
             "blob": blob,
             "in_tree": in_tree,
             "out_tree": out_tree,
+            # the devices it was compiled for: jax binds a deserialized
+            # executable to EVERY device of the backend unless told
+            "device_ids": [d.id for d in
+                           compiled.runtime_executable().local_devices()],
         }
         if extra:
             payload.update(extra)
@@ -233,8 +237,10 @@ def make_runner(payload: Dict, jit_factory, kwargs: Optional[Dict] = None):
     try:
         from jax.experimental.serialize_executable import \
             deserialize_and_load
+        by_id = {d.id: d for d in jax.devices()}
         compiled = deserialize_and_load(
-            payload["blob"], payload["in_tree"], payload["out_tree"])
+            payload["blob"], payload["in_tree"], payload["out_tree"],
+            execution_devices=[by_id[i] for i in payload["device_ids"]])
     except Exception as e:
         _count("reject", reason=f"deserialize failed ({e}); recompiling")
         return None

@@ -384,10 +384,10 @@ def _perf_print(name: str, d, report, verbose: bool):
 def perf_gpt2_eager(verbose: bool):
     """Eager-GPT, the BUDGET_r06 configuration (hidden 128, 4 layers,
     seq 128): one traced train step. Expected steady-state shape:
-    ZERO breaks — the flash-attention record-time aval inference now
-    succeeds on toolchains without ``jax.enable_x64`` (the x64 toggle
-    degrades to a no-op there), so the step stays in one fusion window
-    and reaches the fused fwd+vjp steady state. This row was the
+    ZERO breaks — the flash-attention record-time aval inference
+    succeeds (the kernels trace under the scoped ``jax.enable_x64(False)``),
+    so the step stays in one fusion window and reaches the fused fwd+vjp
+    steady state. This row was the
     4-`record_fallback`-breaks/step finding of BUDGET_r06; the gate
     now exists to catch the class COMING BACK."""
     from paddle_tpu.observability.__main__ import _gpt2_step

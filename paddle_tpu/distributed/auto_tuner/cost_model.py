@@ -6,10 +6,11 @@ from __future__ import annotations
 
 from typing import Dict
 
+from ..._core.device import CHIP_PEAKS
 
-# default hardware model (v5e-ish): tunable via the config dict
+# default hardware model (one v5e chip): tunable via the config dict
 _DEFAULTS = dict(
-    chip_flops=197e12,          # bf16 FLOP/s per chip
+    chip_flops=CHIP_PEAKS["TPU v5 lite"].flops,   # bf16 FLOP/s per chip
     hbm_bytes=16e9,             # per chip
     ici_bandwidth=4.5e10,       # bytes/s per link, ring
     mfu=0.4,

@@ -55,7 +55,7 @@ class CommContext:
 
     def __init__(self, store, rank: int, world: int, key: str):
         _export_poll_limit()
-        self._lib = native.get_lib(required=True)
+        self._lib = native.get_lib()
         self._h = self._lib.ptcc_create(rank, world)
         if not self._h:
             raise RuntimeError(f"ptcc_create: {native.last_error()}")
@@ -82,7 +82,7 @@ class CommContext:
         ok = bool(flag_value("FLAGS_pg_native_transport"))
         try:
             if ok:
-                lib = native.get_lib(required=True)
+                lib = native.get_lib()
                 probe = lib.ptcc_create(rank, world)
                 if not probe:
                     ok = False

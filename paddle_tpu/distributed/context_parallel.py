@@ -28,18 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax>=0.8 top-level; older releases keep it in experimental
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, mesh, in_specs, out_specs, unchecked=False):
-    kw = {_CHECK_KW: False} if unchecked else {}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    kw = {"check_vma": False} if unchecked else {}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 NEG_INF = -1e30
 

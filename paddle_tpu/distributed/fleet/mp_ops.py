@@ -16,17 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax>=0.8 top-level; older releases keep it in experimental,
-    # where partial-manual lowering (auto=) trips XLA's PartitionId
-    # restriction under SPMD — fall back to the dense GSPMD path there.
-    from jax import shard_map as _shard_map
-
-    def _mp_shard_map(f, mesh, in_specs, out_specs, axis):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, axis_names={axis},
-                          check_vma=False)
-except ImportError:  # pragma: no cover
-    _mp_shard_map = None
 
 def vocab_parallel_softmax_cross_entropy(hidden, vocab_weight, labels,
                                          mesh: Mesh, axis: str = "mp"):
@@ -56,17 +45,17 @@ def vocab_parallel_softmax_cross_entropy(hidden, vocab_weight, labels,
         return jnp.log(sumexp) - picked
 
     if mesh is None or axis not in mesh.axis_names \
-            or mesh.shape[axis] <= 1 or _mp_shard_map is None:
+            or mesh.shape[axis] <= 1:
         logits = jnp.einsum("bsh,vh->bsv", hidden,
                             vocab_weight).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, -1)
         return -jnp.take_along_axis(logp, labels[..., None],
                                     axis=-1)[..., 0]
 
-    return _mp_shard_map(f, mesh,
-                         in_specs=(P(), P(axis, None), P()),
-                         out_specs=P(), axis=axis)(hidden, vocab_weight,
-                                                   labels)
+    # partial-manual over mp only: dp/pp/sp placement stays with GSPMD
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(), P(axis, None), P()),
+                         out_specs=P(), axis_names={axis},
+                         check_vma=False)(hidden, vocab_weight, labels)
 
 
 # The ParallelCrossEntropy layer lives in mp_layers.py (exported via

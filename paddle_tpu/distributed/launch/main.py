@@ -12,7 +12,8 @@ reference's one-proc-per-GPU. Multi-host jobs launch this once per host
 (or via --ips) and workers meet through jax.distributed
 (init_parallel_env). --nproc_per_node > 1 is supported for CPU-simulated
 multi-process testing (the reference's multi-process-on-one-host test
-pattern, SURVEY §4).
+pattern, SURVEY §4) and refused on a TPU host: the workers get identical
+environments, the first one takes every chip, and its siblings hang.
 """
 from __future__ import annotations
 
@@ -217,10 +218,29 @@ def _node_host(master_host: str) -> str:
         return _socket.gethostbyname(_socket.gethostname())
 
 
+def _refuse_chip_contention(nsp: int):
+    """A TPU chip belongs to one process. Several workers on a host whose
+    chips they would all open is an error here, not a hang there."""
+    if nsp <= 1 or os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    from ..._core.device import tpu_chips_on_host
+    chips = tpu_chips_on_host()
+    if chips:
+        raise SystemExit(
+            f"[launch] refusing to start {nsp} workers on a host with "
+            f"{chips} TPU chip(s): each worker would see every chip and "
+            "the first would take them all. One process drives all local "
+            "chips (single-controller SPMD): launch with "
+            "--nproc_per_node 1 and shard over a Mesh of jax.devices(), "
+            "or set JAX_PLATFORMS=cpu for a CPU-simulated multi-process "
+            "run")
+
+
 def main(argv=None):
     args = _parse_args(argv)
     world = args.nnodes * args.nproc_per_node
     nsp = _nspawn(args)   # per-node spawn count incl. hot spares
+    _refuse_chip_contention(nsp)
     master_ep = args.master or "127.0.0.1:6170"
     host, port = (master_ep.split(":") + ["6170"])[:2]
 

@@ -24,25 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# Partial-manual shard_map over ONLY the pp axis, leaving the other
-# mesh axes (dp, mp) to GSPMD. jax>=0.8 spells this jax.shard_map(...,
-# axis_names={'pp'}, check_vma=False). Older releases keep shard_map in
-# experimental with the spelling auto=<other axes>/check_rep=False, but
-# that lowering trips XLA's PartitionId restriction under SPMD (the same
-# limitation fleet/mp_ops.py documents), so there is no usable
-# partial-manual form — _pp_shard_map is None and pipelined_trunk falls
-# back to the dense GSPMD layer scan (identical numerics, no explicit
-# ppermute streaming).
-try:
-    from jax import shard_map as _shard_map
-
-    def _pp_shard_map(f, mesh, in_specs, out_specs, axis_name):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, axis_names={axis_name},
-                          check_vma=False)
-except ImportError:
-    _pp_shard_map = None
-
 
 # ------------------------------------------------- the collective order
 # THE permutation lists and tick counts the compiled lowerings below
@@ -149,29 +130,15 @@ def pipelined_trunk(block_fn: Callable, mesh: Mesh, num_microbatches: int,
         blocks_spec = jax.tree_util.tree_map(
             lambda leaf: P(axis_name), blocks)
 
-        if _pp_shard_map is None:
-            # jax<0.8: no partial-manual lowering — scan the full layer
-            # stack under GSPMD. Params stay sharded P('pp') on the
-            # layer dim; micro-batching and the explicit ppermute
-            # stream are dropped but the trunk math is unchanged.
-            fn = jax.checkpoint(block_fn) if remat else block_fn
-
-            def body(carry, blk):
-                return fn(carry, blk), None
-
-            # unroll: the rolled while-loop's transpose emits a mixed
-            # s64/s32 dynamic_update_slice index compare that this
-            # jaxlib's HLO verifier rejects after SPMD partitioning
-            y, _ = jax.lax.scan(body, x, blocks, unroll=True)
-            return y
-
-        inner = _pp_shard_map(
+        # partial-manual over ONLY the pp axis: the other mesh axes (dp,
+        # mp) stay with GSPMD
+        inner = jax.shard_map(
             lambda bl, xm: spmd_pipeline(
                 functools.partial(stage, bl), xm, axis_name),
             mesh=mesh,
             in_specs=(blocks_spec, P()),
             out_specs=P(),
-            axis_name=axis_name)
+            axis_names={axis_name}, check_vma=False)
         y_mb = inner(blocks, x_mb)
         return y_mb.reshape(b, *x.shape[1:])
 

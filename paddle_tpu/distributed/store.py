@@ -39,7 +39,7 @@ class TCPStore:
         if timeout is None:
             from .._core.flags import flag_value
             timeout = float(flag_value("FLAGS_tcp_store_timeout_s"))
-        self._lib = native.get_lib(required=True)
+        self._lib = native.get_lib()
         self._server = None
         self._timeout_ms = int(timeout * 1000)
         self._barrier_rounds = {}

@@ -18,7 +18,7 @@ class NativeTokenLoader:
     def __init__(self, path: str, seq_len: int, batch_size: int,
                  shuffle: bool = True, seed: int = 0,
                  prefetch_depth: int = 4):
-        self._lib = native.get_lib(required=True)
+        self._lib = native.get_lib()
         self._h = self._lib.pt_feed_create(
             str(path).encode(), seq_len, batch_size, 1 if shuffle else 0,
             seed, prefetch_depth)

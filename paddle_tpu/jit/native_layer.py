@@ -15,7 +15,7 @@ from .._core import native
 
 class NativeJitLayer:
     def __init__(self, path_prefix: str):
-        self._lib = native.bind_jit(native.get_lib(required=True))
+        self._lib = native.bind_jit(native.get_lib())
         self._h = self._lib.pt_jit_open(path_prefix.encode())
         if not self._h:
             raise RuntimeError(

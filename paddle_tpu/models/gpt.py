@@ -28,7 +28,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -38,6 +37,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import nn
 from ..nn import functional as F
+from .._core import device
+from .._core.flags import flag_value
 from .._core.tensor import Tensor
 from ..distributed.fleet.mp_layers import (ColumnParallelLinear,
                                            RowParallelLinear,
@@ -246,20 +247,17 @@ def param_specs(config: GPTConfig, dp: str = "dp", mp: str = "mp",
     }
 
 
-def _use_flash_kernel(config: GPTConfig, seq: int, mesh_axes) -> bool:
-    """Pallas flash attention. Single-chip path calls the kernel
-    directly; the sharded path goes through mha_spmd, whose
-    custom_partitioning rule keeps batch/head sharding and gathers
-    seq/head_dim (so it composes with GSPMD and the compiled pp
-    shard_map). Off-TPU the kernel only runs in interpret mode when
-    PT_FLASH_INTERPRET=1 (CPU mesh tests / multichip dryrun)."""
+def _use_flash_kernel(config: GPTConfig, seq: int) -> bool:
+    """Pallas flash attention or the einsum path, decided from the config
+    and the shape. The kernel tiles the sequence by 128. On a TPU it is
+    used from seq 256 up. Anywhere else the kernel could only run in the
+    Pallas interpreter, which is a test mode: FLAGS_flash_interpret opts
+    in (CPU mesh tests / multichip dryrun), otherwise the CPU runs the
+    einsum path."""
     if not config.use_flash_attention or seq % 128:
         return False
-    if jax.default_backend() == "tpu":
+    if device.is_tpu():
         return seq >= 256
-    if os.environ.get("PT_FLASH_INTERPRET") == "1":
-        return True
-    from .._core.flags import flag_value
     return bool(flag_value("FLAGS_flash_interpret"))
 
 
@@ -270,8 +268,7 @@ def _ln(x, g, b, eps):
     return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g + b
 
 
-def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None,
-           in_manual_pp=False):
+def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None):
     """One decoder block, pure jnp. x: [B, S, H]. With sp=True the
     residual-stream activations are sharded along the sequence dim over the
     mp axis (Megatron-SP, sequence_parallel_utils.py analog) — GSPMD turns
@@ -288,21 +285,14 @@ def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None,
     k = jnp.swapaxes(k, 1, 2)
     v = jnp.swapaxes(v, 1, 2)
     scale = 1.0 / math.sqrt(c.head_dim)
-    attn = None
-    if _use_flash_kernel(c, s, mesh_axes):
-        if mesh_axes is not None and in_manual_pp:
-            # compiled-pp manual region: nested shard_map dispatch owned
-            # by the op module; None => indivisible, use einsum below
-            from ..ops.pallas.flash_attention import mha_manual
-            attn = mha_manual(q, k, v, mesh_axes, causal=True,
-                              scale=scale)
-        elif mesh_axes is not None:
-            from ..ops.pallas.flash_attention import mha_spmd
-            attn = mha_spmd(q, k, v, causal=True, scale=scale)
+    if _use_flash_kernel(c, s):
+        from ..ops.pallas.flash_attention import mha_forward, mha_sharded
+        if mesh_axes is not None:
+            attn = mha_sharded(q, k, v, mesh_axes, causal=True,
+                               scale=scale)
         else:
-            from ..ops.pallas.flash_attention import mha_forward
             attn = mha_forward(q, k, v, causal=True, scale=scale)
-    if attn is None:
+    else:
         logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
         mask = jnp.tril(jnp.ones((s, s), bool))
         logits = jnp.where(mask, logits, jnp.array(-1e30, logits.dtype))
@@ -324,15 +314,10 @@ def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None,
 
 def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
                 remat=True, sp_sharding=None, pp_trunk=None,
-                return_hidden=False, unroll_layers=False):
+                return_hidden=False):
     """Pure forward: tokens [B, S] int32 -> logits [B, S, V]. pp_trunk,
     when given (distributed.pipeline_compiled.pipelined_trunk), replaces
-    the layer scan with the compiled pp-axis pipeline. unroll_layers
-    replaces the layer scan with a Python loop over the stacked block
-    leaves — numerically identical, but the program carries no while
-    loop: XLA:CPU's SPMD partitioner mis-types the scan transpose's
-    dynamic_update_slice index under mp>1 sharding (s64 vs s32 compare,
-    HLO-verifier reject), so CPU measurement paths unroll."""
+    the layer scan with the compiled pp-axis pipeline."""
     b, s = tokens.shape
     x = params["wte"][tokens] + params["wpe"][:s]
     x = x.astype(jnp.dtype(config.dtype))
@@ -346,15 +331,10 @@ def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
         if remat:
             blk_fn = jax.checkpoint(blk_fn)
 
-        if unroll_layers:
-            for i in range(config.num_layers):
-                x = blk_fn(x, jax.tree_util.tree_map(
-                    lambda a: a[i], params["blocks"]))
-        else:
-            def scan_body(carry, blk):
-                return blk_fn(carry, blk), None
+        def scan_body(carry, blk):
+            return blk_fn(carry, blk), None
 
-            x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
     x = _ln(x, params["lnf_g"], params["lnf_b"], config.layer_norm_eps)
     if return_hidden:
         return x
@@ -363,8 +343,7 @@ def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
 
 
 def gpt_loss(params, tokens, labels, config: GPTConfig, mesh_axes=None,
-             remat=True, sp_sharding=None, pp_trunk=None,
-             unroll_layers=False):
+             remat=True, sp_sharding=None, pp_trunk=None):
     """Mean LM loss. With an mp>1 mesh the head goes through
     vocab-parallel softmax-cross-entropy (mp_ops.py:77-385 analog):
     wte is vocab-sharded over mp, so the full [B, S, V] logits are never
@@ -377,14 +356,12 @@ def gpt_loss(params, tokens, labels, config: GPTConfig, mesh_axes=None,
             vocab_parallel_softmax_cross_entropy
         hidden = gpt_forward(params, tokens, config, mesh_axes, remat,
                              sp_sharding, pp_trunk=pp_trunk,
-                             return_hidden=True,
-                             unroll_layers=unroll_layers)
+                             return_hidden=True)
         loss = vocab_parallel_softmax_cross_entropy(
             hidden, params["wte"], labels, mesh_axes, axis="mp")
         return loss.mean()
     logits = gpt_forward(params, tokens, config, mesh_axes, remat,
-                         sp_sharding, pp_trunk=pp_trunk,
-                         unroll_layers=unroll_layers)
+                         sp_sharding, pp_trunk=pp_trunk)
     logits = logits.astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, -1)
     picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
@@ -395,8 +372,7 @@ def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None,
                      lr: float = 3e-4, wd: float = 0.1, b1: float = 0.9,
                      b2: float = 0.95, zero1: bool = True,
                      seq_shard: bool = False, remat: bool = True,
-                     pp_microbatches: Optional[int] = None,
-                     unroll_layers: bool = False):
+                     pp_microbatches: Optional[int] = None):
     """Build (init_fn, step_fn) — step is ONE compiled XLA program:
     fwd + bwd (remat'd scan) + AdamW, with dp/mp/sp/ZeRO1 shardings when
     `mesh` has those axes. A 'pp' mesh axis (size>1) engages the compiled
@@ -416,7 +392,7 @@ def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None,
         from ..distributed.pipeline_compiled import pipelined_trunk
         n_micro = pp_microbatches or 2 * pp_size
         blk_fn = functools.partial(_block, config=config, mesh_axes=mesh,
-                                   sp_sharding=None, in_manual_pp=True)
+                                   sp_sharding=None)
         pp_trunk = pipelined_trunk(
             lambda x, blk: blk_fn(x, blk), mesh, n_micro, axis_name="pp",
             remat=remat)
@@ -441,7 +417,7 @@ def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None,
     def loss_fn(params, tokens, labels):
         return gpt_loss(params, tokens, labels, config, mesh_axes=mesh,
                         remat=remat, sp_sharding=sp_sharding,
-                        pp_trunk=pp_trunk, unroll_layers=unroll_layers)
+                        pp_trunk=pp_trunk)
 
     return build_adamw_train_step(
         loss_fn, functools.partial(init_gpt_params, config),

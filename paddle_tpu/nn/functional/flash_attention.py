@@ -2,10 +2,13 @@
 analog: flash_attn_qkvpacked:562, flash_attn_unpadded:756,
 flashmask_attention).
 
-Default path is the fused XLA SDPA; when the Pallas TPU kernel is available
-(paddle_tpu.ops.pallas.flash_attention) and shapes qualify, it is used
-instead — the TPU-native replacement for the reference's dynloaded
-flashattn CUDA library (paddle/phi/backends/dynload/flashattn.cc).
+The Pallas TPU kernels (paddle_tpu.ops.pallas) are the path whenever the
+arguments qualify — the TPU-native replacement for the reference's
+dynloaded flashattn CUDA library (paddle/phi/backends/dynload/flashattn.cc).
+What the kernels do not implement (dropout, returned softmax, a window, a
+sequence that does not tile by 128) goes to the fused XLA SDPA, decided
+from the arguments before the call; a kernel that fails is an error, never
+a silent change of path.
 """
 from __future__ import annotations
 
@@ -13,56 +16,20 @@ import jax.numpy as jnp
 
 from .attention import scaled_dot_product_attention
 
-_USE_PALLAS = None
-
-
-def _pallas_available():
-    global _USE_PALLAS
-    if _USE_PALLAS is None:
-        try:
-            from ...ops.pallas import flash_attention as _  # noqa: F401
-            _USE_PALLAS = True
-        except Exception:
-            _USE_PALLAS = False
-    return _USE_PALLAS
-
-
-# Per-kernel sticky disable: a deterministic kernel failure (lowering
-# error, unsupported shape) would otherwise silently pay the full
-# build-then-raise cost and degrade to the O(T^2) dense path on EVERY
-# call with no indication the fast path is gone.
-_KERNEL_STATE = {}
-
-
-def _kernel_failed(name: str, exc: Exception) -> None:
-    import warnings
-    warnings.warn(
-        f"pallas {name} kernel failed ({type(exc).__name__}: {exc}); "
-        f"falling back to the dense O(T^2) reference path for the rest "
-        f"of this process", RuntimeWarning, stacklevel=3)
-    _KERNEL_STATE[name] = False
-
-
-def _kernel_enabled(name: str) -> bool:
-    return _KERNEL_STATE.get(name, True)
-
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
                     return_softmax=False, fixed_seed_offset=None,
                     rng_name="", training=True, name=None):
     """Inputs [batch, seq_len, num_heads, head_dim]; returns (out, softmax)
     tuple like the reference (softmax is None unless return_softmax)."""
-    if _pallas_available() and dropout == 0.0 and not return_softmax:
-        try:
-            from ...ops.pallas import flash_attention as pallas_fa
-            out = pallas_fa(query, key, value, causal=causal)
-            return out, None
-        except Exception:
-            pass
-    out = scaled_dot_product_attention(query, key, value, None, dropout,
-                                       causal, training)
     if return_softmax:
         raise NotImplementedError("return_softmax=True not supported")
+    if dropout == 0.0 and query.shape[1] % 128 == 0 \
+            and key.shape[1] % 128 == 0:
+        from ...ops.pallas import flash_attention as pallas_fa
+        return pallas_fa(query, key, value, causal=causal), None
+    out = scaled_dot_product_attention(query, key, value, None, dropout,
+                                       causal, training)
     return out, None
 
 
@@ -78,16 +45,11 @@ def flashmask_attention(query, key, value, startend_row_indices=None,
     query block are skipped, no [S, S] mask is ever built. The dense
     additive-mask conversion below stays as the numerics reference
     (and the fallback for dropout / window_size)."""
-    if (startend_row_indices is not None and _pallas_available()
-            and dropout == 0.0 and window_size is None
-            and _kernel_enabled("flashmask")):
-        try:
-            from ...ops.pallas.flash_varlen import \
-                flashmask_attention_pallas
-            return flashmask_attention_pallas(
-                query, key, value, startend_row_indices, causal=causal)
-        except Exception as e:
-            _kernel_failed("flashmask", e)
+    if startend_row_indices is not None and dropout == 0.0 \
+            and window_size is None:
+        from ...ops.pallas.flash_varlen import flashmask_attention_pallas
+        return flashmask_attention_pallas(
+            query, key, value, startend_row_indices, causal=causal)
     return flashmask_attention_dense(
         query, key, value, startend_row_indices, dropout, causal,
         training)
@@ -149,16 +111,11 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     is the block-sparse Pallas kernel (per-query-block key-block bounds
     from cu_seqlens — O(T·block) memory); the dense segment-mask below
     stays as the numerics reference / dropout fallback."""
-    if _pallas_available() and dropout == 0.0 and not return_softmax \
-            and _kernel_enabled("varlen"):
-        try:
-            from ...ops.pallas.flash_varlen import flash_attn_varlen
-            out = flash_attn_varlen(query, key, value, cu_seqlens_q,
-                                    cu_seqlens_k, scale=scale,
-                                    causal=causal)
-            return out, None
-        except Exception as e:
-            _kernel_failed("varlen", e)
+    if dropout == 0.0 and not return_softmax:
+        from ...ops.pallas.flash_varlen import flash_attn_varlen
+        out = flash_attn_varlen(query, key, value, cu_seqlens_q,
+                                cu_seqlens_k, scale=scale, causal=causal)
+        return out, None
     return flash_attn_unpadded_dense(
         query, key, value, cu_seqlens_q, cu_seqlens_k, max_seqlen_q,
         max_seqlen_k, scale, dropout, causal, training)

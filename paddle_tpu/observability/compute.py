@@ -81,15 +81,6 @@ _RATE_FLOPS = 0
 
 # ------------------------------------------------------------ analysis
 
-def _cost_dict(compiled) -> Dict:
-    """``compiled.cost_analysis()`` normalized across jax versions
-    (list-of-dicts on 0.4.x, plain dict on newer)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
 def analyze(compiled, n_devices: int = 1) -> Dict:
     """Capture one compiled executable's cost analysis as a plain dict
     (counted: tests assert exactly one call per compile). The flops /
@@ -104,7 +95,7 @@ def analyze(compiled, n_devices: int = 1) -> Dict:
         from . import metrics
         metrics.inc("compute.cost_analysis_calls")
     try:
-        ca = _cost_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         flops = ca.get("flops")
         nbytes = ca.get("bytes accessed")
         trans = ca.get("transcendentals")
@@ -228,21 +219,9 @@ def reset():
 
 # --------------------------------------------------------- peak / roofline
 
-# published per-chip peak FLOP/s (bf16/matmul units — the MLPerf MFU
-# convention) by TPU device_kind substring, newest-first so "v5p"
-# matches before "v5"
-_TPU_PEAK_FLOPS = (
-    ("v6e", 918e12), ("v6", 918e12),
-    ("v5p", 459e12), ("v5e", 197e12), ("v5", 197e12),
-    ("v4", 275e12), ("v3", 123e12), ("v2", 45e12),
-)
-_TPU_PEAK_MEMBW = (
-    ("v6e", 1640e9), ("v6", 1640e9),
-    ("v5p", 2765e9), ("v5e", 819e9), ("v5", 819e9),
-    ("v4", 1228e9), ("v3", 900e9), ("v2", 700e9),
-)
-
-# documented CPU fallbacks (README "Compute efficiency & MFU"): a
+# TPU peaks live in _core/device.py (one table keyed by device_kind; an
+# unknown TPU is an error there). Off a TPU the plane prices against
+# documented CPU envelopes (README "Compute efficiency & MFU"): a
 # nominal AVX2-FMA envelope per core and two-channel DDR4 bandwidth.
 # CPU MFU is a RELATIVE meter (regressions across rounds on one box),
 # not an absolute one.
@@ -251,42 +230,30 @@ _CPU_FLOPS_PER_CYCLE = 16          # 8 fp32 lanes x FMA
 _CPU_MEMBW = 25.6e9
 
 
-def _kind_lookup(table, kind: str, fallback: float) -> float:
-    kind = (kind or "").lower()
-    for sub, peak in table:
-        if sub in kind:
-            return peak
-    return fallback
-
-
 def peak_flops() -> float:
-    """Per-chip peak FLOP/s: FLAGS_device_peak_flops, or the backend
-    autodetect when the flag is 0."""
+    """Per-chip peak FLOP/s: FLAGS_device_peak_flops, or the device's
+    published peak when the flag is 0."""
+    from .._core import device
     from .._core.flags import flag_value
     v = float(flag_value("FLAGS_device_peak_flops"))
     if v > 0:
         return v
-    import jax
-    backend = jax.default_backend()
-    cpu_peak = (os.cpu_count() or 1) * _CPU_GHZ * _CPU_FLOPS_PER_CYCLE
-    if backend != "tpu":
-        return cpu_peak
-    kind = getattr(jax.devices()[0], "device_kind", "")
-    return _kind_lookup(_TPU_PEAK_FLOPS, kind, cpu_peak)
+    if device.is_tpu():
+        return device.chip_peaks().flops
+    return (os.cpu_count() or 1) * _CPU_GHZ * _CPU_FLOPS_PER_CYCLE
 
 
 def peak_membw() -> float:
     """Per-chip peak memory bandwidth (bytes/s) for the roofline
-    ridge: FLAGS_device_peak_membw, or the backend autodetect."""
+    ridge: FLAGS_device_peak_membw, or the device's published peak."""
+    from .._core import device
     from .._core.flags import flag_value
     v = float(flag_value("FLAGS_device_peak_membw"))
     if v > 0:
         return v
-    import jax
-    if jax.default_backend() != "tpu":
-        return _CPU_MEMBW
-    kind = getattr(jax.devices()[0], "device_kind", "")
-    return _kind_lookup(_TPU_PEAK_MEMBW, kind, _CPU_MEMBW)
+    if device.is_tpu():
+        return device.chip_peaks().membw
+    return _CPU_MEMBW
 
 
 def mfu(achieved_flops_per_s: float,

@@ -6,8 +6,10 @@ the dynloaded CUDA flash-attention library
 under paddle/phi/kernels/fusion/gpu (fused_attention, fused_rms_norm,
 swiglu, rope). Instead of NVRTC/CINN codegen, hot ops are written directly
 against the TPU memory hierarchy (HBM -> VMEM -> MXU/VPU) with
-jax.experimental.pallas; everything falls back to the fused XLA path off-TPU
-(interpret mode keeps the kernels testable on the CPU mesh).
+jax.experimental.pallas. On a TPU every kernel is Mosaic-compiled; off a TPU
+the same kernels run in the Pallas interpreter (`_core.device.
+pallas_interpret`), which keeps them testable on the CPU mesh, and
+tests/test_tpu_aot_compile.py runs the real TPU compiler on them in tier-1.
 """
 from .flash_attention import flash_attention, mha_forward
 from .fused import rms_norm, swiglu, fused_rotary_position_embedding

@@ -10,8 +10,15 @@ blocks, dQ over query blocks) using the saved log-sum-exp rows.
 Layout inside the kernels is [batch*heads, seq, head_dim]; the public entry
 takes paddle's [batch, seq, heads, head_dim]. Logit math is fp32 on the MXU
 (preferred_element_type), IO dtype is whatever the caller passes (bf16 on
-TPU). Off-TPU the kernels run in interpret mode so the CPU test mesh
+TPU). On a TPU the kernels are Mosaic-compiled; anywhere else they run in
+interpret mode (`_core.device.pallas_interpret`), so the CPU test mesh
 exercises identical code.
+
+K/V (forward, dQ) and Q/dO (dKV) stay whole-sequence resident in VMEM, so
+the sequence length is capped by the 16 MiB scoped-VMEM limit:
+`check_vmem` computes each kernel's footprint and raises
+`FlashSequenceLimitError` instead of letting Mosaic fail with
+RESOURCE_EXHAUSTED (README "Flash attention sequence limit").
 """
 from __future__ import annotations
 
@@ -21,33 +28,95 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from jax.sharding import PartitionSpec as _P
+
+from ..._core.device import pallas_interpret
+
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _no_x64():
     """Trace pallas kernels with x64 OFF: the framework enables
-    jax_enable_x64 globally (paddle int64 parity), but int64 scalars in
-    Mosaic kernels hit an infinite convert_element_type recursion in the
-    TPU lowering. Kernel math is int32/fp32/bf16 regardless.
+    jax_enable_x64 globally (paddle int64 parity), but Mosaic has no
+    64-bit scalars (an index map returning an int64 zero fails to
+    legalize). Kernel math is int32/fp32/bf16 regardless."""
+    return jax.enable_x64(False)
 
-    Toolchains without the scoped ``jax.enable_x64`` override (it
-    landed in newer jax) run WITHOUT the toggle: the old
-    ``jax.experimental`` context manager only scopes trace-time dtype
-    decisions while interpret-mode lowering happens later outside it
-    (mixed i64/i32 loop carries -> verifier errors), and the kernels
-    pin every dtype explicitly anyway, so x64 mode changes nothing
-    they compute. This is also what lets ``flash_attention`` RECORD
-    into the fusion window on such toolchains — the old AttributeError
-    at record-time aval inference was the eager-GPT 4-breaks/step
-    ``record_fallback`` class the perf lint attributed here."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    import contextlib
-    return contextlib.nullcontext()
+
+# ---------------------------------------------------- scoped-VMEM sequence cap
+
+# Mosaic's default scoped-VMEM limit on v5e ("Scoped allocation with size
+# … and limit 16.00M" from the TPU compiler).
+SCOPED_VMEM_BYTES = 16 << 20
+
+
+class FlashSequenceLimitError(ValueError):
+    """The sequence is longer than the whole-sequence-resident flash
+    kernels can hold in scoped VMEM."""
+
+
+def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
+    """One block as VMEM tiles it: 128 lanes x 8 sublanes of 32 bits."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
+
+
+def vmem_footprint(sq: int, sk: int, d: int, dtype) -> dict:
+    """Scoped-VMEM bytes each kernel needs with its operands in HBM:
+    the pipeline double-buffers every in/out block of the BlockSpecs in
+    `_fwd_call` / `_bwd`. Agrees with the compiler's own "scoped
+    allocation" figure to its printed precision (bf16 and fp32, d 64-256,
+    seq 1k-8k on the v5e ahead-of-time compiler); a [seq, 1] fp32 row
+    pads to 128 lanes, which is why dKV is the largest."""
+    bq, bk = _block_sizes(sq, sk, d)
+    blk = functools.partial(_vmem_block_bytes, dtype=dtype)
+    row = functools.partial(_vmem_block_bytes, cols=1, dtype=jnp.float32)
+    return {
+        "fwd": 2 * (2 * blk(bq, d) + 2 * blk(sk, d) + row(bq)),
+        "dkv": 2 * (2 * blk(sq, d) + 2 * row(sq) + 4 * blk(bk, d)),
+        "dq": 2 * (2 * blk(sk, d) + 3 * blk(bq, d) + 2 * row(bq)),
+    }
+
+
+def _fits(sq: int, sk: int, d: int, dtype, backward: bool):
+    """Name of the first kernel that does not fit, or None."""
+    need = vmem_footprint(sq, sk, d, dtype)
+    for kernel in ("fwd", "dkv", "dq") if backward else ("fwd",):
+        if need[kernel] >= SCOPED_VMEM_BYTES:
+            return kernel, need[kernel]
+    return None
+
+
+def max_seq(d: int, dtype, backward: bool) -> int:
+    """Longest self-attention sequence (a multiple of 512) whose kernels
+    fit: forward only, or forward and backward."""
+    s = 0
+    while _fits(s + 512, s + 512, d, dtype, backward) is None:
+        s += 512
+    return s
+
+
+def _check_vmem(q, k, backward: bool):
+    """Raise the named limit before Mosaic raises RESOURCE_EXHAUSTED.
+    The compiler sometimes fits more by keeping a small operand in VMEM
+    itself (it depends on batch*heads); that is not a length to rely on."""
+    if pallas_interpret():
+        return
+    sq, d = q.shape[-2:]
+    sk = k.shape[-2]
+    over = _fits(sq, sk, d, q.dtype, backward)
+    if over:
+        kernel, need = over
+        raise FlashSequenceLimitError(
+            f"flash attention {kernel} kernel at seq_q {sq}, seq_k {sk}, "
+            f"head_dim {d}, {jnp.dtype(q.dtype).name} needs "
+            f"{need / 2**20:.2f} MiB of scoped VMEM; the limit is "
+            f"{SCOPED_VMEM_BYTES >> 20} MiB because K/V (and Q/dO in the "
+            "backward) stay whole-sequence resident. Longest self-attention "
+            f"sequence at this head_dim and dtype: "
+            f"{max_seq(d, q.dtype, False)} forward only, "
+            f"{max_seq(d, q.dtype, True)} with the backward")
 
 
 def _block_sizes(sq: int, sk: int, d: int):
@@ -143,12 +212,9 @@ def _fwd_call(q, k, v, causal, scale, block_k, kv_len, q_offset, block_q,
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(q, k, v)
     return out, lse
-
-
-
 
 
 # ---------------------------------------------------------------- backward
@@ -264,7 +330,7 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, kv_len,
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, do, lse, delta)
 
     rowspec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
@@ -276,7 +342,7 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, kv_len,
         in_specs=[qspec, full_k, full_k, qspec, rowspec, rowspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -285,17 +351,22 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, kv_len,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _mha(q, k, v, causal, scale):
-    out, _ = _mha_fwd(q, k, v, causal, scale)[0], None
-    return out
+    _check_vmem(q, k, backward=False)
+    return _fwd_res(q, k, v, causal, scale)[0]
 
 
-def _mha_fwd(q, k, v, causal, scale):
+def _fwd_res(q, k, v, causal, scale):
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk, d)
     out, lse = _fwd(q, k, v, causal, scale, bq, bk, kv_len=sk,
                     q_offset=sk - sq)
     return out, (q, k, v, out, lse)
+
+
+def _mha_fwd(q, k, v, causal, scale):
+    _check_vmem(q, k, backward=True)
+    return _fwd_res(q, k, v, causal, scale)
 
 
 def _mha_bwd(causal, scale, res, do):
@@ -360,161 +431,32 @@ def flash_attention(query, key, value, causal=False, scale=None):
                  scale=float(scale))
 
 
-# ------------------------------------------------ SPMD (GSPMD-composable)
-# custom_partitioning teaches the partitioner that the kernel shards
-# freely over batch/head and needs seq/head_dim replicated — the TPU
-# analog of the reference wiring flash-attn into its SPMD rules
-# (phi/infermeta/spmd_rules). Composes with the compiled pp shard_map
-# (partial-manual: dp/mp stay GSPMD-managed inside the pp body).
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as _P
+# ------------------------------------------------------- sharded dispatch
 
+def mha_sharded(q, k, v, mesh, causal=False, scale=None):
+    """Flash attention on mesh-sharded [B, H, S, D] arrays under jit.
 
-_WARNED_REPLICATED = False
-
-
-def _bh_spec(arg_shapes, mesh):
-    sh = arg_shapes[0].sharding
-    spec = getattr(sh, "spec", None)
-    if spec is None:
-        # GSPMDSharding (e.g. inside the compiled-pp partial-manual
-        # shard_map): recover a PartitionSpec over the mesh, else
-        # replicate (correct, just less parallel)
-        try:
-            from jax._src.sharding_impls import parse_flatten_op_sharding
-            parsed = parse_flatten_op_sharding(
-                sh._to_xla_hlo_sharding(len(arg_shapes[0].shape)), mesh)[0]
-            spec = parsed.get_partition_spec()
-        except Exception:
-            global _WARNED_REPLICATED
-            if not _WARNED_REPLICATED:
-                _WARNED_REPLICATED = True
-                import warnings
-                warnings.warn(
-                    "mha_spmd: could not recover a PartitionSpec from "
-                    f"{type(sh).__name__}; flash attention will run "
-                    "fully replicated over batch/head on this call site")
-            spec = _P()
-    b = spec[0] if len(spec) > 0 else None
-    h = spec[1] if len(spec) > 1 else None
-    return b, h
-
-
-def _fwd4(q, k, v, causal, scale):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bq, bk = _block_sizes(sq, sk, d)
-    out, lse = _fwd(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-                    v.reshape(b * h, sk, d), causal, scale, bq, bk,
-                    kv_len=sk, q_offset=sk - sq)
-    return out.reshape(b, h, sq, d), lse.reshape(b, h, sq, 1)
-
-
-def _bwd4(q, k, v, out, lse, do, causal, scale):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bq, bk = _block_sizes(sq, sk, d)
-    dq, dk, dv = _bwd(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-                      v.reshape(b * h, sk, d), out.reshape(b * h, sq, d),
-                      lse.reshape(b * h, sq, 1), do.reshape(b * h, sq, d),
-                      causal, scale, bq, bk, kv_len=sk, q_offset=sk - sq)
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
-
-
-def _make_partitioned(fn, n_arrays, n_outs, rule):
-    p = custom_partitioning(fn, static_argnums=(n_arrays, n_arrays + 1))
-
-    def infer(causal, scale, mesh, arg_shapes, result_shape):
-        b, h = _bh_spec(arg_shapes, mesh)
-        sh4 = NamedSharding(mesh, _P(b, h, None, None))
-        return (sh4,) * n_outs if n_outs > 1 else sh4
-
-    def part(causal, scale, mesh, arg_shapes, result_shape):
-        b, h = _bh_spec(arg_shapes, mesh)
-        sh4 = NamedSharding(mesh, _P(b, h, None, None))
-        args = (sh4,) * n_arrays
-        outs = (sh4,) * n_outs if n_outs > 1 else sh4
-
-        def lower(*arrays):
-            return fn(*arrays, causal, scale)
-
-        return mesh, lower, outs, args
-
-    # Shardy propagation: b/h shard freely, seq/head_dim factors must be
-    # replicated at the kernel boundary. The rule builder is private jax
-    # API; guard it so a future rename only disables the Shardy path
-    # instead of breaking `import paddle_tpu.ops.pallas` for everyone.
-    try:
-        from jax._src.custom_partitioning_sharding_rule import \
-            str_to_sdy_sharding_rule
-        sdy_rule = str_to_sdy_sharding_rule(
-            rule, need_replication_factors=("i", "j", "k", "l"))
-    except Exception:  # pragma: no cover - jax-version dependent
-        sdy_rule = None
-    try:
-        p.def_partition(infer_sharding_from_operands=infer, partition=part,
-                        sharding_rule=sdy_rule)
-    except TypeError:  # pragma: no cover - jax-version dependent
-        # older jax: def_partition has no sharding_rule kwarg (GSPMD-only
-        # propagation); the Shardy rule is an optimization, not required
-        p.def_partition(infer_sharding_from_operands=infer, partition=part)
-    return p
-
-
-_FWD_RULE = "b h i j, b h k j, b h k j -> b h i j, b h i l"
-_BWD_RULE = ("b h i j, b h k j, b h k j, b h i j, b h i l, b h i j "
-             "-> b h i j, b h k j, b h k j")
-
-
-_fwd4_p = _make_partitioned(_fwd4, 3, 2, _FWD_RULE)
-_bwd4_p = _make_partitioned(_bwd4, 6, 3, _BWD_RULE)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def mha_spmd(q, k, v, causal=False, scale=None):
-    """Flash attention on sharded [B, H, S, D] arrays under jit/GSPMD:
-    b/h partitioning preserved, s/d gathered. Use on the multi-chip
-    model path (models/gpt.py); single-chip callers use mha_forward."""
-    out, _ = _mha_spmd_fwd(q, k, v, causal, scale)
-    return out
-
-
-def _mha_spmd_fwd(q, k, v, causal, scale):
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    out, lse = _fwd4_p(q, k, v, bool(causal), float(scale))
-    return out, (q, k, v, out, lse)
-
-
-def _mha_spmd_bwd(causal, scale, res, do):
-    q, k, v, out, lse = res
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    dq, dk, dv = _bwd4_p(q, k, v, out, lse, do, bool(causal),
-                         float(scale))
-    return dq, dk, dv
-
-
-mha_spmd.defvjp(_mha_spmd_fwd, _mha_spmd_bwd)
-
-
-def mha_manual(q, k, v, mesh, causal=False, scale=None):
-    """Flash dispatch for partial-manual regions (compiled-pp bodies),
-    where custom_partitioning sees an empty mesh: shard batch over 'dp'
-    and heads over 'mp' with a nested shard_map on the CONTEXT abstract
-    mesh. Returns None when no axis is shardable (indivisible batch or
-    heads) — the caller must fall back to a GSPMD-friendly path."""
-    axes = tuple(
-        a for a, dim in (("dp", q.shape[0]), ("mp", q.shape[1]))
-        if a in mesh.axis_names and mesh.shape[a] > 1
-        and dim % mesh.shape[a] == 0)
-    if not axes:
-        return None
+    Mosaic kernels cannot be partitioned automatically, so the call is
+    wrapped in a ``shard_map`` that is manual over EVERY mesh axis GSPMD
+    still owns here (size 1 or not): batch splits over 'dp', heads over
+    'mp', seq/head_dim are gathered at the boundary. Under plain jit that
+    is the whole mesh; inside the compiled-pp body ('pp' already manual,
+    pipeline_compiled.py) it is the remaining axes of the context mesh.
+    The TPU analog of the reference wiring flash-attn into its SPMD rules
+    (phi/infermeta/spmd_rules)."""
+    ctx_mesh = jax.sharding.get_abstract_mesh()
+    nested = bool(ctx_mesh.manual_axes)
+    axes = set(mesh.axis_names) - set(ctx_mesh.manual_axes)
+    for axis, dim, what in (("dp", q.shape[0], "batch"),
+                            ("mp", q.shape[1], "heads")):
+        if axis in axes and dim % mesh.shape[axis]:
+            raise ValueError(
+                f"flash attention: {what} {dim} not divisible by mesh "
+                f"axis {axis!r} of size {mesh.shape[axis]}")
     spec = _P("dp" if "dp" in axes else None,
               "mp" if "mp" in axes else None, None, None)
-    ctx_mesh = jax.sharding.get_abstract_mesh()
     return jax.shard_map(
         functools.partial(mha_forward, causal=causal, scale=scale),
-        mesh=ctx_mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        axis_names=set(axes), check_vma=False)(q, k, v)
+        mesh=ctx_mesh if nested else mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=axes, check_vma=False)(q, k, v)

@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _interpret, _no_x64
+from ..._core.device import pallas_interpret
+from .flash_attention import NEG_INF, _no_x64
 
 _BQ = 128
 _BK = 128
@@ -267,7 +268,7 @@ def _varlen_fwd(q, k, v, segq, posq, segk, posk, lo, hi, scale, causal,
                        pl.BlockSpec((1, bq, 1), lambda hh, i: (hh, i, 0))],
             out_shape=[jax.ShapeDtypeStruct((h, tq_pad, d), q.dtype),
                        jax.ShapeDtypeStruct((h, tq_pad, 1), jnp.float32)],
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, segq, posq, segk, posk, lo, hi)
     return out, lse
 
@@ -294,7 +295,7 @@ def _varlen_bwd(q, k, v, out, lse, do, segq, posq, segk, posk,
             out_specs=[kspec, kspec],
             out_shape=[jax.ShapeDtypeStruct((h, tk_pad, d), k.dtype),
                        jax.ShapeDtypeStruct((h, tk_pad, d), v.dtype)],
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, do, lse, delta, segq, posq, segk, posk, klo, khi)
 
     qspec = pl.BlockSpec((1, bq, d), lambda hh, i: (hh, i, 0))
@@ -312,7 +313,7 @@ def _varlen_bwd(q, k, v, out, lse, do, segq, posq, segk, posk,
                       mq, mq, mkf, mkf, qbound, qbound],
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((h, tq_pad, d), q.dtype),
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, do, lse, delta, segq, posq, segk, posk, qlo, qhi)
     return dq, dk, dv
 
@@ -574,7 +575,7 @@ def _fm_fwd(q, k, v, st, en, scale, causal, bq, bk, kv_len):
                        pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0))],
             out_shape=[jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
                        jax.ShapeDtypeStruct((bh, sq_pad, 1), jnp.float32)],
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, st, en)
     return out, lse
 
@@ -598,7 +599,7 @@ def _fm_bwd(q, k, v, out, lse, do, st, en, scale, causal, bq, bk, kv_len):
             out_specs=[kspec, kspec],
             out_shape=[jax.ShapeDtypeStruct((bh, sk_pad, d), k.dtype),
                        jax.ShapeDtypeStruct((bh, sk_pad, d), v.dtype)],
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, do, lse, delta, st, en)
 
     qspec = pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0))
@@ -613,7 +614,7 @@ def _fm_bwd(q, k, v, out, lse, do, st, en, scale, causal, bq, bk, kv_len):
             in_specs=[qspec, kf, kf, qspec, row, row, colf, colf],
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
-            interpret=_interpret(),
+            interpret=pallas_interpret(),
         )(q, k, v, do, lse, delta, st, en)
     return dq, dk, dv
 

@@ -16,16 +16,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..._core.device import pallas_interpret
+from .flash_attention import SCOPED_VMEM_BYTES, _no_x64
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
-
-def _row_block(n: int) -> int:
+def _row_block(n: int, row_bytes: int) -> int:
+    """Rows per grid step: the largest divisor of n up to 256 whose
+    double-buffered blocks (`row_bytes` per row over every in/out array)
+    take at most half the scoped VMEM. 256 rows whatever the width put a
+    LLaMA-class MLP row (11008 wide) at 2.5x the limit."""
+    budget = SCOPED_VMEM_BYTES // 2
     for cand in (256, 128, 64, 32, 16, 8):
-        if n % cand == 0:
+        if n % cand == 0 and 2 * cand * row_bytes <= budget:
             return cand
-    return n
+    if n % 8 and 2 * n * row_bytes <= budget:
+        return n    # small ragged row count: one block
+    raise ValueError(
+        f"fused kernel: no row block for {n} rows of {row_bytes} bytes "
+        f"fits {budget >> 20} MiB of VMEM; pad the row count to a "
+        "multiple of 8")
 
 
 # ----------------------------------------------------------------- rms_norm
@@ -39,16 +48,17 @@ def _rms_kernel(x_ref, w_ref, y_ref, *, eps):
 
 def _rms_fwd_pallas(x2, w, eps):
     n, h = x2.shape
-    bn = _row_block(n)
-    return pl.pallas_call(
-        functools.partial(_rms_kernel, eps=eps),
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn, h), lambda i: (i, 0)),
-                  pl.BlockSpec((1, h), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bn, h), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
-        interpret=_interpret(),
-    )(x2, w.reshape(1, h))
+    bn = _row_block(n, 2 * h * x2.dtype.itemsize)
+    with _no_x64():
+        return pl.pallas_call(
+            functools.partial(_rms_kernel, eps=eps),
+            grid=(n // bn,),
+            in_specs=[pl.BlockSpec((bn, h), lambda i: (i, 0)),
+                      pl.BlockSpec((1, h), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((bn, h), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
+            interpret=pallas_interpret(),
+        )(x2, w.reshape(1, h))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -113,14 +123,15 @@ def _swiglu_kernel(x_ref, g_ref, y_ref):
 
 def _swiglu_fwd_pallas(x2, g2):
     n, h = x2.shape
-    bn = _row_block(n)
+    bn = _row_block(n, 3 * h * x2.dtype.itemsize)
     spec = pl.BlockSpec((bn, h), lambda i: (i, 0))
-    return pl.pallas_call(
-        _swiglu_kernel, grid=(n // bn,),
-        in_specs=[spec, spec], out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
-        interpret=_interpret(),
-    )(x2, g2)
+    with _no_x64():
+        return pl.pallas_call(
+            _swiglu_kernel, grid=(n // bn,),
+            in_specs=[spec, spec], out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
+            interpret=pallas_interpret(),
+        )(x2, g2)
 
 
 @jax.custom_vjp

@@ -35,7 +35,7 @@ class CustomDevice:
 
     def __init__(self, dev_type: str):
         self.device_type = dev_type
-        self._lib = native.get_lib(required=True)
+        self._lib = native.get_lib()
 
     def device_count(self) -> int:
         return self._lib.pt_plugin_device_count(self.device_type.encode())
@@ -95,7 +95,7 @@ class CustomDevice:
 
 def load_custom_device_lib(path: str) -> CustomDevice:
     """dlopen a device plugin .so (LoadCustomRuntimeLib analog)."""
-    lib = native.get_lib(required=True)
+    lib = native.get_lib()
     name = lib.pt_plugin_load(os.fspath(path).encode())
     if not name:
         raise RuntimeError(
@@ -126,7 +126,7 @@ def load_op_library(path: str, op_name: str,
     import jax
     import jax.numpy as jnp
 
-    lib = native.get_lib(required=True)
+    lib = native.get_lib()
     rc = lib.pt_custom_op_load(os.fspath(path).encode(), op_name.encode())
     if rc != 0:
         raise RuntimeError(

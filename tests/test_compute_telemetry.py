@@ -469,8 +469,8 @@ def test_bn_running_stats_update_in_window():
 
 def test_flash_attention_records_into_window():
     """Satellite: flash_attention's record-time aval inference works
-    on toolchains without jax.enable_x64 — the op joins the fusion
-    window (no record_fallback) and matches the SDPA reference."""
+    — the op joins the fusion window (no record_fallback) and matches
+    the SDPA reference."""
     from paddle_tpu.nn.functional.attention import \
         scaled_dot_product_attention
     r = np.random.RandomState(0)

@@ -306,7 +306,7 @@ def test_host_alloc_chunk_flag_consumer():
     size (csrc/allocator.cc)."""
     from paddle_tpu._core import native
     try:
-        lib = native.get_lib(required=True)
+        lib = native.get_lib()
     except Exception:
         pytest.skip("native lib unavailable")
     native._HOST_POOL = None

@@ -1,11 +1,9 @@
-"""Pallas flash attention on the sharded path (mha_spmd).
+"""Pallas flash attention on the sharded path (mha_sharded).
 
-custom_partitioning keeps batch/head sharding and gathers seq/head_dim,
-so the kernel composes with GSPMD and the compiled-pp shard_map
-(VERDICT r2 weak #4: flash was disabled on every sharded path).
+One shard_map dispatch keeps batch/head sharding and gathers
+seq/head_dim, under plain GSPMD jit and nested in the compiled-pp
+shard_map (VERDICT r2 weak #4: flash was disabled on every sharded path).
 """
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,9 +13,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 @pytest.fixture(autouse=True)
 def _interpret_flag():
-    os.environ["PT_FLASH_INTERPRET"] = "1"
+    from paddle_tpu import set_flags
+    set_flags({"FLAGS_flash_interpret": True})
     yield
-    os.environ.pop("PT_FLASH_INTERPRET", None)
+    set_flags({"FLAGS_flash_interpret": False})
 
 
 def _ref_attn(q, k, v, scale):
@@ -28,8 +27,8 @@ def _ref_attn(q, k, v, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def test_mha_spmd_matches_reference_on_mesh():
-    from paddle_tpu.ops.pallas.flash_attention import mha_spmd
+def test_mha_sharded_matches_reference_on_mesh():
+    from paddle_tpu.ops.pallas.flash_attention import mha_sharded
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "mp"))
     r = np.random.RandomState(0)
     q, k, v = (jnp.asarray(r.randn(4, 8, 128, 32).astype("float32"))
@@ -39,7 +38,8 @@ def test_mha_spmd_matches_reference_on_mesh():
     scale = 1.0 / np.sqrt(32)
 
     def loss(q, k, v):
-        return (mha_spmd(q, k, v, causal=True, scale=scale) ** 2).sum()
+        return (mha_sharded(q, k, v, mesh, causal=True,
+                            scale=scale) ** 2).sum()
 
     lv, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
         qd, kd, vd)

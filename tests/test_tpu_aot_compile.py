@@ -1,0 +1,131 @@
+"""Ahead-of-time compile of every Pallas kernel for a real v5e, in tier-1.
+
+The CPU suite runs every kernel in the Pallas interpreter, which accepts
+programs Mosaic refuses (an int64 index map, a block that overflows the
+16 MiB scoped VMEM). libtpu is part of the installation and can describe a
+v5e host without one being attached, so these cases run the REAL TPU
+compiler on compile-only devices with the package's gates open
+(`device.is_tpu` answers True, so `pallas_interpret()` is False). This is
+what keeps "compiles only in interpret mode" from coming back between chip
+runs. No skip for a missing libtpu.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from paddle_tpu._core import device
+from paddle_tpu.models.gpt import GPTConfig, build_train_step, init_gpt_params
+
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+fv = importlib.import_module("paddle_tpu.ops.pallas.flash_varlen")
+fused = importlib.import_module("paddle_tpu.ops.pallas.fused")
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def tpu_gates_open(monkeypatch):
+    monkeypatch.setattr(device, "is_tpu", lambda: True)
+    assert not device.pallas_interpret()
+
+
+def _compile(devs, fn, *shapes):
+    """Trace on v5e compile-only device 0, lower for TPU, run Mosaic + XLA."""
+    sh = SingleDeviceSharding(devs[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+def _sum32(x):
+    return x.astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("shape", [(128, 1024, 64), (192, 512, 64)])
+@pytest.mark.parametrize("backward", [False, True])
+def test_flash_compiles(v5e, shape, backward):
+    def fwd(q, k, v):
+        return fa.mha_forward(q, k, v, causal=True)
+
+    fn = jax.grad(lambda q, k, v: _sum32(fwd(q, k, v)), argnums=(0, 1, 2)) \
+        if backward else fwd
+    _compile(v5e, fn, *[(shape, jnp.bfloat16)] * 3)
+
+
+def test_flash_varlen_compiles(v5e):
+    t, h, d, nseq = 4096, 8, 64, 5
+
+    def loss(q, k, v, cu):
+        return _sum32(fv._varlen_body(q, k, v, cu, cu, 0.125, True))
+
+    _compile(v5e, jax.grad(loss, argnums=(0, 1, 2)),
+             *[((t, h, d), jnp.bfloat16)] * 3, ((nseq + 1,), jnp.int32))
+
+
+def test_flashmask_compiles(v5e):
+    b, s, h, d = 4, 2048, 8, 64
+
+    def loss(q, k, v, startend):
+        return _sum32(fv._flashmask_body(q, k, v, startend, 0.125, True))
+
+    _compile(v5e, jax.grad(loss, argnums=(0, 1, 2)),
+             *[((b, s, h, d), jnp.bfloat16)] * 3, ((b, 1, s, 2), jnp.int32))
+
+
+def test_fused_rms_compiles_at_llama_hidden(v5e):
+    _compile(v5e, lambda x, w: fused._rms(x, w, 1e-6),
+             ((8192, 4096), jnp.bfloat16), ((4096,), jnp.bfloat16))
+
+
+def test_fused_swiglu_compiles_at_llama_mlp_width(v5e):
+    _compile(v5e, fused._swiglu, *[((8192, 11008), jnp.bfloat16)] * 2)
+
+
+def test_flash_sequence_limit_is_a_named_error(v5e):
+    """Past the computed cap the named error comes first, not Mosaic's
+    RESOURCE_EXHAUSTED; at the cap the real compiler still accepts."""
+    cap = fa.max_seq(64, jnp.bfloat16, backward=True)
+    assert cap == 4608 and fa.max_seq(64, jnp.bfloat16, backward=False) > cap
+
+    def grad(q, k, v):
+        return jax.grad(lambda q: _sum32(fa.mha_forward(
+            q, k, v, causal=True)))(q)
+
+    # bh 64: too large for XLA to park an operand in VMEM and mask the limit
+    _compile(v5e, grad, *[((64, cap, 64), jnp.bfloat16)] * 3)
+    with pytest.raises(fa.FlashSequenceLimitError, match="4608 with the"):
+        _compile(v5e, grad, *[((64, cap + 512, 64), jnp.bfloat16)] * 3)
+    # forward only fits longer sequences than the backward does
+    _compile(v5e, lambda q, k, v: fa.mha_forward(q, k, v, causal=True),
+             *[((64, cap + 512, 64), jnp.bfloat16)] * 3)
+
+
+def test_train_step_with_flash_lowers_on_pp2_mp2_mesh(v5e):
+    """Lowering only: Mosaic kernels cannot be auto-partitioned, so flash in
+    a mesh program must sit in a shard_map over every axis GSPMD still owns,
+    size-1 'dp' included, here nested inside the compiled-pp body."""
+    config = GPTConfig(vocab_size=1024, hidden_size=256, num_layers=4,
+                       num_heads=4, max_position_embeddings=256)
+    mesh = Mesh(np.asarray(v5e).reshape(1, 2, 2), ("dp", "pp", "mp"))
+    _, step = build_train_step(config, mesh, remat=True, pp_microbatches=2)
+    params = jax.eval_shape(lambda: init_gpt_params(config, 0))
+    f32 = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32), params)
+    state = {"params": params, "master": f32, "m": f32, "v": f32,
+             "step": jax.ShapeDtypeStruct((), jnp.int32)}
+    batch = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+    text = step.trace(state, batch, batch).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text

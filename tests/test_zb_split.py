@@ -75,13 +75,7 @@ def test_bw_halves_are_dce_split_and_reuse_residuals():
     g = np.float32(1.0)
 
     def flops(jitted, *args):
-        c = jitted.lower(*args).compile().cost_analysis()
-        # jax 0.4.x returns one properties dict per computation in a
-        # list; newer jax returns the dict directly (the
-        # observability/compute.py _cost_dict normalization)
-        if isinstance(c, (list, tuple)):
-            c = c[0] if c else {}
-        return float(c["flops"])
+        return float(jitted.lower(*args).compile().cost_analysis()["flops"])
 
     fl_bx = flops(rt._bx, res, g)
     fl_bw = flops(rt._bw, res, g)
