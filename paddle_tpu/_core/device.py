@@ -225,8 +225,15 @@ def enable_compile_cache() -> str:
 
     Every executable is cached (minimum compile time 0, not JAX's 1 s):
     the eager fusion window compiles dozens of sub-second programs per
-    model, which together are most of a cold eager start."""
+    model, which together are most of a cold eager start.
+
+    Metadata is part of the key (JAX leaves it out by default): the
+    `op_name` of a compiled instruction carries the stage the program
+    gave it (`models/stages.py`) and a device trace is read by it, so an
+    executable cached from a program with other scopes is another
+    program, though its computation is the same."""
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

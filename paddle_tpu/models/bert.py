@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from . import stages
 from .trainer import build_adamw_train_step
 
 
@@ -127,40 +128,48 @@ def _block(x, blk, config: BertConfig, attn_mask=None):
     attn_mask [B, 1, 1, S] additive or None."""
     c = config
     b, s, h = x.shape
-    qkv = jnp.einsum("bsh,hk->bsk", x, blk["qkv_w"]) + blk["qkv_b"]
-    qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
-    q = jnp.swapaxes(qkv[:, :, 0], 1, 2)
-    k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-    v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(c.head_dim)
-    if attn_mask is not None:
-        logits = logits + attn_mask
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(x.dtype)
-    attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-    attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
-    attn = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) + blk["proj_b"]
-    x = _ln(x + attn, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
-    y = jnp.einsum("bsh,hf->bsf", x, blk["fc_w"]) + blk["fc_b"]
-    y = jax.nn.gelu(y, approximate=True)
-    y = jnp.einsum("bsf,fh->bsh", y, blk["fo_w"]) + blk["fo_b"]
-    return _ln(x + y, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
+    with jax.named_scope(stages.ATTN_QKV):
+        qkv = jnp.einsum("bsh,hk->bsk", x, blk["qkv_w"]) + blk["qkv_b"]
+    with jax.named_scope(stages.ATTN_CORE):
+        qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
+        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)
+        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
+        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(c.head_dim)
+        if attn_mask is not None:
+            logits = logits + attn_mask
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
+            x.dtype)
+        attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
+    with jax.named_scope(stages.ATTN_OUT):
+        attn = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) \
+            + blk["proj_b"]
+        x = _ln(x + attn, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
+    with jax.named_scope(stages.MLP):
+        y = jnp.einsum("bsh,hf->bsf", x, blk["fc_w"]) + blk["fc_b"]
+        y = jax.nn.gelu(y, approximate=True)
+        y = jnp.einsum("bsf,fh->bsh", y, blk["fo_w"]) + blk["fo_b"]
+        return _ln(x + y, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
 
 
 def bert_encode(params, tokens, token_type_ids=None, attention_mask=None,
                 config: BertConfig = None, remat=True):
     b, s = tokens.shape
     c = config
-    x = params["wte"][tokens] + params["wpe"][:s]
-    if token_type_ids is not None:
-        x = x + params["wtype"][token_type_ids]
-    else:
-        x = x + params["wtype"][0]
-    x = _ln(x.astype(jnp.dtype(c.dtype)), params["emb_ln_g"],
-            params["emb_ln_b"], c.layer_norm_eps)
+    with jax.named_scope(stages.EMBED):
+        x = params["wte"][tokens] + params["wpe"][:s]
+        if token_type_ids is not None:
+            x = x + params["wtype"][token_type_ids]
+        else:
+            x = x + params["wtype"][0]
+        x = _ln(x.astype(jnp.dtype(c.dtype)), params["emb_ln_g"],
+                params["emb_ln_b"], c.layer_norm_eps)
     add_mask = None
     if attention_mask is not None:
-        add_mask = (1.0 - attention_mask[:, None, None, :].astype(
-            jnp.float32)) * -1e30
+        with jax.named_scope(stages.ATTN_CORE):
+            add_mask = (1.0 - attention_mask[:, None, None, :].astype(
+                jnp.float32)) * -1e30
 
     fn = functools.partial(_block, config=c, attn_mask=add_mask)
     if remat:
@@ -173,21 +182,23 @@ def bert_encode(params, tokens, token_type_ids=None, attention_mask=None,
 def bert_mlm_logits(params, tokens, config: BertConfig, remat=True,
                     attention_mask=None):
     x = bert_encode(params, tokens, None, attention_mask, config, remat)
-    x = jnp.einsum("bsh,hk->bsk", x, params["mlm_w"]) + params["mlm_b"]
-    x = jax.nn.gelu(x, approximate=True)
-    x = _ln(x, params["mlm_ln_g"], params["mlm_ln_b"],
-            config.layer_norm_eps)
-    return jnp.einsum("bsh,vh->bsv", x, params["wte"])
+    with jax.named_scope(stages.LOSS_HEAD):
+        x = jnp.einsum("bsh,hk->bsk", x, params["mlm_w"]) + params["mlm_b"]
+        x = jax.nn.gelu(x, approximate=True)
+        x = _ln(x, params["mlm_ln_g"], params["mlm_ln_b"],
+                config.layer_norm_eps)
+        return jnp.einsum("bsh,vh->bsv", x, params["wte"])
 
 
 def bert_mlm_loss(params, tokens, labels, config: BertConfig, remat=True):
     """labels: -100 for unmasked positions (ignored), else target id."""
     logits = bert_mlm_logits(params, tokens, config, remat)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-    safe = jnp.maximum(labels, 0)
-    picked = jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    return -(picked * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with jax.named_scope(stages.LOSS_HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        safe = jnp.maximum(labels, 0)
+        picked = jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
+        mask = (labels >= 0).astype(jnp.float32)
+        return -(picked * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
 def build_train_step(config: BertConfig, mesh: Optional[Mesh] = None,

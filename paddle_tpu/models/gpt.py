@@ -43,6 +43,7 @@ from .._core.tensor import Tensor
 from ..distributed.fleet.mp_layers import (ColumnParallelLinear,
                                            RowParallelLinear,
                                            VocabParallelEmbedding)
+from . import stages
 
 
 @dataclasses.dataclass
@@ -275,40 +276,47 @@ def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None):
     the boundary into the all-gather/reduce-scatter pair."""
     c = config
     b, s, h = x.shape
-    if sp_sharding is not None:
-        x = jax.lax.with_sharding_constraint(x, sp_sharding)
-    y = _ln(x, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
-    qkv = jnp.einsum("bsh,hk->bsk", y, blk["qkv_w"]) + blk["qkv_b"]
-    qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = jnp.swapaxes(q, 1, 2)  # B,H,S,D
-    k = jnp.swapaxes(k, 1, 2)
-    v = jnp.swapaxes(v, 1, 2)
-    scale = 1.0 / math.sqrt(c.head_dim)
-    if _use_flash_kernel(c, s):
-        from ..ops.pallas.flash_attention import mha_forward, mha_sharded
-        if mesh_axes is not None:
-            attn = mha_sharded(q, k, v, mesh_axes, causal=True,
-                               scale=scale)
+    with jax.named_scope(stages.ATTN_QKV):
+        if sp_sharding is not None:
+            x = jax.lax.with_sharding_constraint(x, sp_sharding)
+        y = _ln(x, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
+        qkv = jnp.einsum("bsh,hk->bsk", y, blk["qkv_w"]) + blk["qkv_b"]
+    with jax.named_scope(stages.ATTN_CORE):
+        qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q = jnp.swapaxes(q, 1, 2)  # B,H,S,D
+        k = jnp.swapaxes(k, 1, 2)
+        v = jnp.swapaxes(v, 1, 2)
+        scale = 1.0 / math.sqrt(c.head_dim)
+        if _use_flash_kernel(c, s):
+            from ..ops.pallas.flash_attention import (mha_forward,
+                                                      mha_sharded)
+            if mesh_axes is not None:
+                attn = mha_sharded(q, k, v, mesh_axes, causal=True,
+                                   scale=scale)
+            else:
+                attn = mha_forward(q, k, v, causal=True, scale=scale)
         else:
-            attn = mha_forward(q, k, v, causal=True, scale=scale)
-    else:
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        logits = jnp.where(mask, logits, jnp.array(-1e30, logits.dtype))
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
-            x.dtype)
-        attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-    attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
-    proj = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) + blk["proj_b"]
-    x = x + proj
-    y = _ln(x, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
-    y = jnp.einsum("bsh,hf->bsf", y, blk["fc_w"]) + blk["fc_b"]
-    y = jax.nn.gelu(y, approximate=True)
-    y = jnp.einsum("bsf,fh->bsh", y, blk["fo_w"]) + blk["fo_b"]
-    out = x + y
-    if sp_sharding is not None:
-        out = jax.lax.with_sharding_constraint(out, sp_sharding)
+            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+            mask = jnp.tril(jnp.ones((s, s), bool))
+            logits = jnp.where(mask, logits,
+                               jnp.array(-1e30, logits.dtype))
+            probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
+                x.dtype)
+            attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
+    with jax.named_scope(stages.ATTN_OUT):
+        proj = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) \
+            + blk["proj_b"]
+        x = x + proj
+    with jax.named_scope(stages.MLP):
+        y = _ln(x, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
+        y = jnp.einsum("bsh,hf->bsf", y, blk["fc_w"]) + blk["fc_b"]
+        y = jax.nn.gelu(y, approximate=True)
+        y = jnp.einsum("bsf,fh->bsh", y, blk["fo_w"]) + blk["fo_b"]
+        out = x + y
+        if sp_sharding is not None:
+            out = jax.lax.with_sharding_constraint(out, sp_sharding)
     return out
 
 
@@ -319,8 +327,9 @@ def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
     when given (distributed.pipeline_compiled.pipelined_trunk), replaces
     the layer scan with the compiled pp-axis pipeline."""
     b, s = tokens.shape
-    x = params["wte"][tokens] + params["wpe"][:s]
-    x = x.astype(jnp.dtype(config.dtype))
+    with jax.named_scope(stages.EMBED):
+        x = params["wte"][tokens] + params["wpe"][:s]
+        x = x.astype(jnp.dtype(config.dtype))
 
     if pp_trunk is not None:
         x = pp_trunk(params["blocks"], x)
@@ -335,11 +344,11 @@ def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
             return blk_fn(carry, blk), None
 
         x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-    x = _ln(x, params["lnf_g"], params["lnf_b"], config.layer_norm_eps)
-    if return_hidden:
-        return x
-    logits = jnp.einsum("bsh,vh->bsv", x, params["wte"])
-    return logits
+    with jax.named_scope(stages.LOSS_HEAD):
+        x = _ln(x, params["lnf_g"], params["lnf_b"], config.layer_norm_eps)
+        if return_hidden:
+            return x
+        return jnp.einsum("bsh,vh->bsv", x, params["wte"])
 
 
 def gpt_loss(params, tokens, labels, config: GPTConfig, mesh_axes=None,
@@ -357,15 +366,18 @@ def gpt_loss(params, tokens, labels, config: GPTConfig, mesh_axes=None,
         hidden = gpt_forward(params, tokens, config, mesh_axes, remat,
                              sp_sharding, pp_trunk=pp_trunk,
                              return_hidden=True)
-        loss = vocab_parallel_softmax_cross_entropy(
-            hidden, params["wte"], labels, mesh_axes, axis="mp")
-        return loss.mean()
+        with jax.named_scope(stages.LOSS_HEAD):
+            loss = vocab_parallel_softmax_cross_entropy(
+                hidden, params["wte"], labels, mesh_axes, axis="mp")
+            return loss.mean()
     logits = gpt_forward(params, tokens, config, mesh_axes, remat,
                          sp_sharding, pp_trunk=pp_trunk)
-    logits = logits.astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, -1)
-    picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -picked.mean()
+    with jax.named_scope(stages.LOSS_HEAD):
+        logits = logits.astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, -1)
+        picked = jnp.take_along_axis(logp, labels[..., None],
+                                     axis=-1)[..., 0]
+        return -picked.mean()
 
 
 def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None,

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from . import stages
 from .trainer import build_adamw_train_step, filter_specs_for_mesh
 
 
@@ -146,37 +147,43 @@ def _block(x, blk, config: LlamaConfig):
     b, s, h = x.shape
     nh, nkv, d = c.num_heads, c.kv_heads, c.head_dim
 
-    y = _rms(x, blk["ln1_g"], c.rms_norm_eps)
-    q = jnp.einsum("bsh,hk->bsk", y, blk["q_w"]).reshape(b, s, nh, d)
-    k = jnp.einsum("bsh,hk->bsk", y, blk["k_w"]).reshape(b, s, nkv, d)
-    v = jnp.einsum("bsh,hk->bsk", y, blk["v_w"]).reshape(b, s, nkv, d)
-    q = _rope(q, c.rope_theta)
-    k = _rope(k, c.rope_theta)
-    if nkv != nh:  # GQA: repeat kv heads
-        rep = nh // nkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) / math.sqrt(d)
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    logits = jnp.where(mask, logits, jnp.array(-1e30, logits.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(x.dtype)
-    attn = jnp.einsum("bhqk,bhkd->bhqd", probs, vt)
-    attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
-    x = x + jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
-
-    y = _rms(x, blk["ln2_g"], c.rms_norm_eps)
-    gate = jnp.einsum("bsh,hf->bsf", y, blk["gate_w"])
-    up = jnp.einsum("bsh,hf->bsf", y, blk["up_w"])
-    act = jax.nn.silu(gate) * up                       # SwiGLU
-    return x + jnp.einsum("bsf,fh->bsh", act, blk["down_w"])
+    with jax.named_scope(stages.ATTN_QKV):
+        y = _rms(x, blk["ln1_g"], c.rms_norm_eps)
+        q = jnp.einsum("bsh,hk->bsk", y, blk["q_w"])
+        k = jnp.einsum("bsh,hk->bsk", y, blk["k_w"])
+        v = jnp.einsum("bsh,hk->bsk", y, blk["v_w"])
+    with jax.named_scope(stages.ATTN_CORE):
+        q = _rope(q.reshape(b, s, nh, d), c.rope_theta)
+        k = _rope(k.reshape(b, s, nkv, d), c.rope_theta)
+        v = v.reshape(b, s, nkv, d)
+        if nkv != nh:  # GQA: repeat kv heads
+            rep = nh // nkv
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        qt = jnp.swapaxes(q, 1, 2)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) / math.sqrt(d)
+        mask = jnp.tril(jnp.ones((s, s), bool))
+        logits = jnp.where(mask, logits, jnp.array(-1e30, logits.dtype))
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
+            x.dtype)
+        attn = jnp.einsum("bhqk,bhkd->bhqd", probs, vt)
+        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
+    with jax.named_scope(stages.ATTN_OUT):
+        x = x + jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
+    with jax.named_scope(stages.MLP):
+        y = _rms(x, blk["ln2_g"], c.rms_norm_eps)
+        gate = jnp.einsum("bsh,hf->bsf", y, blk["gate_w"])
+        up = jnp.einsum("bsh,hf->bsf", y, blk["up_w"])
+        act = jax.nn.silu(gate) * up                       # SwiGLU
+        return x + jnp.einsum("bsf,fh->bsh", act, blk["down_w"])
 
 
 def llama_forward(params, tokens, config: LlamaConfig, remat=True,
                   pp_trunk=None):
-    x = params["wte"][tokens].astype(jnp.dtype(config.dtype))
+    with jax.named_scope(stages.EMBED):
+        x = params["wte"][tokens].astype(jnp.dtype(config.dtype))
     if pp_trunk is not None:
         x = pp_trunk(params["blocks"], x)
     else:
@@ -185,17 +192,20 @@ def llama_forward(params, tokens, config: LlamaConfig, remat=True,
             fn = jax.checkpoint(fn)
         x, _ = jax.lax.scan(lambda c, blk: (fn(c, blk), None), x,
                             params["blocks"])
-    x = _rms(x, params["lnf_g"], config.rms_norm_eps)
-    head = params["wte"] if config.tie_embeddings else params["lm_head"]
-    return jnp.einsum("bsh,vh->bsv", x, head)
+    with jax.named_scope(stages.LOSS_HEAD):
+        x = _rms(x, params["lnf_g"], config.rms_norm_eps)
+        head = params["wte"] if config.tie_embeddings \
+            else params["lm_head"]
+        return jnp.einsum("bsh,vh->bsv", x, head)
 
 
 def llama_loss(params, tokens, labels, config: LlamaConfig, remat=True,
                pp_trunk=None):
     logits = llama_forward(params, tokens, config, remat, pp_trunk)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-    picked = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
-    return -picked.mean()
+    with jax.named_scope(stages.LOSS_HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        picked = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
+        return -picked.mean()
 
 
 def build_train_step(config: LlamaConfig, mesh: Optional[Mesh] = None,

@@ -1,0 +1,36 @@
+"""The stages of the compiled train step, by name.
+
+`models/trainer.py` and the model families open one `jax.named_scope` per
+stage, so every instruction of the compiled program says in its `op_name`
+which stage it belongs to, and a device trace can be read by stage. The
+strings are written here and nowhere else: the models, the trainer and the
+benchmark's readers (`benchmarks/layer_metrics/_stages.py`) import them.
+
+Flat, never nested. A block is ATTN_QKV + ATTN_CORE + ATTN_OUT + MLP and
+nothing else; the same name opened twice is one stage. Direction (forward,
+backward, what remat repeats) is not a scope: JAX writes `jvp(...)`,
+`transpose(jvp(...))` and `rematted_computation` into the path itself.
+A scope exists only while tracing; the compiled program differs by metadata
+strings alone. No JAX import here: the benchmark reads the names before it
+decides which platform JAX may see.
+"""
+EMBED = "embed"             # token / position / type lookups, their sum,
+#                             the embedding LN (bert), the cast
+ATTN_QKV = "attn_qkv"       # entry sharding constraint, the norm that
+#                             feeds attention, q/k/v projection and bias
+ATTN_CORE = "attn_core"     # split into heads, every layout change, rope,
+#                             scores / mask / softmax / context or the
+#                             kernel call with its all-to-alls, merge
+ATTN_OUT = "attn_out"       # output projection, bias, residual add (bert:
+#                             the LN after it)
+MLP = "mlp"                 # its norm, both projections, activation,
+#                             residual add (bert: the LN after it), exit
+#                             sharding constraint
+LOSS_HEAD = "loss_head"     # final norm, MLM transform (bert), logits,
+#                             float32 log-softmax or vocabulary-parallel
+#                             cross-entropy, the pick and the mean
+OPTIMIZER = "optimizer"     # all of the step after value_and_grad: AdamW
+#                             on master weights, the cast to params
+
+BLOCK = (ATTN_QKV, ATTN_CORE, ATTN_OUT, MLP)
+ALL = (EMBED,) + BLOCK + (LOSS_HEAD, OPTIMIZER)

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from . import stages
+
 
 def filter_specs_for_mesh(specs, mesh: Optional[Mesh]):
     """Drop references to axes the mesh doesn't have."""
@@ -91,34 +93,35 @@ def build_adamw_train_step(
 
     def step_fn(state, *batch):
         loss, grads = jax.value_and_grad(loss_fn)(state["params"], *batch)
-        step = state["step"] + 1
-        t = step.astype(jnp.float32)
+        with jax.named_scope(stages.OPTIMIZER):
+            step = state["step"] + 1
+            t = step.astype(jnp.float32)
 
-        def upd(p_master, g, m, v, use_wd):
-            g = g.astype(jnp.float32)
-            m2 = b1 * m + (1 - b1) * g
-            v2 = b2 * v + (1 - b2) * g * g
-            mhat = m2 / (1 - b1 ** t)
-            vhat = v2 / (1 - b2 ** t)
-            decay = wd * p_master if use_wd else 0.0
-            new_master = p_master - lr * (
-                mhat / (jnp.sqrt(vhat) + eps) + decay)
-            return new_master, m2, v2
+            def upd(p_master, g, m, v, use_wd):
+                g = g.astype(jnp.float32)
+                m2 = b1 * m + (1 - b1) * g
+                v2 = b2 * v + (1 - b2) * g * g
+                mhat = m2 / (1 - b1 ** t)
+                vhat = v2 / (1 - b2 ** t)
+                decay = wd * p_master if use_wd else 0.0
+                new_master = p_master - lr * (
+                    mhat / (jnp.sqrt(vhat) + eps) + decay)
+                return new_master, m2, v2
 
-        flat_master, tree = jax.tree_util.tree_flatten(state["master"])
-        outs = [upd(pm, g, m, v, w) for pm, g, m, v, w in zip(
-            flat_master, jax.tree_util.tree_leaves(grads),
-            jax.tree_util.tree_leaves(state["m"]),
-            jax.tree_util.tree_leaves(state["v"]),
-            jax.tree_util.tree_leaves(wd_mask))]
-        new_master = jax.tree_util.tree_unflatten(
-            tree, [o[0] for o in outs])
-        new_m = jax.tree_util.tree_unflatten(tree, [o[1] for o in outs])
-        new_v = jax.tree_util.tree_unflatten(tree, [o[2] for o in outs])
-        new_params = jax.tree_util.tree_map(
-            lambda pm, p: pm.astype(p.dtype), new_master, state["params"])
-        return {"params": new_params, "master": new_master, "m": new_m,
-                "v": new_v, "step": step}, loss
+            flat_master, tree = jax.tree_util.tree_flatten(state["master"])
+            outs = [upd(pm, g, m, v, w) for pm, g, m, v, w in zip(
+                flat_master, jax.tree_util.tree_leaves(grads),
+                jax.tree_util.tree_leaves(state["m"]),
+                jax.tree_util.tree_leaves(state["v"]),
+                jax.tree_util.tree_leaves(wd_mask))]
+            new_master = jax.tree_util.tree_unflatten(
+                tree, [o[0] for o in outs])
+            new_m = jax.tree_util.tree_unflatten(tree, [o[1] for o in outs])
+            new_v = jax.tree_util.tree_unflatten(tree, [o[2] for o in outs])
+            new_params = jax.tree_util.tree_map(
+                lambda pm, p: pm.astype(p.dtype), new_master, state["params"])
+            return {"params": new_params, "master": new_master, "m": new_m,
+                    "v": new_v, "step": step}, loss
 
     if mesh is not None:
         if batch_specs is None:

@@ -40,6 +40,13 @@ from paddle_tpu.observability import metrics
 
 @pytest.fixture
 def compute_on():
+    """The plane on over a zeroed ledger, and both put back. The ledger's
+    totals are the process's: whatever flipped the plane on before this
+    test in the same worker (budget.static_diff in test_record_fastpath.py
+    does, over this file's very train step) leaves its executions in
+    site_flops() and executed_flops(), and a test that reads them after
+    its own first step then sees several steps' worth."""
+    comptel.reset()
     paddle.set_flags({"FLAGS_compute_telemetry": True})
     try:
         yield

@@ -99,6 +99,34 @@ def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout():
     assert ".jax_compile_cache/" in ignored
 
 
+_SCOPE_PROBE = """
+import sys
+import jax
+import jax.numpy as jnp
+from paddle_tpu._core.device import enable_compile_cache
+enable_compile_cache()
+def step_fn(x):
+    with jax.named_scope(sys.argv[1]):
+        return jnp.tanh(x @ x).sum()
+text = jax.jit(step_fn).lower(jnp.ones((64, 64))).compile().as_text()
+print(sys.argv[1] in text)
+"""
+
+
+def test_a_cached_executable_keeps_no_other_programs_scopes(tmp_path):
+    """Two programs that differ by a scope's name alone share no cache
+    entry: the second's compiled text names its own scope, not the one the
+    cache was filled under (by JAX's default key it would)."""
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    for scope in ("attention_a", "attention_b", "attention_b"):
+        out = subprocess.run([sys.executable, "-c", _SCOPE_PROBE, scope],
+                             env=env, capture_output=True, text=True,
+                             timeout=120, check=True).stdout
+        assert out.strip().splitlines()[-1] == "True", scope
+    assert os.listdir(tmp_path)     # the cache was in use
+
+
 # ------------------------------------------- parents that would need a chip
 
 def test_launcher_refuses_sibling_workers_on_a_tpu_host(monkeypatch):
