@@ -4,18 +4,21 @@ Online-softmax blocked attention (the same math the reference reaches via
 the dynloaded flashattn CUDA lib, paddle/phi/backends/dynload/flashattn.cc;
 surface at python/paddle/nn/functional/flash_attention.py). Forward streams
 K/V blocks through VMEM against a resident Q block, carrying (m, l, acc)
-accumulators; backward is the standard two-kernel split (dKV over key
-blocks, dQ over query blocks) using the saved log-sum-exp rows.
+accumulators; backward is one kernel over key blocks that forms every
+score tile once from the saved log-sum-exp rows and returns dQ, dK and dV
+(five products a tile; dQ is summed across key blocks in VMEM).
 
 Layout inside the kernels is [batch*heads, seq, head_dim]; the public entry
-takes paddle's [batch, seq, heads, head_dim]. Logit math is fp32 on the MXU
-(preferred_element_type), IO dtype is whatever the caller passes (bf16 on
-TPU). On a TPU the kernels are Mosaic-compiled; anywhere else they run in
-interpret mode (`_core.device.pallas_interpret`), so the CPU test mesh
-exercises identical code.
+takes paddle's [batch, seq, heads, head_dim]. Every product accumulates in
+fp32 on the MXU (preferred_element_type) from operands of the IO dtype,
+which is whatever the caller passes (bf16 on TPU): p and ds are rounded to
+it once, the softmax math between the products is fp32. On a TPU the
+kernels are Mosaic-compiled; anywhere else they run in interpret mode
+(`_core.device.pallas_interpret`), so the CPU test mesh exercises
+identical code.
 
-K/V (forward, dQ) and Q/dO (dKV) stay whole-sequence resident in VMEM, so
-the sequence length is capped by the 16 MiB scoped-VMEM limit:
+K/V (forward) and Q/dO/dQ (backward) stay whole-sequence resident in VMEM,
+so the sequence length is capped by the 16 MiB scoped-VMEM limit:
 `check_vmem` computes each kernel's footprint and raises
 `FlashSequenceLimitError` instead of letting Mosaic fail with
 RESOURCE_EXHAUSTED (README "Flash attention sequence limit").
@@ -27,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as _P
 
@@ -63,26 +67,31 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
 
 
 def vmem_footprint(sq: int, sk: int, d: int, dtype) -> dict:
-    """Scoped-VMEM bytes each kernel needs with its operands in HBM:
-    the pipeline double-buffers every in/out block of the BlockSpecs in
-    `_fwd_call` / `_bwd`. Agrees with the compiler's own "scoped
-    allocation" figure to its printed precision (bf16 and fp32, d 64-256,
-    seq 1k-8k on the v5e ahead-of-time compiler); a [seq, 1] fp32 row
-    pads to 128 lanes, which is why dKV is the largest."""
+    """Scoped-VMEM bytes each kernel needs with its operands in HBM. The
+    pipeline double-buffers every in/out block of the BlockSpecs in
+    `_fwd_call` / `_bwd`; for the forward that is all, and it agrees with
+    the compiler's own "scoped allocation" figure to its printed precision
+    (a [seq, 1] fp32 row pads to 128 lanes). The backward adds its fp32
+    dQ accumulator and what the body keeps beyond its blocks, taken as
+    three fp32 [bk, bq] tiles and the dK, dV sums: an upper estimate (the
+    compiler's own choices move its figure by a MiB either way), under
+    which the v5e ahead-of-time compiler accepted every length up to the
+    cap in steps of 512 (bf16 and fp32, d 64-256)."""
     bq, bk = _block_sizes(sq, sk, d)
     blk = functools.partial(_vmem_block_bytes, dtype=dtype)
-    row = functools.partial(_vmem_block_bytes, cols=1, dtype=jnp.float32)
+    f32 = functools.partial(_vmem_block_bytes, dtype=jnp.float32)
     return {
-        "fwd": 2 * (2 * blk(bq, d) + 2 * blk(sk, d) + row(bq)),
-        "dkv": 2 * (2 * blk(sq, d) + 2 * row(sq) + 4 * blk(bk, d)),
-        "dq": 2 * (2 * blk(sk, d) + 3 * blk(bq, d) + 2 * row(bq)),
+        "fwd": 2 * (2 * blk(bq, d) + 2 * blk(sk, d) + f32(bq, 1)),
+        "bwd": (2 * (3 * blk(sq, d) + 4 * blk(bk, d)
+                     + 2 * (sq // bq) * f32(1, bq))
+                + f32(d, sq) + 3 * f32(bk, bq) + 2 * f32(bk, d)),
     }
 
 
 def _fits(sq: int, sk: int, d: int, dtype, backward: bool):
     """Name of the first kernel that does not fit, or None."""
     need = vmem_footprint(sq, sk, d, dtype)
-    for kernel in ("fwd", "dkv", "dq") if backward else ("fwd",):
+    for kernel in ("fwd", "bwd") if backward else ("fwd",):
         if need[kernel] >= SCOPED_VMEM_BYTES:
             return kernel, need[kernel]
     return None
@@ -112,7 +121,7 @@ def _check_vmem(q, k, backward: bool):
             f"flash attention {kernel} kernel at seq_q {sq}, seq_k {sk}, "
             f"head_dim {d}, {jnp.dtype(q.dtype).name} needs "
             f"{need / 2**20:.2f} MiB of scoped VMEM; the limit is "
-            f"{SCOPED_VMEM_BYTES >> 20} MiB because K/V (and Q/dO in the "
+            f"{SCOPED_VMEM_BYTES >> 20} MiB because K/V (Q, dO and dQ in the "
             "backward) stay whole-sequence resident. Longest self-attention "
             f"sequence at this head_dim and dtype: "
             f"{max_seq(d, q.dtype, False)} forward only, "
@@ -219,132 +228,108 @@ def _fwd_call(q, k, v, causal, scale, block_k, kv_len, q_offset, block_q,
 
 # ---------------------------------------------------------------- backward
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, causal, scale, block_q, kv_len, q_offset):
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+_NN = (((1,), (0,)), ((), ()))      # a . b
+_TN = (((0,), (0,)), ((), ()))      # a^T . b
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dqt_acc, *, causal, scale, block_q,
+                q_offset):
+    """One (batch*head, key block) grid step: each score tile of this key
+    block is formed once, key-major ([bk, bq], so lse and delta are
+    lane-dense [1, bq] rows and dV, dK plain products), and feeds all five
+    products on operands of the inputs' dtype. dQ is summed over the key
+    axis in VMEM, transposed ([d, sq]: the one transposed product then
+    turns the narrow k, not the tile) and written at the last key block."""
     kj = pl.program_id(1)
     k = k_ref[0]                                    # [bk, d]
     v = v_ref[0]
     bk, d = k.shape
-    sq = q_ref.shape[1]
-    nqb = sq // block_q
-    k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    nqb = q_ref.shape[1] // block_q
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
 
-    def body(i, carry):
+    @pl.when(kj == 0)
+    def _():
+        dqt_acc[...] = jnp.zeros_like(dqt_acc)
+
+    k_minus_q = (jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
+                 - jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 1))
+
+    def tile(masked, i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]   # [bq, 1]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [bq, bk]
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, bk), 0)
-        mask = k_pos < kv_len
-        if causal:
-            mask &= k_pos <= q_pos + q_offset
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # [bq, bk]
-        dv_new = dv + jax.lax.dot_general(
-            p, do.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dk_new = dk + jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bk, d]
-        return dk_new, dv_new
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[0, rows, :]                       # [bq, d]
+        do = do_ref[0, rows, :]
+        # scaled on the float32 tile, as the forward does: lse is of that s
+        st = dot(k, q, _NT) * scale                 # [bk, bq]
+        pt = jnp.exp(st - lse_ref[0, i])
+        if masked:
+            # k_pos <= q_pos + q_offset, positions counted from the tile's
+            pt = jnp.where(
+                k_minus_q <= i * block_q + q_offset - kj * bk, pt, 0.0)
+        dst = (pt * (dot(v, do, _NT) - delta_ref[0, i])).astype(q.dtype)
+        dv = dv + dot(pt.astype(do.dtype), do, _NN)             # [bk, d]
+        dk = dk + dot(dst, q, _NN)
+        dqt_acc[:, rows] += dot(k, dst, _TN)                    # [d, bq]
+        return dk, dv
 
+    carry = (jnp.zeros((bk, d), jnp.float32),) * 2
     if causal:
-        # query rows before this key block's first diagonal see none of it
-        first = jnp.maximum((kj * bk - q_offset) // block_q, 0)
+        # query blocks before `first` see none of this key block; from
+        # `full` on they see all of it and the compare is left out
+        first = jnp.clip((kj * bk - q_offset) // block_q, 0, nqb)
+        full = jnp.clip(
+            ((kj + 1) * bk - 1 - q_offset + block_q - 1) // block_q,
+            first, nqb)
+        carry = jax.lax.fori_loop(first, full,
+                                  functools.partial(tile, True), carry)
     else:
-        first = 0
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first, nqb, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+        full = 0
+    dk, dv = jax.lax.fori_loop(full, nqb, functools.partial(tile, False),
+                               carry)
+    # ds's scale, applied once the tile is contracted away: on [., d]
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               causal, scale, block_k, kv_len, q_offset):
-    qi = pl.program_id(1)
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0]       # [bq, 1]
-    delta = delta_ref[0]   # [bq, 1]
-    bq, d = q.shape
-    sk = k_ref.shape[1]
-    nkb = sk // block_k
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask &= k_pos <= q_pos + q_offset
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        last = (qi * bq + bq - 1) + q_offset
-        nkb_eff = jnp.minimum((last // block_k) + 1, nkb)
-    else:
-        nkb_eff = nkb
-    dq = jax.lax.fori_loop(0, nkb_eff, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _():
+        def write(i, _):
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            dq_ref[0, rows, :] = (dqt_acc[:, rows].T * scale).astype(
+                dq_ref.dtype)
+        jax.lax.fori_loop(0, nqb, write, None)
 
 
-def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, kv_len,
-         q_offset):
+def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, q_offset):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    nqb = sq // block_q
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)                    # [bh, sq, 1]
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+                    axis=-1)                                    # [bh, sq]
+    # Q and dO stay whole-sequence resident; lse and delta come as one
+    # lane-dense row per query block (a [sq, 1] block pads to 128 lanes)
     full_q = pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0))
-    full_row = pl.BlockSpec((1, sq, 1), lambda b, j: (b, 0, 0))
+    full_row = pl.BlockSpec((1, nqb, 1, block_q), lambda b, j: (b, 0, 0, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))
-    full_k = pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0))
-
     with _no_x64():
-        dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          block_q=block_q, kv_len=kv_len, q_offset=q_offset),
-        grid=(bh, sk // block_k),
-        in_specs=[full_q, kspec, kspec, full_q, full_row, full_row],
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, causal=causal, scale=scale,
+                              block_q=block_q, q_offset=q_offset),
+            grid=(bh, sk // block_k),
+            in_specs=[full_q, kspec, kspec, full_q, full_row, full_row],
+            out_specs=[full_q, kspec, kspec],
+            out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+                       jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
+                       jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((d, sq), jnp.float32)],
+            # dQ's block is revisited along the key axis
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=pallas_interpret(),
-        )(q, k, v, do, lse, delta)
-
-    rowspec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
-    with _no_x64():
-        dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          block_k=block_k, kv_len=kv_len, q_offset=q_offset),
-        grid=(bh, sq // block_q),
-        in_specs=[qspec, full_k, full_k, qspec, rowspec, rowspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            interpret=pallas_interpret(),
-        )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+        )(q, k, v, do, lse.reshape(bh, nqb, 1, block_q),
+          delta.reshape(bh, nqb, 1, block_q))
 
 
 # ---------------------------------------------------------------- public
@@ -374,9 +359,8 @@ def _mha_bwd(causal, scale, res, do):
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk, d)
-    dq, dk, dv = _bwd(q, k, v, out, lse, do, causal, scale, bq, bk,
-                      kv_len=sk, q_offset=sk - sq)
-    return dq, dk, dv
+    return _bwd(q, k, v, out, lse, do, causal, scale, bq, bk,
+                q_offset=sk - sq)
 
 
 _mha.defvjp(_mha_fwd, _mha_bwd)

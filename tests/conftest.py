@@ -62,7 +62,6 @@ _SLOW_MODULES = {
     "test_moe",
     "test_distributed",
     "test_rnn",
-    "test_pallas",
     "test_op_suite_ext",
     "test_quantization",
     "test_lbfgs_fused",

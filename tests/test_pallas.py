@@ -4,20 +4,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import with_flag
 
 from paddle_tpu.ops.pallas import (flash_attention, mha_forward, rms_norm,
                                    swiglu, fused_rotary_position_embedding)
 
 
-def _ref_attn(q, k, v, causal, scale):
+def _ref_attn(q, k, v, causal, scale, precision=None):
     # [BH, S, D] fp32 reference
-    s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
+    s = jnp.einsum("bqd,bkd->bqk", q, k,
+                   precision=precision).astype(jnp.float32) * scale
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v)
+    return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v,
+                      precision=precision)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -52,6 +55,82 @@ def test_mha_grads_match_reference(causal):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+# normalized max error of a bfloat16 gradient against the float32 reference:
+# the outputs are rounded to bfloat16 (2^-9 a value) and so are p and ds on
+# their way into the MXU; chip_smoke.py's BF16_TOL is the same figure
+BF16_GRAD_TOL = 2e-2
+
+
+def _weighted_grads(attn, q, k, v, w):
+    return jax.grad(lambda q, k, v: jnp.sum(
+        attn(q, k, v).astype(jnp.float32) * w), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sq,sk", [(256, 256), (256, 512)],
+                         ids=["self", "cross_with_offset"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_mha_backward_over_many_tiles(causal, sq, sk, dtype):
+    """128-wide blocks, so one key block meets query blocks that see none
+    of it, that the diagonal crosses and that see all of it."""
+    rng = np.random.RandomState(9)
+    q = jnp.asarray(rng.randn(2, sq, 64), dtype)
+    k = jnp.asarray(rng.randn(2, sk, 64), dtype)
+    v = jnp.asarray(rng.randn(2, sk, 64), dtype)
+    w = jnp.asarray(rng.randn(2, sq, 64), jnp.float32)
+    scale = 0.125 if dtype == jnp.bfloat16 else 0.17
+    with with_flag("FLAGS_flash_block_q", 128), \
+            with_flag("FLAGS_flash_block_k", 128):
+        got = _weighted_grads(lambda q, k, v: mha_forward(
+            q, k, v, causal=causal, scale=scale), q, k, v, w)
+    want = _weighted_grads(
+        lambda q, k, v: _ref_attn(q, k, v, causal, scale,
+                                  jax.lax.Precision.HIGHEST),
+        *(a.astype(jnp.float32) for a in (q, k, v)), w)
+    for g, r in zip(got, want):
+        assert g.dtype == dtype
+        g, r = np.asarray(g, np.float32), np.asarray(r)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+        else:
+            assert np.abs(g - r).max() / np.abs(r).max() <= BF16_GRAD_TOL
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_mha_backward_is_one_kernel_on_narrow_operands(causal):
+    """dQ, dK and dV come out of ONE pallas_call, and with bfloat16 inputs
+    none of its products takes a float32 operand (p and ds are rounded to
+    the inputs' dtype; the accumulation stays float32)."""
+    a = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(mha_forward(
+        q, k, v, causal=causal).astype(jnp.float32)), argnums=(0, 1, 2)))(
+            a, a, a)
+    calls = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    backward = [e for e in calls
+                if sum(o.aval.shape == a.shape for o in e.outvars) == 3]
+    assert len(calls) == 2 and len(backward) == 1       # forward, backward
+    dots = [e for e in _eqns(backward[0].params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    # five products a tile; the causal kernel holds the loop twice, once
+    # with the diagonal's compare and once without
+    assert len(dots) == (10 if causal else 5)
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
+        assert dot.outvars[0].aval.dtype == jnp.float32
 
 
 def test_mha_cross_attention_shapes():

@@ -53,7 +53,9 @@ def _sum32(x):
     return x.astype(jnp.float32).sum()
 
 
-@pytest.mark.parametrize("shape", [(128, 1024, 64), (192, 512, 64)])
+# gpt2-medium at batch 8, bert-large's widths, gpt3-1.3b's share of one chip
+@pytest.mark.parametrize("shape", [(128, 1024, 64), (192, 512, 64),
+                                   (128, 2048, 64)])
 @pytest.mark.parametrize("backward", [False, True])
 def test_flash_compiles(v5e, shape, backward):
     def fwd(q, k, v):
@@ -97,7 +99,7 @@ def test_flash_sequence_limit_is_a_named_error(v5e):
     """Past the computed cap the named error comes first, not Mosaic's
     RESOURCE_EXHAUSTED; at the cap the real compiler still accepts."""
     cap = fa.max_seq(64, jnp.bfloat16, backward=True)
-    assert cap == 4608 and fa.max_seq(64, jnp.bfloat16, backward=False) > cap
+    assert cap == 6144 and fa.max_seq(64, jnp.bfloat16, backward=False) > cap
 
     def grad(q, k, v):
         return jax.grad(lambda q: _sum32(fa.mha_forward(
@@ -105,7 +107,8 @@ def test_flash_sequence_limit_is_a_named_error(v5e):
 
     # bh 64: too large for XLA to park an operand in VMEM and mask the limit
     _compile(v5e, grad, *[((64, cap, 64), jnp.bfloat16)] * 3)
-    with pytest.raises(fa.FlashSequenceLimitError, match="4608 with the"):
+    with pytest.raises(fa.FlashSequenceLimitError,
+                       match=f"bwd kernel .* {cap} with the"):
         _compile(v5e, grad, *[((64, cap + 512, 64), jnp.bfloat16)] * 3)
     # forward only fits longer sequences than the backward does
     _compile(v5e, lambda q, k, v: fa.mha_forward(q, k, v, causal=True),
