@@ -5,3 +5,5 @@ from . import bert  # noqa: F401
 from . import llama  # noqa: F401
 from .bert import BERT_CONFIGS, BertConfig  # noqa: F401
 from .llama import LLAMA_CONFIGS, LlamaConfig  # noqa: F401
+from . import mla_moe  # noqa: F401
+from .mla_moe import MlaMoeConfig  # noqa: F401

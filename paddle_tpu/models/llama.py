@@ -120,11 +120,13 @@ def wd_mask(config: LlamaConfig) -> Dict:
 
 # ------------------------------------------------------------------ rope
 
-def _rope(x, theta: float):
-    """x [B, S, H, D] -> rotated. Half-split convention."""
+def _rope(x, theta: float, inv_freq=None):
+    """x [B, S, H, D] -> rotated. Half-split convention. `inv_freq` [D/2]
+    replaces theta's plain frequencies (a scaled RoPE such as yarn)."""
     b, s, h, d = x.shape
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
@@ -140,6 +142,13 @@ def _rms(x, g, eps):
     xf = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype) * g
+
+
+def _swiglu(y, gate_w, up_w, down_w):
+    """down(silu(gate y) * up y) on y [..., h]."""
+    gate = jnp.einsum("...h,hf->...f", y, gate_w)
+    up = jnp.einsum("...h,hf->...f", y, up_w)
+    return jnp.einsum("...f,fh->...h", jax.nn.silu(gate) * up, down_w)
 
 
 def _block(x, blk, config: LlamaConfig):
@@ -174,10 +183,7 @@ def _block(x, blk, config: LlamaConfig):
         x = x + jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
     with jax.named_scope(stages.MLP):
         y = _rms(x, blk["ln2_g"], c.rms_norm_eps)
-        gate = jnp.einsum("bsh,hf->bsf", y, blk["gate_w"])
-        up = jnp.einsum("bsh,hf->bsf", y, blk["up_w"])
-        act = jax.nn.silu(gate) * up                       # SwiGLU
-        return x + jnp.einsum("bsf,fh->bsh", act, blk["down_w"])
+        return x + _swiglu(y, blk["gate_w"], blk["up_w"], blk["down_w"])
 
 
 def llama_forward(params, tokens, config: LlamaConfig, remat=True,

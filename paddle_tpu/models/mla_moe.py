@@ -1,0 +1,471 @@
+"""Latent-attention sparse-expert family — functional TPU-compiled path.
+
+Named for its mechanisms, not for a model (DeepSeek-V2/V3-style blocks with
+manifold-constrained hyper-connections, arXiv:2512.24880):
+
+- multi-head latent attention (MLA): queries and keys/values go through
+  low-rank latents with their own RMSNorm; a head's query/key is a no-position
+  part beside a rotary part whose key is one vector a token shared by every
+  head; the value is narrower than the query (the flash kernels' two widths).
+  No projection is absorbed into another: that is serving's trick.
+- yarn-scaled RoPE (`yarn_inv_freq`, `attention_scale`).
+- leading dense SwiGLU layers, then sparse layers: a sigmoid top-k router
+  with a selection bias over ALL routed experts, the routed experts this
+  chip HOLDS (`experts_held`, dropless, ops/moe.py) and a shared expert.
+- `hc_mult` residual streams: every sub-layer reads a learned mixture of the
+  streams and writes back through a per-token matrix that Sinkhorn
+  iterations make doubly stochastic.
+
+`heads_held` and `experts_held` are a chip's share of a layer that several
+chips divide (tensor-parallel heads, expert-parallel experts): the
+attention output is then a partial sum over the held heads and the routed
+output a partial sum over the held experts, and nothing here stands in for
+the absent chips. None means all.
+
+Same compiled-trainer machinery as gpt.py / llama.py: parameters of like
+layers stacked on a leading axis and scanned under whole-block
+`jax.checkpoint`, bf16 compute with fp32 master weights
+(`trainer.build_adamw_train_step`); RoPE, RMSNorm and SwiGLU are llama.py's.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+
+from ..ops import moe
+from . import stages
+from .gpt import _use_flash_kernel
+from .llama import _rms, _rope, _swiglu
+from .trainer import build_adamw_train_step
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass
+class MlaMoeConfig:
+    vocab_size: int = 131072
+    hidden_size: int = 3584
+    num_layers: int = 40
+    first_k_dense: int = 2                    # leading dense layers
+    num_heads: int = 32                       # published
+    heads_held: Optional[int] = None          # on this chip; None = all
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 9216             # dense layers' MLP
+    moe_intermediate_size: int = 1024         # one expert
+    n_routed_experts: int = 64                # the router's outputs
+    experts_held: Optional[Tuple[int, int]] = None   # (first, count)
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 2.0
+    hc_mult: int = 4                          # residual streams
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None       # yarn's keys, as published
+    initializer_range: float = 0.02
+    use_flash_attention: bool = True
+    dtype: str = "bfloat16"
+
+    @property
+    def heads(self) -> int:
+        return self.heads_held or self.num_heads
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_routed_experts)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def sparse_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+
+# ------------------------------------------------------------------- yarn
+
+def yarn_inv_freq(dim: int, base: float, scaling: Optional[dict]):
+    """Rotary inverse frequencies [dim/2] under yarn (Peng et al. 2023, as
+    DeepSeek-V2 computes them): below `low` a component keeps its frequency,
+    above `high` it is divided by `factor`, between them a linear ramp."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    extra = base ** (-2.0 * i / dim)
+    if not scaling:
+        return extra.astype(np.float32)
+    factor = scaling["factor"]
+    original = scaling["original_max_position_embeddings"]
+
+    def corr(rotations):
+        return dim * math.log(original / (2 * math.pi * rotations)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(corr(scaling["beta_fast"])), 0)
+    high = min(math.ceil(corr(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def _mscale(factor: float, a: float) -> float:
+    return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def attention_scale(config: MlaMoeConfig) -> float:
+    """qk_head_dim^-1/2 times yarn's mscale(factor, mscale_all_dim)^2. (The
+    other half of yarn's magnitude correction scales cos and sin by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), which is 1
+    where the two are equal, as in every published config of the family;
+    another ratio is refused rather than dropped.)"""
+    scale = config.qk_head_dim ** -0.5
+    s = config.rope_scaling
+    if s:
+        if s.get("mscale", 1) != s.get("mscale_all_dim", 1):
+            raise NotImplementedError(
+                "yarn with mscale != mscale_all_dim scales cos and sin")
+        scale *= _mscale(s["factor"], s.get("mscale_all_dim", 1)) ** 2
+    return scale
+
+
+# ----------------------------------------------------------------- params
+
+def _init_hc(key, layers: int, c: MlaMoeConfig):
+    """One sub-layer's stream-mixing parameters, float32. phi's columns are
+    (pre: n | post: n | res: n*n). Seeded around the hyper-connections
+    start: alpha 0.01; H_res near the identity (b_res = 2 I + noise), H_post
+    near 1 (b_post = noise), H_pre a seeded mixture (b_pre = noise); the
+    noise keeps Sinkhorn and the mixtures doing work from step 0."""
+    n, h = c.hc_mult, c.hidden_size
+    k = jax.random.split(key, 4)
+    f32 = jnp.float32
+    return {
+        "norm_g": jnp.ones((layers, n * h), f32),
+        "phi": jax.random.normal(k[0], (layers, n * h, 2 * n + n * n), f32)
+        * c.initializer_range,
+        "alpha": jnp.full((layers, 3), 0.01, f32),
+        "b_pre": jax.random.normal(k[1], (layers, n), f32),
+        "b_post": jax.random.normal(k[2], (layers, n), f32) * 0.5,
+        "b_res": 2.0 * jnp.eye(n, dtype=f32)
+        + jax.random.normal(k[3], (layers, n, n), f32) * 0.5,
+    }
+
+
+def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
+    h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
+    out_std = std / math.sqrt(2 * c.num_layers)
+    heads, dn, dr, dv = (c.heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                         c.v_head_dim)
+    ks = iter(jax.random.split(key, 16))
+
+    def norm(shape, scale=std, dtype=dt):
+        return (jax.random.normal(next(ks), (layers,) + shape, jnp.float32)
+                * scale).astype(dtype)
+
+    p = {
+        "ln1_g": jnp.ones((layers, h), dt),
+        "q_a_w": norm((h, c.q_lora_rank)),
+        "q_a_ln": jnp.ones((layers, c.q_lora_rank), dt),
+        "q_b_w": norm((c.q_lora_rank, heads * (dn + dr))),
+        "kv_a_w": norm((h, c.kv_lora_rank + dr)),
+        "kv_a_ln": jnp.ones((layers, c.kv_lora_rank), dt),
+        "kv_b_w": norm((c.kv_lora_rank, heads * (dn + dv))),
+        "o_w": norm((heads * dv, h), out_std),
+        "ln2_g": jnp.ones((layers, h), dt),
+        "hc_attn": _init_hc(next(ks), layers, c),
+        "hc_ffn": _init_hc(next(ks), layers, c),
+    }
+    if not sparse:
+        f = c.intermediate_size
+        p.update(gate_w=norm((h, f)), up_w=norm((h, f)),
+                 down_w=norm((f, h), out_std))
+        return p
+    f, n = c.moe_intermediate_size, c.held[1]
+    fs = f * c.n_shared_experts
+    p.update(
+        # the router is float32, as the family's checkpoints keep it; its
+        # selection bias starts at zero and no gradient reaches it
+        router_w=norm((h, c.n_routed_experts), dtype=jnp.float32),
+        router_b=jnp.zeros((layers, c.n_routed_experts), jnp.float32),
+        shared_gate_w=norm((h, fs)), shared_up_w=norm((h, fs)),
+        shared_down_w=norm((fs, h), out_std),
+        experts={"gate_w": norm((n, h, f)), "up_w": norm((n, h, f)),
+                 "down_w": norm((n, f, h), out_std)})
+    return p
+
+
+def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
+    """Parameters as a pytree: the leading dense layers stacked under
+    "dense", the sparse layers under "sparse" (the scan layouts), an
+    untied embedding and head."""
+    c = config
+    dt, std = jnp.dtype(c.dtype), c.initializer_range
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def norm(key, shape):
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)
+
+    return {
+        "wte": norm(k[0], (c.vocab_size, c.hidden_size)),
+        "dense": _init_layers(k[1], c.first_k_dense, c, sparse=False),
+        "sparse": _init_layers(k[2], c.sparse_layers, c, sparse=True),
+        "lnf_g": jnp.ones((c.hidden_size,), dt),
+        "lm_head": norm(k[3], (c.vocab_size, c.hidden_size)),
+    }
+
+
+def wd_mask(params) -> Dict:
+    """Weight decay on the matrices (`*_w`, `phi`) and the embeddings; none
+    on norm gains, the router's bias, or the mixing's scalars and biases."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: path[-1].key in ("wte", "lm_head", "phi")
+        or path[-1].key.endswith("_w"), params)
+
+
+def count_params(config: MlaMoeConfig) -> Dict[str, int]:
+    """Parameters held here, by group."""
+    shapes = jax.eval_shape(lambda: init_mla_moe_params(config, 0))
+
+    def size(tree):
+        return sum(math.prod(a.shape)
+                   for a in jax.tree_util.tree_leaves(tree))
+
+    out = {"embedding_and_head": size(shapes["wte"])
+           + size(shapes["lm_head"]),
+           "dense_layers": size(shapes["dense"]),
+           "sparse_layers": size(shapes["sparse"]),
+           "routed_experts": size(shapes["sparse"]["experts"])}
+    out["total"] = size(shapes)
+    return out
+
+
+# ------------------------------------------------------- residual streams
+
+def sinkhorn(m, iters: int, eps: float):
+    """m [n, n, ...] positive -> doubly stochastic over its first two axes:
+    `iters` times, divide each row (axis 1 summed) by its sum + eps, then
+    each column. The token axes stay minor, so a row sum is a few
+    elementwise adds and no cross-lane reduction."""
+    for _ in range(iters):
+        m = m / (m.sum(1, keepdims=True) + eps)
+        m = m / (m.sum(0, keepdims=True) + eps)
+    return m
+
+
+def mix_coefficients(x, hc, c: MlaMoeConfig):
+    """The streams x [n, B, S, h] -> (H_pre [n, B, S], H_post [n, B, S],
+    H_res [n, n, B, S]) in float32: per token, u = RMSNorm(vec(x)); three
+    projections of u with their scales and biases; a sigmoid, twice a
+    sigmoid, and Sinkhorn of the clipped exponential."""
+    n = c.hc_mult
+    xf = x.astype(jnp.float32)
+    rinv = jax.lax.rsqrt((xf * xf).mean((0, 3), keepdims=True) + c.hc_eps)
+    u = xf * rinv * hc["norm_g"].reshape(n, 1, 1, -1)
+    proj = jnp.einsum("nbsh,nhk->kbs", u,
+                      hc["phi"].reshape(n, c.hidden_size, -1),
+                      precision=_HIGHEST)                    # [2n+n*n, B, S]
+    a = hc["alpha"]
+    pre = a[0] * proj[:n] + hc["b_pre"][:, None, None]
+    post = a[1] * proj[n:2 * n] + hc["b_post"][:, None, None]
+    res = a[2] * proj[2 * n:].reshape((n, n) + proj.shape[1:]) \
+        + hc["b_res"][:, :, None, None]
+    lo, hi = c.hc_res_clamp
+    return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
+            sinkhorn(jnp.exp(jnp.clip(res, lo, hi)), c.hc_sinkhorn_iters,
+                     c.hc_eps))
+
+
+def _read_in(x, hc, c: MlaMoeConfig):
+    """x [n, B, S, h] -> (h_in [B, S, h] = sum_i H_pre[i] x[i], H_post,
+    H_res), elementwise in float32 (no product for the MXU to round)."""
+    with jax.named_scope(stages.RESIDUAL_MIX):
+        h_pre, h_post, h_res = mix_coefficients(x, hc, c)
+        h_in = sum(h_pre[j][..., None] * x[j].astype(jnp.float32)
+                   for j in range(c.hc_mult)).astype(x.dtype)
+        return h_in, h_post, h_res
+
+
+def _write_back(x, y, h_post, h_res, c: MlaMoeConfig):
+    """x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y."""
+    n = c.hc_mult
+    with jax.named_scope(stages.RESIDUAL_MIX):
+        streams = [x[j].astype(jnp.float32) for j in range(n)]
+        yf = y.astype(jnp.float32)
+        return jnp.stack([
+            sum(h_res[i, j][..., None] * streams[j] for j in range(n))
+            + h_post[i][..., None] * yf for i in range(n)]).astype(x.dtype)
+
+
+def _sublayer(x, hc, fn, c: MlaMoeConfig):
+    """x [n, B, S, h] -> x': y = fn(h_in) between the read-in and the
+    write-back. Both halves of the mixing are `jax.checkpoint`ed on their
+    own: their float32 copies of the four streams (0.47 GB each at 8,192
+    tokens) would otherwise all be kept for the layer's backward pass,
+    where now one half at a time recomputes its own from the bfloat16
+    streams. `fn` opens its own stages and returns (y, aux)."""
+    h_in, h_post, h_res = jax.checkpoint(
+        functools.partial(_read_in, c=c))(x, hc)
+    y, aux = fn(h_in)
+    return jax.checkpoint(functools.partial(_write_back, c=c))(
+        x, y, h_post, h_res), aux
+
+
+# ---------------------------------------------------------------- a block
+
+def _attention(y, blk, c: MlaMoeConfig):
+    """MLA on y [B, S, h] (already mixed in; its norm is here) -> the held
+    heads' part of the output projection, [B, S, h]."""
+    b, s, _ = y.shape
+    heads, dn, dr, dv = (c.heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                         c.v_head_dim)
+    eps = c.rms_norm_eps
+    with jax.named_scope(stages.ATTN_QKV):
+        y = _rms(y, blk["ln1_g"], eps)
+        c_q = _rms(jnp.einsum("bsh,hr->bsr", y, blk["q_a_w"]),
+                   blk["q_a_ln"], eps)
+        q = jnp.einsum("bsr,rk->bsk", c_q, blk["q_b_w"])
+        kv_a = jnp.einsum("bsh,hr->bsr", y, blk["kv_a_w"])
+        c_kv = _rms(kv_a[..., :c.kv_lora_rank], blk["kv_a_ln"], eps)
+        k_rope = kv_a[..., c.kv_lora_rank:]
+        kv = jnp.einsum("bsr,rk->bsk", c_kv, blk["kv_b_w"])
+    with jax.named_scope(stages.ATTN_CORE):
+        inv_freq = yarn_inv_freq(dr, c.rope_theta, c.rope_scaling)
+        q = q.reshape(b, s, heads, dn + dr)
+        q = jnp.concatenate(
+            [q[..., :dn], _rope(q[..., dn:], c.rope_theta, inv_freq)], -1)
+        k_rope = _rope(k_rope[:, :, None, :], c.rope_theta, inv_freq)
+        kv = kv.reshape(b, s, heads, dn + dv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, heads, dr))], -1)
+        qt, kt, vt = (jnp.swapaxes(a, 1, 2) for a in (q, k, kv[..., dn:]))
+        scale = attention_scale(c)
+        if _use_flash_kernel(c, s):
+            from ..ops.pallas.flash_attention import mha_forward
+            attn = mha_forward(qt, kt, vt, causal=True, scale=scale)
+        else:
+            logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * scale
+            mask = jnp.tril(jnp.ones((s, s), bool))
+            logits = jnp.where(mask, logits, jnp.array(-1e30, logits.dtype))
+            probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
+                y.dtype)
+            attn = jnp.einsum("bhqk,bhkd->bhqd", probs, vt)
+        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, heads * dv)
+    with jax.named_scope(stages.ATTN_OUT):
+        return jnp.einsum("bsk,kh->bsh", attn, blk["o_w"]), None
+
+
+def _dense_ffn(y, blk, c: MlaMoeConfig):
+    with jax.named_scope(stages.MLP):
+        y = _rms(y, blk["ln2_g"], c.rms_norm_eps)
+        return _swiglu(y, blk["gate_w"], blk["up_w"], blk["down_w"]), None
+
+
+def _sparse_ffn(y, blk, c: MlaMoeConfig):
+    """Shared expert + the held routed experts' part; aux = the router's
+    choices [B*S, k], over all the experts."""
+    b, s, h = y.shape
+    with jax.named_scope(stages.MLP):
+        y = _rms(y, blk["ln2_g"], c.rms_norm_eps)
+        shared = _swiglu(y, blk["shared_gate_w"], blk["shared_up_w"],
+                         blk["shared_down_w"])
+    flat = y.reshape(b * s, h)
+    with jax.named_scope(stages.ROUTER):
+        ids, weights = moe.sigmoid_topk_route(
+            flat, blk["router_w"], blk["router_b"], c.num_experts_per_tok,
+            c.routed_scaling_factor)
+    with jax.named_scope(stages.EXPERTS):
+        routed = moe.held_experts_ffn(flat, ids, weights, blk["experts"],
+                                      c.held)
+    with jax.named_scope(stages.MLP):
+        return shared + routed.reshape(b, s, h), ids
+
+
+def _block(x, blk, c: MlaMoeConfig, sparse: bool):
+    """One layer on the streams x [n, B, S, h] -> (x', router choices or
+    None): an attention sub-layer and a feed-forward one, each with its own
+    stream mixing."""
+    x, _ = _sublayer(x, blk["hc_attn"],
+                     functools.partial(_attention, blk=blk, c=c), c)
+    return _sublayer(x, blk["hc_ffn"], functools.partial(
+        _sparse_ffn if sparse else _dense_ffn, blk=blk, c=c), c)
+
+
+def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
+    """tokens [B, S] -> (final hidden [B, S, h], choices [L_sparse, T, k] or
+    None): the embedding copied to the n streams, the dense layers, the
+    sparse layers, the streams summed."""
+    with jax.named_scope(stages.EMBED):
+        x = params["wte"][tokens].astype(jnp.dtype(c.dtype))
+        x = jnp.broadcast_to(x, (c.hc_mult,) + x.shape)
+    ids = None
+    for group, sparse in (("dense", False), ("sparse", True)):
+        fn = functools.partial(_block, c=c, sparse=sparse)
+        if remat:
+            fn = jax.checkpoint(fn)
+
+        def body(carry, blk, fn=fn, keep=want_ids and sparse):
+            carry, chosen = fn(carry, blk)
+            return carry, chosen if keep else None
+
+        x, chosen = jax.lax.scan(body, x, params[group])
+        ids = chosen if sparse else ids
+    with jax.named_scope(stages.LOSS_HEAD):
+        x = x.astype(jnp.float32).sum(0).astype(x.dtype)
+        return _rms(x, params["lnf_g"], c.rms_norm_eps), ids
+
+
+def mla_moe_forward(params, tokens, config: MlaMoeConfig, remat=True):
+    """tokens [B, S] int32 -> logits [B, S, V] over the held rows."""
+    x, _ = _trunk(params, tokens, config, remat, want_ids=False)
+    with jax.named_scope(stages.LOSS_HEAD):
+        return jnp.einsum("bsh,vh->bsv", x, params["lm_head"])
+
+
+def mla_moe_loss(params, tokens, labels, config: MlaMoeConfig, remat=True):
+    """Mean next-token cross-entropy in float32."""
+    logits = mla_moe_forward(params, tokens, config, remat)
+    with jax.named_scope(stages.LOSS_HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        picked = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
+        return -picked.mean()
+
+
+def routing_stats(params, tokens, config: MlaMoeConfig):
+    """Per sparse layer, the (token, expert) pairs each of ALL the routed
+    experts drew on this batch: int32 [L_sparse, n_routed_experts]. Jit it;
+    it runs the forward pass."""
+    _, ids = _trunk(params, tokens, config, remat=False, want_ids=True)
+    return (ids[..., None] == jnp.arange(config.n_routed_experts)).sum(
+        (1, 2)).astype(jnp.int32)
+
+
+def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None,
+                     lr: float = 3e-4, wd: float = 0.1, b1: float = 0.9,
+                     b2: float = 0.95, remat: bool = True):
+    """(init_fn, step): step(state, tokens, labels) -> (state, loss) is ONE
+    compiled XLA program (forward, backward through the rematted scans,
+    AdamW), through `trainer.build_adamw_train_step` as gpt.py's. One chip's
+    share runs without its exchange; a mesh of several chips needs an `ep`
+    axis and the all-to-all, which the trainer does not have yet."""
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "mla_moe.build_train_step runs one chip's share; experts over "
+            "an 'ep' mesh axis are not implemented")
+    init_params = functools.partial(init_mla_moe_params, config)
+    shapes = jax.eval_shape(lambda: init_params(0))
+    specs = jax.tree_util.tree_map(lambda _: P(), shapes)
+    return build_adamw_train_step(
+        functools.partial(mla_moe_loss, config=config, remat=remat),
+        init_params, specs, wd_mask(shapes), mesh=mesh, lr=lr, wd=wd,
+        b1=b1, b2=b2)

@@ -1,13 +1,24 @@
-"""Mixture-of-Experts functional core (TPU-native).
+"""Mixture-of-Experts functional core (TPU-native). Two paths:
 
-The reference's MoE stack (incubate/distributed/models/moe/moe_layer.py:261,
-gates under moe/gate/, all-to-all dispatch via global_scatter/global_gather,
-fused kernel incubate/nn/functional/fused_moe.py) is CUDA-centric: ragged
-token dispatch with index scatter/gather. On TPU the idiomatic form is the
-GShard/Switch dense-dispatch formulation: fixed expert capacity C, one-hot
-dispatch/combine tensors, and einsum dispatch so everything is static-shaped
-and lands on the MXU; under GSPMD an 'ep'-sharded expert dim lowers the
-dispatch einsums to the same all-to-all the reference issues by hand.
+**Capacity path** (`top1_gating`, `top2_gating`, `moe_dispatch`,
+`moe_combine`, `moe_ffn`): the GShard/Switch dense-dispatch formulation for
+the eager `MoELayer` (incubate/distributed/models/moe) and the registered
+ops. The reference's MoE stack (moe_layer.py:261, gates under moe/gate/,
+all-to-all dispatch via global_scatter/global_gather, fused kernel
+incubate/nn/functional/fused_moe.py) is CUDA-centric: ragged token dispatch
+with index scatter/gather. Here: fixed expert capacity C, one-hot
+dispatch/combine tensors [S, E, C] and einsum dispatch, so everything is
+static-shaped and lands on the MXU; tokens over capacity are dropped; under
+GSPMD an 'ep'-sharded expert dim lowers the dispatch einsums to the same
+all-to-all the reference issues by hand. Top-1 and top-2 only.
+
+**Dropless path** (`sigmoid_topk_route`, `held_experts_ffn`): what the
+compiled trainer's sparse families use (models/mla_moe.py). Any k, no
+capacity, no dropped pair, no [S, E, C] tensor: the (token, expert) pairs
+are sorted by expert and run through grouped products
+(`jax.lax.ragged_dot`, a Mosaic grouped matmul on a TPU). The layer is told
+which experts it holds, as expert parallelism tells it: it routes over all
+E and computes the part of the result its own experts give.
 
 Shapes: tokens x [S, M] (leading group/batch dims folded by callers),
 logits [S, E], dispatch/combine [S, E, C], expert weights stacked [E, ...].
@@ -16,6 +27,7 @@ jit/pjit.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -146,6 +158,205 @@ def moe_ffn(x, gate_w, w0, b0, w1, b1, *, k: int = 2,
         + b1[:, None, :].astype(x.dtype)
     out = moe_combine(ye, combine.astype(x.dtype))
     return out, aux.astype(jnp.float32)
+
+
+# ----------------------------------------------------------- dropless path
+
+def sigmoid_topk_route(x, w_r, b, k: int, scale: float):
+    """Sigmoid top-k routing with a selection bias (DeepSeek-V3's
+    `noaux_tc` with one group), in float32.
+
+    x [T, M], w_r [M, E], b [E] -> (ids [T, k] int32, weights [T, k]
+    float32). Scores s = sigmoid(x w_r); the k experts with the largest
+    s + b are chosen (b steers the choice only: its load-driven update is a
+    training recipe, and no gradient reaches it); the weights are the
+    chosen s, normalised to sum 1, times `scale`. The product is a true
+    float32 one (HIGHEST: a TPU's default rounds float32 operands to
+    bfloat16, and a near-tie between two experts flips on that)."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               w_r.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(s + b.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    return ids.astype(jnp.int32), w
+
+
+CHUNKS = 4      # the sorted pairs are run in this many chunks of rows
+
+
+def _gather_sum(rows, pos, live, weights=None):
+    """sum_j [live[:, j]] weights[:, j] * rows[pos[:, j]] in float32, [T, M]:
+    k gathers of T rows each, so no [T, k, M] array is ever formed."""
+    out = 0
+    for j in range(pos.shape[1]):
+        mine = jnp.where(live[:, j, None], rows[pos[:, j]], 0).astype(
+            jnp.float32)
+        out = out + (mine if weights is None else mine * weights[:, j, None])
+    return out
+
+
+@jax.custom_vjp
+def _dispatch(x, token, pos, live):
+    """x [T, M] -> a chunk's rows x[token], [C, M]. `pos` [T, k] is where
+    each of a token's k pairs sits in the chunk and `live` whether it sits
+    there at all (in this chunk, and a pair of a held expert): the
+    backward sums a token's live rows, a gather too, so no scatter runs in
+    either direction. Rows that are not live belong to pairs of experts
+    held elsewhere; the grouped products never touch them, so what comes
+    back for them is not a gradient and is left out."""
+    return x[token]
+
+
+def _dispatch_fwd(x, token, pos, live):
+    return x[token], (pos, live)
+
+
+def _dispatch_bwd(res, d_rows):
+    pos, live = res
+    return (_gather_sum(d_rows, pos, live).astype(d_rows.dtype), None, None,
+            None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weights, pair, pos, live):
+    """A chunk's rows [C, M] (row r is pair `pair[r]` = token * k + j),
+    weights [T, k] -> float32 [T, M]: each token gathers its live pairs'
+    rows and sums them by weight."""
+    return _gather_sum(rows, pos, live, weights)
+
+
+def _combine_fwd(rows, weights, pair, pos, live):
+    return _gather_sum(rows, pos, live, weights), (rows, weights, pair, pos,
+                                                   live)
+
+
+def _combine_bwd(res, d_out):
+    """In sorted space: a row's gradient is its token's d_out times the
+    pair's weight, and the weight's gradient the row's product with that
+    d_out. (A row that is not live was zeroed before it came here, and
+    that `where` stops what this returns for it.)"""
+    rows, weights, pair, pos, live = res
+    mine = d_out[pair // weights.shape[1]]                      # [C, M]
+    d_rows = (mine * weights.reshape(-1)[pair][:, None]).astype(rows.dtype)
+    d_sorted = (mine * rows.astype(jnp.float32)).sum(-1)        # [C]
+    d_weights = jnp.where(live, d_sorted[pos], 0).astype(weights.dtype)
+    return d_rows, d_weights, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _chunk(x, weights, experts, order, where, starts, ends, lo, rows):
+    """The chunk of `rows` sorted pairs from `lo` through the held experts:
+    float32 [T, M], the weighted sum of its live rows' outputs at their
+    tokens."""
+    pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    pos = where - lo
+    live = (pos >= 0) & (pos < rows) & (where < ends[-1])
+    pos = jnp.clip(pos, 0, rows - 1)
+    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    mine = _dispatch(x, pair // weights.shape[1], pos, live)    # [C, M]
+    gate = jax.lax.ragged_dot(mine, experts["gate_w"], sizes)
+    up = jax.lax.ragged_dot(mine, experts["up_w"], sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["down_w"],
+                             sizes)
+    valid = jnp.arange(rows) < sizes.sum()
+    return _combine(jnp.where(valid[:, None], out, 0), weights, pair, pos,
+                    live)
+
+
+def _chunk_starts(order):
+    pairs = order.shape[0]
+    chunks = CHUNKS if pairs % (CHUNKS * 8) == 0 else 1
+    rows = pairs // chunks
+    return jnp.arange(chunks, dtype=jnp.int32) * rows, rows
+
+
+@jax.custom_vjp
+def _chunks(x, weights, experts, order, where, starts, ends):
+    """Every chunk that holds a held pair, summed: float32 [T, M]. The
+    backward is written out (one chunk at a time, recomputed in the branch
+    that runs it): differentiating the `cond` would hand each branch's
+    operands, the expert matrices among them, back once a chunk."""
+    los, rows = _chunk_starts(order)
+
+    def body(out, lo):
+        return jax.lax.cond(
+            lo < ends[-1],
+            lambda out: out + _chunk(x, weights, experts, order, where,
+                                     starts, ends, lo, rows),
+            lambda out: out, out), None
+
+    return jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32), los)[0]
+
+
+def _chunks_fwd(*args):
+    return _chunks(*args), args
+
+
+def _chunks_bwd(res, d_out):
+    x, weights, experts, order, where, starts, ends = res
+    los, rows = _chunk_starts(order)
+    add = functools.partial(jax.tree_util.tree_map, jnp.add)
+
+    def body(grads, lo):
+        def active(grads):
+            _, vjp = jax.vjp(
+                lambda x, weights, experts: _chunk(
+                    x, weights, experts, order, where, starts, ends, lo,
+                    rows), x, weights, experts)
+            return add(grads, vjp(d_out))
+
+        return jax.lax.cond(lo < ends[-1], active, lambda g: g, grads), None
+
+    grads, _ = jax.lax.scan(
+        body, jax.tree_util.tree_map(jnp.zeros_like, (x, weights, experts)),
+        los)
+    return grads + (None, None, None, None)
+
+
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
+
+
+def held_experts_ffn(x, ids, weights, experts, held):
+    """The part of a routed-experts layer that the experts held here give.
+
+    x [T, M]; ids, weights [T, k] from a router over ALL experts;
+    `experts` the SwiGLU matrices of the held ones, stacked: gate_w, up_w
+    [n, M, F], down_w [n, F, M]; `held` = (first, count): experts first ..
+    first + count - 1 are here. Returns sum over the pairs that chose a
+    held expert of weight * down(silu(gate x) * up x), [T, M]; what the
+    experts held elsewhere would add is left out (expert parallelism adds
+    it in its exchange, which this function does not stand in for).
+
+    Dropless with static shapes, and no capacity. The T*k pairs are sorted
+    so that the pairs of held experts come first, grouped by expert, and
+    the rest last; the sorted order is cut into `CHUNKS` chunks of
+    T*k / CHUNKS rows, and a chunk runs (gather, three grouped products
+    over its part of each expert's group, weighted combine) only if a held
+    pair lies in it. Balanced routing to an eighth of the experts fills
+    half of the first chunk and the other three cost a branch not taken;
+    a router that sends every token to held experts fills all of them, and
+    no pair is dropped either way. Buffers have a chunk's rows. Within a
+    running chunk a pair that chose no held expert costs no product (the
+    grouped products stop at the held pairs) but does cost its row of the
+    gather and the elementwise pass. Gradients reach x, the weights and
+    the expert matrices; ids are integers."""
+    first, count = held
+    t, k = ids.shape
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)    # [T*k]
+    where = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+    ends = jnp.cumsum((key[:, None] == jnp.arange(count)).sum(0)).astype(
+        jnp.int32)                              # each held group's end
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+    return _chunks(x, weights.astype(jnp.float32), experts, order, where,
+                   starts, ends).astype(x.dtype)
 
 
 # -------------------------------------------------- eager op registration

@@ -9,7 +9,10 @@ score tile once from the saved log-sum-exp rows and returns dQ, dK and dV
 (five products a tile; dQ is summed across key blocks in VMEM).
 
 Layout inside the kernels is [batch*heads, seq, head_dim]; the public entry
-takes paddle's [batch, seq, heads, head_dim]. Every product accumulates in
+takes paddle's [batch, seq, heads, head_dim]. Two widths: q and k share
+`d` (written `d_qk` where both appear), v, the output and its gradient have
+`d_v`, read from v's shape; `d_v = d_qk` is ordinary multi-head attention,
+latent attention has 192 and 128. Every product accumulates in
 fp32 on the MXU (preferred_element_type) from operands of the IO dtype,
 which is whatever the caller passes (bf16 on TPU): p and ds are rounded to
 it once, the softmax math between the products is fp32. On a TPU the
@@ -66,8 +69,9 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
     return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
 
 
-def vmem_footprint(sq: int, sk: int, d: int, dtype) -> dict:
-    """Scoped-VMEM bytes each kernel needs with its operands in HBM. The
+def vmem_footprint(sq: int, sk: int, d: int, dtype, d_v: int = None) -> dict:
+    """Scoped-VMEM bytes each kernel needs with its operands in HBM; `d` is
+    the width of q and k, `d_v` that of v and the output (`d` if None). The
     pipeline double-buffers every in/out block of the BlockSpecs in
     `_fwd_call` / `_bwd`; for the forward that is all, and it agrees with
     the compiler's own "scoped allocation" figure to its printed precision
@@ -77,55 +81,60 @@ def vmem_footprint(sq: int, sk: int, d: int, dtype) -> dict:
     compiler's own choices move its figure by a MiB either way), under
     which the v5e ahead-of-time compiler accepted every length up to the
     cap in steps of 512 (bf16 and fp32, d 64-256)."""
+    dv = d if d_v is None else d_v
     bq, bk = _block_sizes(sq, sk, d)
     blk = functools.partial(_vmem_block_bytes, dtype=dtype)
     f32 = functools.partial(_vmem_block_bytes, dtype=jnp.float32)
     return {
-        "fwd": 2 * (2 * blk(bq, d) + 2 * blk(sk, d) + f32(bq, 1)),
-        "bwd": (2 * (3 * blk(sq, d) + 4 * blk(bk, d)
-                     + 2 * (sq // bq) * f32(1, bq))
-                + f32(d, sq) + 3 * f32(bk, bq) + 2 * f32(bk, d)),
+        # q, o; k, v; the lse row
+        "fwd": 2 * (blk(bq, d) + blk(bq, dv) + blk(sk, d) + blk(sk, dv)
+                    + f32(bq, 1)),
+        # q, dq, do; k, dk, v, dv; the lse and delta rows
+        "bwd": (2 * (2 * blk(sq, d) + blk(sq, dv) + 2 * blk(bk, d)
+                     + 2 * blk(bk, dv) + 2 * (sq // bq) * f32(1, bq))
+                + f32(d, sq) + 3 * f32(bk, bq) + f32(bk, d) + f32(bk, dv)),
     }
 
 
-def _fits(sq: int, sk: int, d: int, dtype, backward: bool):
+def _fits(sq: int, sk: int, d: int, dtype, backward: bool, d_v: int = None):
     """Name of the first kernel that does not fit, or None."""
-    need = vmem_footprint(sq, sk, d, dtype)
+    need = vmem_footprint(sq, sk, d, dtype, d_v)
     for kernel in ("fwd", "bwd") if backward else ("fwd",):
         if need[kernel] >= SCOPED_VMEM_BYTES:
             return kernel, need[kernel]
     return None
 
 
-def max_seq(d: int, dtype, backward: bool) -> int:
+def max_seq(d: int, dtype, backward: bool, d_v: int = None) -> int:
     """Longest self-attention sequence (a multiple of 512) whose kernels
-    fit: forward only, or forward and backward."""
+    fit at q/k width `d` and v width `d_v` (`d` if None): forward only, or
+    forward and backward."""
     s = 0
-    while _fits(s + 512, s + 512, d, dtype, backward) is None:
+    while _fits(s + 512, s + 512, d, dtype, backward, d_v) is None:
         s += 512
     return s
 
 
-def _check_vmem(q, k, backward: bool):
+def _check_vmem(q, k, v, backward: bool):
     """Raise the named limit before Mosaic raises RESOURCE_EXHAUSTED.
     The compiler sometimes fits more by keeping a small operand in VMEM
     itself (it depends on batch*heads); that is not a length to rely on."""
     if pallas_interpret():
         return
     sq, d = q.shape[-2:]
-    sk = k.shape[-2]
-    over = _fits(sq, sk, d, q.dtype, backward)
+    sk, dv = k.shape[-2], v.shape[-1]
+    over = _fits(sq, sk, d, q.dtype, backward, dv)
     if over:
         kernel, need = over
         raise FlashSequenceLimitError(
             f"flash attention {kernel} kernel at seq_q {sq}, seq_k {sk}, "
-            f"head_dim {d}, {jnp.dtype(q.dtype).name} needs "
-            f"{need / 2**20:.2f} MiB of scoped VMEM; the limit is "
+            f"head_dim {d} (q, k) and {dv} (v), {jnp.dtype(q.dtype).name} "
+            f"needs {need / 2**20:.2f} MiB of scoped VMEM; the limit is "
             f"{SCOPED_VMEM_BYTES >> 20} MiB because K/V (Q, dO and dQ in the "
             "backward) stay whole-sequence resident. Longest self-attention "
-            f"sequence at this head_dim and dtype: "
-            f"{max_seq(d, q.dtype, False)} forward only, "
-            f"{max_seq(d, q.dtype, True)} with the backward")
+            f"sequence at these widths and dtype: "
+            f"{max_seq(d, q.dtype, False, dv)} forward only, "
+            f"{max_seq(d, q.dtype, True, dv)} with the backward")
 
 
 def _block_sizes(sq: int, sk: int, d: int):
@@ -146,8 +155,9 @@ def _block_sizes(sq: int, sk: int, d: int):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
                 block_k, kv_len, q_offset):
     qi = pl.program_id(1)
-    q = q_ref[0]                                    # [bq, d]
-    bq, d = q.shape
+    q = q_ref[0]                                    # [bq, d_qk]
+    bq = q.shape[0]
+    dv = v_ref.shape[2]
     sk_pad = k_ref.shape[1]
     nkb = sk_pad // block_k
 
@@ -155,8 +165,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
 
     def body(j, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]          # [bk, d]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]          # [bk, d_qk]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]          # [bk, d_v]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # [bq, bk]
@@ -177,7 +187,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
 
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    acc0 = jnp.zeros((bq, dv), jnp.float32)
     if causal:
         # keys beyond the last valid diagonal block never contribute
         last = (qi * bq + bq - 1) + q_offset
@@ -194,16 +204,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
 
 def _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, q_offset):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = v.shape[1:]
     grid = (bh, sq // block_q)
     with _no_x64():
         out, lse = _fwd_call(q, k, v, causal, scale, block_k, kv_len,
-                             q_offset, block_q, grid, bh, sq, sk, d)
+                             q_offset, block_q, grid, bh, sq, sk, d, dv)
     return out, lse
 
 
 def _fwd_call(q, k, v, causal, scale, block_k, kv_len, q_offset, block_q,
-              grid, bh, sq, sk, d):
+              grid, bh, sq, sk, d, dv):
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
                           block_k=block_k, kv_len=kv_len, q_offset=q_offset),
@@ -211,14 +221,14 @@ def _fwd_call(q, k, v, causal, scale, block_k, kv_len, q_offset, block_q,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, sk, dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         interpret=pallas_interpret(),
@@ -243,8 +253,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     axis in VMEM, transposed ([d, sq]: the one transposed product then
     turns the narrow k, not the tile) and written at the last key block."""
     kj = pl.program_id(1)
-    k = k_ref[0]                                    # [bk, d]
-    v = v_ref[0]
+    k = k_ref[0]                                    # [bk, d_qk]
+    v = v_ref[0]                                    # [bk, d_v]
     bk, d = k.shape
     nqb = q_ref.shape[1] // block_q
     dot = functools.partial(jax.lax.dot_general,
@@ -260,8 +270,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def tile(masked, i, carry):
         dk, dv = carry
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
-        q = q_ref[0, rows, :]                       # [bq, d]
-        do = do_ref[0, rows, :]
+        q = q_ref[0, rows, :]                       # [bq, d_qk]
+        do = do_ref[0, rows, :]                     # [bq, d_v]
         # scaled on the float32 tile, as the forward does: lse is of that s
         st = dot(k, q, _NT) * scale                 # [bk, bq]
         pt = jnp.exp(st - lse_ref[0, i])
@@ -270,12 +280,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             pt = jnp.where(
                 k_minus_q <= i * block_q + q_offset - kj * bk, pt, 0.0)
         dst = (pt * (dot(v, do, _NT) - delta_ref[0, i])).astype(q.dtype)
-        dv = dv + dot(pt.astype(do.dtype), do, _NN)             # [bk, d]
-        dk = dk + dot(dst, q, _NN)
+        dv = dv + dot(pt.astype(do.dtype), do, _NN)             # [bk, d_v]
+        dk = dk + dot(dst, q, _NN)                              # [bk, d_qk]
         dqt_acc[:, rows] += dot(k, dst, _TN)                    # [d, bq]
         return dk, dv
 
-    carry = (jnp.zeros((bk, d), jnp.float32),) * 2
+    carry = (jnp.zeros((bk, d), jnp.float32),
+             jnp.zeros((bk, v.shape[1]), jnp.float32))
     if causal:
         # query blocks before `first` see none of this key block; from
         # `full` on they see all of it and the compare is left out
@@ -304,25 +315,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, q_offset):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = v.shape[1:]
     nqb = sq // block_q
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                                    # [bh, sq]
     # Q and dO stay whole-sequence resident; lse and delta come as one
     # lane-dense row per query block (a [sq, 1] block pads to 128 lanes)
     full_q = pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0))
+    full_do = pl.BlockSpec((1, sq, dv), lambda b, j: (b, 0, 0))
     full_row = pl.BlockSpec((1, nqb, 1, block_q), lambda b, j: (b, 0, 0, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))
+    vspec = pl.BlockSpec((1, block_k, dv), lambda b, j: (b, j, 0))
     with _no_x64():
         return pl.pallas_call(
             functools.partial(_bwd_kernel, causal=causal, scale=scale,
                               block_q=block_q, q_offset=q_offset),
             grid=(bh, sk // block_k),
-            in_specs=[full_q, kspec, kspec, full_q, full_row, full_row],
-            out_specs=[full_q, kspec, kspec],
+            in_specs=[full_q, kspec, vspec, full_do, full_row, full_row],
+            out_specs=[full_q, kspec, vspec],
             out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                        jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
+                       jax.ShapeDtypeStruct((bh, sk, dv), v.dtype)],
             scratch_shapes=[pltpu.VMEM((d, sq), jnp.float32)],
             # dQ's block is revisited along the key axis
             compiler_params=pltpu.CompilerParams(
@@ -336,7 +349,7 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, q_offset):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _mha(q, k, v, causal, scale):
-    _check_vmem(q, k, backward=False)
+    _check_vmem(q, k, v, backward=False)
     return _fwd_res(q, k, v, causal, scale)[0]
 
 
@@ -350,7 +363,7 @@ def _fwd_res(q, k, v, causal, scale):
 
 
 def _mha_fwd(q, k, v, causal, scale):
-    _check_vmem(q, k, backward=True)
+    _check_vmem(q, k, v, backward=True)
     return _fwd_res(q, k, v, causal, scale)
 
 
@@ -370,18 +383,20 @@ def mha_forward(q, k, v, causal=False, scale=None):
     """Differentiable blocked attention on [BH or B,H fused, S, D] arrays.
 
     Accepts [B, H, S, D] or [BH, S, D]; returns the same rank it was given.
+    v may have another last dimension than q and k (latent attention:
+    192 and 128); the output has v's. The default scale is q's.
     """
     squeeze = q.ndim == 4
     if squeeze:
         b, h, sq, d = q.shape
         q = q.reshape(b * h, sq, d)
         k = k.reshape(b * h, k.shape[2], d)
-        v = v.reshape(b * h, v.shape[2], d)
+        v = v.reshape(b * h, v.shape[2], v.shape[3])
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     out = _mha(q, k, v, bool(causal), float(scale))
     if squeeze:
-        out = out.reshape(b, h, sq, d)
+        out = out.reshape(b, h, sq, out.shape[-1])
     return out
 
 
@@ -391,9 +406,9 @@ def _fa_kernel_body(q, k, v, causal, scale):
     sk = k.shape[1]
     qt = jnp.swapaxes(q, 1, 2).reshape(b * h, sq, d)
     kt = jnp.swapaxes(k, 1, 2).reshape(b * h, sk, d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * h, sk, d)
+    vt = jnp.swapaxes(v, 1, 2).reshape(b * h, sk, v.shape[-1])
     out = _mha(qt, kt, vt, causal, scale)
-    return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2)
+    return jnp.swapaxes(out.reshape(b, h, sq, v.shape[-1]), 1, 2)
 
 
 def flash_attention(query, key, value, causal=False, scale=None):

@@ -66,6 +66,21 @@ def test_flash_compiles(v5e, shape, backward):
     _compile(v5e, fn, *[(shape, jnp.bfloat16)] * 3)
 
 
+# latent attention: q and k of width 192 (128 + 64 rotary), v of 128; the
+# benchmark's 4 rows x 4 held heads at 2048
+@pytest.mark.parametrize("backward", [False, True])
+def test_flash_compiles_at_two_widths(v5e, backward):
+    def fwd(q, k, v):
+        return fa.mha_forward(q, k, v, causal=True)
+
+    fn = jax.grad(lambda q, k, v: _sum32(fwd(q, k, v)), argnums=(0, 1, 2)) \
+        if backward else fwd
+    compiled = _compile(v5e, fn, ((16, 2048, 192), jnp.bfloat16),
+                        ((16, 2048, 192), jnp.bfloat16),
+                        ((16, 2048, 128), jnp.bfloat16))
+    assert "bf16[16,2048,128]" in compiled.as_text()
+
+
 def test_flash_varlen_compiles(v5e):
     t, h, d, nseq = 4096, 8, 64, 5
 
