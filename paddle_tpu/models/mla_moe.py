@@ -14,7 +14,8 @@ manifold-constrained hyper-connections, arXiv:2512.24880):
   chip HOLDS (`experts_held`, dropless, ops/moe.py) and a shared expert.
 - `hc_mult` residual streams: every sub-layer reads a learned mixture of the
   streams and writes back through a per-token matrix that Sinkhorn
-  iterations make doubly stochastic.
+  iterations make doubly stochastic (ops/pallas/stream_mix.py: four
+  kernels, each one pass over the streams).
 
 `heads_held` and `experts_held` are a chip's share of a layer that several
 chips divide (tensor-parallel heads, expert-parallel experts): the
@@ -40,13 +41,11 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import moe
+from ..ops.pallas import stream_mix
 from . import stages
 from .gpt import _use_flash_kernel
 from .llama import _rms, _rope, _swiglu
 from .trainer import build_adamw_train_step
-
-_HIGHEST = jax.lax.Precision.HIGHEST
-
 
 @dataclasses.dataclass
 class MlaMoeConfig:
@@ -252,73 +251,23 @@ def count_params(config: MlaMoeConfig) -> Dict[str, int]:
 
 # ------------------------------------------------------- residual streams
 
-def sinkhorn(m, iters: int, eps: float):
-    """m [n, n, ...] positive -> doubly stochastic over its first two axes:
-    `iters` times, divide each row (axis 1 summed) by its sum + eps, then
-    each column. The token axes stay minor, so a row sum is a few
-    elementwise adds and no cross-lane reduction."""
-    for _ in range(iters):
-        m = m / (m.sum(1, keepdims=True) + eps)
-        m = m / (m.sum(0, keepdims=True) + eps)
-    return m
-
-
-def mix_coefficients(x, hc, c: MlaMoeConfig):
-    """The streams x [n, B, S, h] -> (H_pre [n, B, S], H_post [n, B, S],
-    H_res [n, n, B, S]) in float32: per token, u = RMSNorm(vec(x)); three
-    projections of u with their scales and biases; a sigmoid, twice a
-    sigmoid, and Sinkhorn of the clipped exponential."""
-    n = c.hc_mult
-    xf = x.astype(jnp.float32)
-    rinv = jax.lax.rsqrt((xf * xf).mean((0, 3), keepdims=True) + c.hc_eps)
-    u = xf * rinv * hc["norm_g"].reshape(n, 1, 1, -1)
-    proj = jnp.einsum("nbsh,nhk->kbs", u,
-                      hc["phi"].reshape(n, c.hidden_size, -1),
-                      precision=_HIGHEST)                    # [2n+n*n, B, S]
-    a = hc["alpha"]
-    pre = a[0] * proj[:n] + hc["b_pre"][:, None, None]
-    post = a[1] * proj[n:2 * n] + hc["b_post"][:, None, None]
-    res = a[2] * proj[2 * n:].reshape((n, n) + proj.shape[1:]) \
-        + hc["b_res"][:, :, None, None]
-    lo, hi = c.hc_res_clamp
-    return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
-            sinkhorn(jnp.exp(jnp.clip(res, lo, hi)), c.hc_sinkhorn_iters,
-                     c.hc_eps))
-
-
-def _read_in(x, hc, c: MlaMoeConfig):
-    """x [n, B, S, h] -> (h_in [B, S, h] = sum_i H_pre[i] x[i], H_post,
-    H_res), elementwise in float32 (no product for the MXU to round)."""
-    with jax.named_scope(stages.RESIDUAL_MIX):
-        h_pre, h_post, h_res = mix_coefficients(x, hc, c)
-        h_in = sum(h_pre[j][..., None] * x[j].astype(jnp.float32)
-                   for j in range(c.hc_mult)).astype(x.dtype)
-        return h_in, h_post, h_res
-
-
-def _write_back(x, y, h_post, h_res, c: MlaMoeConfig):
-    """x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y."""
-    n = c.hc_mult
-    with jax.named_scope(stages.RESIDUAL_MIX):
-        streams = [x[j].astype(jnp.float32) for j in range(n)]
-        yf = y.astype(jnp.float32)
-        return jnp.stack([
-            sum(h_res[i, j][..., None] * streams[j] for j in range(n))
-            + h_post[i][..., None] * yf for i in range(n)]).astype(x.dtype)
+sinkhorn = stream_mix.sinkhorn
 
 
 def _sublayer(x, hc, fn, c: MlaMoeConfig):
-    """x [n, B, S, h] -> x': y = fn(h_in) between the read-in and the
-    write-back. Both halves of the mixing are `jax.checkpoint`ed on their
-    own: their float32 copies of the four streams (0.47 GB each at 8,192
-    tokens) would otherwise all be kept for the layer's backward pass,
-    where now one half at a time recomputes its own from the bfloat16
-    streams. `fn` opens its own stages and returns (y, aux)."""
-    h_in, h_post, h_res = jax.checkpoint(
-        functools.partial(_read_in, c=c))(x, hc)
+    """x [n, B, S, h] -> x': y = fn(h_in) between the read-in, h_in =
+    sum_i H_pre[i] x[i], and the write-back, x'[i] = sum_j H_res[i, j] x[j]
+    + H_post[i] y, with the coefficients a token from the norm of its
+    flattened streams, three projections and Sinkhorn, all in float32
+    (ops/pallas/stream_mix.py: one pass over the bfloat16 streams for each
+    half in each direction, the backward written by hand, so nothing is
+    checkpointed here). `fn` opens its own stages and returns (y, aux)."""
+    with jax.named_scope(stages.RESIDUAL_MIX):
+        h_in, mix, x = stream_mix.read_in(
+            x, hc, c.hc_sinkhorn_iters, c.hc_eps, tuple(c.hc_res_clamp))
     y, aux = fn(h_in)
-    return jax.checkpoint(functools.partial(_write_back, c=c))(
-        x, y, h_post, h_res), aux
+    with jax.named_scope(stages.RESIDUAL_MIX):
+        return stream_mix.write_back(x, y, mix), aux
 
 
 # ---------------------------------------------------------------- a block

@@ -10,6 +10,8 @@ jax.experimental.pallas. On a TPU every kernel is Mosaic-compiled; off a TPU
 the same kernels run in the Pallas interpreter (`_core.device.
 pallas_interpret`), which keeps them testable on the CPU mesh, and
 tests/test_tpu_aot_compile.py runs the real TPU compiler on them in tier-1.
+`stream_mix` (the mixing of several residual streams, models/mla_moe.py) is
+imported as a module: `read_in`, `write_back`.
 """
 from .flash_attention import flash_attention, mha_forward
 from .fused import rms_norm, swiglu, fused_rotary_position_embedding

@@ -21,6 +21,7 @@ from benchmarks.runners import mla_moe as runner
 from paddle_tpu.models import mla_moe as m
 from paddle_tpu.models import stages
 from paddle_tpu.ops import moe
+from paddle_tpu.ops.pallas import stream_mix
 
 # the package exports a function of the module's name
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
@@ -294,6 +295,164 @@ def test_mixing_at_the_identity_is_a_plain_residual(tiny):
     np.testing.assert_allclose(out[1], x[1] + jnp.tanh(x[0]), atol=1e-5)
 
 
+# ------------------------------------------ the mixing kernels' oracle
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+MIX = dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+
+
+def mix_coefficients(x, hc, n, iters, eps, clamp):
+    """The formulation models/mla_moe.py had before the kernels, as `jnp`:
+    the streams x [n, B, S, h] -> (H_pre [n, B, S], H_post [n, B, S], H_res
+    [n, n, B, S]) in float32: per token, u = RMSNorm(vec(x)); three
+    projections of u with their scales and biases; a sigmoid, twice a
+    sigmoid, and Sinkhorn of the clipped exponential."""
+    xf = x.astype(jnp.float32)
+    rinv = jax.lax.rsqrt((xf * xf).mean((0, 3), keepdims=True) + eps)
+    u = xf * rinv * hc["norm_g"].reshape(n, 1, 1, -1)
+    proj = jnp.einsum("nbsh,nhk->kbs", u,
+                      hc["phi"].reshape(n, x.shape[-1], -1),
+                      precision=_HIGHEST)                    # [2n+n*n, B, S]
+    a = hc["alpha"]
+    pre = a[0] * proj[:n] + hc["b_pre"][:, None, None]
+    post = a[1] * proj[n:2 * n] + hc["b_post"][:, None, None]
+    res = a[2] * proj[2 * n:].reshape((n, n) + proj.shape[1:]) \
+        + hc["b_res"][:, :, None, None]
+    return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
+            m.sinkhorn(jnp.exp(jnp.clip(res, *clamp)), iters, eps))
+
+
+def oracle_read_in(x, hc, iters, eps, clamp):
+    """-> (h_in = sum_i H_pre[i] x[i], H_post over H_res row by row, x),
+    elementwise in float32: what `stream_mix.read_in` returns."""
+    n = x.shape[0]
+    h_pre, h_post, h_res = mix_coefficients(x, hc, n, iters, eps, clamp)
+    h_in = sum(h_pre[j][..., None] * x[j].astype(jnp.float32)
+               for j in range(n)).astype(x.dtype)
+    return h_in, jnp.concatenate(
+        [h_post, h_res.reshape((n * n,) + h_post.shape[1:])]), x
+
+
+def oracle_write_back(x, y, mix):
+    """x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y."""
+    n = x.shape[0]
+    h_post, h_res = mix[:n], mix[n:].reshape((n, n) + mix.shape[1:])
+    streams = [x[j].astype(jnp.float32) for j in range(n)]
+    yf = y.astype(jnp.float32)
+    return jnp.stack([
+        sum(h_res[i, j][..., None] * streams[j] for j in range(n))
+        + h_post[i][..., None] * yf for i in range(n)]).astype(x.dtype)
+
+
+def _mixing_parameters(n, h):
+    k = jax.random.split(jax.random.PRNGKey(n), 5)
+    f32 = jnp.float32
+    return {"norm_g": 1 + 0.1 * jax.random.normal(k[0], (n * h,), f32),
+            "phi": jax.random.normal(k[1], (n * h, 2 * n + n * n), f32) * 0.3,
+            "alpha": jnp.asarray([0.3, 0.2, 0.4], f32),
+            "b_pre": jax.random.normal(k[2], (n,), f32),
+            "b_post": jax.random.normal(k[3], (n,), f32) * 0.5,
+            "b_res": 2.0 * jnp.eye(n, dtype=f32)
+            + jax.random.normal(k[4], (n, n), f32) * 0.5}
+
+
+@functools.lru_cache(maxsize=None)
+def _mixing_both(n, tokens, dtype):
+    """{name: (the kernels', the oracle's)} for every output and every
+    cotangent of the two halves, each half on its own with seeded
+    cotangents: read-in forward and backward, write-back forward and
+    backward."""
+    h, dt = 128, jnp.dtype(dtype)
+    k = jax.random.split(jax.random.PRNGKey(7), 6)
+
+    def normal(key, shape, dtype=dt):
+        return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+
+    hc = _mixing_parameters(n, h)
+    x = normal(k[0], (n,) + tokens + (h,))
+    y = normal(k[1], tokens + (h,))
+    found = {}
+
+    def both(fn_new, fn_old, args, cotangent, outputs, gradients):
+        for i, fn in enumerate((fn_new, fn_old)):
+            out, pull = jax.vjp(fn, *args)
+            grads = pull(cotangent(out))
+            for name, value in list(zip(outputs, jax.tree_util.tree_leaves(
+                    out))) + list(zip(gradients, jax.tree_util.tree_leaves(
+                        grads))):
+                found.setdefault(name, [None, None])[i] = value
+
+    mix_shape = (n + n * n,) + tokens
+    both(lambda x, hc: stream_mix.read_in(x, hc, **MIX),
+         lambda x, hc: oracle_read_in(x, hc, **MIX), (x, hc),
+         lambda out: (normal(k[2], out[0].shape),
+                      normal(k[3], mix_shape, jnp.float32),
+                      normal(k[4], x.shape)),
+         ("h_in", "mix"),
+         ("read_in dx", "d alpha", "d b_post", "d b_pre", "d b_res",
+          "d norm_g", "d phi"))
+    mix = found["mix"][1]
+    both(stream_mix.write_back, oracle_write_back, (x, y, mix),
+         lambda out: normal(k[5], out.shape), ("x_out",),
+         ("write_back dx", "dy", "d mix"))
+    return found
+
+
+MIXING_CASES = [(n, tokens, dtype) for n in (2, 4)
+                for tokens in ((2, 64), (3, 50))        # 128 and 150 tokens
+                for dtype in ("float32", "bfloat16")]
+MIXING_OUTPUTS = ("h_in", "mix", "x_out", "read_in dx", "write_back dx", "dy",
+                  "d mix", "d alpha", "d b_pre", "d b_post", "d b_res",
+                  "d norm_g", "d phi")
+
+
+@pytest.mark.parametrize("what", MIXING_OUTPUTS)
+@pytest.mark.parametrize("n, tokens, dtype", MIXING_CASES)
+def test_mixing_kernels_against_the_oracle(n, tokens, dtype, what):
+    """The four kernels (interpreted) against `jax.vjp` of the `jnp`
+    formulation: a tile of 128 tokens, so 128 tokens are one tile and 150
+    are padded. What is float32 agrees to float32 rounding; what is
+    rounded to bfloat16 streams may differ by one rounding (the oracle's
+    stream cotangent is rounded three times, the kernels' once)."""
+    got, want = _mixing_both(n, tokens, dtype)[what]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    scale = float(jnp.abs(want.astype(jnp.float32)).max())
+    rounded = got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        rtol=2.0 ** -6 if rounded else 2e-5,
+        atol=(2.0 ** -8 if rounded else 2e-5) * scale)
+
+
+def test_read_in_hands_the_streams_on():
+    """`read_in` returns x itself for `write_back`, so that x has one
+    consumer: the two halves' cotangents meet inside the read-in's
+    backward kernel and in no XLA add."""
+    n, h = 2, 128
+    hc = _mixing_parameters(n, h)
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, 1, 128, h), jnp.float32)
+
+    def sublayer(x):
+        h_in, mix, x = stream_mix.read_in(x, hc, **MIX)
+        return stream_mix.write_back(x, jnp.tanh(h_in), mix).sum()
+
+    np.testing.assert_array_equal(stream_mix.read_in(x, hc, **MIX)[2], x)
+    jaxpr = str(jax.make_jaxpr(jax.grad(sublayer))(x))
+    # (Sinkhorn's `jax.vjp` inside the kernel adds [n, n, tile] ones)
+    assert "f32[2,2,128] = add_any" in jaxpr
+    assert not re.search(r"f32\[2,(?:1,128|128),128\] = add_any", jaxpr)
+
+
+def test_a_width_that_cannot_be_tiled_is_a_named_error(monkeypatch):
+    monkeypatch.setattr(stream_mix, "pallas_interpret", lambda: False)
+    with pytest.raises(stream_mix.StreamWidthError, match="multiple of 128"):
+        stream_mix.tiling(4, 4096, 3000, jnp.bfloat16)
+    with pytest.raises(stream_mix.StreamWidthError, match="MiB"):
+        stream_mix.tiling(4, 4096, 32768, jnp.bfloat16)
+    assert stream_mix.tiling(4, 4096, 3584, jnp.bfloat16) == (128, 512)
+    assert stream_mix.tiling(2, 100, 1024, jnp.float32) == (128, 512)
+
+
 # ------------------------------------------------------------------- yarn
 
 def test_yarn_against_the_closed_forms():
@@ -444,8 +603,6 @@ def test_every_stage_in_every_direction(remat):
     want = {(s, d) for s in THROUGH_BLOCK + SPARSE_ONLY for d in through} | {
         (s, d) for s in (stages.EMBED, stages.LOSS_HEAD)
         for d in ("forward", "backward")} | {(stages.OPTIMIZER, "update")}
-    # the two halves of the mixing are checkpointed on their own
-    want.add((stages.RESIDUAL_MIX, "remat"))
     assert {found for found in placed.values() if found[0]} == want
     unscoped = {re.sub(r"^jit\(step_fn\)/(?:transpose\(jvp\(\)\)|jvp\(\))/",
                        "", path)

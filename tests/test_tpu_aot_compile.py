@@ -24,6 +24,7 @@ from paddle_tpu.models.gpt import GPTConfig, build_train_step, init_gpt_params
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 fv = importlib.import_module("paddle_tpu.ops.pallas.flash_varlen")
 fused = importlib.import_module("paddle_tpu.ops.pallas.fused")
+sm = importlib.import_module("paddle_tpu.ops.pallas.stream_mix")
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +109,66 @@ def test_fused_rms_compiles_at_llama_hidden(v5e):
 
 def test_fused_swiglu_compiles_at_llama_mlp_width(v5e):
     _compile(v5e, fused._swiglu, *[((8192, 11008), jnp.bfloat16)] * 2)
+
+
+# the sparse cell's streams: 4 x (2 x 2048 tokens) x 3584, bfloat16
+STREAMS = (4, 2 * 2048, 3584)
+
+
+def _mixing_shapes():
+    n, t, h = STREAMS
+    rows, kp, bf16, f32 = n + n * n, 32, jnp.bfloat16, jnp.float32
+    x, y = (STREAMS, bf16), ((t, h), bf16)
+    g, sb = ((n, 3 * kp, h), bf16), ((kp, 2), f32)
+    mix, praw, rinv = ((rows, t), f32), ((kp, t), f32), ((1, t), f32)
+    coef = sm._coef(n, 20, 1e-6, (-30.0, 30.0))
+    return {
+        "read_in_forward": (lambda x, g, sb: sm.read_in_forward(
+            x, g, sb, coef, 1e-6), (x, g, sb)),
+        "write_back_forward": (sm.write_back_forward, (x, y, mix)),
+        "write_back_backward": (sm.write_back_backward, (x, x, y, mix)),
+        "read_in_backward": (lambda *a: sm.read_in_backward(*a, coef), (
+            y, x, x, g, praw, rinv, mix, sb))}
+
+
+@pytest.mark.parametrize("kernel", ["read_in_forward", "write_back_forward",
+                                    "write_back_backward",
+                                    "read_in_backward"])
+def test_stream_mixing_compiles_at_the_sparse_cells_shapes(v5e, kernel):
+    fn, shapes = _mixing_shapes()[kernel]
+    text = _compile(v5e, fn, *shapes).as_text()
+    # no float32 copy of a stream, or of all of them, reaches HBM
+    assert "f32[4096,3584]" not in text and "f32[4,4096,3584]" not in text
+
+
+def test_stream_mixing_whole_sublayer_compiles_with_its_gradient(v5e):
+    """`read_in` and `write_back` under `jax.grad`, parameters packed and
+    unpacked by XLA around the four Mosaic calls."""
+    n, t, h = STREAMS
+    k = 2 * n + n * n
+    f32 = jnp.float32
+    hc = {"norm_g": ((n * h,), f32), "phi": ((n * h, k), f32),
+          "alpha": ((3,), f32), "b_pre": ((n,), f32), "b_post": ((n,), f32),
+          "b_res": ((n, n), f32)}
+
+    def loss(x, *leaves):
+        params = dict(zip(hc, leaves))
+        h_in, mix, x = sm.read_in(x, params, 20, 1e-6, (-30.0, 30.0))
+        return _sum32(sm.write_back(x, h_in, mix))
+
+    compiled = _compile(v5e, jax.grad(loss, argnums=tuple(range(7))),
+                        ((n, 2, 2048, h), jnp.bfloat16), *hc.values())
+    # read-in, and both backward kernels; the gradient of a sum does not
+    # need the write-back's forward result
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+
+
+def test_stream_width_that_cannot_be_tiled_is_a_named_error(v5e):
+    """Mosaic tiles the lanes by 128: another width raises before it."""
+    with pytest.raises(sm.StreamWidthError, match="multiple of 128"):
+        _compile(v5e, sm.write_back_forward, ((4, 4096, 3000), jnp.bfloat16),
+                 ((4096, 3000), jnp.bfloat16), ((20, 4096), jnp.float32))
 
 
 def test_flash_sequence_limit_is_a_named_error(v5e):
