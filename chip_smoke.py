@@ -155,7 +155,8 @@ def _check_losses(losses):
 
 def phase_train(sz: Sizes):
     from paddle_tpu._core import device
-    from paddle_tpu.models.gpt import _use_flash_kernel, build_train_step
+    from paddle_tpu.models.blocks import use_flash_kernel
+    from paddle_tpu.models.gpt import build_train_step
 
     config = _gpt_config(sz)
     init_fn, step = build_train_step(config, mesh=None, lr=1e-4, remat=True)
@@ -168,7 +169,8 @@ def phase_train(sz: Sizes):
     out = {"tpu_custom_calls": text.count("tpu_custom_call"),
            "pallas_interpret": device.pallas_interpret()}
     if not sz.dry_run:
-        _check(_use_flash_kernel(config, sz.seq), "flash gate is closed")
+        _check(use_flash_kernel(config.use_flash_attention, sz.seq),
+               "flash gate is closed")
         _check(not out["pallas_interpret"], "Pallas is in interpret mode")
         _check(out["tpu_custom_calls"] > 0,
                "no tpu_custom_call in the lowered train step")

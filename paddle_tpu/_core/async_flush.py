@@ -28,8 +28,8 @@ Contracts:
   while anything else is wrapped in EnforceNotMet with the flight
   recorder's post-mortem already dumped from the worker.
 - **shutdown**: an atexit hook drains and retires the worker; a
-  process must not exit with a leaked flush thread (bench_suite row 9
-  asserts this).
+  process must not exit with a leaked flush thread
+  (tests/test_async_flush.py asserts this).
 """
 from __future__ import annotations
 

@@ -214,7 +214,7 @@ COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_compile_cache")
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for an entry point
-    that touches the chip (chip_smoke.py, bench.py, bench_suite.py,
+    that touches the chip (chip_smoke.py, benchmarks/run.py,
     __graft_entry__.py) — not at package import, so library users and
     tier-1 keep JAX's defaults. Returns the directory in use.
 

@@ -176,10 +176,6 @@ define_flag("FLAGS_recompute_segments", 2,
 define_flag("FLAGS_amp_dtype", "bfloat16",
             "Default auto-cast dtype for amp O1/O2 (bf16 is the TPU "
             "tensor-core dtype the way fp16 is CUDA's).")
-define_flag("FLAGS_flash_block_q", 512,
-            "Pallas flash-attention max query block size.")
-define_flag("FLAGS_flash_block_k", 512,
-            "Pallas flash-attention max key block size.")
 
 # ---- io / misc
 define_flag("FLAGS_dataloader_num_workers", 0,
@@ -483,7 +479,7 @@ define_flag("FLAGS_distributed_telemetry", False,
             "a cluster step table (per-rank skew, straggler flags), a "
             "comm-overlap report, and a merged per-rank chrome trace. "
             "Off = one module-level check per step, zero registry and "
-            "zero store work (bench row 10).")
+            "zero store work (tests/test_distributed_telemetry.py).")
 define_flag("FLAGS_distributed_telemetry_interval", 1,
             "Telemetry plane: steps between frame publications (1 = "
             "every step boundary).")
@@ -513,7 +509,8 @@ define_flag("FLAGS_memory_telemetry", False,
             "XLA memory_analysis cached on the executable-cache entry, "
             "donation savings accounting, and OOM postmortems at the "
             "execute sites. Off = one module-level check per choke "
-            "point, zero census and zero registry work (bench row 11).")
+            "point, zero census and zero registry work "
+            "(tests/test_memory_telemetry.py).")
 define_flag("FLAGS_compute_telemetry", False,
             "Compute-efficiency telemetry plane (observability/"
             "compute.py): per-executable XLA cost_analysis (FLOPs, "
@@ -525,7 +522,7 @@ define_flag("FLAGS_compute_telemetry", False,
             "device profiles (each recorded op's lowering wrapped in a "
             "jax.named_scope carrying its paddle file:line). Off = one "
             "module-level check per site, zero registry and zero "
-            "analysis work (bench row 14).")
+            "analysis work (tests/test_compute_telemetry.py).")
 define_flag("FLAGS_device_peak_flops", 0.0,
             "Per-chip peak FLOP/s the MFU column divides by. 0 = "
             "the device's published peak: a TPU from the device_kind "
@@ -555,7 +552,7 @@ define_flag("FLAGS_goodput", False,
             "ring when no step progress happens within "
             "FLAGS_goodput_hang_factor x the median step time. Off = "
             "one module-level check per probe, zero ring mutations "
-            "(bench row 16).")
+            "(tests/test_goodput.py).")
 define_flag("FLAGS_goodput_hang_factor", 8.0,
             "Goodput hang watchdog: the job is declared hung when no "
             "probe-visible progress happens within this factor x the "
@@ -600,7 +597,7 @@ define_flag("FLAGS_monitor", False,
             "every FLAGS_monitor_interval_s, feeding the /metrics "
             "exporter and the online regression watchdog. Off = one "
             "module-level check per step hook, zero registry work, no "
-            "sampler thread, no bound port (bench row 20).")
+            "sampler thread, no bound port (tests/test_monitor.py).")
 define_flag("FLAGS_monitor_interval_s", 1.0,
             "Monitor sampler period in seconds (each tick appends one "
             "timestamped sample per series).")

@@ -77,8 +77,8 @@ MESH_EPOCH = 0
 # one module-attr read per flush, zero extra key bytes.
 SPMD = None
 
-# sharding-component builds (diagnostics + the bench row-12 off-freeze
-# assert: a no-mesh run must never touch the sharding key path)
+# sharding-component builds (diagnostics + tests/test_spmd_step.py's
+# off-freeze assert: a no-mesh run must never touch the sharding key path)
 SHARD_SIG_BUILDS = 0
 
 # Perf-lint flush observer (analysis/perf_checks.py installs
@@ -120,8 +120,8 @@ def mark_cost_stale():
 # reuse the skeleton's cached out-avals + interned entries, re-binding
 # only external input payloads. Any mismatch falls back to the full
 # record path for the rest of the segment. FAST_OPS counts replayed
-# ops process-wide (tests + bench row 17); _FAST_GEN is the skeleton
-# generation — bumping it (mesh-epoch bump / replan, relevant
+# ops process-wide (tests/test_record_fastpath.py); _FAST_GEN is the
+# skeleton generation — bumping it (mesh-epoch bump / replan, relevant
 # set_flags) invalidates every armed skeleton at its next fast record.
 FAST_OPS = 0
 _FAST_PATH = True
@@ -167,7 +167,7 @@ _flags.watch_flag("FLAGS_lazy_max_segment_ops", invalidate_skeletons)
 # flip — they all break the per-op replay that feeds the plan) or a
 # live-set change demotes that shape to per-op skeleton replay and
 # re-arms the streak. REPLAY_STEPS counts driven seals process-wide
-# (bench rows 17/18 and the off-freeze assertions read it).
+# (tests/test_step_replay.py and the off-freeze assertions read it).
 REPLAY_STEPS = 0
 _STEP_REPLAY_AFTER = 3
 

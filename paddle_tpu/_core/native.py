@@ -258,8 +258,8 @@ def get_eager_core():
     `eager_core_status()` says which path this process is on; set
     PT_DISABLE_NATIVE_EAGER=1 to force the python path. Consumers
     cache their own resolution (dispatch._EAGER_CORE, lazy._NC) so
-    bench row 17 and the fallback tests can force either prong
-    in-process."""
+    the fallback tests (tests/test_record_fastpath.py) can force either
+    prong in-process."""
     global _EAGER_CORE, _EAGER_CORE_TRIED, _EAGER_CORE_WHY_NOT
     if _EAGER_CORE_TRIED:
         return _EAGER_CORE

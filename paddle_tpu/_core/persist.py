@@ -3,7 +3,7 @@
 Process restart, elastic re-plan and serving cold-start used to pay
 ``lower().compile()`` for every sealed segment, fused step and
 optimizer update — the goodput ledger's compile bucket prices exactly
-this badput (bench row 8's ~740ms re-plan was mostly recompile). This
+this badput (an adaptive re-plan is mostly recompile). This
 module serializes compiled executables through jax's AOT surface
 (SNIPPETS [1] pjit Lowered/compile split -> serialize_executable) under
 a content-addressed filename, so an ``ExecCache`` miss consults disk

@@ -27,7 +27,7 @@ cast_churn (fixable), scaler_flow and quant_error_budget. Surfaces:
   donation, dead captures) in place and re-checks;
 - this module's `check_segment` / `check_program` / `check_guards` /
   `check_reshard` / `check_pipeline_schedule` API;
-- `python -m paddle_tpu.analysis` — traces the bench_suite models plus
+- `python -m paddle_tpu.analysis` — traces lenet, resnet50 and bert plus
   the distributed configs and reports (`--json`, `--fix`).
 """
 from __future__ import annotations

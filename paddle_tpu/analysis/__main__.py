@@ -1,4 +1,4 @@
-"""CLI: trace the bench_suite + distributed configs, run the sanitizer.
+"""CLI: trace the model zoo + distributed configs, run the sanitizer.
 
     python -m paddle_tpu.analysis
         [--models lenet,resnet50,bert,reshard,replan,pipeline]
@@ -32,8 +32,8 @@ counters); the sharded models record under a dryrun dp×mp mesh and
 run the PartitionSpec propagation sweep (implicit reshards, mp-layer
 round trips, comm-hotspot ranking). Needs ≥4 devices for the dryrun
 mesh — on a single-device host the CLI re-execs itself with 8 forced
-CPU devices. Perf findings are expected (exit 0 reports them; the
-bench_suite --diff gate compares their COUNTS across rounds).
+CPU devices. Perf findings are expected (exit 0 reports them; nothing
+compares their counts from one PR to the next: ROADMAP C5).
 
 ``--mem`` switches to the MEM lint (analysis/mem_liveness.py): each
 bench model's forward+loss is recorded (aval inference only) and the
@@ -42,8 +42,9 @@ state + compiled-temp estimate — is priced at candidate pod shapes
 (default dp×mp ∈ {1×1, 4×2, 2×2×2}; ``--mesh 4,2`` picks one) via
 `CandidateMesh`, i.e. WITHOUT compiling and on a host that cannot
 build the mesh. With FLAGS_memory_budget_bytes set, shapes that do
-not fit carry ``oom_risk`` findings (bench row 15 gates their count
-with zero tolerance). Exit 0 reports findings, like --perf.
+not fit carry ``oom_risk`` findings (tests/test_mem_analysis.py seeds
+one; no test gates the CLI's count: ROADMAP C5). Exit 0 reports
+findings, like --perf.
 """
 from __future__ import annotations
 
@@ -161,10 +162,10 @@ def run_resnet50(execute: bool, verbose: bool):
 
 
 def run_bert(execute: bool, verbose: bool):
-    """bench_suite row 3 builds a pure-jax compiled trainer
-    (models/bert.py) — there is no framework-level program to lint, so
-    the sweep covers the process-wide tracer caches after building the
-    step, plus an eager proxy of the attention arithmetic."""
+    """models/bert.py is a pure-jax compiled trainer: there is no
+    framework-level program to lint, so the sweep covers the process-wide
+    tracer caches after building the step, plus an eager proxy of the
+    attention arithmetic."""
     import numpy as np
     import paddle_tpu as paddle
     from paddle_tpu import analysis
@@ -666,8 +667,8 @@ def _mem_main(args) -> int:
 
 
 def _plan_main(args) -> int:
-    """--plan: record the dryrun sweep model (the bench row-12 shape:
-    two bias-free Linear(64,64) over [8, 32, 64] + cross-entropy) and
+    """--plan: record the dryrun sweep model (the shape
+    tests/test_planner.py sweeps: two bias-free Linear(64,64) over [8, 32, 64] + cross-entropy) and
     run the whole-program auto-parallelism planner over every dp×mp×pp
     factorization of --world. Static end to end: no devices, no
     compile — a laptop plans a pod. Exit code 0 only when a feasible

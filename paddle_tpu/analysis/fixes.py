@@ -41,7 +41,7 @@ contradictions, distributed findings) are NOT touched: their repair
 needs intent the checker cannot infer, so fix mode reports them
 exactly like warn mode.
 
-Every applied fix bumps `sanitizer.fixes_applied` (bench_suite row 5
+Every applied fix bumps `sanitizer.fixes_applied` (tests/test_analysis.py
 asserts the counter stays FROZEN over a clean program — fix mode must
 never rewrite correct code) and notes a flight-recorder event. After
 applying, the caller re-runs the checkers to prove the diagnostic

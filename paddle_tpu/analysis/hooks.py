@@ -38,16 +38,17 @@ def segment_sweeps() -> int:
     """Flush-time sweeps since process start — lives in the
     observability metrics registry (`sanitizer.segment_sweeps`; counted
     unconditionally because this path only runs in warn/error mode).
-    bench_suite row 5 asserts it stays frozen with
-    FLAGS_static_checks=off (checker work is exactly 0, not merely
-    'too small to measure')."""
+    tests/test_observability.py (`test_off_mode_zero_registry_work`)
+    asserts the whole registry, this counter included, stays frozen with
+    FLAGS_static_checks=off (checker work is exactly 0, not merely 'too
+    small to measure')."""
     from ..observability import metrics
     return metrics.counter("sanitizer.segment_sweeps").value
 
 
 def fixes_applied() -> int:
     """Autofix rewrites since process start (`sanitizer.fixes_applied`
-    registry counter). bench_suite row 5 asserts it stays frozen when
+    registry counter). tests/test_analysis.py asserts it stays frozen when
     fix mode sweeps a CLEAN program — the sanitizer must never rewrite
     correct code."""
     from ..observability import metrics

@@ -30,8 +30,8 @@ always describes fully published chunks.
 Counters: `resilience.grow_bcast_chunks` / `grow_bcast_bytes` on the
 publishing side, `resilience.grow_state_received` /
 `grow_bcast_rejects` on the receiving side. All of it only runs on the
-growth path — the faults-off freeze gate (bench rows 7/8/22) never
-sees these move.
+growth path — the faults-off freeze gate (tests/test_resilience.py,
+`test_faults_off_zero_overhead_gate`) never sees these move.
 """
 from __future__ import annotations
 

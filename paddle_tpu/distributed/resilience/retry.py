@@ -14,8 +14,8 @@ sanitizer-counter precedent): every re-attempt bumps
 `resilience.retries`, an exhausted budget bumps `resilience.gave_up`,
 and each attempt lands a flight-recorder event when the ring is armed.
 A first-attempt success does ZERO registry work, which is what lets
-bench row 7 freeze the `resilience.*` counters across the faults-off
-path.
+tests/test_resilience.py (`test_faults_off_zero_overhead_gate`) freeze
+the `resilience.*` counters across the faults-off path.
 """
 from __future__ import annotations
 

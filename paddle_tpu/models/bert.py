@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import stages
+from .blocks import (attention, gelu_mlp, layer_norm, lm_head_loss, normal,
+                     scan_layers)
 from .trainer import build_adamw_train_step
 
 
@@ -56,30 +58,26 @@ def init_bert_params(config: BertConfig, seed: int = 0) -> Dict:
     h, f, L = c.hidden_size, c.intermediate_size, c.num_layers
     dt = jnp.dtype(c.dtype)
     std = c.initializer_range
+    out_std = std / math.sqrt(2 * L)
     ks = jax.random.split(key, 8)
-
-    def norm(k, shape, scale=std):
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
-
     return {
-        "wte": norm(ks[0], (c.vocab_size, h)),
-        "wpe": norm(ks[1], (c.max_position_embeddings, h)),
-        "wtype": norm(ks[2], (c.type_vocab_size, h)),
+        "wte": normal(ks[0], (c.vocab_size, h), std, dt),
+        "wpe": normal(ks[1], (c.max_position_embeddings, h), std, dt),
+        "wtype": normal(ks[2], (c.type_vocab_size, h), std, dt),
         "emb_ln_g": jnp.ones((h,), dt), "emb_ln_b": jnp.zeros((h,), dt),
         "blocks": {
-            "qkv_w": norm(ks[3], (L, h, 3 * h)),
+            "qkv_w": normal(ks[3], (L, h, 3 * h), std, dt),
             "qkv_b": jnp.zeros((L, 3 * h), dt),
-            "proj_w": norm(ks[4], (L, h, h),
-                           scale=std / math.sqrt(2 * L)),
+            "proj_w": normal(ks[4], (L, h, h), out_std, dt),
             "proj_b": jnp.zeros((L, h), dt),
             "ln1_g": jnp.ones((L, h), dt), "ln1_b": jnp.zeros((L, h), dt),
-            "fc_w": norm(ks[5], (L, h, f)), "fc_b": jnp.zeros((L, f), dt),
-            "fo_w": norm(ks[6], (L, f, h),
-                         scale=std / math.sqrt(2 * L)),
+            "fc_w": normal(ks[5], (L, h, f), std, dt),
+            "fc_b": jnp.zeros((L, f), dt),
+            "fo_w": normal(ks[6], (L, f, h), out_std, dt),
             "fo_b": jnp.zeros((L, h), dt),
             "ln2_g": jnp.ones((L, h), dt), "ln2_b": jnp.zeros((L, h), dt),
         },
-        "mlm_w": norm(ks[7], (h, h)), "mlm_b": jnp.zeros((h,), dt),
+        "mlm_w": normal(ks[7], (h, h), std, dt), "mlm_b": jnp.zeros((h,), dt),
         "mlm_ln_g": jnp.ones((h,), dt), "mlm_ln_b": jnp.zeros((h,), dt),
     }
 
@@ -116,41 +114,29 @@ def wd_mask(config: BertConfig) -> Dict:
     }
 
 
-def _ln(x, g, b, eps):
-    xf = x.astype(jnp.float32)
-    mu = xf.mean(-1, keepdims=True)
-    var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g + b
-
-
 def _block(x, blk, config: BertConfig, attn_mask=None):
-    """Post-norm encoder block (BERT convention). x [B, S, H];
+    """Post-norm encoder block (BERT convention): x [B, S, H] -> (x, None);
     attn_mask [B, 1, 1, S] additive or None."""
     c = config
-    b, s, h = x.shape
+    b, s, _ = x.shape
     with jax.named_scope(stages.ATTN_QKV):
         qkv = jnp.einsum("bsh,hk->bsk", x, blk["qkv_w"]) + blk["qkv_b"]
     with jax.named_scope(stages.ATTN_CORE):
         qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
-        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)
-        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(c.head_dim)
-        if attn_mask is not None:
-            logits = logits + attn_mask
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
-            x.dtype)
-        attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
+        # flash=False: no kernel serves this family yet. ROADMAP A2 turns
+        # it on here (the two bert cells judge it).
+        attn = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                         causal=False, scale=1.0 / math.sqrt(c.head_dim),
+                         flash=False, mask=attn_mask)
     with jax.named_scope(stages.ATTN_OUT):
         attn = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) \
             + blk["proj_b"]
-        x = _ln(x + attn, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
+        x = layer_norm(x + attn, blk["ln1_g"], blk["ln1_b"],
+                       c.layer_norm_eps)
     with jax.named_scope(stages.MLP):
-        y = jnp.einsum("bsh,hf->bsf", x, blk["fc_w"]) + blk["fc_b"]
-        y = jax.nn.gelu(y, approximate=True)
-        y = jnp.einsum("bsf,fh->bsh", y, blk["fo_w"]) + blk["fo_b"]
-        return _ln(x + y, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
+        y = gelu_mlp(x, blk["fc_w"], blk["fc_b"], blk["fo_w"], blk["fo_b"])
+        return layer_norm(x + y, blk["ln2_g"], blk["ln2_b"],
+                          c.layer_norm_eps), None
 
 
 def bert_encode(params, tokens, token_type_ids=None, attention_mask=None,
@@ -163,48 +149,46 @@ def bert_encode(params, tokens, token_type_ids=None, attention_mask=None,
             x = x + params["wtype"][token_type_ids]
         else:
             x = x + params["wtype"][0]
-        x = _ln(x.astype(jnp.dtype(c.dtype)), params["emb_ln_g"],
-                params["emb_ln_b"], c.layer_norm_eps)
+        x = layer_norm(x.astype(jnp.dtype(c.dtype)), params["emb_ln_g"],
+                       params["emb_ln_b"], c.layer_norm_eps)
     add_mask = None
     if attention_mask is not None:
         with jax.named_scope(stages.ATTN_CORE):
             add_mask = (1.0 - attention_mask[:, None, None, :].astype(
                 jnp.float32)) * -1e30
-
-    fn = functools.partial(_block, config=c, attn_mask=add_mask)
-    if remat:
-        fn = jax.checkpoint(fn)
-    x, _ = jax.lax.scan(lambda carry, blk: (fn(carry, blk), None), x,
-                        params["blocks"])
+    x, _ = scan_layers(
+        functools.partial(_block, config=c, attn_mask=add_mask), x,
+        params["blocks"], remat)
     return x
+
+
+def _mlm_transform(params, x, config: BertConfig):
+    """The MLM head before the tied classifier: dense, gelu, LayerNorm."""
+    x = jnp.einsum("bsh,hk->bsk", x, params["mlm_w"]) + params["mlm_b"]
+    x = jax.nn.gelu(x, approximate=True)
+    return layer_norm(x, params["mlm_ln_g"], params["mlm_ln_b"],
+                      config.layer_norm_eps)
 
 
 def bert_mlm_logits(params, tokens, config: BertConfig, remat=True,
                     attention_mask=None):
     x = bert_encode(params, tokens, None, attention_mask, config, remat)
     with jax.named_scope(stages.LOSS_HEAD):
-        x = jnp.einsum("bsh,hk->bsk", x, params["mlm_w"]) + params["mlm_b"]
-        x = jax.nn.gelu(x, approximate=True)
-        x = _ln(x, params["mlm_ln_g"], params["mlm_ln_b"],
-                config.layer_norm_eps)
-        return jnp.einsum("bsh,vh->bsv", x, params["wte"])
+        return jnp.einsum("bsh,vh->bsv", _mlm_transform(params, x, config),
+                          params["wte"])
 
 
 def bert_mlm_loss(params, tokens, labels, config: BertConfig, remat=True):
     """labels: -100 for unmasked positions (ignored), else target id."""
-    logits = bert_mlm_logits(params, tokens, config, remat)
+    x = bert_encode(params, tokens, config=config, remat=remat)
     with jax.named_scope(stages.LOSS_HEAD):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-        safe = jnp.maximum(labels, 0)
-        picked = jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
-        mask = (labels >= 0).astype(jnp.float32)
-        return -(picked * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        return lm_head_loss(_mlm_transform(params, x, config),
+                            params["wte"], labels, ignore_negative=True)
 
 
-def build_train_step(config: BertConfig, mesh: Optional[Mesh] = None,
-                     lr: float = 1e-4, remat: bool = True, **adamw):
-    loss = functools.partial(bert_mlm_loss, config=config, remat=remat)
+def build_train_step(config: BertConfig, mesh: Optional[Mesh] = None, *,
+                     remat: bool = True, lr: float = 1e-4, **adamw):
     return build_adamw_train_step(
-        lambda p, t, l: loss(p, t, l),
+        functools.partial(bert_mlm_loss, config=config, remat=remat),
         functools.partial(init_bert_params, config),
         param_specs(config), wd_mask(config), mesh=mesh, lr=lr, **adamw)

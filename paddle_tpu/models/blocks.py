@@ -1,0 +1,182 @@
+"""What the compiled model families are built from, each written once.
+
+gpt.py, bert.py, llama.py and mla_moe.py keep their configuration, their
+parameter tree and the composition of their block; the norms, the rotary
+embedding, the MLPs, the attention core, the loss head and the layer loop
+are here, pure `jax.numpy`. The decisions a kernel or memory PR touches are
+made in one function each: Mosaic kernel or einsum in `attention`,
+vocabulary-parallel or dense logits in `lm_head_loss`, checkpoint-then-scan
+in `scan_layers`, the pipeline over a `pp` axis in `layer_trunk`.
+
+No function here opens a `stages` scope: the caller stands in the stage
+its call belongs to (models/stages.py), so the scopes stay flat.
+"""
+from __future__ import annotations
+
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from .._core import device
+from .._core.flags import flag_value
+from ..distributed.fleet.mp_ops import vocab_parallel_softmax_cross_entropy
+from ..distributed.pipeline_compiled import pipelined_trunk
+from ..ops.pallas.flash_attention import mha_forward, mha_sharded
+
+
+def normal(key, shape, std, dtype):
+    """Seeded normal weights: drawn in float32, scaled, then cast."""
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+# ------------------------------------------------------ norms, rope, MLPs
+
+def layer_norm(x, g, b, eps):
+    xf = x.astype(jnp.float32)
+    mu = xf.mean(-1, keepdims=True)
+    var = ((xf - mu) ** 2).mean(-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g + b
+
+
+def rms_norm(x, g, eps):
+    xf = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (xf * scale).astype(x.dtype) * g
+
+
+def rope(x, theta: float, inv_freq=None):
+    """x [B, S, H, D] -> rotated. Half-split convention. `inv_freq` [D/2]
+    replaces theta's plain frequencies (a scaled RoPE such as yarn)."""
+    b, s, h, d = x.shape
+    half = d // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    xf1 = x1.astype(jnp.float32)
+    xf2 = x2.astype(jnp.float32)
+    return jnp.concatenate(
+        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
+        axis=-1).astype(x.dtype)
+
+
+def swiglu(y, gate_w, up_w, down_w):
+    """down(silu(gate y) * up y) on y [..., h]."""
+    gate = jnp.einsum("...h,hf->...f", y, gate_w)
+    up = jnp.einsum("...h,hf->...f", y, up_w)
+    return jnp.einsum("...f,fh->...h", jax.nn.silu(gate) * up, down_w)
+
+
+def gelu_mlp(y, fc_w, fc_b, fo_w, fo_b):
+    """fo(gelu(fc y)) on y [B, S, h], gelu in its tanh form."""
+    y = jnp.einsum("bsh,hf->bsf", y, fc_w) + fc_b
+    y = jax.nn.gelu(y, approximate=True)
+    return jnp.einsum("bsf,fh->bsh", y, fo_w) + fo_b
+
+
+# -------------------------------------------------------------- attention
+
+def use_flash_kernel(flash: bool, seq: int) -> bool:
+    """Pallas flash attention or the einsum path, decided from what the
+    caller asks and the shape. The kernel tiles the sequence by 128. On a
+    TPU it is used from seq 256 up. Anywhere else the kernel could only run
+    in the Pallas interpreter, which is a test mode: FLAGS_flash_interpret
+    opts in (CPU mesh tests / multichip dryrun), otherwise the CPU runs the
+    einsum path."""
+    if not flash or seq % 128:
+        return False
+    if device.is_tpu():
+        return seq >= 256
+    return bool(flag_value("FLAGS_flash_interpret"))
+
+
+def attention(q, k, v, *, causal: bool, scale: float, flash: bool,
+              mesh: Optional[Mesh] = None, mask=None):
+    """softmax(scale q k^T) v on q, k [B, S, H, D] and v [B, S, H, D_v] as
+    the projections leave them -> [B, S, H * D_v]. `mask`, additive and
+    broadcastable to [B, H, S, S], is bert's padding mask; the kernels take
+    none, so a masked call is the einsum path whatever `flash` says. On a
+    `mesh` the kernel runs manual over every axis (`mha_sharded`)."""
+    b, s, heads, _ = q.shape
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))    # [B, H, S, D]
+    if mask is None and use_flash_kernel(flash, s):
+        if mesh is not None:
+            out = mha_sharded(q, k, v, mesh, causal=causal, scale=scale)
+        else:
+            out = mha_forward(q, k, v, causal=causal, scale=scale)
+    else:
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        if causal:
+            logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits,
+                               jnp.array(-1e30, logits.dtype))
+        if mask is not None:
+            logits = logits + mask
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
+            q.dtype)
+        out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    return jnp.swapaxes(out, 1, 2).reshape(b, s, heads * v.shape[-1])
+
+
+# -------------------------------------------------------------- loss head
+
+def cross_entropy(logits, labels, ignore_negative: bool = False):
+    """Mean float32 cross-entropy of logits [..., V] against labels [...].
+    With `ignore_negative` the mean is over the positions whose label is
+    >= 0 (masked-LM's -100 elsewhere), and 0 where there is none."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+    if not ignore_negative:
+        picked = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
+        return -picked.mean()
+    safe = jnp.maximum(labels, 0)
+    picked = jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
+    mask = (labels >= 0).astype(jnp.float32)
+    return -(picked * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+def lm_head_loss(hidden, head_w, labels, mesh: Optional[Mesh] = None,
+                 ignore_negative: bool = False):
+    """Mean loss of hidden [B, S, h] under the classifier head_w [V, h].
+    On a mesh whose `mp` axis divides the vocabulary the head goes through
+    vocabulary-parallel softmax-cross-entropy (mp_ops.py:77-385 analog):
+    head_w is vocabulary-sharded over mp, so the full [B, S, V] logits are
+    never materialized; each shard computes [B, S, V/mp] and three small
+    collectives finish the loss. Its caller (gpt) labels every position;
+    a masked mean takes the dense path."""
+    if mesh is not None and not ignore_negative \
+            and "mp" in mesh.axis_names and mesh.shape["mp"] > 1 \
+            and head_w.shape[0] % mesh.shape["mp"] == 0:
+        return vocab_parallel_softmax_cross_entropy(
+            hidden, head_w, labels, mesh, axis="mp").mean()
+    return cross_entropy(jnp.einsum("bsh,vh->bsv", hidden, head_w), labels,
+                         ignore_negative)
+
+
+# ------------------------------------------------------------- layer loop
+
+def scan_layers(block_fn: Callable, x, stacked, remat: bool):
+    """Scan `block_fn(x, layer) -> (x, y)` over parameters stacked on a
+    leading layer axis -> (x, ys); compile time is O(1) in depth. `remat`
+    checkpoints the whole block (the reference's recompute pass)."""
+    if remat:
+        block_fn = jax.checkpoint(block_fn)
+    return jax.lax.scan(block_fn, x, stacked)
+
+
+def layer_trunk(block_fn: Callable, mesh: Optional[Mesh], num_layers: int,
+                remat: bool, pp_microbatches: Optional[int] = None):
+    """What a forward calls in place of the scan on a mesh whose `pp` axis
+    is larger than 1: `trunk(stacked, x) -> x`, the compiled
+    collective-permute pipeline over the stacked layer axis
+    (distributed/pipeline_compiled.py). None on any other mesh."""
+    pp = mesh.shape.get("pp", 1) if mesh is not None else 1
+    if pp == 1:
+        return None
+    if num_layers % pp:
+        raise ValueError(f"num_layers {num_layers} not divisible by pp {pp}")
+    return pipelined_trunk(lambda x, blk: block_fn(x, blk)[0], mesh,
+                           pp_microbatches or 2 * pp, axis_name="pp",
+                           remat=remat)

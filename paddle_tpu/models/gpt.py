@@ -37,13 +37,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import nn
 from ..nn import functional as F
-from .._core import device
-from .._core.flags import flag_value
 from .._core.tensor import Tensor
 from ..distributed.fleet.mp_layers import (ColumnParallelLinear,
                                            RowParallelLinear,
                                            VocabParallelEmbedding)
 from . import stages
+from .blocks import (attention, gelu_mlp, layer_norm, layer_trunk,
+                     lm_head_loss, normal, scan_layers)
+from .trainer import build_adamw_train_step
 
 
 @dataclasses.dataclass
@@ -193,33 +194,27 @@ def init_gpt_params(config: GPTConfig, seed: int = 0) -> Dict[str, Any]:
     h, f_, L = config.hidden_size, config.ffn, config.num_layers
     v, s_max = config.vocab_size, config.max_position_embeddings
     std = config.initializer_range
+    out_std = std / math.sqrt(2 * L)
     dt = jnp.dtype(config.dtype)
     ks = jax.random.split(key, 8)
-
-    def norm(k, shape, scale=std):
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
-
-    params = {
-        "wte": norm(ks[0], (v, h)),
-        "wpe": norm(ks[1], (s_max, h)),
+    return {
+        "wte": normal(ks[0], (v, h), std, dt),
+        "wpe": normal(ks[1], (s_max, h), std, dt),
         "blocks": {
             "ln1_g": jnp.ones((L, h), dt), "ln1_b": jnp.zeros((L, h), dt),
-            "qkv_w": norm(ks[2], (L, h, 3 * h)),
+            "qkv_w": normal(ks[2], (L, h, 3 * h), std, dt),
             "qkv_b": jnp.zeros((L, 3 * h), dt),
-            "proj_w": norm(ks[3], (L, h, h),
-                           scale=std / math.sqrt(2 * L)),
+            "proj_w": normal(ks[3], (L, h, h), out_std, dt),
             "proj_b": jnp.zeros((L, h), dt),
             "ln2_g": jnp.ones((L, h), dt), "ln2_b": jnp.zeros((L, h), dt),
-            "fc_w": norm(ks[4], (L, h, f_)),
+            "fc_w": normal(ks[4], (L, h, f_), std, dt),
             "fc_b": jnp.zeros((L, f_), dt),
-            "fo_w": norm(ks[5], (L, f_, h),
-                         scale=std / math.sqrt(2 * L)),
+            "fo_w": normal(ks[5], (L, f_, h), out_std, dt),
             "fo_b": jnp.zeros((L, h), dt),
         },
         "lnf_g": jnp.ones((h,), dt),
         "lnf_b": jnp.zeros((h,), dt),
     }
-    return params
 
 
 def param_specs(config: GPTConfig, dp: str = "dp", mp: str = "mp",
@@ -248,183 +243,109 @@ def param_specs(config: GPTConfig, dp: str = "dp", mp: str = "mp",
     }
 
 
-def _use_flash_kernel(config: GPTConfig, seq: int) -> bool:
-    """Pallas flash attention or the einsum path, decided from the config
-    and the shape. The kernel tiles the sequence by 128. On a TPU it is
-    used from seq 256 up. Anywhere else the kernel could only run in the
-    Pallas interpreter, which is a test mode: FLAGS_flash_interpret opts
-    in (CPU mesh tests / multichip dryrun), otherwise the CPU runs the
-    einsum path."""
-    if not config.use_flash_attention or seq % 128:
-        return False
-    if device.is_tpu():
-        return seq >= 256
-    return bool(flag_value("FLAGS_flash_interpret"))
-
-
-def _ln(x, g, b, eps):
-    xf = x.astype(jnp.float32)
-    mu = xf.mean(-1, keepdims=True)
-    var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g + b
-
-
 def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None):
-    """One decoder block, pure jnp. x: [B, S, H]. With sp=True the
-    residual-stream activations are sharded along the sequence dim over the
-    mp axis (Megatron-SP, sequence_parallel_utils.py analog) — GSPMD turns
-    the boundary into the all-gather/reduce-scatter pair."""
+    """One decoder block, pure jnp: x [B, S, H] -> (x, None). With
+    sp_sharding the residual-stream activations are sharded along the
+    sequence dim over the mp axis (Megatron-SP, sequence_parallel_utils.py
+    analog): GSPMD turns the boundary into the all-gather/reduce-scatter
+    pair."""
     c = config
-    b, s, h = x.shape
+    b, s, _ = x.shape
     with jax.named_scope(stages.ATTN_QKV):
         if sp_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, sp_sharding)
-        y = _ln(x, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
+        y = layer_norm(x, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
         qkv = jnp.einsum("bsh,hk->bsk", y, blk["qkv_w"]) + blk["qkv_b"]
     with jax.named_scope(stages.ATTN_CORE):
         qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = jnp.swapaxes(q, 1, 2)  # B,H,S,D
-        k = jnp.swapaxes(k, 1, 2)
-        v = jnp.swapaxes(v, 1, 2)
-        scale = 1.0 / math.sqrt(c.head_dim)
-        if _use_flash_kernel(c, s):
-            from ..ops.pallas.flash_attention import (mha_forward,
-                                                      mha_sharded)
-            if mesh_axes is not None:
-                attn = mha_sharded(q, k, v, mesh_axes, causal=True,
-                                   scale=scale)
-            else:
-                attn = mha_forward(q, k, v, causal=True, scale=scale)
-        else:
-            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-            mask = jnp.tril(jnp.ones((s, s), bool))
-            logits = jnp.where(mask, logits,
-                               jnp.array(-1e30, logits.dtype))
-            probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
-                x.dtype)
-            attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, h)
+        attn = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                         causal=True, scale=1.0 / math.sqrt(c.head_dim),
+                         flash=c.use_flash_attention, mesh=mesh_axes)
     with jax.named_scope(stages.ATTN_OUT):
         proj = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) \
             + blk["proj_b"]
         x = x + proj
     with jax.named_scope(stages.MLP):
-        y = _ln(x, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
-        y = jnp.einsum("bsh,hf->bsf", y, blk["fc_w"]) + blk["fc_b"]
-        y = jax.nn.gelu(y, approximate=True)
-        y = jnp.einsum("bsf,fh->bsh", y, blk["fo_w"]) + blk["fo_b"]
-        out = x + y
+        y = layer_norm(x, blk["ln2_g"], blk["ln2_b"], c.layer_norm_eps)
+        out = x + gelu_mlp(y, blk["fc_w"], blk["fc_b"], blk["fo_w"],
+                           blk["fo_b"])
         if sp_sharding is not None:
             out = jax.lax.with_sharding_constraint(out, sp_sharding)
-    return out
+    return out, None
 
 
-def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
-                remat=True, sp_sharding=None, pp_trunk=None,
-                return_hidden=False):
-    """Pure forward: tokens [B, S] int32 -> logits [B, S, V]. pp_trunk,
-    when given (distributed.pipeline_compiled.pipelined_trunk), replaces
-    the layer scan with the compiled pp-axis pipeline."""
-    b, s = tokens.shape
+def _hidden(params, tokens, config: GPTConfig, mesh_axes, remat,
+            sp_sharding, pp_trunk):
+    """tokens [B, S] int32 -> the final norm's output [B, S, H]."""
+    s = tokens.shape[1]
     with jax.named_scope(stages.EMBED):
         x = params["wte"][tokens] + params["wpe"][:s]
         x = x.astype(jnp.dtype(config.dtype))
-
     if pp_trunk is not None:
         x = pp_trunk(params["blocks"], x)
     else:
-        blk_fn = functools.partial(_block, config=config,
-                                   mesh_axes=mesh_axes,
-                                   sp_sharding=sp_sharding)
-        if remat:
-            blk_fn = jax.checkpoint(blk_fn)
-
-        def scan_body(carry, blk):
-            return blk_fn(carry, blk), None
-
-        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+        x, _ = scan_layers(
+            functools.partial(_block, config=config, mesh_axes=mesh_axes,
+                              sp_sharding=sp_sharding),
+            x, params["blocks"], remat)
     with jax.named_scope(stages.LOSS_HEAD):
-        x = _ln(x, params["lnf_g"], params["lnf_b"], config.layer_norm_eps)
-        if return_hidden:
-            return x
+        return layer_norm(x, params["lnf_g"], params["lnf_b"],
+                          config.layer_norm_eps)
+
+
+def gpt_forward(params, tokens, config: GPTConfig, mesh_axes=None,
+                remat=True, sp_sharding=None, pp_trunk=None):
+    """Pure forward: tokens [B, S] int32 -> logits [B, S, V] under the tied
+    head. pp_trunk, when given (`blocks.layer_trunk`), replaces the layer
+    scan with the compiled pp-axis pipeline."""
+    x = _hidden(params, tokens, config, mesh_axes, remat, sp_sharding,
+                pp_trunk)
+    with jax.named_scope(stages.LOSS_HEAD):
         return jnp.einsum("bsh,vh->bsv", x, params["wte"])
 
 
 def gpt_loss(params, tokens, labels, config: GPTConfig, mesh_axes=None,
              remat=True, sp_sharding=None, pp_trunk=None):
-    """Mean LM loss. With an mp>1 mesh the head goes through
-    vocab-parallel softmax-cross-entropy (mp_ops.py:77-385 analog):
-    wte is vocab-sharded over mp, so the full [B, S, V] logits are never
-    materialized — each shard computes [B, S, V/mp] and three small
-    collectives finish the loss."""
-    if mesh_axes is not None and "mp" in mesh_axes.axis_names \
-            and mesh_axes.shape["mp"] > 1 \
-            and config.vocab_size % mesh_axes.shape["mp"] == 0:
-        from ..distributed.fleet.mp_ops import \
-            vocab_parallel_softmax_cross_entropy
-        hidden = gpt_forward(params, tokens, config, mesh_axes, remat,
-                             sp_sharding, pp_trunk=pp_trunk,
-                             return_hidden=True)
-        with jax.named_scope(stages.LOSS_HEAD):
-            loss = vocab_parallel_softmax_cross_entropy(
-                hidden, params["wte"], labels, mesh_axes, axis="mp")
-            return loss.mean()
-    logits = gpt_forward(params, tokens, config, mesh_axes, remat,
-                         sp_sharding, pp_trunk=pp_trunk)
+    """Mean LM loss; vocabulary-parallel on a mesh with mp > 1
+    (`blocks.lm_head_loss`)."""
+    x = _hidden(params, tokens, config, mesh_axes, remat, sp_sharding,
+                pp_trunk)
     with jax.named_scope(stages.LOSS_HEAD):
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, -1)
-        picked = jnp.take_along_axis(logp, labels[..., None],
-                                     axis=-1)[..., 0]
-        return -picked.mean()
+        return lm_head_loss(x, params["wte"], labels, mesh_axes)
 
 
-def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None,
-                     lr: float = 3e-4, wd: float = 0.1, b1: float = 0.9,
-                     b2: float = 0.95, zero1: bool = True,
-                     seq_shard: bool = False, remat: bool = True,
-                     pp_microbatches: Optional[int] = None):
-    """Build (init_fn, step_fn) — step is ONE compiled XLA program:
-    fwd + bwd (remat'd scan) + AdamW, with dp/mp/sp/ZeRO1 shardings when
-    `mesh` has those axes. A 'pp' mesh axis (size>1) engages the compiled
-    collective-permute pipeline (pipeline_compiled.py) over the stacked
-    layer dim. Delegates the optimizer/sharding machinery to
-    models.trainer.build_adamw_train_step."""
-    from .trainer import build_adamw_train_step
-
-    pp_size = (mesh.shape.get("pp", 1) if mesh is not None else 1)
-    use_pp = pp_size > 1
-    if use_pp and config.num_layers % pp_size:
-        raise ValueError(f"num_layers {config.num_layers} not divisible "
-                         f"by pp {pp_size}")
-
-    pp_trunk = None
-    if use_pp:
-        from ..distributed.pipeline_compiled import pipelined_trunk
-        n_micro = pp_microbatches or 2 * pp_size
-        blk_fn = functools.partial(_block, config=config, mesh_axes=mesh,
-                                   sp_sharding=None)
-        pp_trunk = pipelined_trunk(
-            lambda x, blk: blk_fn(x, blk), mesh, n_micro, axis_name="pp",
-            remat=remat)
-
-    sp_sharding = None
-    if seq_shard and mesh is not None and "mp" in mesh.axis_names \
-            and "dp" in mesh.axis_names:
-        sp_sharding = NamedSharding(mesh, P("dp", "mp", None))
-
-    # decay only matrix weights + embeddings; LayerNorm gains/biases and
-    # bias vectors are excluded (Megatron/reference convention)
-    _DECAY_KEYS = {"wte", "wpe", "qkv_w", "proj_w", "fc_w", "fo_w"}
-    wd_mask = {
+def wd_mask(config: GPTConfig) -> Dict[str, Any]:
+    """Decay only matrix weights + embeddings; LayerNorm gains/biases and
+    bias vectors are excluded (Megatron/reference convention)."""
+    decay = {"qkv_w", "proj_w", "fc_w", "fo_w"}
+    return {
         "wte": True, "wpe": True,
-        "blocks": {k: (k in _DECAY_KEYS)
+        "blocks": {k: (k in decay)
                    for k in ["ln1_g", "ln1_b", "qkv_w", "qkv_b",
                              "proj_w", "proj_b", "ln2_g", "ln2_b",
                              "fc_w", "fc_b", "fo_w", "fo_b"]},
         "lnf_g": False, "lnf_b": False,
     }
+
+
+def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None, *,
+                     remat: bool = True, seq_shard: bool = False,
+                     pp_microbatches: Optional[int] = None, **adamw):
+    """Build (init_fn, step_fn): step is ONE compiled XLA program, fwd +
+    bwd (remat'd scan) + AdamW, with dp/mp/sp/ZeRO-1 shardings when `mesh`
+    has those axes. A 'pp' mesh axis (size > 1) engages the compiled
+    collective-permute pipeline over the stacked layer dim, in which the
+    sequence is not sharded. `adamw` (lr, wd, b1, b2, eps) goes to
+    models.trainer.build_adamw_train_step, which owns the optimizer and
+    the shardings."""
+    pp_trunk = layer_trunk(
+        functools.partial(_block, config=config, mesh_axes=mesh),
+        mesh, config.num_layers, remat, pp_microbatches)
+
+    sp_sharding = None
+    if seq_shard and mesh is not None and "mp" in mesh.axis_names \
+            and "dp" in mesh.axis_names:
+        sp_sharding = NamedSharding(mesh, P("dp", "mp", None))
 
     def loss_fn(params, tokens, labels):
         return gpt_loss(params, tokens, labels, config, mesh_axes=mesh,
@@ -433,5 +354,5 @@ def build_train_step(config: GPTConfig, mesh: Optional[Mesh] = None,
 
     return build_adamw_train_step(
         loss_fn, functools.partial(init_gpt_params, config),
-        param_specs(config, pp="pp" if use_pp else None), wd_mask,
-        mesh=mesh, lr=lr, wd=wd, b1=b1, b2=b2, zero1=zero1)
+        param_specs(config, pp=None if pp_trunk is None else "pp"),
+        wd_mask(config), mesh=mesh, **adamw)

@@ -26,7 +26,8 @@ the absent chips. None means all.
 Same compiled-trainer machinery as gpt.py / llama.py: parameters of like
 layers stacked on a leading axis and scanned under whole-block
 `jax.checkpoint`, bf16 compute with fp32 master weights
-(`trainer.build_adamw_train_step`); RoPE, RMSNorm and SwiGLU are llama.py's.
+(`trainer.build_adamw_train_step`); RoPE, RMSNorm, SwiGLU, the attention
+core, the loss head and the layer scan are blocks.py's.
 """
 from __future__ import annotations
 
@@ -43,9 +44,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..ops import moe
 from ..ops.pallas import stream_mix
 from . import stages
-from .gpt import _use_flash_kernel
-from .llama import _rms, _rope, _swiglu
+from .blocks import (attention, lm_head_loss, normal, rms_norm, rope,
+                     scan_layers, swiglu)
 from .trainer import build_adamw_train_step
+
 
 @dataclasses.dataclass
 class MlaMoeConfig:
@@ -169,8 +171,7 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
     ks = iter(jax.random.split(key, 16))
 
     def norm(shape, scale=std, dtype=dt):
-        return (jax.random.normal(next(ks), (layers,) + shape, jnp.float32)
-                * scale).astype(dtype)
+        return normal(next(ks), (layers,) + shape, scale, dtype)
 
     p = {
         "ln1_g": jnp.ones((layers, h), dt),
@@ -212,15 +213,12 @@ def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
     dt, std = jnp.dtype(c.dtype), c.initializer_range
     k = jax.random.split(jax.random.PRNGKey(seed), 4)
 
-    def norm(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)
-
     return {
-        "wte": norm(k[0], (c.vocab_size, c.hidden_size)),
+        "wte": normal(k[0], (c.vocab_size, c.hidden_size), std, dt),
         "dense": _init_layers(k[1], c.first_k_dense, c, sparse=False),
         "sparse": _init_layers(k[2], c.sparse_layers, c, sparse=True),
         "lnf_g": jnp.ones((c.hidden_size,), dt),
-        "lm_head": norm(k[3], (c.vocab_size, c.hidden_size)),
+        "lm_head": normal(k[3], (c.vocab_size, c.hidden_size), std, dt),
     }
 
 
@@ -280,44 +278,34 @@ def _attention(y, blk, c: MlaMoeConfig):
                          c.v_head_dim)
     eps = c.rms_norm_eps
     with jax.named_scope(stages.ATTN_QKV):
-        y = _rms(y, blk["ln1_g"], eps)
-        c_q = _rms(jnp.einsum("bsh,hr->bsr", y, blk["q_a_w"]),
-                   blk["q_a_ln"], eps)
+        y = rms_norm(y, blk["ln1_g"], eps)
+        c_q = rms_norm(jnp.einsum("bsh,hr->bsr", y, blk["q_a_w"]),
+                       blk["q_a_ln"], eps)
         q = jnp.einsum("bsr,rk->bsk", c_q, blk["q_b_w"])
         kv_a = jnp.einsum("bsh,hr->bsr", y, blk["kv_a_w"])
-        c_kv = _rms(kv_a[..., :c.kv_lora_rank], blk["kv_a_ln"], eps)
+        c_kv = rms_norm(kv_a[..., :c.kv_lora_rank], blk["kv_a_ln"], eps)
         k_rope = kv_a[..., c.kv_lora_rank:]
         kv = jnp.einsum("bsr,rk->bsk", c_kv, blk["kv_b_w"])
     with jax.named_scope(stages.ATTN_CORE):
         inv_freq = yarn_inv_freq(dr, c.rope_theta, c.rope_scaling)
         q = q.reshape(b, s, heads, dn + dr)
         q = jnp.concatenate(
-            [q[..., :dn], _rope(q[..., dn:], c.rope_theta, inv_freq)], -1)
-        k_rope = _rope(k_rope[:, :, None, :], c.rope_theta, inv_freq)
+            [q[..., :dn], rope(q[..., dn:], c.rope_theta, inv_freq)], -1)
+        k_rope = rope(k_rope[:, :, None, :], c.rope_theta, inv_freq)
         kv = kv.reshape(b, s, heads, dn + dv)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, heads, dr))], -1)
-        qt, kt, vt = (jnp.swapaxes(a, 1, 2) for a in (q, k, kv[..., dn:]))
-        scale = attention_scale(c)
-        if _use_flash_kernel(c, s):
-            from ..ops.pallas.flash_attention import mha_forward
-            attn = mha_forward(qt, kt, vt, causal=True, scale=scale)
-        else:
-            logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * scale
-            mask = jnp.tril(jnp.ones((s, s), bool))
-            logits = jnp.where(mask, logits, jnp.array(-1e30, logits.dtype))
-            probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
-                y.dtype)
-            attn = jnp.einsum("bhqk,bhkd->bhqd", probs, vt)
-        attn = jnp.swapaxes(attn, 1, 2).reshape(b, s, heads * dv)
+        attn = attention(q, k, kv[..., dn:], causal=True,
+                         scale=attention_scale(c),
+                         flash=c.use_flash_attention)
     with jax.named_scope(stages.ATTN_OUT):
         return jnp.einsum("bsk,kh->bsh", attn, blk["o_w"]), None
 
 
 def _dense_ffn(y, blk, c: MlaMoeConfig):
     with jax.named_scope(stages.MLP):
-        y = _rms(y, blk["ln2_g"], c.rms_norm_eps)
-        return _swiglu(y, blk["gate_w"], blk["up_w"], blk["down_w"]), None
+        y = rms_norm(y, blk["ln2_g"], c.rms_norm_eps)
+        return swiglu(y, blk["gate_w"], blk["up_w"], blk["down_w"]), None
 
 
 def _sparse_ffn(y, blk, c: MlaMoeConfig):
@@ -325,9 +313,9 @@ def _sparse_ffn(y, blk, c: MlaMoeConfig):
     choices [B*S, k], over all the experts."""
     b, s, h = y.shape
     with jax.named_scope(stages.MLP):
-        y = _rms(y, blk["ln2_g"], c.rms_norm_eps)
-        shared = _swiglu(y, blk["shared_gate_w"], blk["shared_up_w"],
-                         blk["shared_down_w"])
+        y = rms_norm(y, blk["ln2_g"], c.rms_norm_eps)
+        shared = swiglu(y, blk["shared_gate_w"], blk["shared_up_w"],
+                        blk["shared_down_w"])
     flat = y.reshape(b * s, h)
     with jax.named_scope(stages.ROUTER):
         ids, weights = moe.sigmoid_topk_route(
@@ -340,14 +328,15 @@ def _sparse_ffn(y, blk, c: MlaMoeConfig):
         return shared + routed.reshape(b, s, h), ids
 
 
-def _block(x, blk, c: MlaMoeConfig, sparse: bool):
-    """One layer on the streams x [n, B, S, h] -> (x', router choices or
-    None): an attention sub-layer and a feed-forward one, each with its own
-    stream mixing."""
+def _block(x, blk, c: MlaMoeConfig, sparse: bool, want_ids: bool):
+    """One layer on the streams x [n, B, S, h] -> (x', router choices where
+    `want_ids` and the layer is sparse, else None): an attention sub-layer
+    and a feed-forward one, each with its own stream mixing."""
     x, _ = _sublayer(x, blk["hc_attn"],
                      functools.partial(_attention, blk=blk, c=c), c)
-    return _sublayer(x, blk["hc_ffn"], functools.partial(
+    x, ids = _sublayer(x, blk["hc_ffn"], functools.partial(
         _sparse_ffn if sparse else _dense_ffn, blk=blk, c=c), c)
+    return x, ids if want_ids else None
 
 
 def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
@@ -357,21 +346,15 @@ def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
     with jax.named_scope(stages.EMBED):
         x = params["wte"][tokens].astype(jnp.dtype(c.dtype))
         x = jnp.broadcast_to(x, (c.hc_mult,) + x.shape)
-    ids = None
-    for group, sparse in (("dense", False), ("sparse", True)):
-        fn = functools.partial(_block, c=c, sparse=sparse)
-        if remat:
-            fn = jax.checkpoint(fn)
-
-        def body(carry, blk, fn=fn, keep=want_ids and sparse):
-            carry, chosen = fn(carry, blk)
-            return carry, chosen if keep else None
-
-        x, chosen = jax.lax.scan(body, x, params[group])
-        ids = chosen if sparse else ids
+    x, _ = scan_layers(
+        functools.partial(_block, c=c, sparse=False, want_ids=False), x,
+        params["dense"], remat)
+    x, ids = scan_layers(
+        functools.partial(_block, c=c, sparse=True, want_ids=want_ids), x,
+        params["sparse"], remat)
     with jax.named_scope(stages.LOSS_HEAD):
         x = x.astype(jnp.float32).sum(0).astype(x.dtype)
-        return _rms(x, params["lnf_g"], c.rms_norm_eps), ids
+        return rms_norm(x, params["lnf_g"], c.rms_norm_eps), ids
 
 
 def mla_moe_forward(params, tokens, config: MlaMoeConfig, remat=True):
@@ -383,11 +366,9 @@ def mla_moe_forward(params, tokens, config: MlaMoeConfig, remat=True):
 
 def mla_moe_loss(params, tokens, labels, config: MlaMoeConfig, remat=True):
     """Mean next-token cross-entropy in float32."""
-    logits = mla_moe_forward(params, tokens, config, remat)
+    x, _ = _trunk(params, tokens, config, remat, want_ids=False)
     with jax.named_scope(stages.LOSS_HEAD):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-        picked = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
-        return -picked.mean()
+        return lm_head_loss(x, params["lm_head"], labels)
 
 
 def routing_stats(params, tokens, config: MlaMoeConfig):
@@ -399,9 +380,8 @@ def routing_stats(params, tokens, config: MlaMoeConfig):
         (1, 2)).astype(jnp.int32)
 
 
-def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None,
-                     lr: float = 3e-4, wd: float = 0.1, b1: float = 0.9,
-                     b2: float = 0.95, remat: bool = True):
+def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None, *,
+                     remat: bool = True, **adamw):
     """(init_fn, step): step(state, tokens, labels) -> (state, loss) is ONE
     compiled XLA program (forward, backward through the rematted scans,
     AdamW), through `trainer.build_adamw_train_step` as gpt.py's. One chip's
@@ -416,5 +396,4 @@ def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None,
     specs = jax.tree_util.tree_map(lambda _: P(), shapes)
     return build_adamw_train_step(
         functools.partial(mla_moe_loss, config=config, remat=remat),
-        init_params, specs, wd_mask(shapes), mesh=mesh, lr=lr, wd=wd,
-        b1=b1, b2=b2)
+        init_params, specs, wd_mask(shapes), mesh=mesh, **adamw)

@@ -1,7 +1,7 @@
 """Generic compiled trainer: one XLA program = fwd + bwd + fused AdamW.
 
-Shared by the model families (gpt/llama/bert): takes a pure loss fn, a
-param-init fn, GSPMD param specs and a weight-decay mask, and returns
+Shared by the model families (gpt/llama/bert/mla_moe): takes a pure loss fn,
+a param-init fn, GSPMD param specs and a weight-decay mask, and returns
 (init_fn, step_fn) with dp/mp/pp/ZeRO-1 shardings and buffer donation —
 the TPU-native analog of the reference's fused optimizer + DistributedStrategy
 plumbing (HybridParallelOptimizer, dygraph_sharding_optimizer.py)."""
@@ -51,20 +51,19 @@ def zero1_opt_specs(specs, param_shapes, mesh: Optional[Mesh],
 
 
 def build_adamw_train_step(
-        loss_fn: Callable,            # (params, *batch) -> scalar loss
+        loss_fn: Callable,            # (params, tokens, labels) -> loss
         init_params_fn: Callable,     # (seed) -> params pytree
         specs,                        # PartitionSpec tree (or None)
         wd_mask,                      # bool tree matching params
         mesh: Optional[Mesh] = None,
         lr: float = 3e-4, wd: float = 0.1, b1: float = 0.9,
-        b2: float = 0.95, eps: float = 1e-8, zero1: bool = True,
-        batch_specs=None,             # specs for batch args (default dp)
-        n_batch_args: int = 2):
-    """Returns (init_fn, step_fn); step(state, *batch) -> (state, loss)."""
+        b2: float = 0.95, eps: float = 1e-8):
+    """Returns (init_fn, step_fn); step(state, tokens, labels) -> (state,
+    loss). On a mesh the batch is sharded over `dp` and so is the optimizer
+    state (ZeRO-1)."""
     specs = filter_specs_for_mesh(specs, mesh)
     param_shapes = jax.eval_shape(lambda: init_params_fn(0))
-    opt_specs = zero1_opt_specs(specs, param_shapes, mesh) if zero1 \
-        else specs
+    opt_specs = zero1_opt_specs(specs, param_shapes, mesh)
 
     def to_sharding(tree):
         if mesh is None:
@@ -91,8 +90,9 @@ def build_adamw_train_step(
                 "m": to_sharding(opt_specs), "v": to_sharding(opt_specs),
                 "step": NamedSharding(mesh, P())}
 
-    def step_fn(state, *batch):
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], *batch)
+    def step_fn(state, tokens, labels):
+        loss, grads = jax.value_and_grad(loss_fn)(state["params"], tokens,
+                                                  labels)
         with jax.named_scope(stages.OPTIMIZER):
             step = state["step"] + 1
             t = step.astype(jnp.float32)
@@ -124,15 +124,12 @@ def build_adamw_train_step(
                     "v": new_v, "step": step}, loss
 
     if mesh is not None:
-        if batch_specs is None:
-            batch_specs = tuple(P("dp" if "dp" in mesh.axis_names
-                                  else None, None)
-                                for _ in range(n_batch_args))
+        batch = NamedSharding(
+            mesh, P("dp" if "dp" in mesh.axis_names else None, None))
         st_sh = _state_shardings()
         jstep = jax.jit(
             step_fn,
-            in_shardings=(st_sh,) + tuple(
-                NamedSharding(mesh, sp) for sp in batch_specs),
+            in_shardings=(st_sh, batch, batch),
             out_shardings=(st_sh, NamedSharding(mesh, P())),
             donate_argnums=(0,))
     else:

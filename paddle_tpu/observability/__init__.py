@@ -23,7 +23,8 @@ and OOM postmortems with a typed re-raise — `stats()` gains a
 
 Cost when everything is off: one module-level boolean check per
 instrumentation point (`observability._state.ACTIVE`), zero registry
-work — asserted by bench_suite row 6.
+work — asserted by tests/test_observability.py
+(`test_off_mode_zero_registry_work`).
 
     python -m paddle_tpu.observability        # demo workload + stats
 """

@@ -89,7 +89,7 @@ def _lenet_step():
     """LeNet train step fed through the REAL input path — a DataLoader
     wrapped in DevicePrefetcher (FLAGS_prefetch_depth double buffer) —
     so the budget's host gap includes input feed the way a training
-    loop pays it. Also the workload bench row 9 snapshots."""
+    loop pays it."""
     import numpy as np
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F
@@ -153,8 +153,8 @@ def _resnet50_step():
 
 def _gpt2_step():
     """Eager dygraph GPT train step (the fusion-window path — the
-    compiled functional trainer bench.py measures has no per-op host
-    work to budget). Layer count/width via BUDGET_GPT_LAYERS/HIDDEN."""
+    compiled functional trainer the benchmark's cells run has no per-op
+    host work to budget). Layer count/width via BUDGET_GPT_LAYERS/HIDDEN."""
     import numpy as np
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import (GPTConfig, GPTForPretraining,

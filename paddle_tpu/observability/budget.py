@@ -93,7 +93,7 @@ def collect(run_fn: Callable[[], None], steps: int,
         was_on = flag_value("FLAGS_observability")
         enable()
         # delta against a pre-run snapshot, NOT reset(): a session that
-        # already has observability on (bench rows freeze-asserting
+        # already has observability on (a test freeze-asserting
         # counters around this call) must not have its registry wiped
         before = stats()
         _memtel.reset_peak()
@@ -144,7 +144,7 @@ def collect(run_fn: Callable[[], None], steps: int,
         "peak_flops": peak_fl,
         # cost_analysis() calls DURING the measured window: a warm
         # steady state makes ZERO (captured-once-per-compile contract,
-        # counter-asserted in tests and the bench row)
+        # counter-asserted in tests/test_compute_telemetry.py)
         "cost_analysis_calls_measured": int(cost_calls),
         **_comptel.roofline(flops, cbytes, peak=peak_fl),
         "executables": cexecs[-6:],

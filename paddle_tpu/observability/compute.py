@@ -36,7 +36,7 @@ and which ops burn the FLOPs?* Four pillars:
 Off-cost follows the house pattern: ``FLAGS_compute_telemetry`` is
 watcher-cached into ``_state.COMPUTE`` (folded into ``_state.ACTIVE``);
 off = one module-attribute read per site, zero registry and zero
-analysis work (bench_suite row 14 asserts both exactly).
+analysis work (tests/test_compute_telemetry.py asserts both exactly).
 """
 from __future__ import annotations
 

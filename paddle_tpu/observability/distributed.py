@@ -40,7 +40,7 @@ Store key namespace::
 Off-cost follows the house pattern: `FLAGS_distributed_telemetry` is
 cached into the `_state.DIST` module gate by a flag watcher; when off,
 the step hook is one module-attribute read and NO registry or store
-work happens (bench_suite row 10 asserts both exactly).
+work happens (tests/test_distributed_telemetry.py asserts both exactly).
 """
 from __future__ import annotations
 

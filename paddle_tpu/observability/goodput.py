@@ -37,7 +37,7 @@ that marks step boundaries): span begin/end events from
 base, recovery probes set the sticky flag. Accrual happens at every
 transition, so the **additivity identity** — bucket sum == wall
 since ledger start — holds by construction (asserted by
-`check_additivity`, the budget tool and bench row 16). Spans from
+`check_additivity`, the budget tool and tests/test_goodput.py). Spans from
 OTHER threads (the async flush worker) are overlapped work, not wall
 time: their durations land in a side `offthread` map, never the
 partition.
@@ -65,7 +65,8 @@ badput source per rank).
 Off-cost is the house pattern: `FLAGS_goodput` is watcher-cached into
 `_state.GOODPUT` (folded into `_state.ACTIVE` so spans exist when
 only this plane is on); off = one module-attribute read per probe,
-zero ring mutations, frozen registry (bench row 16).
+zero ring mutations, frozen registry (tests/test_goodput.py,
+`test_goodput_off_is_zero_work`).
 """
 from __future__ import annotations
 
@@ -593,7 +594,7 @@ def budget_section(before: Dict, after: Dict, steps: int) -> Dict:
     total = sum(d["buckets"].values())
     wall = d["wall_us"]
     # explicit raise, not assert: the identity must hold under
-    # python -O too (bench row 16 gates on it)
+    # python -O too (the budget tool gates on it)
     if abs(total - wall) > max(0.05 * max(wall, 1.0), 50.0):
         raise RuntimeError(
             f"goodput additivity violated: bucket sum {total:.1f}us != "

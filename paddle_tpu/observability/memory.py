@@ -35,8 +35,8 @@ memory, not FLOPs, picks the mesh degree). Four pillars:
 Off-cost follows the house pattern: ``FLAGS_memory_telemetry`` is
 watcher-cached into the ``_state.MEM`` module gate (folded into
 ``_state.ACTIVE``); off = one module-attribute read at every choke
-point, zero census and zero registry work (bench_suite row 11 asserts
-both exactly).
+point, zero census and zero registry work
+(tests/test_memory_telemetry.py asserts both exactly).
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@ The registry itself is always functional — gating lives at the
 instrumented CALL SITES (one `_state.ACTIVE`/`_state.METRICS` check),
 so a subsystem that is already behind its own flag (the program
 sanitizer's sweep counter) can count unconditionally. `MUTATIONS`
-counts every registry update; bench_suite row 6 asserts it stays
-frozen across the dispatch microbench with observability off — the
+counts every registry update; tests/test_observability.py asserts it
+stays frozen across a dispatch loop with observability off — the
 "zero instrumentation work when disabled" contract, exact and immune
 to wall-clock noise (same technique as the sanitizer's row 5).
 

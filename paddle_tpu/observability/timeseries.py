@@ -32,7 +32,8 @@ sustained shift is reported exactly once, not every sample.
 
 Off = the usual discipline: ONE module-attribute read per step hook
 (`_state.MONITOR`), no sampler thread, no bound port, zero registry
-mutations — asserted by bench row 20.
+mutations — asserted by tests/test_monitor.py
+(`test_monitor_off_is_free_across_lenet_loop`).
 """
 from __future__ import annotations
 

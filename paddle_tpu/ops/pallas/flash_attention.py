@@ -137,12 +137,15 @@ def _check_vmem(q, k, v, backward: bool):
             f"{max_seq(d, q.dtype, True, dv)} with the backward")
 
 
+# Largest query and key block. PR 25 swept twelve block pairs on a v5e at
+# gpt2-medium's and gpt3-1.3b's shapes: every pair other than 512 x 512 was
+# slower (ROADMAP C3).
+MAX_BLOCK = 512
+
+
 def _block_sizes(sq: int, sk: int, d: int):
-    from ..._core.flags import flag_value
-    cap_q = int(flag_value("FLAGS_flash_block_q"))
-    cap_k = int(flag_value("FLAGS_flash_block_k"))
-    bq = min(cap_q, sq) if sq % cap_q == 0 else min(128, sq)
-    bk = min(cap_k, sk) if sk % cap_k == 0 else min(128, sk)
+    bq = min(MAX_BLOCK, sq) if sq % MAX_BLOCK == 0 else min(128, sq)
+    bk = min(MAX_BLOCK, sk) if sk % MAX_BLOCK == 0 else min(128, sk)
     if sq % bq:
         bq = sq  # small/ragged: single block (wrapper pads first)
     if sk % bk:

@@ -1,0 +1,278 @@
+"""models/blocks.py: what the four compiled families are built from, each
+against its plain formula, and the seam kept: the kernel decision, the
+vocabulary-parallel head and the layer loop live in blocks.py alone, and a
+seed gives the weights it gave before the families shared it."""
+import ast
+import hashlib
+import math
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from conftest import with_flag
+from jax.sharding import Mesh
+
+from paddle_tpu._core import device
+from paddle_tpu.models import bert, blocks, gpt, llama, mla_moe
+
+MODELS = pathlib.Path(blocks.__file__).parent
+
+
+# --------------------------------------------------------------- attention
+
+def _former(q, k, v, scale_scores, causal, mask=None):
+    """The einsum-softmax path as each family wrote it before blocks.py:
+    head-major operands, the family's way of scaling, tril or an additive
+    mask, float32 softmax, merge."""
+    b, s, h, _ = q.shape
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
+    logits = scale_scores(jnp.einsum("bhqd,bhkd->bhqk", q, k))
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits, -1e30)
+    if mask is not None:
+        logits = logits + mask
+    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    return jnp.swapaxes(out, 1, 2).reshape(b, s, h * v.shape[-1])
+
+
+def _qkv(seq, heads, d_qk, d_v, kv_heads=None, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(keys[0], (2, seq, heads, d_qk), jnp.float32),
+            jax.random.normal(keys[1], (2, seq, kv_heads or heads, d_qk),
+                              jnp.float32),
+            jax.random.normal(keys[2], (2, seq, kv_heads or heads, d_v),
+                              jnp.float32))
+
+
+@pytest.mark.parametrize("family", ["gpt", "bert", "bert_masked",
+                                    "llama_gqa", "mla_moe"])
+def test_attention_is_each_familys_former_formula(family):
+    d, d_v = (24, 16) if family == "mla_moe" else (32, 32)
+    q, k, v = _qkv(48, 4, d, d_v, kv_heads=2 if family == "llama_gqa"
+                   else None)
+    if family == "llama_gqa":       # the repeat stays the family's
+        k, v = (jnp.repeat(a, 2, axis=2) for a in (k, v))
+    mask = None
+    if family == "bert_masked":
+        keep = jnp.arange(48)[None, :] < jnp.array([[48], [31]])
+        mask = (1.0 - keep[:, None, None, :].astype(jnp.float32)) * -1e30
+    causal = not family.startswith("bert")
+    if family in ("gpt", "mla_moe"):
+        scale = 0.7 / math.sqrt(d)
+        want = _former(q, k, v, lambda s: s * scale, causal)
+    else:       # bert and llama divided by the root
+        scale = 1.0 / math.sqrt(d)
+        want = _former(q, k, v, lambda s: s / math.sqrt(d), causal, mask)
+    got = blocks.attention(q, k, v, causal=causal, scale=scale, flash=False,
+                           mask=mask)
+    assert got.shape == (2, 48, 4 * d_v)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("seq", [128, 256])
+def test_attention_kernel_in_the_interpreter_equals_einsum(seq):
+    q, k, v = _qkv(seq, 2, 64, 64, seed=1)
+    scale = 1.0 / 8
+    plain = blocks.attention(q, k, v, causal=True, scale=scale, flash=False)
+    with with_flag("FLAGS_flash_interpret", True):
+        assert blocks.use_flash_kernel(True, seq)
+        kernel = blocks.attention(q, k, v, causal=True, scale=scale,
+                                  flash=True)
+    np.testing.assert_allclose(kernel, plain, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("flash, seq, tpu, interpret, want", [
+    (True, 1024, True, False, True), (False, 1024, True, False, False),
+    (True, 128, True, False, False), (True, 1000, True, False, False),
+    (True, 1024, False, False, False), (True, 128, False, True, True)])
+def test_kernel_or_einsum_is_decided_once(flash, seq, tpu, interpret, want,
+                                          monkeypatch):
+    monkeypatch.setattr(device, "is_tpu", lambda: tpu)
+    with with_flag("FLAGS_flash_interpret", interpret):
+        assert blocks.use_flash_kernel(flash, seq) is want
+
+
+# --------------------------------------------------------------- loss head
+
+def _np_nll(logits, labels):
+    x = np.asarray(logits, np.float64)
+    x = x - x.max(-1, keepdims=True)
+    logp = x - np.log(np.exp(x).sum(-1, keepdims=True))
+    return -np.take_along_axis(logp, np.maximum(labels, 0)[..., None],
+                               -1)[..., 0]
+
+
+def _logits_labels():
+    rng = np.random.RandomState(3)
+    return (jnp.asarray(rng.randn(2, 12, 50) * 3, jnp.bfloat16),
+            rng.randint(0, 50, (2, 12)).astype(np.int32))
+
+
+def test_cross_entropy_against_numpy():
+    logits, labels = _logits_labels()
+    want = _np_nll(logits.astype(jnp.float32), labels)
+    got = blocks.cross_entropy(logits, jnp.asarray(labels))
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want.mean(), rtol=1e-5)
+    # masked: the mean is over the labelled positions alone
+    masked = np.where(np.arange(12)[None, :] % 3 == 0, labels, -100)
+    got = blocks.cross_entropy(logits, jnp.asarray(masked),
+                               ignore_negative=True)
+    np.testing.assert_allclose(got, want[masked >= 0].mean(), rtol=1e-5)
+
+
+def test_masked_cross_entropy_with_every_or_no_position_labelled():
+    logits, labels = _logits_labels()
+    assert float(blocks.cross_entropy(logits, jnp.asarray(labels), True)) \
+        == pytest.approx(float(blocks.cross_entropy(
+            logits, jnp.asarray(labels))), rel=1e-6)
+    none = jnp.full(labels.shape, -100, jnp.int32)
+    assert float(blocks.cross_entropy(logits, none, True)) == 0.0
+
+
+def test_lm_head_loss_on_an_mp_mesh_equals_the_unsharded_one():
+    rng = np.random.RandomState(4)
+    hidden = jnp.asarray(rng.randn(2, 16, 32), jnp.float32)
+    head = jnp.asarray(rng.randn(64, 32) * 0.2, jnp.float32)
+    labels = jnp.asarray(rng.randint(0, 64, (2, 16)), jnp.int32)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("mp",))
+    f = jax.value_and_grad(blocks.lm_head_loss, argnums=(0, 1))
+    want, want_g = f(hidden, head, labels)
+    got, got_g = jax.jit(lambda *a: f(*a, mesh))(hidden, head, labels)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    # the sharded head never forms [B, S, V]
+    text = jax.jit(lambda *a: blocks.lm_head_loss(*a, mesh)).lower(
+        hidden, head, labels).as_text()
+    assert "x16x64x" not in text and "x16x32x" in text
+
+
+# -------------------------------------------------------------- layer loop
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_scan_layers_value_and_gradient(remat):
+    rng = np.random.RandomState(5)
+    stacked = {"w": jnp.asarray(rng.randn(3, 8, 8) * 0.3, jnp.float32),
+               "b": jnp.asarray(rng.randn(3, 8), jnp.float32)}
+    x = jnp.asarray(rng.randn(4, 8), jnp.float32)
+
+    def block(x, layer):
+        y = jnp.tanh(x @ layer["w"] + layer["b"])
+        return y, y.sum()
+
+    def loop(x, stacked):
+        for i in range(3):
+            x, _ = block(x, jax.tree_util.tree_map(lambda a: a[i], stacked))
+        return (x ** 2).sum()
+
+    def scanned(x, stacked):
+        out, ys = blocks.scan_layers(block, x, stacked, remat)
+        assert ys.shape == (3,)
+        return (out ** 2).sum()
+
+    want, want_g = jax.value_and_grad(loop, argnums=(0, 1))(x, stacked)
+    got, got_g = jax.value_and_grad(scanned, argnums=(0, 1))(x, stacked)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+        got_g, want_g)
+    text = jax.make_jaxpr(jax.grad(scanned))(x, stacked).pretty_print()
+    assert ("checkpoint" in text or "remat" in text) is remat
+
+
+def test_layer_trunk_is_the_pipeline_on_a_pp_axis_alone():
+    def block(x, layer):
+        return x, None
+
+    devs = np.asarray(jax.devices()[:4])
+    assert blocks.layer_trunk(block, None, 4, True) is None
+    assert blocks.layer_trunk(
+        block, Mesh(devs.reshape(2, 1, 2), ("dp", "pp", "mp")), 3,
+        True) is None
+    pp = Mesh(devs.reshape(1, 2, 2), ("dp", "pp", "mp"))
+    assert callable(blocks.layer_trunk(block, pp, 4, True, 2))
+    with pytest.raises(ValueError, match="not divisible by pp 2"):
+        blocks.layer_trunk(block, pp, 3, True)
+
+
+# ---------------------------------------------------------------- the seam
+
+def _imports(path):
+    """Every module a file imports, absolute from the package root."""
+    found = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.ImportFrom):
+            base = ["paddle_tpu", "models"][:3 - node.level] \
+                if node.level else []
+            module = ".".join(base + ([node.module] if node.module else []))
+            found.add(module)
+            found.update(f"{module}.{a.name}" for a in node.names)
+        elif isinstance(node, ast.Import):
+            found.update(a.name for a in node.names)
+    return found
+
+
+def test_kernels_and_collectives_enter_models_through_blocks_alone():
+    seam = ("paddle_tpu.ops.pallas.flash_attention",
+            "paddle_tpu.distributed.fleet.mp_ops",
+            "paddle_tpu.distributed.pipeline_compiled")
+    for path in MODELS.glob("*.py"):
+        reached = [m for m in _imports(path) if m.startswith(seam)]
+        if path.name == "blocks.py":
+            assert all(any(m.startswith(s) for m in reached) for s in seam)
+        else:
+            assert not reached, (path.name, reached)
+    theirs = _imports(MODELS / "mla_moe.py")
+    assert not [m for m in theirs
+                if m.startswith(("paddle_tpu.models.gpt",
+                                 "paddle_tpu.models.llama"))]
+    for family in (gpt, bert, llama, mla_moe):
+        for name in ("_ln", "_rms", "_rope", "_swiglu", "_use_flash_kernel"):
+            assert not hasattr(family, name), (family.__name__, name)
+
+
+# --------------------------------------------------------------- the seeds
+
+def _digest(params):
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(leaf.dtype).encode())
+        h.update(str(leaf.shape).encode())
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()
+
+
+# sha256 over every leaf's path, dtype, shape and bytes of the parameters
+# seed 0 gave at commit 48462e9 (PR 27), before the families shared
+# `blocks.normal`: the benchmark's reference reads the same tree, and
+# `--seed` must keep meaning the same weights
+SEEDED = {
+    "gpt": (lambda: gpt.init_gpt_params(gpt.GPTConfig(
+        vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+        max_position_embeddings=64), 0),
+        "40fe6a83f15b3e7e22b6005223520cbc6be579fb8aee8dbf082b34a04f17d0e5"),
+    "bert": (lambda: bert.init_bert_params(
+        bert.BERT_CONFIGS["bert-tiny"], 0),
+        "9acc54cd169e8c28c0719f85fc68cc1d37234e0c25d1c8e54b0ded1e3f661f2b"),
+    "llama": (lambda: llama.init_llama_params(
+        llama.LLAMA_CONFIGS["llama-tiny"], 0),
+        "2d14101f3b9f3f272d778756564018d17d178396fc185a4473f080de0053e0bd"),
+    "mla_moe": (lambda: mla_moe.init_mla_moe_params(mla_moe.MlaMoeConfig(
+        vocab_size=64, hidden_size=64, num_layers=2, first_k_dense=1,
+        num_heads=4, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=64,
+        moe_intermediate_size=32, n_routed_experts=8,
+        experts_held=(2, 4)), 0),
+        "7a3916542772c6182ae99590e6544cb194f6068dbc8f74d7517e9d5c955d1fb3"),
+}
+
+
+@pytest.mark.parametrize("family", sorted(SEEDED))
+def test_a_seed_gives_the_weights_it_gave_before(family):
+    init, want = SEEDED[family]
+    assert _digest(init()) == want

@@ -51,9 +51,9 @@ def test_llama_gqa_heads_repeat():
 def test_llama_rope_position_dependence():
     """RoPE: shifting a token's position must change its logits (unlike a
     no-PE model)."""
-    from paddle_tpu.models.llama import _rope
+    from paddle_tpu.models.blocks import rope
     x = jnp.ones((1, 4, 2, 8), jnp.float32)
-    r = _rope(x, 10000.0)
+    r = rope(x, 10000.0)
     # same content at different positions must differ after rotation
     assert not np.allclose(np.asarray(r[0, 0]), np.asarray(r[0, 3]))
     # norm is preserved (rotation)

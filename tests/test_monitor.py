@@ -247,7 +247,7 @@ def test_seeded_slowdown_fires_once_with_flight_evidence(
 def test_monitor_off_is_free_across_lenet_loop():
     """Satellite: with FLAGS_monitor off (async flush ON — the hardest
     regime) a LeNet train loop must see zero sampler threads, no bound
-    port, and a frozen registry (the bench rows 6/10/11 discipline)."""
+    port, and a frozen registry (the off-path discipline of every plane)."""
     import paddle_tpu.nn.functional as F
     from paddle_tpu.vision.models import LeNet
 
@@ -268,7 +268,7 @@ def test_monitor_off_is_free_across_lenet_loop():
     # static checks off for the freeze window: the sanitizer plane
     # (conftest runs the suite in warn mode) legitimately counts its
     # sweeps — the frozen-registry assertion is about the MONITOR
-    # being free, the bench row 6 discipline
+    # being free, as tests/test_observability.py holds the registry
     with with_flag("FLAGS_async_flush", True), \
             with_flag("FLAGS_static_checks", "off"):
         step()                                  # warm off-clock

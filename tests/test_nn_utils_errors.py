@@ -103,7 +103,7 @@ def test_flag_surface_and_aliases():
                             "FLAGS_comm_task_timeout_s",
                             "FLAGS_recompute_segments",
                             "FLAGS_amp_dtype",
-                            "FLAGS_flash_block_q",
+                            "FLAGS_flash_interpret",
                             "FLAGS_dataloader_num_workers"])
     assert got["FLAGS_fuse_buffer_size_mb"] == 25
     assert got["FLAGS_amp_dtype"] == "bfloat16"

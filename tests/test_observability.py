@@ -94,7 +94,7 @@ def test_flag_gate_sync():
 
 def test_off_mode_zero_registry_work():
     """With observability off, the dispatch microbench must do ZERO
-    registry mutations — the bench row 6 gate, asserted exactly (the
+    registry mutations, asserted exactly (the
     sanitizer is silenced too: its sweep counter is a legitimate
     registry write gated by its own flag)."""
     x = paddle.to_tensor(np.ones((8, 8), "float32"))

@@ -1,10 +1,11 @@
 """Pallas kernel numerics vs reference jnp implementations (interpret mode
 on the CPU test mesh — same kernel code that runs compiled on TPU)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import with_flag
 
 from paddle_tpu.ops.pallas import (flash_attention, mha_forward, rms_norm,
                                    swiglu, fused_rotary_position_embedding)
@@ -73,19 +74,20 @@ def _weighted_grads(attn, q, k, v, w):
 @pytest.mark.parametrize("sq,sk", [(256, 256), (256, 512)],
                          ids=["self", "cross_with_offset"])
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
-def test_mha_backward_over_many_tiles(causal, sq, sk, dtype):
+def test_mha_backward_over_many_tiles(causal, sq, sk, dtype, monkeypatch):
     """128-wide blocks, so one key block meets query blocks that see none
     of it, that the diagonal crosses and that see all of it."""
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
+        "MAX_BLOCK", 128)
     rng = np.random.RandomState(9)
     q = jnp.asarray(rng.randn(2, sq, 64), dtype)
     k = jnp.asarray(rng.randn(2, sk, 64), dtype)
     v = jnp.asarray(rng.randn(2, sk, 64), dtype)
     w = jnp.asarray(rng.randn(2, sq, 64), jnp.float32)
     scale = 0.125 if dtype == jnp.bfloat16 else 0.17
-    with with_flag("FLAGS_flash_block_q", 128), \
-            with_flag("FLAGS_flash_block_k", 128):
-        got = _weighted_grads(lambda q, k, v: mha_forward(
-            q, k, v, causal=causal, scale=scale), q, k, v, w)
+    got = _weighted_grads(lambda q, k, v: mha_forward(
+        q, k, v, causal=causal, scale=scale), q, k, v, w)
     want = _weighted_grads(
         lambda q, k, v: _ref_attn(q, k, v, causal, scale,
                                   jax.lax.Precision.HIGHEST),

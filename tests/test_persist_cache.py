@@ -6,7 +6,7 @@ Contracts under test:
 - an ExecCache miss consults disk BEFORE lower().compile(): a process
   that already stored a segment reloads it without bumping
   ``compiles.segment`` (the warm-restart core, drilled cross-process
-  by bench row 18);
+  by `test_cross_process_warm_start`);
 - every integrity failure — truncation, flipped payload bytes, bad
   magic, a wrong format version — is a CLEAN recompile with a
   ``cache.persist.reject`` counter and a logged reason, never a crash,
@@ -197,8 +197,7 @@ def test_disk_budget_prunes_oldest(checks_off, tmp_path):
 
 
 def test_inactive_without_dir(checks_off, tmp_path):
-    """Both flags off: zero disk traffic (the off-freeze contract of
-    bench row 18's off leg)."""
+    """Both flags off: zero disk traffic (the off-freeze contract)."""
     assert not persist.ACTIVE
     x = paddle.to_tensor(np.full((8, 8), 4.5, "float32"))
     _fresh_compile(x)
