@@ -2,11 +2,19 @@
 
 Online-softmax blocked attention (the same math the reference reaches via
 the dynloaded flashattn CUDA lib, paddle/phi/backends/dynload/flashattn.cc;
-surface at python/paddle/nn/functional/flash_attention.py). Forward streams
-K/V blocks through VMEM against a resident Q block, carrying (m, l, acc)
-accumulators; backward is one kernel over key blocks that forms every
-score tile once from the saved log-sum-exp rows and returns dQ, dK and dV
-(five products a tile; dQ is summed across key blocks in VMEM).
+surface at python/paddle/nn/functional/flash_attention.py). Forward is one
+kernel over query blocks: it walks the key blocks a query block sees,
+carrying (m, l, acc) in float32, and writes the output and one log-sum-exp
+row per query block; backward is one kernel over key blocks that forms
+every score tile once from those rows and returns dQ, dK and dV (five
+products a tile; dQ is summed across key blocks in VMEM). Both form a
+score tile key-major ([block_k, block_q]), so the softmax statistics are
+lane-dense [1, block_q] rows, and both run two loops when causal: one over
+the blocks seen whole, whose body has no iota, compare or select, and one
+over the blocks the diagonal crosses; blocks beyond it are not visited
+(`tile_counts`). The forward takes a tile into the running softmax
+`SUB_KEYS` keys at a time and keeps the output transposed ([d_v, block_q])
+until it is written.
 
 Layout inside the kernels is [batch*heads, seq, head_dim]; the public entry
 takes paddle's [batch, seq, heads, head_dim]. Two widths: q and k share
@@ -72,23 +80,23 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
 def vmem_footprint(sq: int, sk: int, d: int, dtype, d_v: int = None) -> dict:
     """Scoped-VMEM bytes each kernel needs with its operands in HBM; `d` is
     the width of q and k, `d_v` that of v and the output (`d` if None). The
-    pipeline double-buffers every in/out block of the BlockSpecs in
-    `_fwd_call` / `_bwd`; for the forward that is all, and it agrees with
-    the compiler's own "scoped allocation" figure to its printed precision
-    (a [seq, 1] fp32 row pads to 128 lanes). The backward adds its fp32
-    dQ accumulator and what the body keeps beyond its blocks, taken as
-    three fp32 [bk, bq] tiles and the dK, dV sums: an upper estimate (the
-    compiler's own choices move its figure by a MiB either way), under
-    which the v5e ahead-of-time compiler accepted every length up to the
-    cap in steps of 512 (bf16 and fp32, d 64-256)."""
+    pipeline double-buffers every in/out block of the BlockSpecs in `_fwd`
+    / `_bwd`. To that the forward adds what its body keeps of one tile:
+    the float32 scores, p rounded to the inputs' dtype and the transposed
+    accumulator before and after a sub-block; the backward its fp32 dQ
+    accumulator, three fp32 [bk, bq] tiles and the dK, dV sums. Both are
+    upper estimates (the compiler's own choices move its figure by a MiB
+    either way), under which the v5e ahead-of-time compiler accepted every
+    length up to the cap in steps of 512 (bf16 and fp32, d 64-256)."""
     dv = d if d_v is None else d_v
     bq, bk = _block_sizes(sq, sk, d)
     blk = functools.partial(_vmem_block_bytes, dtype=dtype)
     f32 = functools.partial(_vmem_block_bytes, dtype=jnp.float32)
     return {
         # q, o; k, v; the lse row
-        "fwd": 2 * (blk(bq, d) + blk(bq, dv) + blk(sk, d) + blk(sk, dv)
-                    + f32(bq, 1)),
+        "fwd": (2 * (blk(bq, d) + blk(bq, dv) + blk(sk, d) + blk(sk, dv)
+                     + f32(1, bq))
+                + f32(bk, bq) + blk(bk, bq) + 2 * f32(dv, bq)),
         # q, dq, do; k, dk, v, dv; the lse and delta rows
         "bwd": (2 * (2 * blk(sq, d) + blk(sq, dv) + 2 * blk(bk, d)
                      + 2 * blk(bk, dv) + 2 * (sq // bq) * f32(1, bq))
@@ -137,9 +145,9 @@ def _check_vmem(q, k, v, backward: bool):
             f"{max_seq(d, q.dtype, True, dv)} with the backward")
 
 
-# Largest query and key block. PR 25 swept twelve block pairs on a v5e at
-# gpt2-medium's and gpt3-1.3b's shapes: every pair other than 512 x 512 was
-# slower (ROADMAP C3).
+# Largest query and key block. PR 25 (the backward) and PR 29 (the forward as
+# it is now) swept the block pairs on a v5e at gpt2-medium's and gpt3-1.3b's
+# shapes: every pair other than 512 x 512 was slower (ROADMAP C3).
 MAX_BLOCK = 512
 
 
@@ -155,96 +163,129 @@ def _block_sizes(sq: int, sk: int, d: int):
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
-                block_k, kv_len, q_offset):
-    qi = pl.program_id(1)
-    q = q_ref[0]                                    # [bq, d_qk]
-    bq = q.shape[0]
-    dv = v_ref.shape[2]
-    sk_pad = k_ref.shape[1]
-    nkb = sk_pad // block_k
-
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]          # [bk, d_qk]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]          # [bk, d_v]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [bq, bk]
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask &= k_pos <= q_pos + q_offset
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, dv), jnp.float32)
-    if causal:
-        # keys beyond the last valid diagonal block never contribute
-        last = (qi * bq + bq - 1) + q_offset
-        nkb_eff = jnp.minimum((last // block_k) + 1, nkb)
-    else:
-        nkb_eff = nkb
-    m, l, acc = jax.lax.fori_loop(0, nkb_eff, body, (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    # [bq, 1]: the trailing singleton keeps the block's last dim equal to
-    # the array's (TPU tiling rule) and broadcasts cleanly in the bwd
-    lse_ref[0] = (m + jnp.log(l_safe))[:, None]
-
-
-def _fwd(q, k, v, causal, scale, block_q, block_k, kv_len, q_offset):
-    bh, sq, d = q.shape
-    sk, dv = v.shape[1:]
-    grid = (bh, sq // block_q)
-    with _no_x64():
-        out, lse = _fwd_call(q, k, v, causal, scale, block_k, kv_len,
-                             q_offset, block_q, grid, bh, sq, sk, d, dv)
-    return out, lse
-
-
-def _fwd_call(q, k, v, causal, scale, block_k, kv_len, q_offset, block_q,
-              grid, bh, sq, sk, d, dv):
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                          block_k=block_k, kv_len=kv_len, q_offset=q_offset),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk, dv), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-        ],
-        interpret=pallas_interpret(),
-    )(q, k, v)
-    return out, lse
-
-
-# ---------------------------------------------------------------- backward
-
 _NT = (((1,), (1,)), ((), ()))      # a . b^T
 _NN = (((1,), (0,)), ((), ()))      # a . b
 _TN = (((0,), (0,)), ((), ()))      # a^T . b
 
+
+def _visible_key_blocks(qi, block_q, block_k, nkb, causal, q_offset):
+    """`(full, seen)` for query block `qi` (an index, traced or not, or an
+    array of them): key blocks [0, full) are visible to every query of the
+    block, the diagonal `k_pos <= q_pos + q_offset` crosses [full, seen),
+    and [seen, nkb) lie above it. The forward kernel's two loops run over
+    exactly these ranges."""
+    if not causal:
+        return nkb, nkb
+    limit = qi * block_q + q_offset     # last key the block's first query sees
+    full = jnp.clip((limit + 1) // block_k, 0, nkb)
+    seen = jnp.clip((limit + block_q - 1) // block_k + 1, full, nkb)
+    return full, seen
+
+
+def tile_counts(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+                q_offset: int):
+    """`(masked, full, skipped)` score tiles of one batch*head in the
+    forward: tiles that run the body with the diagonal's compare, tiles
+    that run the one without, and tiles that are not visited."""
+    nqb, nkb = sq // block_q, sk // block_k
+    full, seen = _visible_key_blocks(jnp.arange(nqb), block_q, block_k, nkb,
+                                     causal, q_offset)
+    full = int(jnp.sum(jnp.broadcast_to(full, (nqb,))))
+    seen = int(jnp.sum(jnp.broadcast_to(seen, (nqb,))))
+    return seen - full, full, nqb * nkb - seen
+
+
+# Keys of a score tile taken into the running softmax at a time. The whole
+# tile's k.qT is issued first; walked in sub-blocks of this many keys, one
+# sub-block's exponentials run while the MXU streams the rest of the tile and
+# the previous sub-block's vT.pT (PR 29, v5e: 128 beat 64, 256 and the whole
+# tile at every shape; it is one MXU pass of the contraction).
+SUB_KEYS = 128
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
+                block_k, q_offset):
+    """One (batch*head, query block) grid step over the key blocks this
+    query block sees. Every score tile is key-major ([bk, bq]), so the
+    running maximum and sum are sublane reductions into lane-dense [1, bq]
+    rows and the accumulator is the transposed output [d_v, bq], turned
+    once at the end. Only the tiles the diagonal crosses pay for a mask."""
+    qi = pl.program_id(1)
+    bq = q_ref.shape[1]
+    dv = v_ref.shape[2]
+    nkb = k_ref.shape[1] // block_k
+    # a ragged length is one key block of its own size, taken whole
+    sub = SUB_KEYS if block_k % SUB_KEYS == 0 else block_k
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+
+    def tile(masked, j, carry):
+        m, l, acct = carry                  # [1, bq], [1, bq], [d_v, bq]
+        first = pl.multiple_of(j * block_k, block_k)
+        # q is read here, not above the loops: held across them it is
+        # spilled to VMEM and read back in every tile
+        st = dot(k_ref[0, pl.ds(first, block_k), :], q_ref[0], _NT) * scale
+        if masked:
+            k_minus_q = (jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 0)
+                         - jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 1))
+            # k_pos <= q_pos + q_offset, positions counted from the tile's corner
+            diagonal = qi * bq + q_offset - j * block_k
+        for r in range(0, block_k, sub):
+            s = st[r:r + sub]                                   # [sub, bq]
+            if masked:
+                s = jnp.where(k_minus_q <= diagonal - r, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            pt = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(pt, axis=0, keepdims=True)
+            v = v_ref[0, pl.ds(first + r, sub), :]              # [sub, d_v]
+            acct = alpha * acct + dot(v, pt.astype(v.dtype), _TN)
+            m = m_new
+        return m, l, acct
+
+    full, seen = _visible_key_blocks(qi, bq, block_k, nkb, causal, q_offset)
+    carry = (jnp.full((1, bq), NEG_INF, jnp.float32),
+             jnp.zeros((1, bq), jnp.float32),
+             jnp.zeros((dv, bq), jnp.float32))
+    carry = jax.lax.fori_loop(0, full, functools.partial(tile, False), carry)
+    if causal:
+        carry = jax.lax.fori_loop(full, seen, functools.partial(tile, True),
+                                  carry)
+    m, l, acct = carry
+    l_safe = jnp.where(l == 0.0, 1.0, l)            # a block that saw no key
+    o_ref[0] = (acct / l_safe).T.astype(o_ref.dtype)
+    lse_ref[0, 0] = m + jnp.log(l_safe)
+
+
+def _fwd(q, k, v, causal, scale, block_q, block_k, q_offset):
+    """out [bh, sq, d_v] and lse as the backward reads it: one lane-dense
+    float32 row per query block, [bh, nqb, 1, block_q]."""
+    bh, sq, d = q.shape
+    sk, dv = v.shape[1:]
+    nqb = sq // block_q
+    with _no_x64():
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, causal=causal, scale=scale,
+                              block_k=block_k, q_offset=q_offset),
+            grid=(bh, nqb),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, sk, dv), lambda b, i: (b, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
+                jax.ShapeDtypeStruct((bh, nqb, 1, block_q), jnp.float32),
+            ],
+            interpret=pallas_interpret(),
+        )(q, k, v)
+
+
+# ---------------------------------------------------------------- backward
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dqt_acc, *, causal, scale, block_q,
@@ -279,7 +320,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = dot(k, q, _NT) * scale                 # [bk, bq]
         pt = jnp.exp(st - lse_ref[0, i])
         if masked:
-            # k_pos <= q_pos + q_offset, positions counted from the tile's
+            # k_pos <= q_pos + q_offset, positions counted from the tile's corner
             pt = jnp.where(
                 k_minus_q <= i * block_q + q_offset - kj * bk, pt, 0.0)
         dst = (pt * (dot(v, do, _NT) - delta_ref[0, i])).astype(q.dtype)
@@ -344,8 +385,7 @@ def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, q_offset):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=pallas_interpret(),
-        )(q, k, v, do, lse.reshape(bh, nqb, 1, block_q),
-          delta.reshape(bh, nqb, 1, block_q))
+        )(q, k, v, do, lse, delta.reshape(bh, nqb, 1, block_q))
 
 
 # ---------------------------------------------------------------- public
@@ -360,8 +400,7 @@ def _fwd_res(q, k, v, causal, scale):
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk, d)
-    out, lse = _fwd(q, k, v, causal, scale, bq, bk, kv_len=sk,
-                    q_offset=sk - sq)
+    out, lse = _fwd(q, k, v, causal, scale, bq, bk, q_offset=sk - sq)
     return out, (q, k, v, out, lse)
 
 

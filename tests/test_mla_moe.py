@@ -541,7 +541,8 @@ def test_one_width_is_what_it_was():
     f32 = functools.partial(fa._vmem_block_bytes, dtype=jnp.float32)
     for s, d in ((1024, 64), (2048, 128), (2560, 192)):
         bq, bk = fa._block_sizes(s, s, d)
-        old = {"fwd": 2 * (2 * blk(bq, d) + 2 * blk(s, d) + f32(bq, 1)),
+        old = {"fwd": (2 * (2 * blk(bq, d) + 2 * blk(s, d) + f32(1, bq))
+                       + f32(bk, bq) + blk(bk, bq) + 2 * f32(d, bq)),
                "bwd": (2 * (3 * blk(s, d) + 4 * blk(bk, d)
                             + 2 * (s // bq) * f32(1, bq))
                        + f32(d, s) + 3 * f32(bk, bq) + 2 * f32(bk, d))}

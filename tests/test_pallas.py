@@ -11,15 +11,19 @@ from paddle_tpu.ops.pallas import (flash_attention, mha_forward, rms_norm,
                                    swiglu, fused_rotary_position_embedding)
 
 
-def _ref_attn(q, k, v, causal, scale, precision=None):
-    # [BH, S, D] fp32 reference
+def _ref_scores(q, k, causal, scale, precision=None):
     s = jnp.einsum("bqd,bkd->bqk", q, k,
                    precision=precision).astype(jnp.float32) * scale
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
+    return s
+
+
+def _ref_attn(q, k, v, causal, scale, precision=None):
+    # [BH, S, D] fp32 reference
+    p = jax.nn.softmax(_ref_scores(q, k, causal, scale, precision), axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v,
                       precision=precision)
 
@@ -133,6 +137,146 @@ def test_mha_backward_is_one_kernel_on_narrow_operands(causal):
     for dot in dots:
         assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
         assert dot.outvars[0].aval.dtype == jnp.float32
+
+
+# (max block, sq, sk): 128-wide blocks so that one query block meets key
+# blocks it sees whole, key blocks the diagonal crosses and key blocks it
+# skips; then the real 512-wide blocks, each walked in four sub-blocks
+@pytest.mark.parametrize("d,dv", [(64, 64), (192, 128), (48, 32)],
+                         ids=["d64", "mla_192_128", "d48_32"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("max_block,sq,sk", [
+    (128, 256, 256), (128, 256, 512), (512, 1024, 1024), (512, 512, 1024)],
+    ids=["self", "cross_with_offset", "self_512", "cross_with_offset_512"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_mha_forward_over_many_tiles(causal, max_block, sq, sk, dtype, d, dv,
+                                     monkeypatch):
+    """Output and the saved log-sum-exp rows against a float32 HIGHEST
+    reference, over masked, unmasked and skipped tiles."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "MAX_BLOCK", max_block)
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(2, sq, d), dtype)
+    k = jnp.asarray(rng.randn(2, sk, d), dtype)
+    v = jnp.asarray(rng.randn(2, sk, dv), dtype)
+    scale = 0.125 if dtype == jnp.bfloat16 else 0.17
+    out, (_, _, _, _, lse) = fa._fwd_res(q, k, v, causal, scale)
+    bq, bk = fa._block_sizes(sq, sk, d)
+    assert (bq, bk) == (max_block, max_block)
+    assert out.dtype == dtype and out.shape == (2, sq, dv)
+    assert lse.dtype == jnp.float32 and lse.shape == (2, sq // bq, 1, bq)
+    q32, k32, v32 = (a.astype(jnp.float32) for a in (q, k, v))
+    want = np.asarray(_ref_attn(q32, k32, v32, causal, scale,
+                                jax.lax.Precision.HIGHEST))
+    want_lse = np.asarray(jax.scipy.special.logsumexp(_ref_scores(
+        q32, k32, causal, scale, jax.lax.Precision.HIGHEST), axis=-1))
+    got = np.asarray(out, np.float32)
+    got_lse = np.asarray(lse).reshape(2, sq)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(got_lse, want_lse, rtol=1e-4, atol=1e-4)
+    else:
+        assert np.abs(got - want).max() / np.abs(want).max() <= BF16_GRAD_TOL
+        # the scores are float32 sums of exact bfloat16 products
+        np.testing.assert_allclose(got_lse, want_lse, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("sq,sk", [(192, 192), (128, 192), (320, 320)],
+                         ids=["s192", "q128_k192", "s320"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_mha_takes_a_ragged_length_as_one_block(causal, sq, sk):
+    """A length that no block divides is one block of its own size, which
+    the forward does not walk in sub-blocks."""
+    rng = np.random.RandomState(12)
+    q = jnp.asarray(rng.randn(2, sq, 64), jnp.float32)
+    k = jnp.asarray(rng.randn(2, sk, 64), jnp.float32)
+    v = jnp.asarray(rng.randn(2, sk, 64), jnp.float32)
+    w = jnp.asarray(rng.randn(2, sq, 64), jnp.float32)
+    out = mha_forward(q, k, v, causal=causal, scale=0.125)
+    ref = _ref_attn(q, k, v, causal, 0.125, jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+    got = _weighted_grads(lambda q, k, v: mha_forward(
+        q, k, v, causal=causal, scale=0.125), q, k, v, w)
+    want = _weighted_grads(lambda q, k, v: _ref_attn(
+        q, k, v, causal, 0.125, jax.lax.Precision.HIGHEST), q, k, v, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,want", [
+    (1024, 1024, 512, 512, True, (2, 1, 1)),        # gpt2-medium's cell
+    (2048, 2048, 512, 512, True, (4, 6, 6)),        # gpt3-1.3b's
+    (1024, 1024, 512, 512, False, (0, 4, 0)),
+    (256, 512, 128, 128, True, (2, 5, 1)),          # sq < sk: offset 256
+    (512, 1024, 512, 512, True, (1, 1, 0)),
+    (512, 256, 128, 128, True, (2, 1, 5)),          # sq > sk: rows that see no key
+    (1024, 1024, 512, 128, True, (8, 4, 4)),
+], ids=["gpt2m", "gpt3xl", "full", "offset", "offset_512", "negative_offset",
+        "narrow_keys"])
+def test_forward_tile_counts_against_hand_counts(sq, sk, bq, bk, causal, want):
+    """(masked, full, skipped) per batch*head, from the function that gives
+    the kernel its loop bounds."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    got = fa.tile_counts(sq, sk, bq, bk, causal, sk - sq)
+    assert got == want and sum(got) == (sq // bq) * (sk // bk)
+
+
+def _loop_bodies(jaxpr):
+    """Bodies of a kernel's loops: traced bounds make a `while`, static
+    ones a `scan`."""
+    return [e.params["body_jaxpr" if e.primitive.name == "while"
+                     else "jaxpr"].jaxpr
+            for e in jaxpr.eqns if e.primitive.name in ("while", "scan")]
+
+
+_COMPARES = {"lt", "le", "gt", "ge", "eq", "ne"}
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_mha_forward_is_one_kernel_that_masks_only_the_diagonals_tiles(causal):
+    """One pallas_call; a loop whose body holds no compare and no select
+    and, when causal, a second whose body holds one of each a sub-block;
+    bfloat16 operands on every product; lse leaves as the lane-dense rows
+    the backward reads, and the gradient reshapes or copies no lse."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    bh, s, d = 2, 1024, 64
+    a = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16)
+    bq, bk = fa._block_sizes(s, s, d)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: fa._fwd_res(q, k, v, causal, 0.125))(a, a, a)
+    calls = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    lse_shape = (bh, s // bq, 1, bq)
+    assert [o.aval.shape for o in calls[0].outvars] == [a.shape, lse_shape]
+    assert calls[0].outvars[1].aval.dtype == jnp.float32
+    kernel = calls[0].params["jaxpr"]
+    per_loop = []
+    for body in _loop_bodies(kernel):
+        names = [e.primitive.name for e in _eqns(body)]
+        per_loop.append((sum(n in _COMPARES for n in names),
+                         names.count("select_n")))
+    sub_blocks = bk // fa.SUB_KEYS
+    assert per_loop == ([(0, 0), (sub_blocks, sub_blocks)] if causal
+                        else [(0, 0)])
+    dots = [e for e in _eqns(kernel) if e.primitive.name == "dot_general"]
+    assert len(dots) == (1 + sub_blocks) * len(per_loop)
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
+        assert dot.outvars[0].aval.dtype == jnp.float32
+
+    grad = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(fa.mha_forward(
+        q, k, v, causal=causal).astype(jnp.float32)), argnums=(0, 1, 2)))(
+            a, a, a)
+    forward, backward = [e for e in _eqns(grad.jaxpr)
+                         if e.primitive.name == "pallas_call"]
+    # the very rows the forward wrote are an operand of the backward
+    assert any(v is forward.outvars[1] for v in backward.invars)
+    for e in _eqns(grad.jaxpr):
+        assert not any(v is forward.outvars[1] for v in e.invars) \
+            or e is backward, e
 
 
 def test_mha_cross_attention_shapes():
