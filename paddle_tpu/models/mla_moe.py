@@ -17,6 +17,23 @@ manifold-constrained hyper-connections, arXiv:2512.24880):
   iterations make doubly stochastic (ops/pallas/stream_mix.py: four
   kernels, each one pass over the streams).
 
+Three mechanisms are optional, and a configuration that leaves one out
+compiles none of it:
+
+- the streams: `hc_mult` None is the plain pre-norm residual, x + F(norm x)
+  (the absence of streams, not one stream through a 1 x 1 Sinkhorn);
+- the load-driven selection bias (`router_bias_update_rate`, Wang et al.
+  2024, arXiv:2408.15664; DeepSeek-V3 section 2.1.2): after each step an
+  expert that drew more than the mean of the step's pairs has its bias
+  lowered by the rate, one that drew fewer has it raised. No gradient, no
+  AdamW: `trainer.build_adamw_train_step`'s `state_update`. 0 holds the
+  bias where it is;
+- a multi-token-prediction module of depth 1 (`mtp_layers` 1; DeepSeek-V3
+  section 2.2): one more sparse layer behind the last, on parameters of its
+  own, fed the projection of [norm(hidden) ; norm(embedding of the next
+  token)], through the SAME embedding and head, with the token after next
+  as its target; the step's loss is L_main + `mtp_loss_weight` * L_mtp.
+
 `heads_held` and `experts_held` are a chip's share of a layer that several
 chips divide (tensor-parallel heads, expert-parallel experts): the
 attention output is then a partial sum over the held heads and the routed
@@ -69,7 +86,8 @@ class MlaMoeConfig:
     n_shared_experts: int = 1
     num_experts_per_tok: int = 4
     routed_scaling_factor: float = 2.0
-    hc_mult: int = 4                          # residual streams
+    hc_mult: Optional[int] = 4                # residual streams; None =
+    #                                           the plain residual
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
@@ -77,8 +95,17 @@ class MlaMoeConfig:
     rope_theta: float = 10000.0
     rope_scaling: Optional[dict] = None       # yarn's keys, as published
     initializer_range: float = 0.02
+    router_bias_update_rate: float = 0.0      # gamma; 0 = the bias is held
+    mtp_layers: int = 0                       # prediction modules: 0 or 1
+    mtp_loss_weight: float = 0.3              # lambda
     use_flash_attention: bool = True
     dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.mtp_layers not in (0, 1):
+            raise NotImplementedError(
+                "multi-token prediction of depth 1 only: each further "
+                "module feeds on the one before it")
 
     @property
     def heads(self) -> int:
@@ -183,9 +210,10 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
         "kv_b_w": norm((c.kv_lora_rank, heads * (dn + dv))),
         "o_w": norm((heads * dv, h), out_std),
         "ln2_g": jnp.ones((layers, h), dt),
-        "hc_attn": _init_hc(next(ks), layers, c),
-        "hc_ffn": _init_hc(next(ks), layers, c),
     }
+    if c.hc_mult is not None:
+        p.update(hc_attn=_init_hc(next(ks), layers, c),
+                 hc_ffn=_init_hc(next(ks), layers, c))
     if not sparse:
         f = c.intermediate_size
         p.update(gate_w=norm((h, f)), up_w=norm((h, f)),
@@ -195,7 +223,8 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
     fs = f * c.n_shared_experts
     p.update(
         # the router is float32, as the family's checkpoints keep it; its
-        # selection bias starts at zero and no gradient reaches it
+        # selection bias starts at zero and no gradient reaches it (what
+        # moves it, if anything does, is `_move_router_biases`)
         router_w=norm((h, c.n_routed_experts), dtype=jnp.float32),
         router_b=jnp.zeros((layers, c.n_routed_experts), jnp.float32),
         shared_gate_w=norm((h, fs)), shared_up_w=norm((h, fs)),
@@ -208,18 +237,30 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
 def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
     """Parameters as a pytree: the leading dense layers stacked under
     "dense", the sparse layers under "sparse" (the scan layouts), an
-    untied embedding and head."""
+    untied embedding and head; with a prediction module, under "mtp" its
+    two norms, the projection of [hidden ; embedding], its one sparse
+    layer (stacked, a stack of one) and its final norm."""
     c = config
-    dt, std = jnp.dtype(c.dtype), c.initializer_range
-    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
+    root = jax.random.PRNGKey(seed)
+    k = jax.random.split(root, 4)
 
-    return {
-        "wte": normal(k[0], (c.vocab_size, c.hidden_size), std, dt),
+    params = {
+        "wte": normal(k[0], (c.vocab_size, h), std, dt),
         "dense": _init_layers(k[1], c.first_k_dense, c, sparse=False),
         "sparse": _init_layers(k[2], c.sparse_layers, c, sparse=True),
-        "lnf_g": jnp.ones((c.hidden_size,), dt),
-        "lm_head": normal(k[3], (c.vocab_size, c.hidden_size), std, dt),
+        "lnf_g": jnp.ones((h,), dt),
+        "lm_head": normal(k[3], (c.vocab_size, h), std, dt),
     }
+    if c.mtp_layers:
+        # keys of its own, so that the trunk's do not move with the module
+        k = jax.random.split(jax.random.fold_in(root, 4), 2)
+        params["mtp"] = {
+            "hnorm_g": jnp.ones((h,), dt), "enorm_g": jnp.ones((h,), dt),
+            "eh_w": normal(k[0], (2 * h, h), std, dt),
+            "layer": _init_layers(k[1], 1, c, sparse=True),
+            "lnf_g": jnp.ones((h,), dt)}
+    return params
 
 
 def wd_mask(params) -> Dict:
@@ -243,6 +284,8 @@ def count_params(config: MlaMoeConfig) -> Dict[str, int]:
            "dense_layers": size(shapes["dense"]),
            "sparse_layers": size(shapes["sparse"]),
            "routed_experts": size(shapes["sparse"]["experts"])}
+    if "mtp" in shapes:
+        out["mtp_module"] = size(shapes["mtp"])
     out["total"] = size(shapes)
     return out
 
@@ -329,23 +372,45 @@ def _sparse_ffn(y, blk, c: MlaMoeConfig):
 
 
 def _block(x, blk, c: MlaMoeConfig, sparse: bool, want_ids: bool):
-    """One layer on the streams x [n, B, S, h] -> (x', router choices where
-    `want_ids` and the layer is sparse, else None): an attention sub-layer
-    and a feed-forward one, each with its own stream mixing."""
-    x, _ = _sublayer(x, blk["hc_attn"],
-                     functools.partial(_attention, blk=blk, c=c), c)
-    x, ids = _sublayer(x, blk["hc_ffn"], functools.partial(
-        _sparse_ffn if sparse else _dense_ffn, blk=blk, c=c), c)
+    """One layer -> (x', router choices where `want_ids` and the layer is
+    sparse, else None): an attention sub-layer and a feed-forward one. On
+    the streams x [n, B, S, h] each has its own stream mixing; without
+    streams x is [B, S, h] and each is the plain pre-norm residual."""
+    ffn = functools.partial(_sparse_ffn if sparse else _dense_ffn, blk=blk,
+                            c=c)
+    if c.hc_mult is None:
+        y, _ = _attention(x, blk, c)
+        with jax.named_scope(stages.ATTN_OUT):
+            x = x + y
+        y, ids = ffn(x)
+        with jax.named_scope(stages.MLP):
+            x = x + y
+    else:
+        x, _ = _sublayer(x, blk["hc_attn"],
+                         functools.partial(_attention, blk=blk, c=c), c)
+        x, ids = _sublayer(x, blk["hc_ffn"], ffn, c)
     return x, ids if want_ids else None
 
 
+def _spread(x, c: MlaMoeConfig):
+    """[B, S, h] -> what the blocks carry: a copy for each stream."""
+    return x if c.hc_mult is None else jnp.broadcast_to(
+        x, (c.hc_mult,) + x.shape)
+
+
+def _gather(x, c: MlaMoeConfig):
+    """What the blocks carry -> [B, S, h]: the streams summed."""
+    return x if c.hc_mult is None else x.astype(jnp.float32).sum(0).astype(
+        x.dtype)
+
+
 def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
-    """tokens [B, S] -> (final hidden [B, S, h], choices [L_sparse, T, k] or
-    None): the embedding copied to the n streams, the dense layers, the
-    sparse layers, the streams summed."""
+    """tokens [B, S] -> (the last layer's output [B, S, h], before the final
+    norm; choices [L_sparse, T, k] or None): the embedding (copied to the
+    streams where there are any), the dense layers, the sparse layers (the
+    streams summed)."""
     with jax.named_scope(stages.EMBED):
-        x = params["wte"][tokens].astype(jnp.dtype(c.dtype))
-        x = jnp.broadcast_to(x, (c.hc_mult,) + x.shape)
+        x = _spread(params["wte"][tokens].astype(jnp.dtype(c.dtype)), c)
     x, _ = scan_layers(
         functools.partial(_block, c=c, sparse=False, want_ids=False), x,
         params["dense"], remat)
@@ -353,38 +418,146 @@ def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
         functools.partial(_block, c=c, sparse=True, want_ids=want_ids), x,
         params["sparse"], remat)
     with jax.named_scope(stages.LOSS_HEAD):
-        x = x.astype(jnp.float32).sum(0).astype(x.dtype)
-        return rms_norm(x, params["lnf_g"], c.rms_norm_eps), ids
+        return _gather(x, c), ids
+
+
+def _mtp_loss(params, hidden, labels, c: MlaMoeConfig, remat: bool,
+              want_ids: bool):
+    """The prediction module on the trunk's `hidden` [B, S, h] -> (mean
+    cross-entropy of the token after next, its router's choices [1, T, k]
+    or None). `labels` [B, S] are the next tokens: their embedding is the
+    module's second input, and shifted once more they are its targets, the
+    last position having none. One scope around all of it, outside the
+    stages its layer opens (models/stages.py)."""
+    mtp, eps = params["mtp"], c.rms_norm_eps
+    with jax.named_scope(stages.MTP):
+        nxt = params["wte"][labels].astype(hidden.dtype)
+        x = jnp.concatenate([rms_norm(hidden, mtp["hnorm_g"], eps),
+                             rms_norm(nxt, mtp["enorm_g"], eps)], -1)
+        x = _spread(jnp.einsum("bsk,kh->bsh", x, mtp["eh_w"]), c)
+        x, ids = scan_layers(
+            functools.partial(_block, c=c, sparse=True, want_ids=want_ids),
+            x, mtp["layer"], remat)
+        x = rms_norm(_gather(x, c), mtp["lnf_g"], eps)
+        after_next = jnp.concatenate(
+            [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], 1)
+        return lm_head_loss(x, params["lm_head"], after_next,
+                            ignore_negative=True), ids
 
 
 def mla_moe_forward(params, tokens, config: MlaMoeConfig, remat=True):
     """tokens [B, S] int32 -> logits [B, S, V] over the held rows."""
     x, _ = _trunk(params, tokens, config, remat, want_ids=False)
     with jax.named_scope(stages.LOSS_HEAD):
+        x = rms_norm(x, params["lnf_g"], config.rms_norm_eps)
         return jnp.einsum("bsh,vh->bsv", x, params["lm_head"])
 
 
-def mla_moe_loss(params, tokens, labels, config: MlaMoeConfig, remat=True):
-    """Mean next-token cross-entropy in float32."""
-    x, _ = _trunk(params, tokens, config, remat, want_ids=False)
+def loss_parts(params, tokens, labels, config: MlaMoeConfig, remat=True,
+               want_ids=False):
+    """(L_main, L_mtp or None, choices or None): the two mean
+    cross-entropies in float32, apart, and with `want_ids` every router's
+    choices [L_sparse (+ 1 for the module's, last), T, k]."""
+    c = config
+    hidden, ids = _trunk(params, tokens, c, remat, want_ids)
     with jax.named_scope(stages.LOSS_HEAD):
-        return lm_head_loss(x, params["lm_head"], labels)
+        main = lm_head_loss(rms_norm(hidden, params["lnf_g"], c.rms_norm_eps),
+                            params["lm_head"], labels)
+    if not c.mtp_layers:
+        return main, None, ids
+    mtp, mtp_ids = _mtp_loss(params, hidden, labels, c, remat, want_ids)
+    return main, mtp, jnp.concatenate([ids, mtp_ids]) if want_ids else None
+
+
+def mla_moe_loss(params, tokens, labels, config: MlaMoeConfig, remat=True,
+                 want_ids=False):
+    """The step's scalar: mean next-token cross-entropy in float32, plus
+    `mtp_loss_weight` times the prediction module's where there is one.
+    With `want_ids`, (that, every router's choices) for `state_update`."""
+    main, mtp, ids = loss_parts(params, tokens, labels, config, remat,
+                                want_ids)
+    loss = main if mtp is None else main + config.mtp_loss_weight * mtp
+    return (loss, ids) if want_ids else loss
+
+
+def _pairs_drawn(ids, config: MlaMoeConfig):
+    """Choices [L, T, k] -> the pairs each of ALL the routed experts drew,
+    int32 [L, n_routed_experts]."""
+    return (ids[..., None] == jnp.arange(config.n_routed_experts)).sum(
+        (1, 2)).astype(jnp.int32)
 
 
 def routing_stats(params, tokens, config: MlaMoeConfig):
-    """Per sparse layer, the (token, expert) pairs each of ALL the routed
-    experts drew on this batch: int32 [L_sparse, n_routed_experts]. Jit it;
-    it runs the forward pass."""
+    """Per sparse layer of the trunk, the (token, expert) pairs each of ALL
+    the routed experts drew on this batch: int32 [L_sparse,
+    n_routed_experts]. Jit it; it runs the forward pass."""
     _, ids = _trunk(params, tokens, config, remat=False, want_ids=True)
-    return (ids[..., None] == jnp.arange(config.n_routed_experts)).sum(
-        (1, 2)).astype(jnp.int32)
+    return _pairs_drawn(ids, config)
+
+
+def step_facts(params, tokens, labels, config: MlaMoeConfig):
+    """What a step computes besides its scalar, from one forward pass:
+    `loss_main` and `loss_mtp` apart (the second absent without a module),
+    `pairs` as `routing_stats` counts them and `biases`, both [L_sparse
+    (+ 1 for the module's, last), n_routed_experts]. Jit it."""
+    main, mtp, ids = loss_parts(params, tokens, labels, config, remat=False,
+                                want_ids=True)
+    facts = {"loss_main": main, "pairs": _pairs_drawn(ids, config),
+             "biases": _router_biases(params)}
+    if mtp is not None:
+        facts["loss_mtp"] = mtp
+    return facts
+
+
+def _router_biases(params):
+    """[L_sparse (+ 1), n_routed_experts], in the order of the choices."""
+    found = [params["sparse"]["router_b"]]
+    if "mtp" in params:
+        found.append(params["mtp"]["layer"]["router_b"])
+    return jnp.concatenate(found)
+
+
+def _move_router_biases(master, ids, config: MlaMoeConfig):
+    """The trainer's `state_update`: master weights -> the same with every
+    router's selection bias moved by the load of the step that has just
+    run, b_e + gamma * sign(mean(c) - c_e), c_e the pairs that chose expert
+    e in the step's batch over ALL the experts (on one chip this chip's
+    tokens'; a deployment sums the counts over its chips first, an
+    exchange nothing here stands in for)."""
+    drawn = _pairs_drawn(ids, config).astype(jnp.float32)
+    biases = _router_biases(master)
+    biases = biases + config.router_bias_update_rate * jnp.sign(
+        drawn.mean(-1, keepdims=True) - drawn)
+    layers = config.sparse_layers
+    master = dict(master, sparse=dict(master["sparse"],
+                                      router_b=biases[:layers]))
+    if "mtp" in master:
+        layer = dict(master["mtp"]["layer"], router_b=biases[layers:])
+        master["mtp"] = dict(master["mtp"], layer=layer)
+    return master
+
+
+def move_biases_only(state, tokens, labels, config: MlaMoeConfig):
+    """The train state after the biases' move of one step WITHOUT the step:
+    a forward pass on the batch, the load it shows, `_move_router_biases`
+    on master and parameters alike; no gradient, no AdamW, the step count
+    as it was. What a deployment's thousands of steps do to the load (the
+    rule balances it, the weights hardly moving meanwhile) a benchmark can
+    reach in a few hundred of these before it times real steps. Jit it
+    with the state donated."""
+    _, ids = mla_moe_loss(state["params"], tokens, labels, config,
+                          remat=False, want_ids=True)
+    return dict(state, **{
+        name: _move_router_biases(state[name], ids, config)
+        for name in ("params", "master")})
 
 
 def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None, *,
                      remat: bool = True, **adamw):
     """(init_fn, step): step(state, tokens, labels) -> (state, loss) is ONE
     compiled XLA program (forward, backward through the rematted scans,
-    AdamW), through `trainer.build_adamw_train_step` as gpt.py's. One chip's
+    AdamW, and where the configuration has a rate the selection biases'
+    move), through `trainer.build_adamw_train_step` as gpt.py's. One chip's
     share runs without its exchange; a mesh of several chips needs an `ep`
     axis and the all-to-all, which the trainer does not have yet."""
     if mesh is not None and mesh.size > 1:
@@ -394,6 +567,10 @@ def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None, *,
     init_params = functools.partial(init_mla_moe_params, config)
     shapes = jax.eval_shape(lambda: init_params(0))
     specs = jax.tree_util.tree_map(lambda _: P(), shapes)
+    moves = bool(config.router_bias_update_rate)
     return build_adamw_train_step(
-        functools.partial(mla_moe_loss, config=config, remat=remat),
-        init_params, specs, wd_mask(shapes), mesh=mesh, **adamw)
+        functools.partial(mla_moe_loss, config=config, remat=remat,
+                          want_ids=moves),
+        init_params, specs, wd_mask(shapes), mesh=mesh,
+        state_update=functools.partial(
+            _move_router_biases, config=config) if moves else None, **adamw)

@@ -6,10 +6,17 @@ which stage it belongs to, and a device trace can be read by stage. The
 strings are written here and nowhere else: the models, the trainer and the
 benchmark's readers (`benchmarks/layer_metrics/_stages.py`) import them.
 
-Flat, never nested. A dense block is ATTN_QKV + ATTN_CORE + ATTN_OUT + MLP
-and nothing else; a sparse family (models/mla_moe.py) adds ROUTER and
-EXPERTS beside MLP, which is then its dense MLP and its shared expert, and
-RESIDUAL_MIX around every sub-layer. The same name opened twice is one stage. Direction (forward,
+Flat, never nested, with one exception (MTP, below). A dense block is
+ATTN_QKV + ATTN_CORE + ATTN_OUT + MLP and nothing else; a sparse family
+(models/mla_moe.py) adds ROUTER and EXPERTS beside MLP, which is then its
+dense MLP and its shared expert, and RESIDUAL_MIX around every sub-layer.
+The same name opened twice is one stage. MTP is the exception: one scope
+around the whole multi-token-prediction module, opened OUTSIDE the stages
+its layer and its head pass open themselves, so a path reads
+`.../jvp(mtp)/.../attn_core/...`. A reader files an instruction under the
+FIRST stage name of its path (`layer_metrics/_stages.place`), so the module
+goes whole under MTP and every other stage keeps meaning the trunk.
+Direction (forward,
 backward, what remat repeats) is not a scope: JAX writes `jvp(...)`,
 `transpose(jvp(...))` and `rematted_computation` into the path itself.
 A scope exists only while tracing; the compiled program differs by metadata
@@ -32,14 +39,21 @@ LOSS_HEAD = "loss_head"     # final norm, MLM transform (bert), logits,
 #                             float32 log-softmax or vocabulary-parallel
 #                             cross-entropy, the pick and the mean
 OPTIMIZER = "optimizer"     # all of the step after value_and_grad: AdamW
-#                             on master weights, the cast to params
+#                             on master weights, a family's own
+#                             `state_update` (the routers' selection
+#                             biases moved by load), the cast to params
 ROUTER = "moe_router"       # expert scores, the top-k, the routing weights
 EXPERTS = "moe_experts"     # the routed experts held here: sort, gather,
 #                             grouped products, activation, weighted combine
 RESIDUAL_MIX = "residual_mix"   # several residual streams: the norm of the
 #                             flattened streams, the three projections,
 #                             Sinkhorn, read-in and write-back
+MTP = "mtp"                 # the multi-token-prediction module, whole: its
+#                             two norms, the projection of [hidden ; next
+#                             embedding], its layer (which opens the block's
+#                             stages inside this one), its final norm, its
+#                             pass through the shared head and its loss
 
 BLOCK = (ATTN_QKV, ATTN_CORE, ATTN_OUT, MLP)
 ALL = (EMBED,) + BLOCK + (LOSS_HEAD, OPTIMIZER) \
-    + (ROUTER, EXPERTS, RESIDUAL_MIX)
+    + (ROUTER, EXPERTS, RESIDUAL_MIX) + (MTP,)

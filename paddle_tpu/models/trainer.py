@@ -51,16 +51,27 @@ def zero1_opt_specs(specs, param_shapes, mesh: Optional[Mesh],
 
 
 def build_adamw_train_step(
-        loss_fn: Callable,            # (params, tokens, labels) -> loss
+        loss_fn: Callable,            # (params, tokens, labels) -> loss,
+        #                               or (loss, aux) with `state_update`
         init_params_fn: Callable,     # (seed) -> params pytree
         specs,                        # PartitionSpec tree (or None)
         wd_mask,                      # bool tree matching params
         mesh: Optional[Mesh] = None,
         lr: float = 3e-4, wd: float = 0.1, b1: float = 0.9,
-        b2: float = 0.95, eps: float = 1e-8):
+        b2: float = 0.95, eps: float = 1e-8,
+        state_update: Optional[Callable] = None):
     """Returns (init_fn, step_fn); step(state, tokens, labels) -> (state,
     loss). On a mesh the batch is sharded over `dp` and so is the optimizer
-    state (ZeRO-1)."""
+    state (ZeRO-1).
+
+    `state_update(master, aux) -> master` is for what a step moves by
+    another rule than AdamW's: `loss_fn` then returns (loss, aux), and the
+    function is handed the float32 master weights as AdamW left them and
+    returns them with the leaves it replaces (the rest as they came, which
+    compiles to nothing). It runs inside the same program, before the cast
+    that makes the parameters, so master and parameter move alike; no
+    gradient, moment or weight decay has a say in what it writes. Without
+    it the step is what it was, instruction for instruction."""
     specs = filter_specs_for_mesh(specs, mesh)
     param_shapes = jax.eval_shape(lambda: init_params_fn(0))
     opt_specs = zero1_opt_specs(specs, param_shapes, mesh)
@@ -91,8 +102,11 @@ def build_adamw_train_step(
                 "step": NamedSharding(mesh, P())}
 
     def step_fn(state, tokens, labels):
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], tokens,
-                                                  labels)
+        loss, grads = jax.value_and_grad(
+            loss_fn, has_aux=state_update is not None)(
+                state["params"], tokens, labels)
+        if state_update is not None:
+            loss, aux = loss
         with jax.named_scope(stages.OPTIMIZER):
             step = state["step"] + 1
             t = step.astype(jnp.float32)
@@ -118,6 +132,8 @@ def build_adamw_train_step(
                 tree, [o[0] for o in outs])
             new_m = jax.tree_util.tree_unflatten(tree, [o[1] for o in outs])
             new_v = jax.tree_util.tree_unflatten(tree, [o[2] for o in outs])
+            if state_update is not None:
+                new_master = state_update(new_master, aux)
             new_params = jax.tree_util.tree_map(
                 lambda pm, p: pm.astype(p.dtype), new_master, state["params"])
             return {"params": new_params, "master": new_master, "m": new_m,

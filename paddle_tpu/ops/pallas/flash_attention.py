@@ -20,7 +20,10 @@ Layout inside the kernels is [batch*heads, seq, head_dim]; the public entry
 takes paddle's [batch, seq, heads, head_dim]. Two widths: q and k share
 `d` (written `d_qk` where both appear), v, the output and its gradient have
 `d_v`, read from v's shape; `d_v = d_qk` is ordinary multi-head attention,
-latent attention has 192 and 128. Every product accumulates in
+latent attention has 192 and 128, or 256 and 256 (caps in bfloat16,
+`max_seq(256, bfloat16, ., 256)`: 6,144 forward only, 2,560 with the
+backward, whose key block is 256 at those widths, `_bwd_block_k`). Every
+product accumulates in
 fp32 on the MXU (preferred_element_type) from operands of the IO dtype,
 which is whatever the caller passes (bf16 on TPU): p and ds are rounded to
 it once, the softmax math between the products is fp32. On a TPU the
@@ -84,12 +87,17 @@ def vmem_footprint(sq: int, sk: int, d: int, dtype, d_v: int = None) -> dict:
     / `_bwd`. To that the forward adds what its body keeps of one tile:
     the float32 scores, p rounded to the inputs' dtype and the transposed
     accumulator before and after a sub-block; the backward its fp32 dQ
-    accumulator, three fp32 [bk, bq] tiles and the dK, dV sums. Both are
-    upper estimates (the compiler's own choices move its figure by a MiB
-    either way), under which the v5e ahead-of-time compiler accepted every
-    length up to the cap in steps of 512 (bf16 and fp32, d 64-256)."""
+    accumulator, three fp32 [bk, bq] tiles, one more for each of the two
+    score-shaped products (k q^T over d, v dO^T over d_v) that contracts
+    256 or more, and the dK, dV sums; its key block is `_bwd_block_k`'s.
+    Both are upper estimates (the compiler's own choices move its figure by
+    a MiB either way), under which the v5e ahead-of-time compiler accepted
+    every length up to the cap in steps of 512 (bf16 and fp32, d 64-256).
+    Without the deep products' tiles it read 14.25 MiB at 160 x 2048 x
+    256 / 256 with 512-key blocks, where the compiler took 16.50."""
     dv = d if d_v is None else d_v
     bq, bk = _block_sizes(sq, sk, d)
+    bkb = _bwd_block_k(bk, d, dv)
     blk = functools.partial(_vmem_block_bytes, dtype=dtype)
     f32 = functools.partial(_vmem_block_bytes, dtype=jnp.float32)
     return {
@@ -98,9 +106,11 @@ def vmem_footprint(sq: int, sk: int, d: int, dtype, d_v: int = None) -> dict:
                      + f32(1, bq))
                 + f32(bk, bq) + blk(bk, bq) + 2 * f32(dv, bq)),
         # q, dq, do; k, dk, v, dv; the lse and delta rows
-        "bwd": (2 * (2 * blk(sq, d) + blk(sq, dv) + 2 * blk(bk, d)
-                     + 2 * blk(bk, dv) + 2 * (sq // bq) * f32(1, bq))
-                + f32(d, sq) + 3 * f32(bk, bq) + f32(bk, d) + f32(bk, dv)),
+        "bwd": (2 * (2 * blk(sq, d) + blk(sq, dv) + 2 * blk(bkb, d)
+                     + 2 * blk(bkb, dv) + 2 * (sq // bq) * f32(1, bq))
+                + f32(d, sq)
+                + (3 + (d >= DEEP_PRODUCT) + (dv >= DEEP_PRODUCT))
+                * f32(bkb, bq) + f32(bkb, d) + f32(bkb, dv)),
     }
 
 
@@ -159,6 +169,22 @@ def _block_sizes(sq: int, sk: int, d: int):
     if sk % bk:
         bk = sk
     return bq, bk
+
+
+# A product that contracts this much or more keeps its partial sums in a
+# float32 tile of its own (what the v5e compiler's figures say at 256 / 256).
+DEEP_PRODUCT = 256
+
+
+def _bwd_block_k(bk: int, d: int, dv: int) -> int:
+    """The backward's key block, from the forward's `bk`: half of a full
+    `MAX_BLOCK` where q / k or v are `DEEP_PRODUCT` wide or wider. A key
+    block carries k, dk [bk, d], v, dv [bk, d_v] and every [bk, bq] tile of
+    the body; at 512 keys and 256 / 256 the kernel does not fit at any
+    length worth having (16.50 MiB at 2,048), at 256 keys it does to 2,560.
+    The query block is the forward's always: the lse rows are written in
+    it."""
+    return bk // 2 if bk == MAX_BLOCK and max(d, dv) >= DEEP_PRODUCT else bk
 
 
 # ---------------------------------------------------------------- forward
@@ -414,8 +440,8 @@ def _mha_bwd(causal, scale, res, do):
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk, d)
-    return _bwd(q, k, v, out, lse, do, causal, scale, bq, bk,
-                q_offset=sk - sq)
+    return _bwd(q, k, v, out, lse, do, causal, scale, bq,
+                _bwd_block_k(bk, d, v.shape[-1]), q_offset=sk - sq)
 
 
 _mha.defvjp(_mha_fwd, _mha_bwd)

@@ -217,6 +217,30 @@ def test_flash_sequence_limit_is_a_named_error(v5e):
                  ((64, longest + 4096, 128), jnp.bfloat16))
 
 
+def test_flash_at_256_wide_compiles_at_the_new_cells_shape(v5e):
+    """Latent attention with a 256-wide value and all 20 heads (ISSUE 30:
+    8 x 20 x 2048 at 256 / 256): with 512-key blocks the compiler took
+    16.50 MiB for the backward and refused; `_bwd_block_k` gives it 256
+    keys, and the repaired estimate's cap is one the compiler accepts."""
+    assert fa._bwd_block_k(512, 256, 256) == fa._bwd_block_k(512, 256, 128) \
+        == 256
+    assert fa._bwd_block_k(512, 192, 128) == fa._bwd_block_k(512, 64, 64) \
+        == 512 and fa._bwd_block_k(128, 256, 256) == 128
+    cap = fa.max_seq(256, jnp.bfloat16, backward=True, d_v=256)
+    assert cap == 2560
+    assert fa.max_seq(256, jnp.bfloat16, backward=False, d_v=256) == 6144
+
+    def grad(q, k, v):
+        return jax.grad(lambda q, k, v: _sum32(fa.mha_forward(
+            q, k, v, causal=True)), (0, 1, 2))(q, k, v)
+
+    for seq in (2048, cap):
+        _compile(v5e, grad, *[((160, seq, 256), jnp.bfloat16)] * 3)
+    with pytest.raises(fa.FlashSequenceLimitError,
+                       match=f"bwd kernel .* {cap} with the"):
+        _compile(v5e, grad, *[((160, cap + 512, 256), jnp.bfloat16)] * 3)
+
+
 def test_train_step_with_flash_lowers_on_pp2_mp2_mesh(v5e):
     """Lowering only: Mosaic kernels cannot be auto-partitioned, so flash in
     a mesh program must sit in a shard_map over every axis GSPMD still owns,
