@@ -23,7 +23,7 @@ from .._core import device
 from .._core.flags import flag_value
 from ..distributed.fleet.mp_ops import vocab_parallel_softmax_cross_entropy
 from ..distributed.pipeline_compiled import pipelined_trunk
-from ..ops.pallas.flash_attention import mha_forward, mha_sharded
+from ..ops.pallas.flash_attention import mha_seq_major, mha_sharded
 
 
 def normal(key, shape, std, dtype):
@@ -99,25 +99,27 @@ def attention(q, k, v, *, causal: bool, scale: float, flash: bool,
     """softmax(scale q k^T) v on q, k [B, S, H, D] and v [B, S, H, D_v] as
     the projections leave them -> [B, S, H * D_v]. `mask`, additive and
     broadcastable to [B, H, S, S], is bert's padding mask; the kernels take
-    none, so a masked call is the einsum path whatever `flash` says. On a
-    `mesh` the kernel runs manual over every axis (`mha_sharded`)."""
+    none, so a masked call is the einsum path whatever `flash` says. The
+    kernels read the projections' own layout, the heads side by side in a
+    row (`mha_seq_major`): nothing is swapped or copied around them. On a
+    `mesh` they run manual over every axis (`mha_sharded`). The einsum
+    path swaps the heads to the front and back."""
     b, s, heads, _ = q.shape
-    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))    # [B, H, S, D]
     if mask is None and use_flash_kernel(flash, s):
+        q, k, v = (a.reshape(b, s, -1) for a in (q, k, v))  # [B, S, H D]
         if mesh is not None:
-            out = mha_sharded(q, k, v, mesh, causal=causal, scale=scale)
-        else:
-            out = mha_forward(q, k, v, causal=causal, scale=scale)
-    else:
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-        if causal:
-            logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits,
-                               jnp.array(-1e30, logits.dtype))
-        if mask is not None:
-            logits = logits + mask
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
-            q.dtype)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+            return mha_sharded(q, k, v, mesh, causal=causal, scale=scale,
+                               heads=heads)
+        return mha_seq_major(q, k, v, heads, causal=causal, scale=scale)
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))    # [B, H, S, D]
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits,
+                           jnp.array(-1e30, logits.dtype))
+    if mask is not None:
+        logits = logits + mask
+    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     return jnp.swapaxes(out, 1, 2).reshape(b, s, heads * v.shape[-1])
 
 

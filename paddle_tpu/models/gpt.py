@@ -255,10 +255,25 @@ def _block(x, blk, config: GPTConfig, mesh_axes, sp_sharding=None):
         if sp_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, sp_sharding)
         y = layer_norm(x, blk["ln1_g"], blk["ln1_b"], c.layer_norm_eps)
-        qkv = jnp.einsum("bsh,hk->bsk", y, blk["qkv_w"]) + blk["qkv_b"]
+        if mesh_axes is None or mesh_axes.shape.get("mp", 1) == 1:
+            # q, k and v each as a product of its own: three [B, S, h]
+            # arrays the kernels index as they are, where slices of one
+            # [B, S, 3h] product would be three copies
+            h = c.hidden_size
+            q, k, v = (jnp.einsum("bsh,hk->bsk", y,
+                                  blk["qkv_w"][:, i * h:(i + 1) * h])
+                       + blk["qkv_b"][i * h:(i + 1) * h] for i in range(3))
+        else:
+            # qkv_w's columns are sharded over mp as one [h, 3h] matrix: a
+            # column block of it lives on other chips than its heads, so
+            # the one product is split, by heads
+            qkv = jnp.einsum("bsh,hk->bsk", y, blk["qkv_w"]) + blk["qkv_b"]
+            qkv = qkv.reshape(b, s, 3, c.hidden_size)
+            q, k, v = (qkv[:, :, i] for i in range(3))
     with jax.named_scope(stages.ATTN_CORE):
-        qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
-        attn = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+        q, k, v = (a.reshape(b, s, c.num_heads, c.head_dim)
+                   for a in (q, k, v))
+        attn = attention(q, k, v,
                          causal=True, scale=1.0 / math.sqrt(c.head_dim),
                          flash=c.use_flash_attention, mesh=mesh_axes)
     with jax.named_scope(stages.ATTN_OUT):

@@ -328,17 +328,22 @@ def _attention(y, blk, c: MlaMoeConfig):
         kv_a = jnp.einsum("bsh,hr->bsr", y, blk["kv_a_w"])
         c_kv = rms_norm(kv_a[..., :c.kv_lora_rank], blk["kv_a_ln"], eps)
         k_rope = kv_a[..., c.kv_lora_rank:]
-        kv = jnp.einsum("bsr,rk->bsk", c_kv, blk["kv_b_w"])
+        # each head's columns of kv_b_w are its keys' then its values': v as
+        # a product of its own is written [B, S, H d_v], as the kernels
+        # read it, where a slice of the one product is a copy
+        kv_w = blk["kv_b_w"].reshape(-1, heads, dn + dv)
+        k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, kv_w[..., :dn])
+        v = jnp.einsum("bsr,rk->bsk", c_kv,
+                       kv_w[..., dn:].reshape(-1, heads * dv))
     with jax.named_scope(stages.ATTN_CORE):
         inv_freq = yarn_inv_freq(dr, c.rope_theta, c.rope_scaling)
         q = q.reshape(b, s, heads, dn + dr)
         q = jnp.concatenate(
             [q[..., :dn], rope(q[..., dn:], c.rope_theta, inv_freq)], -1)
         k_rope = rope(k_rope[:, :, None, :], c.rope_theta, inv_freq)
-        kv = kv.reshape(b, s, heads, dn + dv)
         k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, heads, dr))], -1)
-        attn = attention(q, k, kv[..., dn:], causal=True,
+            [k_nope, jnp.broadcast_to(k_rope, (b, s, heads, dr))], -1)
+        attn = attention(q, k, v.reshape(b, s, heads, dv), causal=True,
                          scale=attention_scale(c),
                          flash=c.use_flash_attention)
     with jax.named_scope(stages.ATTN_OUT):

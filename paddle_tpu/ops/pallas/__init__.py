@@ -13,10 +13,10 @@ tests/test_tpu_aot_compile.py runs the real TPU compiler on them in tier-1.
 `stream_mix` (the mixing of several residual streams, models/mla_moe.py) is
 imported as a module: `read_in`, `write_back`.
 """
-from .flash_attention import flash_attention, mha_forward
+from .flash_attention import flash_attention, mha_forward, mha_seq_major
 from .fused import rms_norm, swiglu, fused_rotary_position_embedding
 
 __all__ = [
-    "flash_attention", "mha_forward", "rms_norm", "swiglu",
+    "flash_attention", "mha_forward", "mha_seq_major", "rms_norm", "swiglu",
     "fused_rotary_position_embedding",
 ]

@@ -16,26 +16,40 @@ over the blocks the diagonal crosses; blocks beyond it are not visited
 `SUB_KEYS` keys at a time and keeps the output transposed ([d_v, block_q])
 until it is written.
 
-Layout inside the kernels is [batch*heads, seq, head_dim]; the public entry
-takes paddle's [batch, seq, heads, head_dim]. Two widths: q and k share
-`d` (written `d_qk` where both appear), v, the output and its gradient have
-`d_v`, read from v's shape; `d_v = d_qk` is ordinary multi-head attention,
-latent attention has 192 and 128, or 256 and 256 (caps in bfloat16,
-`max_seq(256, bfloat16, ., 256)`: 6,144 forward only, 2,560 with the
-backward, whose key block is 256 at those widths, `_bwd_block_k`). Every
-product accumulates in
-fp32 on the MXU (preferred_element_type) from operands of the IO dtype,
-which is whatever the caller passes (bf16 on TPU): p and ds are rounded to
-it once, the softmax math between the products is fp32. On a TPU the
-kernels are Mosaic-compiled; anywhere else they run in interpret mode
-(`_core.device.pallas_interpret`), so the CPU test mesh exercises
-identical code.
+Two entries, one kernel body. `mha_seq_major` takes q, k [batch, seq,
+heads * d_qk] and v [batch, seq, heads * d_v] as the projections write them
+and returns the output, dQ, dK and dV in that layout: a grid step handles
+the `g` heads whose columns fill whole 128-lane blocks (`head_group`: 1 at
+128 or 256 / 256, 2 at 64 and at 192 / 128), one head after another on lane
+slices of the loaded blocks, and writes the group's results as one block,
+so nothing is swapped or copied around the kernels. `mha_forward` takes
+head-major [batch*heads, seq, head_dim], one head a grid step: the same
+body as a group of one on blocks one head wide. `head_group` decides from
+the shapes alone; where it gives None (a head count g does not divide, a
+length past the grouped kernels' fit) `mha_seq_major` swaps the heads to
+the front for `mha_forward` and back. paddle's [batch, seq, heads,
+head_dim] (`flash_attention`) is the seq-major layout once flattened. Two
+widths: q and k share `d` (written `d_qk` where both appear), v, the output
+and its gradient have `d_v`, read from v's shape; `d_v = d_qk` is ordinary
+multi-head attention, latent attention has 192 and 128, or 256 and 256.
+Every product accumulates in fp32 on the MXU (preferred_element_type) from
+operands of the IO dtype, which is whatever the caller passes (bf16 on
+TPU): p and ds are rounded to it once, the softmax math between the
+products is fp32. On a TPU the kernels are Mosaic-compiled; anywhere else
+they run in interpret mode (`_core.device.pallas_interpret`), so the CPU
+test mesh exercises identical code.
 
 K/V (forward) and Q/dO/dQ (backward) stay whole-sequence resident in VMEM,
 so the sequence length is capped by the 16 MiB scoped-VMEM limit:
-`check_vmem` computes each kernel's footprint and raises
+`vmem_footprint` computes each kernel's need, `_check_vmem` raises
 `FlashSequenceLimitError` instead of letting Mosaic fail with
-RESOURCE_EXHAUSTED (README "Flash attention sequence limit").
+RESOURCE_EXHAUSTED, and the backward's key block is halved where the whole
+one does not fit (`_bwd_block_k`). The documented caps (`max_seq`; bfloat16
+with the backward: 7,168 at head_dim 64, 6,144 at 128, 3,584 at 192 / 128,
+2,560 at 256 / 256; README "Flash attention sequence limit") are the
+head-major entry's; a group's blocks are g heads wide, so the seq-major
+kernels end earlier (5,632 at 64, 2,048 at 192 / 128) and the head-major
+ones take over.
 """
 from __future__ import annotations
 
@@ -80,77 +94,157 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
     return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
 
 
-def vmem_footprint(sq: int, sk: int, d: int, dtype, d_v: int = None) -> dict:
+# A product that contracts this much or more keeps its partial sums in a
+# float32 tile of its own (what the v5e compiler's figures say at 256 / 256).
+DEEP_PRODUCT = 256
+
+
+_f32_block_bytes = functools.partial(_vmem_block_bytes, dtype=jnp.float32)
+
+
+def _fwd_bytes(bq, bk, sk, d, dv, dtype, group):
+    blk, f32 = functools.partial(_vmem_block_bytes, dtype=dtype), \
+        _f32_block_bytes
+    # q, o; k, v; the lse rows
+    need = (2 * (blk(bq, group * d) + blk(bq, group * dv)
+                 + blk(sk, group * d) + blk(sk, group * dv)
+                 + group * f32(1, bq))
+            + f32(bk, bq) + blk(bk, bq) + 2 * f32(dv, bq))
+    if group > 1:
+        # a head's lanes of the loaded q, k and v; the heads' outputs side
+        # by side before they are turned
+        need += (blk(bq, d) + blk(bk, d) + blk(bk, dv)
+                 + 2 * f32(group * dv, bq))
+    return need
+
+
+def _bwd_bytes(sq, bq, bkb, d, dv, dtype, group):
+    blk, f32 = functools.partial(_vmem_block_bytes, dtype=dtype), \
+        _f32_block_bytes
+    # q, dq, do; k, dk, v, dv; the lse and delta rows
+    need = (2 * (2 * blk(sq, group * d) + blk(sq, group * dv)
+                 + 2 * blk(bkb, group * d) + 2 * blk(bkb, group * dv)
+                 + 2 * group * (sq // bq) * f32(1, bq))
+            + f32(group * d, sq)
+            + (3 + (d >= DEEP_PRODUCT) + (dv >= DEEP_PRODUCT))
+            * f32(bkb, bq) + f32(bkb, d) + f32(bkb, dv))
+    if group > 1:
+        # a head's lanes of the loaded q, dO, k and v; the heads' dK and dV
+        # side by side before they are written
+        need += (blk(bq, d) + blk(bq, dv) + blk(bkb, d) + blk(bkb, dv)
+                 + f32(bkb, group * d) + f32(bkb, group * dv))
+    return need
+
+
+def _bwd_block_k(sq: int, sk: int, d: int, dv: int, dtype,
+                 group: int = 1) -> int:
+    """The backward's key block: the forward's, or half of it where that
+    does not fit and the half does (the smaller step first, ROADMAP A1 c).
+    A key block carries k, dk [bk, g d], v, dv [bk, g d_v] and every
+    [bk, bq] tile of the body: at 512 keys and 256 / 256 the kernel takes
+    16.50 MiB at 2,048 and fits with 256 keys to 2,560; two heads of
+    192 / 128 a grid step need 256 keys at 2,048 as well. The query block
+    is the forward's always: the lse rows are written in it."""
+    bq, bk = _block_sizes(sq, sk, d)
+    half = bk // 2
+    if (bk == MAX_BLOCK
+            and _bwd_bytes(sq, bq, bk, d, dv, dtype, group)
+            >= SCOPED_VMEM_BYTES
+            > _bwd_bytes(sq, bq, half, d, dv, dtype, group)):
+        return half
+    return bk
+
+
+def vmem_footprint(sq: int, sk: int, d: int, dtype, d_v: int = None,
+                   group: int = 1) -> dict:
     """Scoped-VMEM bytes each kernel needs with its operands in HBM; `d` is
-    the width of q and k, `d_v` that of v and the output (`d` if None). The
-    pipeline double-buffers every in/out block of the BlockSpecs in `_fwd`
-    / `_bwd`. To that the forward adds what its body keeps of one tile:
-    the float32 scores, p rounded to the inputs' dtype and the transposed
-    accumulator before and after a sub-block; the backward its fp32 dQ
-    accumulator, three fp32 [bk, bq] tiles, one more for each of the two
-    score-shaped products (k q^T over d, v dO^T over d_v) that contracts
-    256 or more, and the dK, dV sums; its key block is `_bwd_block_k`'s.
-    Both are upper estimates (the compiler's own choices move its figure by
-    a MiB either way), under which the v5e ahead-of-time compiler accepted
-    every length up to the cap in steps of 512 (bf16 and fp32, d 64-256).
-    Without the deep products' tiles it read 14.25 MiB at 160 x 2048 x
-    256 / 256 with 512-key blocks, where the compiler took 16.50."""
+    the width of q and k, `d_v` that of v and the output (`d` if None),
+    `group` the heads a grid step handles (1: the head-major entry, whose
+    blocks are one head wide; g: the seq-major entry's, g d and g d_v
+    wide, `head_group`). The pipeline double-buffers every in/out block of
+    the BlockSpecs in `_fwd` / `_bwd`. To that the forward adds what its
+    body keeps of one tile: the float32 scores, p rounded to the inputs'
+    dtype and the transposed accumulator before and after a sub-block; the
+    backward its fp32 dQ accumulator [g d, sq], three fp32 [bk, bq] tiles,
+    one more for each of the two score-shaped products (k q^T over d,
+    v dO^T over d_v) that contracts 256 or more, and the dK, dV sums; its
+    key block is `_bwd_block_k`'s. A group of several heads adds one
+    head's lanes of each loaded block and the group's results side by
+    side. Both are upper estimates (the compiler's own choices move its
+    figure by a MiB either way), under which the v5e ahead-of-time
+    compiler accepted every length up to the cap in steps of 512 (bf16
+    and fp32, d 64-256). Without the deep products' tiles it read 14.25
+    MiB at 160 x 2048 x 256 / 256 with 512-key blocks, where the compiler
+    took 16.50."""
     dv = d if d_v is None else d_v
     bq, bk = _block_sizes(sq, sk, d)
-    bkb = _bwd_block_k(bk, d, dv)
-    blk = functools.partial(_vmem_block_bytes, dtype=dtype)
-    f32 = functools.partial(_vmem_block_bytes, dtype=jnp.float32)
-    return {
-        # q, o; k, v; the lse row
-        "fwd": (2 * (blk(bq, d) + blk(bq, dv) + blk(sk, d) + blk(sk, dv)
-                     + f32(1, bq))
-                + f32(bk, bq) + blk(bk, bq) + 2 * f32(dv, bq)),
-        # q, dq, do; k, dk, v, dv; the lse and delta rows
-        "bwd": (2 * (2 * blk(sq, d) + blk(sq, dv) + 2 * blk(bkb, d)
-                     + 2 * blk(bkb, dv) + 2 * (sq // bq) * f32(1, bq))
-                + f32(d, sq)
-                + (3 + (d >= DEEP_PRODUCT) + (dv >= DEEP_PRODUCT))
-                * f32(bkb, bq) + f32(bkb, d) + f32(bkb, dv)),
-    }
+    bkb = _bwd_block_k(sq, sk, d, dv, dtype, group)
+    return {"fwd": _fwd_bytes(bq, bk, sk, d, dv, dtype, group),
+            "bwd": _bwd_bytes(sq, bq, bkb, d, dv, dtype, group)}
 
 
-def _fits(sq: int, sk: int, d: int, dtype, backward: bool, d_v: int = None):
+def _fits(sq: int, sk: int, d: int, dtype, backward: bool, d_v: int = None,
+          group: int = 1):
     """Name of the first kernel that does not fit, or None."""
-    need = vmem_footprint(sq, sk, d, dtype, d_v)
+    need = vmem_footprint(sq, sk, d, dtype, d_v, group)
     for kernel in ("fwd", "bwd") if backward else ("fwd",):
         if need[kernel] >= SCOPED_VMEM_BYTES:
             return kernel, need[kernel]
     return None
 
 
-def max_seq(d: int, dtype, backward: bool, d_v: int = None) -> int:
+def max_seq(d: int, dtype, backward: bool, d_v: int = None,
+            group: int = 1) -> int:
     """Longest self-attention sequence (a multiple of 512) whose kernels
     fit at q/k width `d` and v width `d_v` (`d` if None): forward only, or
-    forward and backward."""
+    forward and backward. `group` 1 is the head-major entry, which every
+    shape can take: its caps are the documented ones."""
     s = 0
-    while _fits(s + 512, s + 512, d, dtype, backward, d_v) is None:
+    while _fits(s + 512, s + 512, d, dtype, backward, d_v, group) is None:
         s += 512
     return s
 
 
-def _check_vmem(q, k, v, backward: bool):
-    """Raise the named limit before Mosaic raises RESOURCE_EXHAUSTED.
-    The compiler sometimes fits more by keeping a small operand in VMEM
-    itself (it depends on batch*heads); that is not a length to rely on."""
+def head_group(heads: int, d_qk: int, d_v: int, sq: int, sk: int, dtype):
+    """Heads a grid step of the seq-major entry handles on q, k
+    [B, S, heads d_qk] and v [B, S, heads d_v], or None where the shapes
+    take the head-major entry: the smallest g for which g d_qk and g d_v
+    are multiples of 128 lanes (1 at 128 or 256 / 256, 2 at 64 and at
+    192 / 128), if it divides `heads` and the grouped kernels, forward and
+    backward, fit in scoped VMEM. Their blocks are g heads wide, so they
+    end earlier than the head-major ones (5,632 against 7,168 at head_dim
+    64 in bfloat16)."""
+    group = next((g for g in (1, 2, 4, 8)
+                  if g * d_qk % 128 == 0 and g * d_v % 128 == 0), None)
+    if group is None or heads % group:
+        return None
+    if _fits(sq, sk, d_qk, dtype, True, d_v, group) is not None:
+        return None
+    return group
+
+
+def _check_vmem(q, k, v, backward: bool, heads: int = 1, group: int = 1):
+    """Raise the named limit before Mosaic raises RESOURCE_EXHAUSTED, on
+    the operands as `_mha` takes them: head-major [BH, S, D], or
+    [B, S, heads D] in groups of `group` (which `head_group` only gives
+    where they fit). The compiler sometimes fits more by keeping a small
+    operand in VMEM itself (it depends on batch*heads); that is not a
+    length to rely on."""
     if pallas_interpret():
         return
-    sq, d = q.shape[-2:]
-    sk, dv = k.shape[-2], v.shape[-1]
-    over = _fits(sq, sk, d, q.dtype, backward, dv)
+    sq, sk = q.shape[1], k.shape[1]
+    d, dv = q.shape[2] // heads, v.shape[2] // heads
+    over = _fits(sq, sk, d, q.dtype, backward, dv, group)
     if over:
         kernel, need = over
         raise FlashSequenceLimitError(
             f"flash attention {kernel} kernel at seq_q {sq}, seq_k {sk}, "
-            f"head_dim {d} (q, k) and {dv} (v), {jnp.dtype(q.dtype).name} "
+            f"head_dim {d} (q, k) and {dv} (v), {jnp.dtype(q.dtype).name}, "
+            f"{group} head{'s' * (group > 1)} a grid step, "
             f"needs {need / 2**20:.2f} MiB of scoped VMEM; the limit is "
             f"{SCOPED_VMEM_BYTES >> 20} MiB because K/V (Q, dO and dQ in the "
             "backward) stay whole-sequence resident. Longest self-attention "
-            f"sequence at these widths and dtype: "
+            f"sequence at these widths and dtype, one head a grid step: "
             f"{max_seq(d, q.dtype, False, dv)} forward only, "
             f"{max_seq(d, q.dtype, True, dv)} with the backward")
 
@@ -169,22 +263,6 @@ def _block_sizes(sq: int, sk: int, d: int):
     if sk % bk:
         bk = sk
     return bq, bk
-
-
-# A product that contracts this much or more keeps its partial sums in a
-# float32 tile of its own (what the v5e compiler's figures say at 256 / 256).
-DEEP_PRODUCT = 256
-
-
-def _bwd_block_k(bk: int, d: int, dv: int) -> int:
-    """The backward's key block, from the forward's `bk`: half of a full
-    `MAX_BLOCK` where q / k or v are `DEEP_PRODUCT` wide or wider. A key
-    block carries k, dk [bk, d], v, dv [bk, d_v] and every [bk, bq] tile of
-    the body; at 512 keys and 256 / 256 the kernel does not fit at any
-    length worth having (16.50 MiB at 2,048), at 256 keys it does to 2,560.
-    The query block is the forward's always: the lse rows are written in
-    it."""
-    return bk // 2 if bk == MAX_BLOCK and max(d, dv) >= DEEP_PRODUCT else bk
 
 
 # ---------------------------------------------------------------- forward
@@ -229,28 +307,40 @@ def tile_counts(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
 SUB_KEYS = 128
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
+def _head(x, h: int, width: int, group: int):
+    """Head `h`'s lanes of a loaded [rows, group * width] value. The value
+    is sliced, not the ref: Mosaic takes no ref view at a lane offset that
+    is not a multiple of 128. A group of one is the block itself."""
+    return x if group == 1 else x[:, h * width:(h + 1) * width]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, group, causal, scale,
                 block_k, q_offset):
-    """One (batch*head, query block) grid step over the key blocks this
-    query block sees. Every score tile is key-major ([bk, bq]), so the
-    running maximum and sum are sublane reductions into lane-dense [1, bq]
-    rows and the accumulator is the transposed output [d_v, bq], turned
-    once at the end. Only the tiles the diagonal crosses pay for a mask."""
-    qi = pl.program_id(1)
+    """One (batch, head group, query block) grid step over the key blocks
+    this query block sees, one head of the group after another. Every
+    score tile is key-major ([bk, bq]), so the running maximum and sum are
+    sublane reductions into lane-dense [1, bq] rows and the accumulator is
+    the transposed output [d_v, bq]; the group's accumulators are turned
+    once, side by side, into one [bq, g d_v] store. Only the tiles the
+    diagonal crosses pay for a mask."""
+    qi = pl.program_id(2)
     bq = q_ref.shape[1]
-    dv = v_ref.shape[2]
+    d = q_ref.shape[2] // group
+    dv = v_ref.shape[2] // group
     nkb = k_ref.shape[1] // block_k
     # a ragged length is one key block of its own size, taken whole
     sub = SUB_KEYS if block_k % SUB_KEYS == 0 else block_k
     dot = functools.partial(jax.lax.dot_general,
                             preferred_element_type=jnp.float32)
+    full, seen = _visible_key_blocks(qi, bq, block_k, nkb, causal, q_offset)
 
-    def tile(masked, j, carry):
+    def tile(h, masked, j, carry):
         m, l, acct = carry                  # [1, bq], [1, bq], [d_v, bq]
         first = pl.multiple_of(j * block_k, block_k)
         # q is read here, not above the loops: held across them it is
         # spilled to VMEM and read back in every tile
-        st = dot(k_ref[0, pl.ds(first, block_k), :], q_ref[0], _NT) * scale
+        st = dot(_head(k_ref[0, pl.ds(first, block_k), :], h, d, group),
+                 _head(q_ref[0], h, d, group), _NT) * scale
         if masked:
             k_minus_q = (jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 0)
                          - jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 1))
@@ -264,48 +354,59 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
             pt = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
             l = alpha * l + jnp.sum(pt, axis=0, keepdims=True)
-            v = v_ref[0, pl.ds(first + r, sub), :]              # [sub, d_v]
+            v = _head(v_ref[0, pl.ds(first + r, sub), :], h, dv, group)
             acct = alpha * acct + dot(v, pt.astype(v.dtype), _TN)
             m = m_new
         return m, l, acct
 
-    full, seen = _visible_key_blocks(qi, bq, block_k, nkb, causal, q_offset)
-    carry = (jnp.full((1, bq), NEG_INF, jnp.float32),
-             jnp.zeros((1, bq), jnp.float32),
-             jnp.zeros((dv, bq), jnp.float32))
-    carry = jax.lax.fori_loop(0, full, functools.partial(tile, False), carry)
-    if causal:
-        carry = jax.lax.fori_loop(full, seen, functools.partial(tile, True),
-                                  carry)
-    m, l, acct = carry
-    l_safe = jnp.where(l == 0.0, 1.0, l)            # a block that saw no key
-    o_ref[0] = (acct / l_safe).T.astype(o_ref.dtype)
-    lse_ref[0, 0] = m + jnp.log(l_safe)
+    outs = []
+    for h in range(group):
+        carry = (jnp.full((1, bq), NEG_INF, jnp.float32),
+                 jnp.zeros((1, bq), jnp.float32),
+                 jnp.zeros((dv, bq), jnp.float32))
+        carry = jax.lax.fori_loop(0, full,
+                                  functools.partial(tile, h, False), carry)
+        if causal:
+            carry = jax.lax.fori_loop(full, seen,
+                                      functools.partial(tile, h, True), carry)
+        m, l, acct = carry
+        l_safe = jnp.where(l == 0.0, 1.0, l)        # a block that saw no key
+        outs.append(acct / l_safe)
+        lse_ref[0, h, 0] = m + jnp.log(l_safe)
+    out = outs[0] if group == 1 else jnp.concatenate(outs, axis=0)
+    o_ref[0] = out.T.astype(o_ref.dtype)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, q_offset):
-    """out [bh, sq, d_v] and lse as the backward reads it: one lane-dense
-    float32 row per query block, [bh, nqb, 1, block_q]."""
-    bh, sq, d = q.shape
-    sk, dv = v.shape[1:]
+def _fwd(q, k, v, heads, group, causal, scale, block_q, block_k, q_offset):
+    """out [b, sq, heads d_v] and lse as the backward reads it: one
+    lane-dense float32 row per head and query block,
+    [b, heads, nqb, 1, block_q]. A grid step takes the `group` heads whose
+    columns make one block of q, k [b, s, heads d] and v [b, s, heads d_v];
+    the head-major entry's [bh, s, d] is `heads` 1 in a group of one."""
+    b, sq, hd = q.shape
+    sk, hdv = v.shape[1:]
+    gd, gdv = hd // heads * group, hdv // heads * group
     nqb = sq // block_q
     with _no_x64():
         return pl.pallas_call(
-            functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                              block_k=block_k, q_offset=q_offset),
-            grid=(bh, nqb),
+            functools.partial(_fwd_kernel, group=group, causal=causal,
+                              scale=scale, block_k=block_k,
+                              q_offset=q_offset),
+            grid=(b, heads // group, nqb),
             in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, sk, dv), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, block_q, gd), lambda b, g, i: (b, i, g)),
+                pl.BlockSpec((1, sk, gd), lambda b, g, i: (b, 0, g)),
+                pl.BlockSpec((1, sk, gdv), lambda b, g, i: (b, 0, g)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0)),
+                pl.BlockSpec((1, block_q, gdv), lambda b, g, i: (b, i, g)),
+                pl.BlockSpec((1, group, 1, 1, block_q),
+                             lambda b, g, i: (b, g, i, 0, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
-                jax.ShapeDtypeStruct((bh, nqb, 1, block_q), jnp.float32),
+                jax.ShapeDtypeStruct((b, sq, hdv), q.dtype),
+                jax.ShapeDtypeStruct((b, heads, nqb, 1, block_q),
+                                     jnp.float32),
             ],
             interpret=pallas_interpret(),
         )(q, k, v)
@@ -314,18 +415,20 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, q_offset):
 # ---------------------------------------------------------------- backward
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dqt_acc, *, causal, scale, block_q,
-                q_offset):
-    """One (batch*head, key block) grid step: each score tile of this key
-    block is formed once, key-major ([bk, bq], so lse and delta are
-    lane-dense [1, bq] rows and dV, dK plain products), and feeds all five
-    products on operands of the inputs' dtype. dQ is summed over the key
-    axis in VMEM, transposed ([d, sq]: the one transposed product then
-    turns the narrow k, not the tile) and written at the last key block."""
-    kj = pl.program_id(1)
-    k = k_ref[0]                                    # [bk, d_qk]
-    v = v_ref[0]                                    # [bk, d_v]
-    bk, d = k.shape
+                dq_ref, dk_ref, dv_ref, dqt_acc, *, group, causal, scale,
+                block_q, q_offset):
+    """One (batch, head group, key block) grid step, one head of the group
+    after another: each score tile of this key block is formed once,
+    key-major ([bk, bq], so lse and delta are lane-dense [1, bq] rows and
+    dV, dK plain products), and feeds all five products on operands of the
+    inputs' dtype. dQ is summed over the key axis in VMEM, transposed
+    ([g d, sq]: the one transposed product then turns the narrow k, not the
+    tile) and written at the last key block; dK and dV leave as the
+    group's [bk, g d] and [bk, g d_v] blocks."""
+    kj = pl.program_id(2)
+    bk = k_ref.shape[1]
+    d = k_ref.shape[2] // group
+    dv_width = v_ref.shape[2] // group
     nqb = q_ref.shape[1] // block_q
     dot = functools.partial(jax.lax.dot_general,
                             preferred_element_type=jnp.float32)
@@ -337,26 +440,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_minus_q = (jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
                  - jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 1))
 
-    def tile(masked, i, carry):
+    def tile(h, k, v, masked, i, carry):
         dk, dv = carry
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
-        q = q_ref[0, rows, :]                       # [bq, d_qk]
-        do = do_ref[0, rows, :]                     # [bq, d_v]
+        q = _head(q_ref[0, rows, :], h, d, group)           # [bq, d_qk]
+        do = _head(do_ref[0, rows, :], h, dv_width, group)  # [bq, d_v]
         # scaled on the float32 tile, as the forward does: lse is of that s
         st = dot(k, q, _NT) * scale                 # [bk, bq]
-        pt = jnp.exp(st - lse_ref[0, i])
+        pt = jnp.exp(st - lse_ref[0, h, i])
         if masked:
             # k_pos <= q_pos + q_offset, positions counted from the tile's corner
             pt = jnp.where(
                 k_minus_q <= i * block_q + q_offset - kj * bk, pt, 0.0)
-        dst = (pt * (dot(v, do, _NT) - delta_ref[0, i])).astype(q.dtype)
+        dst = (pt * (dot(v, do, _NT) - delta_ref[0, h, i])).astype(q.dtype)
         dv = dv + dot(pt.astype(do.dtype), do, _NN)             # [bk, d_v]
         dk = dk + dot(dst, q, _NN)                              # [bk, d_qk]
-        dqt_acc[:, rows] += dot(k, dst, _TN)                    # [d, bq]
+        dqt_acc[h * d:(h + 1) * d, rows] += dot(k, dst, _TN)    # [d, bq]
         return dk, dv
 
-    carry = (jnp.zeros((bk, d), jnp.float32),
-             jnp.zeros((bk, v.shape[1]), jnp.float32))
     if causal:
         # query blocks before `first` see none of this key block; from
         # `full` on they see all of it and the compare is left out
@@ -364,17 +465,26 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         full = jnp.clip(
             ((kj + 1) * bk - 1 - q_offset + block_q - 1) // block_q,
             first, nqb)
-        carry = jax.lax.fori_loop(first, full,
-                                  functools.partial(tile, True), carry)
     else:
         full = 0
-    dk, dv = jax.lax.fori_loop(full, nqb, functools.partial(tile, False),
-                               carry)
-    # ds's scale, applied once the tile is contracted away: on [., d]
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dks, dvs = [], []
+    for h in range(group):
+        k = _head(k_ref[0], h, d, group)                # [bk, d_qk]
+        v = _head(v_ref[0], h, dv_width, group)         # [bk, d_v]
+        carry = (jnp.zeros((bk, d), jnp.float32),
+                 jnp.zeros((bk, dv_width), jnp.float32))
+        if causal:
+            carry = jax.lax.fori_loop(
+                first, full, functools.partial(tile, h, k, v, True), carry)
+        dk, dv = jax.lax.fori_loop(
+            full, nqb, functools.partial(tile, h, k, v, False), carry)
+        # ds's scale, applied once the tile is contracted away: on [., d]
+        dks.append((dk * scale).astype(dk_ref.dtype))
+        dvs.append(dv.astype(dv_ref.dtype))
+    dk_ref[0] = dks[0] if group == 1 else jnp.concatenate(dks, axis=1)
+    dv_ref[0] = dvs[0] if group == 1 else jnp.concatenate(dvs, axis=1)
 
-    @pl.when(kj == pl.num_programs(1) - 1)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _():
         def write(i, _):
             rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
@@ -383,72 +493,95 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         jax.lax.fori_loop(0, nqb, write, None)
 
 
-def _bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, q_offset):
-    bh, sq, d = q.shape
-    sk, dv = v.shape[1:]
+def _head_sums(x, heads: int):
+    """x [b, s, heads w] float32 -> each head's sum over its w columns,
+    [b, heads, s]. Several heads share a row's lanes, and a reduction over
+    part of them would have XLA lay the whole array out again, one head a
+    row (w 64 padded to 128 lanes); a product with the heads' 0 / 1
+    membership columns, at full float32 precision, reads it where it is."""
+    if heads == 1:
+        return jnp.sum(x, axis=-1)[:, None]
+    member = jnp.repeat(jnp.eye(heads, dtype=x.dtype), x.shape[2] // heads,
+                        axis=0)                                 # [h w, h]
+    return jnp.einsum("bsk,kh->bhs", x, member,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _bwd(q, k, v, out, lse, do, heads, group, causal, scale, block_q,
+         block_k, q_offset):
+    b, sq, hd = q.shape
+    sk, hdv = v.shape[1:]
+    d, dv = hd // heads, hdv // heads
     nqb = sq // block_q
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                    # [bh, sq]
+    delta = _head_sums(do.astype(jnp.float32) * out.astype(jnp.float32),
+                       heads).reshape(b, heads, nqb, 1, block_q)
     # Q and dO stay whole-sequence resident; lse and delta come as one
-    # lane-dense row per query block (a [sq, 1] block pads to 128 lanes)
-    full_q = pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0))
-    full_do = pl.BlockSpec((1, sq, dv), lambda b, j: (b, 0, 0))
-    full_row = pl.BlockSpec((1, nqb, 1, block_q), lambda b, j: (b, 0, 0, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))
-    vspec = pl.BlockSpec((1, block_k, dv), lambda b, j: (b, j, 0))
+    # lane-dense row per head and query block (a [sq, 1] block pads to 128
+    # lanes)
+    full_q = pl.BlockSpec((1, sq, group * d), lambda b, g, j: (b, 0, g))
+    full_do = pl.BlockSpec((1, sq, group * dv), lambda b, g, j: (b, 0, g))
+    full_row = pl.BlockSpec((1, group, nqb, 1, block_q),
+                            lambda b, g, j: (b, g, 0, 0, 0))
+    kspec = pl.BlockSpec((1, block_k, group * d), lambda b, g, j: (b, j, g))
+    vspec = pl.BlockSpec((1, block_k, group * dv), lambda b, g, j: (b, j, g))
     with _no_x64():
         return pl.pallas_call(
-            functools.partial(_bwd_kernel, causal=causal, scale=scale,
-                              block_q=block_q, q_offset=q_offset),
-            grid=(bh, sk // block_k),
+            functools.partial(_bwd_kernel, group=group, causal=causal,
+                              scale=scale, block_q=block_q,
+                              q_offset=q_offset),
+            grid=(b, heads // group, sk // block_k),
             in_specs=[full_q, kspec, vspec, full_do, full_row, full_row],
             out_specs=[full_q, kspec, vspec],
-            out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-                       jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, sk, dv), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((d, sq), jnp.float32)],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((group * d, sq), jnp.float32)],
             # dQ's block is revisited along the key axis
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=pallas_interpret(),
-        )(q, k, v, do, lse, delta.reshape(bh, nqb, 1, block_q))
+        )(q, k, v, do, lse, delta)
 
 
 # ---------------------------------------------------------------- public
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _mha(q, k, v, causal, scale):
-    _check_vmem(q, k, v, backward=False)
-    return _fwd_res(q, k, v, causal, scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _mha(q, k, v, heads, group, causal, scale):
+    """q, k [b, s, heads d], v [b, s, heads d_v], `group` heads a grid
+    step; the head-major [bh, s, d] is `heads` 1, `group` 1."""
+    _check_vmem(q, k, v, False, heads, group)
+    return _fwd_res(q, k, v, heads, group, causal, scale)[0]
 
 
-def _fwd_res(q, k, v, causal, scale):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    bq, bk = _block_sizes(sq, sk, d)
-    out, lse = _fwd(q, k, v, causal, scale, bq, bk, q_offset=sk - sq)
+def _fwd_res(q, k, v, heads, group, causal, scale):
+    sq, sk = q.shape[1], k.shape[1]
+    bq, bk = _block_sizes(sq, sk, q.shape[2] // heads)
+    out, lse = _fwd(q, k, v, heads, group, causal, scale, bq, bk,
+                    q_offset=sk - sq)
     return out, (q, k, v, out, lse)
 
 
-def _mha_fwd(q, k, v, causal, scale):
-    _check_vmem(q, k, v, backward=True)
-    return _fwd_res(q, k, v, causal, scale)
+def _mha_fwd(q, k, v, heads, group, causal, scale):
+    _check_vmem(q, k, v, True, heads, group)
+    return _fwd_res(q, k, v, heads, group, causal, scale)
 
 
-def _mha_bwd(causal, scale, res, do):
+def _mha_bwd(heads, group, causal, scale, res, do):
     q, k, v, out, lse = res
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    bq, bk = _block_sizes(sq, sk, d)
-    return _bwd(q, k, v, out, lse, do, causal, scale, bq,
-                _bwd_block_k(bk, d, v.shape[-1]), q_offset=sk - sq)
+    sq, sk = q.shape[1], k.shape[1]
+    d, dv = q.shape[2] // heads, v.shape[2] // heads
+    bq, _ = _block_sizes(sq, sk, d)
+    return _bwd(q, k, v, out, lse, do, heads, group, causal, scale, bq,
+                _bwd_block_k(sq, sk, d, dv, q.dtype, group),
+                q_offset=sk - sq)
 
 
 _mha.defvjp(_mha_fwd, _mha_bwd)
 
 
 def mha_forward(q, k, v, causal=False, scale=None):
-    """Differentiable blocked attention on [BH or B,H fused, S, D] arrays.
+    """Differentiable blocked attention on head-major arrays, one head a
+    grid step: the entry every shape under the caps can take.
 
     Accepts [B, H, S, D] or [BH, S, D]; returns the same rank it was given.
     v may have another last dimension than q and k (latent attention:
@@ -462,21 +595,39 @@ def mha_forward(q, k, v, causal=False, scale=None):
         v = v.reshape(b * h, v.shape[2], v.shape[3])
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    out = _mha(q, k, v, bool(causal), float(scale))
+    out = _mha(q, k, v, 1, 1, bool(causal), float(scale))
     if squeeze:
         out = out.reshape(b, h, sq, out.shape[-1])
     return out
 
 
+def mha_seq_major(q, k, v, heads, causal=False, scale=None):
+    """Differentiable blocked attention on q, k [B, S, heads d] and v
+    [B, S, heads d_v] as the projections write them -> [B, S, heads d_v],
+    and dQ, dK, dV in the same layout. Where `head_group` gives a group the
+    kernels index those arrays directly, a group's columns a grid step, and
+    nothing is copied around them; elsewhere (an odd head count at
+    head_dim 64, a length past the grouped kernels' fit) the heads are
+    swapped to the front for `mha_forward` and the output swapped back."""
+    b, sq, hd = q.shape
+    sk, d, dv = k.shape[1], hd // heads, v.shape[2] // heads
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    group = head_group(heads, d, dv, sq, sk, q.dtype)
+    if group is not None:
+        return _mha(q, k, v, heads, group, bool(causal), float(scale))
+    q, k, v = (jnp.swapaxes(a.reshape(b, a.shape[1], heads, -1), 1, 2)
+               for a in (q, k, v))                          # [B, H, S, D]
+    out = mha_forward(q, k, v, causal=causal, scale=scale)
+    return jnp.swapaxes(out, 1, 2).reshape(b, sq, heads * dv)
+
+
 def _fa_kernel_body(q, k, v, causal, scale):
-    # paddle layout [B, S, H, D] -> [BH, S, D]
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    qt = jnp.swapaxes(q, 1, 2).reshape(b * h, sq, d)
-    kt = jnp.swapaxes(k, 1, 2).reshape(b * h, sk, d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * h, sk, v.shape[-1])
-    out = _mha(qt, kt, vt, causal, scale)
-    return jnp.swapaxes(out.reshape(b, h, sq, v.shape[-1]), 1, 2)
+    # paddle layout [B, S, H, D]: the seq-major entry's once flattened
+    b, sq, h, _ = q.shape
+    out = mha_seq_major(*(a.reshape(a.shape[0], a.shape[1], -1)
+                          for a in (q, k, v)), h, causal, scale)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def flash_attention(query, key, value, causal=False, scale=None):
@@ -500,30 +651,40 @@ def flash_attention(query, key, value, causal=False, scale=None):
 
 # ------------------------------------------------------- sharded dispatch
 
-def mha_sharded(q, k, v, mesh, causal=False, scale=None):
-    """Flash attention on mesh-sharded [B, H, S, D] arrays under jit.
+def mha_sharded(q, k, v, mesh, causal=False, scale=None, heads=None):
+    """Flash attention on mesh-sharded arrays under jit: [B, S, heads D]
+    as the projections write them (`mha_seq_major` on every shard), or,
+    with `heads` None, head-major [B, H, S, D] (`mha_forward`).
 
     Mosaic kernels cannot be partitioned automatically, so the call is
     wrapped in a ``shard_map`` that is manual over EVERY mesh axis GSPMD
     still owns here (size 1 or not): batch splits over 'dp', heads over
-    'mp', seq/head_dim are gathered at the boundary. Under plain jit that
-    is the whole mesh; inside the compiled-pp body ('pp' already manual,
+    'mp' (contiguous shares of the heads D columns are heads), seq and
+    head_dim are gathered at the boundary. Under plain jit that is the
+    whole mesh; inside the compiled-pp body ('pp' already manual,
     pipeline_compiled.py) it is the remaining axes of the context mesh.
     The TPU analog of the reference wiring flash-attn into its SPMD rules
     (phi/infermeta/spmd_rules)."""
     ctx_mesh = jax.sharding.get_abstract_mesh()
     nested = bool(ctx_mesh.manual_axes)
     axes = set(mesh.axis_names) - set(ctx_mesh.manual_axes)
+    n_heads = q.shape[1] if heads is None else heads
     for axis, dim, what in (("dp", q.shape[0], "batch"),
-                            ("mp", q.shape[1], "heads")):
+                            ("mp", n_heads, "heads")):
         if axis in axes and dim % mesh.shape[axis]:
             raise ValueError(
                 f"flash attention: {what} {dim} not divisible by mesh "
                 f"axis {axis!r} of size {mesh.shape[axis]}")
-    spec = _P("dp" if "dp" in axes else None,
-              "mp" if "mp" in axes else None, None, None)
+    dp, mp = ("dp" if "dp" in axes else None), ("mp" if "mp" in axes else None)
+    if heads is None:
+        spec = _P(dp, mp, None, None)
+        body = functools.partial(mha_forward, causal=causal, scale=scale)
+    else:
+        spec = _P(dp, None, mp)
+        body = functools.partial(
+            mha_seq_major, heads=heads // (mesh.shape[mp] if mp else 1),
+            causal=causal, scale=scale)
     return jax.shard_map(
-        functools.partial(mha_forward, causal=causal, scale=scale),
-        mesh=ctx_mesh if nested else mesh,
+        body, mesh=ctx_mesh if nested else mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=axes, check_vma=False)(q, k, v)

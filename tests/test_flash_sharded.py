@@ -27,19 +27,31 @@ def _ref_attn(q, k, v, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def test_mha_sharded_matches_reference_on_mesh():
+@pytest.mark.parametrize("layout", ["head_major", "seq_major"])
+def test_mha_sharded_matches_reference_on_mesh(layout):
+    """[B, H, S, D] split over (dp, mp, -, -), and the projections' own
+    [B, S, H D] split over (dp, -, mp): contiguous shares of its columns
+    are heads, 2 of the 8 a shard, which the kernels take as one pair."""
     from paddle_tpu.ops.pallas.flash_attention import mha_sharded
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "mp"))
     r = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(r.randn(4, 8, 128, 32).astype("float32"))
+    heads, d = 8, 64 if layout == "seq_major" else 32
+    q, k, v = (jnp.asarray(r.randn(4, heads, 128, d).astype("float32"))
                for _ in range(3))
-    sh = NamedSharding(mesh, P("dp", "mp", None, None))
-    qd, kd, vd = (jax.device_put(a, sh) for a in (q, k, v))
-    scale = 1.0 / np.sqrt(32)
+    scale = 1.0 / np.sqrt(d)
+    if layout == "head_major":
+        spec, kwargs = P("dp", "mp", None, None), {}
+        there = back = lambda a: a
+    else:
+        spec, kwargs = P("dp", None, "mp"), {"heads": heads}
+        there = lambda a: jnp.swapaxes(a, 1, 2).reshape(4, 128, heads * d)
+        back = lambda a: jnp.swapaxes(a.reshape(4, 128, heads, d), 1, 2)
+    sh = NamedSharding(mesh, spec)
+    qd, kd, vd = (jax.device_put(there(a), sh) for a in (q, k, v))
 
     def loss(q, k, v):
-        return (mha_sharded(q, k, v, mesh, causal=True,
-                            scale=scale) ** 2).sum()
+        return (mha_sharded(q, k, v, mesh, causal=True, scale=scale,
+                            **kwargs) ** 2).sum()
 
     lv, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
         qd, kd, vd)
@@ -50,9 +62,18 @@ def test_mha_sharded_matches_reference_on_mesh():
     lr, gref = jax.value_and_grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
     assert abs(float(lv) - float(lr)) / abs(float(lr)) < 1e-5
     for a, b in zip(grads, gref):
-        rel = (np.abs(np.asarray(a) - np.asarray(b)).max()
+        assert a.sharding.spec == spec
+        rel = (np.abs(np.asarray(back(a)) - np.asarray(b)).max()
                / (np.abs(np.asarray(b)).max() + 1e-9))
         assert rel < 1e-4
+
+
+def test_mha_sharded_names_the_axis_the_heads_do_not_divide():
+    from paddle_tpu.ops.pallas.flash_attention import mha_sharded
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "mp"))
+    q = jnp.zeros((2, 128, 6 * 64), jnp.float32)
+    with pytest.raises(ValueError, match="heads 6 not divisible .* 'mp'"):
+        mha_sharded(q, q, q, mesh, causal=True, heads=6)
 
 
 def test_gpt_train_step_flash_equals_einsum_on_hybrid_mesh():

@@ -548,9 +548,17 @@ def test_one_width_is_what_it_was():
                        + f32(d, s) + 3 * f32(bk, bq) + 2 * f32(bk, d))}
         assert fa.vmem_footprint(s, s, d, jnp.bfloat16) == old
         assert fa.vmem_footprint(s, s, d, jnp.bfloat16, d) == old
-    assert fa.max_seq(64, jnp.bfloat16, True) == 6144
-    assert fa.max_seq(192, jnp.bfloat16, True) == 2560
-    assert fa.max_seq(192, jnp.bfloat16, True, 128) == 3072
+    # the caps that formula gave with 512-key blocks (6,144 / 2,560 /
+    # 3,072), one step of 512 further since the backward halves its key
+    # block where the whole one does not fit (`_bwd_block_k`)
+    assert fa.max_seq(64, jnp.bfloat16, True) == 7168
+    assert fa.max_seq(192, jnp.bfloat16, True) == 3072
+    assert fa.max_seq(192, jnp.bfloat16, True, 128) == 3584
+    for d, dv, before in ((64, 64, 6144), (192, 192, 2560),
+                          (192, 128, 3072)):
+        assert fa._bwd_block_k(before, before, d, dv, jnp.bfloat16) == 512
+        assert fa._bwd_block_k(before + 512, before + 512, d, dv,
+                               jnp.bfloat16) == 256
 
 
 def test_the_limit_message_names_both_widths(monkeypatch):
@@ -560,7 +568,8 @@ def test_the_limit_message_names_both_widths(monkeypatch):
     with pytest.raises(fa.FlashSequenceLimitError) as err:
         fa._check_vmem(q, q, v, backward=True)
     assert "head_dim 192 (q, k) and 128 (v)" in str(err.value)
-    assert "3072 with the backward" in str(err.value)
+    assert "3584 with the backward" in str(err.value)
+    assert "1 head a grid step" in str(err.value)
 
 
 # ------------------------------------------------- scopes in the real step

@@ -306,14 +306,21 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, where):
 @pytest.mark.parametrize("seq, d_v, causal", [(1024, 256, True),
                                               (1536, 256, True),
                                               (1024, 128, False)])
-def test_flash_backward_with_a_narrower_key_block(seq, d_v, causal):
-    """At widths of 256 the backward runs 256-key blocks against the
-    forward's 512-query blocks (`_bwd_block_k`): the only shapes at which
-    the two differ, here in the interpreter against the einsum's
-    gradients."""
+def test_flash_backward_with_a_narrower_key_block(seq, d_v, causal,
+                                                  monkeypatch):
+    """Where 512 keys do not fit the backward runs 256-key blocks against
+    the forward's 512-query blocks (`_bwd_block_k`; at 256 / 256 from
+    2,048 on, which the GLM cell runs): the only shapes at which the two
+    differ, here in the interpreter, at lengths it can afford and a limit
+    cut to match, against the einsum's gradients."""
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     bq, bk = fa._block_sizes(seq, seq, 256)
-    assert (bq, bk, fa._bwd_block_k(bk, 256, d_v)) == (512, 512, 256)
+    assert fa._bwd_block_k(2048, 2048, 256, 256, jnp.bfloat16) == 256
+    whole, half = (fa._bwd_bytes(seq, bq, keys, 256, d_v, jnp.float32, 1)
+                   for keys in (512, 256))
+    monkeypatch.setattr(fa, "SCOPED_VMEM_BYTES", (whole + half) // 2)
+    assert (bq, bk, fa._bwd_block_k(seq, seq, 256, d_v, jnp.float32)) \
+        == (512, 512, 256)
     k0, k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seq), 4)
     q, k = (jax.random.normal(key, (1, seq, 256), jnp.float32)
             for key in (k0, k1))

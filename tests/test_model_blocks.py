@@ -84,6 +84,105 @@ def test_attention_kernel_in_the_interpreter_equals_einsum(seq):
     np.testing.assert_allclose(kernel, plain, rtol=2e-4, atol=2e-5)
 
 
+# heads, d_qk, d_v of the families that take the kernel branch: gpt's pairs
+# of 64, the latent widths in pairs (192 / 128) and alone (256 / 256), and
+# a head count that cannot be paired (the head-major kernels, with swaps)
+@pytest.mark.parametrize("heads, d, d_v", [(4, 64, 64), (2, 192, 128),
+                                           (2, 256, 256), (3, 64, 64)],
+                         ids=["gpt", "mla_192_128", "mla_256_256",
+                              "three_heads"])
+def test_attention_kernel_branch_is_each_familys_former_formula(heads, d,
+                                                                d_v):
+    """Value and the three gradients of the kernel branch, in the
+    interpreter, against the formula the families wrote."""
+    q, k, v = _qkv(128, heads, d, d_v, seed=2)
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, 128, heads * d_v),
+                          jnp.float32)
+    scale = 0.7 / math.sqrt(d)
+
+    def kernel(q, k, v):
+        return blocks.attention(q, k, v, causal=True, scale=scale,
+                                flash=True)
+
+    def former(q, k, v):
+        return _former(q, k, v, lambda s: s * scale, True)
+
+    with with_flag("FLAGS_flash_interpret", True):
+        got, pull = jax.vjp(kernel, q, k, v)
+        grads = pull(w)
+    want, pull = jax.vjp(former, q, k, v)
+    assert got.shape == (2, 128, heads * d_v)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    for a, b in zip(grads, pull(w)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("causal, masked", [(True, False), (False, False),
+                                            (False, True)],
+                         ids=["causal", "full", "masked"])
+def test_attention_einsum_branch_traces_as_it_did(causal, masked):
+    """bert, llama and every masked call take the einsum branch, whose
+    jaxpr is the one `attention` gave before the kernels read the
+    projections' layout: swaps, two einsums, float32 softmax, merge."""
+    q, k, v = (jax.ShapeDtypeStruct((2, 128, 4, 32), jnp.bfloat16),) * 3
+    mask = jax.ShapeDtypeStruct((2, 1, 1, 128), jnp.float32)
+
+    def before(q, k, v, mask=None):
+        b, s, heads, _ = q.shape
+        q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.25
+        if causal:
+            logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits,
+                               jnp.array(-1e30, logits.dtype))
+        if mask is not None:
+            logits = logits + mask
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(
+            q.dtype)
+        out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        return jnp.swapaxes(out, 1, 2).reshape(b, s, heads * v.shape[-1])
+
+    def now(q, k, v, mask=None):
+        # `flash=True`: a mask, or the CPU without the interpreter flag,
+        # is the einsum branch whatever the caller asks
+        return blocks.attention(q, k, v, causal=causal, scale=0.25,
+                                flash=masked, mask=mask)
+
+    args = (q, k, v, mask) if masked else (q, k, v)
+    assert str(jax.make_jaxpr(now)(*args)) == str(
+        jax.make_jaxpr(before)(*args))
+    grad = jax.grad(lambda f, *a: f(*a).astype(jnp.float32).sum(),
+                    argnums=(1, 2, 3))
+    assert str(jax.make_jaxpr(lambda *a: grad(now, *a))(*args)) == str(
+        jax.make_jaxpr(lambda *a: grad(before, *a))(*args))
+
+
+def test_gpt_block_projects_q_k_v_apart_from_the_one_qkv_matrix():
+    """Off an `mp` mesh `gpt._block` makes q, k and v as three products on
+    column blocks of `qkv_w` (what the kernels read without a copy); on
+    one, as one product split by heads. The parameter tree is the same and
+    so is the block's value, and its jaxpr holds three / one projection."""
+    config = gpt.GPTConfig(vocab_size=64, hidden_size=32, num_layers=1,
+                           num_heads=4, max_position_embeddings=16,
+                           dtype="float32", use_flash_attention=False)
+    blk = jax.tree_util.tree_map(lambda a: a[0],
+                                 gpt.init_gpt_params(config, 3)["blocks"])
+    blk["qkv_b"] = jnp.linspace(-1.0, 1.0, 96)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32), jnp.float32)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("mp",))
+
+    def projections(mesh):
+        jaxpr = jax.make_jaxpr(lambda x: gpt._block(x, blk, config, mesh))(x)
+        return [e.outvars[0].aval.shape for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "dot_general"][:3]
+
+    assert projections(None) == [(2, 16, 32)] * 3
+    assert projections(mesh)[0] == (2, 16, 96)
+    apart, _ = gpt._block(x, blk, config, None)
+    whole, _ = gpt._block(x, blk, config, mesh)
+    np.testing.assert_allclose(apart, whole, rtol=1e-5, atol=1e-6)
+
+
 @pytest.mark.parametrize("flash, seq, tpu, interpret, want", [
     (True, 1024, True, False, True), (False, 1024, True, False, False),
     (True, 128, True, False, False), (True, 1000, True, False, False),

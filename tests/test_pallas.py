@@ -161,11 +161,12 @@ def test_mha_forward_over_many_tiles(causal, max_block, sq, sk, dtype, d, dv,
     k = jnp.asarray(rng.randn(2, sk, d), dtype)
     v = jnp.asarray(rng.randn(2, sk, dv), dtype)
     scale = 0.125 if dtype == jnp.bfloat16 else 0.17
-    out, (_, _, _, _, lse) = fa._fwd_res(q, k, v, causal, scale)
+    out, (_, _, _, _, lse) = fa._fwd_res(q, k, v, 1, 1, causal, scale)
     bq, bk = fa._block_sizes(sq, sk, d)
     assert (bq, bk) == (max_block, max_block)
     assert out.dtype == dtype and out.shape == (2, sq, dv)
-    assert lse.dtype == jnp.float32 and lse.shape == (2, sq // bq, 1, bq)
+    assert lse.dtype == jnp.float32 \
+        and lse.shape == (2, 1, sq // bq, 1, bq)
     q32, k32, v32 = (a.astype(jnp.float32) for a in (q, k, v))
     want = np.asarray(_ref_attn(q32, k32, v32, causal, scale,
                                 jax.lax.Precision.HIGHEST))
@@ -246,10 +247,10 @@ def test_mha_forward_is_one_kernel_that_masks_only_the_diagonals_tiles(causal):
     a = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16)
     bq, bk = fa._block_sizes(s, s, d)
     jaxpr = jax.make_jaxpr(
-        lambda q, k, v: fa._fwd_res(q, k, v, causal, 0.125))(a, a, a)
+        lambda q, k, v: fa._fwd_res(q, k, v, 1, 1, causal, 0.125))(a, a, a)
     calls = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
-    lse_shape = (bh, s // bq, 1, bq)
+    lse_shape = (bh, 1, s // bq, 1, bq)
     assert [o.aval.shape for o in calls[0].outvars] == [a.shape, lse_shape]
     assert calls[0].outvars[1].aval.dtype == jnp.float32
     kernel = calls[0].params["jaxpr"]
@@ -277,6 +278,171 @@ def test_mha_forward_is_one_kernel_that_masks_only_the_diagonals_tiles(causal):
     for e in _eqns(grad.jaxpr):
         assert not any(v is forward.outvars[1] for v in e.invars) \
             or e is backward, e
+
+
+# ----------------------------------------- the seq-major entry (`head_group`)
+
+def _by_head(a, heads):
+    """[b, s, heads w] -> head-major [b heads, s, w]."""
+    b, s, _ = a.shape
+    return jnp.swapaxes(a.reshape(b, s, heads, -1), 1, 2).reshape(
+        b * heads, s, -1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sq,sk", [(256, 256), (128, 256)],
+                         ids=["self", "cross_with_offset"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("d,dv,heads,group", [
+    (64, 64, 4, 2), (192, 128, 2, 2), (128, 128, 2, 1), (256, 256, 2, 1)],
+    ids=["d64_g2", "mla_192_128_g2", "d128_g1", "d256_g1"])
+def test_seq_major_entry_equals_the_head_major_one(d, dv, heads, group,
+                                                   causal, sq, sk, dtype):
+    """q, k, v read as the projections write them, `group` heads a grid
+    step: out and lse are the head-major entry's to the bit (per head the
+    tile arithmetic is the same), dQ, dK, dV within the file's limits
+    (`delta` is summed by another route)."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    assert fa.head_group(heads, d, dv, sq, sk, dtype) == group
+    rng = np.random.RandomState(5)
+    b = 2
+    q = jnp.asarray(rng.randn(b, sq, heads * d), dtype)
+    k = jnp.asarray(rng.randn(b, sk, heads * d), dtype)
+    v = jnp.asarray(rng.randn(b, sk, heads * dv), dtype)
+    w = jnp.asarray(rng.randn(b, sq, heads * dv), dtype)
+    scale = 0.125 if dtype == jnp.bfloat16 else 0.17
+
+    out, (_, _, _, _, lse) = fa._fwd_res(q, k, v, heads, group, causal,
+                                         scale)
+    qh, kh, vh = (_by_head(a, heads) for a in (q, k, v))
+    want, (_, _, _, _, want_lse) = fa._fwd_res(qh, kh, vh, 1, 1, causal,
+                                               scale)
+    assert out.shape == (b, sq, heads * dv) and out.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(_by_head(out, heads), np.float32),
+                                  np.asarray(want, np.float32))
+    assert lse.shape[:2] == (b, heads)
+    np.testing.assert_array_equal(
+        np.asarray(lse).reshape(want_lse.shape), np.asarray(want_lse))
+
+    _, pull = jax.vjp(lambda q, k, v: fa.mha_seq_major(
+        q, k, v, heads, causal=causal, scale=scale), q, k, v)
+    _, pull_h = jax.vjp(lambda q, k, v: mha_forward(
+        q, k, v, causal=causal, scale=scale), qh, kh, vh)
+    for g, r in zip(pull(w), pull_h(_by_head(w, heads))):
+        assert g.dtype == dtype
+        g = np.asarray(_by_head(g, heads), np.float32)
+        r = np.asarray(r, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+        else:
+            assert np.abs(g - r).max() / np.abs(r).max() <= BF16_GRAD_TOL
+
+
+# heads, d_qk, d_v, sq, sk, dtype -> heads a grid step, counted by hand:
+# the smallest g with g d_qk and g d_v multiples of 128 lanes, if it
+# divides the heads and the grouped forward and backward fit in 16 MiB
+@pytest.mark.parametrize("heads,d,dv,sq,sk,dtype,want", [
+    (16, 64, 64, 1024, 1024, "bfloat16", 2),      # gpt2-medium
+    (16, 64, 64, 2048, 2048, "bfloat16", 2),      # gpt3-1.3b's share
+    (20, 256, 256, 2048, 2048, "bfloat16", 1),    # GLM-4.7-Flash
+    (4, 192, 128, 2048, 2048, "bfloat16", 2),     # Xing's held heads
+    (16, 128, 128, 2048, 2048, "bfloat16", 1),
+    (15, 64, 64, 1024, 1024, "bfloat16", None),   # 15 heads in pairs
+    (3, 192, 128, 1024, 1024, "bfloat16", None),
+    (16, 48, 32, 1024, 1024, "bfloat16", 8),      # 384 and 256 lanes
+    (4, 48, 32, 1024, 1024, "bfloat16", None),    # 4 heads, groups of 8
+    (16, 80, 80, 1024, 1024, "bfloat16", None),   # 8 x 80: 640 lanes,
+                                                  # too wide to fit
+    (16, 72, 72, 1024, 1024, "bfloat16", None),   # 16 x 72 is the first
+    (16, 64, 64, 5632, 5632, "bfloat16", 2),      # the grouped cap at 64
+    (16, 64, 64, 6144, 6144, "bfloat16", None),   # past it: head-major
+    (4, 192, 128, 2560, 2560, "bfloat16", None),
+    (20, 256, 256, 2560, 2560, "bfloat16", 1),    # g 1: the same cap
+    (20, 256, 256, 3072, 3072, "bfloat16", None),
+    (16, 64, 64, 3072, 3072, "float32", 2),
+    (16, 64, 64, 3584, 3584, "float32", None)])
+def test_head_group_decision_table(heads, d, dv, sq, sk, dtype, want):
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    assert fa.head_group(heads, d, dv, sq, sk, jnp.dtype(dtype)) == want
+    if want is not None:
+        assert heads % want == 0 and want * d % 128 == 0 \
+            and want * dv % 128 == 0
+        assert fa._fits(sq, sk, d, jnp.dtype(dtype), True, dv, want) is None
+
+
+def test_the_grouped_kernels_end_before_the_head_major_ones():
+    """A group's blocks are g heads wide (and dQ's scratch g d rows), so
+    the seq-major entry ends earlier; no head-major cap is lower than it
+    was (6,144 / 3,072 / 2,560 before the key block was chosen by fit)."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    bf16 = jnp.bfloat16
+    assert fa.max_seq(64, bf16, True, group=2) == 5632
+    assert fa.max_seq(64, bf16, True) == 7168
+    assert fa.max_seq(192, bf16, True, 128, group=2) == 2048
+    assert fa.max_seq(192, bf16, True, 128) == 3584
+    assert fa.max_seq(256, bf16, True, 256) == 2560
+    assert fa.max_seq(128, bf16, True) == 6144
+    # the key block is halved where the whole one does not fit and the half
+    # does, at any width
+    assert fa._bwd_block_k(4608, 4608, 64, 64, bf16, 2) == 512
+    assert fa._bwd_block_k(5120, 5120, 64, 64, bf16, 2) == 256
+    assert fa._bwd_block_k(6144, 6144, 64, 64, bf16) == 512
+    assert fa._bwd_block_k(7168, 7168, 64, 64, bf16) == 256
+    assert fa._bwd_block_k(2048, 2048, 192, 128, bf16, 2) == 256
+    assert fa._bwd_block_k(2048, 2048, 192, 128, bf16) == 512
+    assert fa._bwd_block_k(2048, 2048, 256, 256, bf16) == 256
+    # only a whole `MAX_BLOCK` is halved
+    assert fa._bwd_block_k(256, 256, 256, 256, bf16) == 128
+    assert fa._bwd_block_k(384, 384, 64, 64, bf16, 2) == 128
+
+
+def test_seq_major_entry_falls_back_to_head_major_with_its_swaps():
+    """Three heads of 64 cannot be paired: the same call swaps the heads
+    to the front, runs the head-major kernels and swaps back."""
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(2, 128, 3 * 64), jnp.float32)
+               for _ in range(3))
+    assert fa.head_group(3, 64, 64, 128, 128, q.dtype) is None
+    got = fa.mha_seq_major(q, k, v, 3, causal=True)
+    want = mha_forward(*(_by_head(a, 3) for a in (q, k, v)), causal=True)
+    np.testing.assert_array_equal(_by_head(got, 3), want)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fa.mha_seq_major(
+        q, k, v, 3, causal=True))(q, k, v)
+    swaps = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "transpose"
+             and e.outvars[0].aval.ndim == 4]
+    assert len(swaps) == 4                  # q, k, v there; the output back
+
+
+@pytest.mark.parametrize("heads,d,dv", [(4, 64, 64), (2, 192, 128)],
+                         ids=["d64", "mla_192_128"])
+def test_attention_kernel_branch_transposes_nothing_of_qs_size(heads, d, dv):
+    """`blocks.attention` hands the kernels the projections' layout: the
+    gradient's jaxpr holds no transpose of an array as large as q (the
+    small `delta` rows do not count), two pallas_calls, and the backward
+    takes q, k, v and dO and gives dQ, dK, dV in that layout."""
+    from conftest import with_flag
+    from paddle_tpu.models import blocks
+    b, s = 2, 256
+    q, k = (jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16),) * 2
+    v = jax.ShapeDtypeStruct((b, s, heads, dv), jnp.bfloat16)
+    with with_flag("FLAGS_flash_interpret", True):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: blocks.attention(
+                q, k, v, causal=True, scale=0.1, flash=True).astype(
+                    jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+    size = min(b * s * heads * d, b * s * heads * dv)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    for e in eqns:
+        if e.primitive.name == "transpose":
+            assert all(np.prod(o.aval.shape) < size for o in e.outvars), e
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    flat = [(b, s, heads * d), (b, s, heads * d), (b, s, heads * dv)]
+    assert [o.aval.shape for o in calls[0].invars] == flat
+    assert [o.aval.shape for o in calls[1].invars[:4]] == flat + [flat[2]]
+    assert [o.aval.shape for o in calls[1].outvars] == flat
 
 
 def test_mha_cross_attention_shapes():

@@ -10,6 +10,8 @@ what keeps "compiles only in interpret mode" from coming back between chip
 runs. No skip for a missing libtpu.
 """
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +94,7 @@ def test_flash_gradient_keeps_no_padded_lse(v5e):
         q, k, v, causal=True)), argnums=(0, 1, 2)),
         *[(shape, jnp.bfloat16)] * 3).as_text()
     assert "f32[256,1024,1]" not in text
-    assert "f32[256,2,1,512]" in text
+    assert "f32[256,1,2,1,512]" in text
     assert text.count("tpu_custom_call") == 2
 
 
@@ -187,16 +189,19 @@ def test_stream_width_that_cannot_be_tiled_is_a_named_error(v5e):
 
 def test_flash_sequence_limit_is_a_named_error(v5e):
     """Past the computed cap the named error comes first, not Mosaic's
-    RESOURCE_EXHAUSTED; at the cap the real compiler still accepts."""
+    RESOURCE_EXHAUSTED; at the cap the real compiler still accepts. The
+    cap is the head-major entry's, 6,144 while the backward's key block was
+    512 at every length and 7,168 since it is chosen by fit."""
     cap = fa.max_seq(64, jnp.bfloat16, backward=True)
-    assert cap == 6144 and fa.max_seq(64, jnp.bfloat16, backward=False) > cap
+    assert cap == 7168 and fa.max_seq(64, jnp.bfloat16, backward=False) > cap
 
     def grad(q, k, v):
         return jax.grad(lambda q: _sum32(fa.mha_forward(
             q, k, v, causal=True)))(q)
 
     # bh 64: too large for XLA to park an operand in VMEM and mask the limit
-    _compile(v5e, grad, *[((64, cap, 64), jnp.bfloat16)] * 3)
+    for seq in (6144, cap):
+        _compile(v5e, grad, *[((64, seq, 64), jnp.bfloat16)] * 3)
     with pytest.raises(fa.FlashSequenceLimitError,
                        match=f"bwd kernel .* {cap} with the"):
         _compile(v5e, grad, *[((64, cap + 512, 64), jnp.bfloat16)] * 3)
@@ -221,11 +226,13 @@ def test_flash_at_256_wide_compiles_at_the_new_cells_shape(v5e):
     """Latent attention with a 256-wide value and all 20 heads (ISSUE 30:
     8 x 20 x 2048 at 256 / 256): with 512-key blocks the compiler took
     16.50 MiB for the backward and refused; `_bwd_block_k` gives it 256
-    keys, and the repaired estimate's cap is one the compiler accepts."""
-    assert fa._bwd_block_k(512, 256, 256) == fa._bwd_block_k(512, 256, 128) \
-        == 256
-    assert fa._bwd_block_k(512, 192, 128) == fa._bwd_block_k(512, 64, 64) \
-        == 512 and fa._bwd_block_k(128, 256, 256) == 128
+    keys (by fit, as at every width since), and the repaired estimate's cap
+    is one the compiler accepts."""
+    bf16 = jnp.bfloat16
+    assert fa._bwd_block_k(2048, 2048, 256, 256, bf16) == 256
+    assert fa._bwd_block_k(2048, 2048, 192, 128, bf16) \
+        == fa._bwd_block_k(2048, 2048, 64, 64, bf16) == 512 \
+        and fa._bwd_block_k(384, 384, 256, 256, bf16) == 128
     cap = fa.max_seq(256, jnp.bfloat16, backward=True, d_v=256)
     assert cap == 2560
     assert fa.max_seq(256, jnp.bfloat16, backward=False, d_v=256) == 6144
@@ -239,6 +246,112 @@ def test_flash_at_256_wide_compiles_at_the_new_cells_shape(v5e):
     with pytest.raises(fa.FlashSequenceLimitError,
                        match=f"bwd kernel .* {cap} with the"):
         _compile(v5e, grad, *[((160, cap + 512, 256), jnp.bfloat16)] * 3)
+
+
+# ------------------------------- the seq-major entry (heads side by side)
+
+def _seq_major(heads):
+    def fwd(q, k, v):
+        return fa.mha_seq_major(q, k, v, heads, causal=True)
+    return fwd, jax.grad(lambda q, k, v: _sum32(fwd(q, k, v)),
+                         argnums=(0, 1, 2))
+
+
+def _of_size(text, size, *kinds):
+    """Lines of compiled HLO whose instruction is one of `kinds` and whose
+    result has `size` elements."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(",
+                     line)
+        if m and m.group(2) in kinds and m.group(1) \
+                and math.prod(map(int, m.group(1).split(","))) == size:
+            found.append(line.strip())
+    return found
+
+
+def _mosaic_calls(text, size):
+    """(operands, results) of `size` elements for each Mosaic call."""
+    shapes = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = (.*?) [\w-]+\(", line)
+        if m:
+            shapes[m.group(1)] = [
+                math.prod(map(int, dims.split(","))) if dims else 1
+                for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2))]
+    calls = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = .*? custom-call\((.*?)\), ",
+                     line)
+        operands = [shapes[name.strip().lstrip("%")][0]
+                    for name in re.sub(r"/\*.*?\*/", "",
+                                       m.group(2)).split(",")]
+        calls.append((operands.count(size), shapes[m.group(1)].count(size)))
+    return calls
+
+
+# batch, seq, heads, d_qk, d_v, heads a grid step: the four kernel cells,
+# and the longest the grouped kernels take at head_dim 64
+@pytest.mark.parametrize("b, s, heads, d, dv, group", [
+    (16, 1024, 16, 64, 64, 2), (8, 2048, 16, 64, 64, 2),
+    (8, 2048, 20, 256, 256, 1), (2, 2048, 4, 192, 128, 2),
+    (4, 5632, 16, 64, 64, 2)],
+    ids=["gpt2m", "gpt3xl_share", "glm47f", "xing4", "grouped_cap_at_64"])
+def test_seq_major_kernels_compile_with_no_copy_around_them(v5e, b, s, heads,
+                                                            d, dv, group):
+    assert fa.head_group(heads, d, dv, s, s, jnp.bfloat16) == group
+    shapes = [((b, s, heads * d), jnp.bfloat16)] * 2 \
+        + [((b, s, heads * dv), jnp.bfloat16)]
+    forward, gradient = _seq_major(heads)
+    for fn, calls in ((forward, [(3, 1)]), (gradient, [(3, 1), (4, 3)])):
+        text = _compile(v5e, fn, *shapes).as_text()
+        for size in {b * s * heads * d, b * s * heads * dv}:
+            assert not _of_size(text, size, "copy", "transpose")
+        if d == dv:
+            assert _mosaic_calls(text, b * s * heads * d) == calls
+
+
+def test_past_the_grouped_fit_the_head_major_kernels_take_over(v5e):
+    """At head_dim 64 the pairs of heads end at 5,632; 6,144 and the
+    head-major cap (7,168) compile through the same call, with the swaps."""
+    cap = fa.max_seq(64, jnp.bfloat16, backward=True)
+    _, gradient = _seq_major(16)
+    for seq in (6144, cap):
+        assert fa.head_group(16, 64, 64, seq, seq, jnp.bfloat16) is None
+        text = _compile(v5e, gradient,
+                        *[((4, seq, 1024), jnp.bfloat16)] * 3).as_text()
+        assert f"bf16[64,{seq},64]" in text
+    with pytest.raises(fa.FlashSequenceLimitError, match="1 head a grid"):
+        _compile(v5e, gradient, *[((4, cap + 512, 1024), jnp.bfloat16)] * 3)
+
+
+def test_gpt2_medium_block_reaches_the_kernels_without_a_copy(v5e):
+    """One checkpointed gpt2-medium layer and its gradient at the cell's
+    batch: the three Mosaic calls (forward, its remat, backward) take q, k
+    and v as the three qkv products wrote them, and no `copy` or
+    `transpose` of q's element count stands under `attn_core`."""
+    from paddle_tpu.models import blocks, gpt, stages
+    config = GPTConfig(vocab_size=50304, hidden_size=1024, num_layers=1,
+                       num_heads=16, max_position_embeddings=1024,
+                       dtype="bfloat16", use_flash_attention=True)
+    params = jax.eval_shape(lambda: init_gpt_params(config, 0))["blocks"]
+    x = ((16, 1024, 1024), jnp.bfloat16)
+
+    def loss(x, *leaves):
+        stacked = dict(zip(params, leaves))
+        out, _ = blocks.scan_layers(
+            lambda x, blk: gpt._block(x, blk, config, None), x, stacked, True)
+        return _sum32(out)
+
+    text = _compile(v5e, jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x,
+                    *[(a.shape, a.dtype) for a in params.values()]).as_text()
+    size = 16 * 1024 * 1024
+    moved = [line for line in _of_size(text, size, "copy", "transpose")
+             if stages.ATTN_CORE in line]
+    assert not moved, moved
+    assert sorted(_mosaic_calls(text, size)) == [(3, 1), (3, 1), (4, 3)]
 
 
 def test_train_step_with_flash_lowers_on_pp2_mp2_mesh(v5e):
