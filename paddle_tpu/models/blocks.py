@@ -13,10 +13,12 @@ its call belongs to (models/stages.py), so the scopes stay flat.
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+import math
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 
 from .._core import device
@@ -46,9 +48,36 @@ def rms_norm(x, g, eps):
     return (xf * scale).astype(x.dtype) * g
 
 
-def rope(x, theta: float, inv_freq=None):
+def yarn_inv_freq(dim: int, base: float, scaling: Optional[dict]):
+    """Rotary inverse frequencies [dim/2] under yarn (Peng et al. 2023, as
+    DeepSeek-V2 computes them): below `low` a component keeps its frequency,
+    above `high` it is divided by `factor`, between them a linear ramp.
+    One yarn, two callers: the latent family scales its scores by mscale^2
+    (`mla_moe.attention_scale`), the decoder family its cos and sin by
+    `attention_factor` (`rope`'s `factor`)."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    extra = base ** (-2.0 * i / dim)
+    if not scaling:
+        return extra.astype(np.float32)
+    factor = scaling["factor"]
+    original = scaling["original_max_position_embeddings"]
+
+    def corr(rotations):
+        return dim * math.log(original / (2 * math.pi * rotations)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(corr(scaling["beta_fast"])), 0)
+    high = min(math.ceil(corr(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x, theta: float, inv_freq=None, factor: Optional[float] = None):
     """x [B, S, H, D] -> rotated. Half-split convention. `inv_freq` [D/2]
-    replaces theta's plain frequencies (a scaled RoPE such as yarn)."""
+    replaces theta's plain frequencies (a scaled RoPE such as yarn);
+    `factor` multiplies cos and sin (yarn's `attention_factor` where a
+    model applies it there: q and k both carry it, the scores its
+    square)."""
     b, s, h, d = x.shape
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half) \
@@ -56,6 +85,8 @@ def rope(x, theta: float, inv_freq=None):
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half], x[..., half:]
     xf1 = x1.astype(jnp.float32)
     xf2 = x2.astype(jnp.float32)
@@ -95,31 +126,51 @@ def use_flash_kernel(flash: bool, seq: int) -> bool:
 
 
 def attention(q, k, v, *, causal: bool, scale: float, flash: bool,
-              mesh: Optional[Mesh] = None, mask=None):
-    """softmax(scale q k^T) v on q, k [B, S, H, D] and v [B, S, H, D_v] as
-    the projections leave them -> [B, S, H * D_v]. `mask`, additive and
+              mesh: Optional[Mesh] = None, mask=None,
+              window: Optional[int] = None):
+    """softmax(scale q k^T) v on q [B, S, H, D], k [B, S, H_kv, D] and v
+    [B, S, H_kv, D_v] as the projections leave them -> [B, S, H * D_v].
+    H_kv may be a divisor of H (grouped-query attention): query head h
+    attends through K/V head h // (H / H_kv), and nothing is repeated in
+    either branch. `window` (with `causal`) is a second diagonal: query i
+    sees key j iff j <= i and i - j < window. `mask`, additive and
     broadcastable to [B, H, S, S], is bert's padding mask; the kernels take
     none, so a masked call is the einsum path whatever `flash` says. The
     kernels read the projections' own layout, the heads side by side in a
-    row (`mha_seq_major`): nothing is swapped or copied around them. On a
-    `mesh` they run manual over every axis (`mha_sharded`). The einsum
-    path swaps the heads to the front and back."""
+    row (`mha_seq_major`, which names a head's K/V head in its index maps
+    and skips the tiles outside the window): nothing is swapped or copied
+    around them. On a `mesh` they run manual over every axis
+    (`mha_sharded`). The einsum path swaps the heads to the front and
+    back, and computes the same."""
     b, s, heads, _ = q.shape
+    kv_heads = k.shape[2]
     if mask is None and use_flash_kernel(flash, s):
         q, k, v = (a.reshape(b, s, -1) for a in (q, k, v))  # [B, S, H D]
         if mesh is not None:
             return mha_sharded(q, k, v, mesh, causal=causal, scale=scale,
-                               heads=heads)
-        return mha_seq_major(q, k, v, heads, causal=causal, scale=scale)
+                               heads=heads, kv_heads=kv_heads, window=window)
+        return mha_seq_major(q, k, v, heads, causal=causal, scale=scale,
+                             kv_heads=kv_heads, window=window)
     q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))    # [B, H, S, D]
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    grouped = kv_heads != heads
+    if grouped:     # a K/V head's query heads side by side: [B, H_kv, r, ..]
+        q = q.reshape(b, kv_heads, heads // kv_heads, s, -1)
+        logits = jnp.einsum("bgrqd,bgkd->bgrqk", q, k) * scale
+    else:
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
-        logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits,
-                           jnp.array(-1e30, logits.dtype))
+        seen = jnp.tril(jnp.ones((s, s), bool))
+        if window is not None:
+            seen = seen & ~jnp.tril(jnp.ones((s, s), bool), -window)
+        logits = jnp.where(seen, logits, jnp.array(-1e30, logits.dtype))
     if mask is not None:
-        logits = logits + mask
+        logits = logits + (mask[:, :, None] if grouped else mask)
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    if grouped:
+        out = jnp.einsum("bgrqk,bgkd->bgrqd", probs, v).reshape(
+            b, heads, s, -1)
+    else:
+        out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     return jnp.swapaxes(out, 1, 2).reshape(b, s, heads * v.shape[-1])
 
 
@@ -166,6 +217,35 @@ def scan_layers(block_fn: Callable, x, stacked, remat: bool):
     if remat:
         block_fn = jax.checkpoint(block_fn)
     return jax.lax.scan(block_fn, x, stacked)
+
+
+def scan_periods(block_fns: Sequence[Callable], x, stacked, remat: bool):
+    """`scan_layers` for a model whose layers repeat in a PERIOD of several
+    kinds (three window layers to each full one): `block_fns[i](x, layer)
+    -> (x, y)` is the i-th layer of a period, `stacked` the parameters of
+    all the layers on a leading axis, in order, so a period's are
+    consecutive. The scan is over periods and its body is one period, its
+    kinds each compiled once, so compile time is O(1) in depth. `remat`
+    checkpoints each layer of the body (a period's backward then holds one
+    layer's internals and a period's layer inputs, not four layers'
+    internals). -> (x, ys), ys stacked over all the layers."""
+    size = len(block_fns)
+    if size == 1:
+        return scan_layers(block_fns[0], x, stacked, remat)
+    if remat:
+        block_fns = [jax.checkpoint(fn) for fn in block_fns]
+
+    def period(x, layers):
+        ys = []
+        for i, fn in enumerate(block_fns):
+            x, y = fn(x, jax.tree_util.tree_map(lambda a: a[i], layers))
+            ys.append(y)
+        return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
+
+    x, ys = jax.lax.scan(period, x, jax.tree_util.tree_map(
+        lambda a: a.reshape((-1, size) + a.shape[1:]), stacked))
+    return x, jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), ys)
 
 
 def layer_trunk(block_fn: Callable, mesh: Optional[Mesh], num_layers: int,

@@ -8,7 +8,7 @@ manifold-constrained hyper-connections, arXiv:2512.24880):
   part beside a rotary part whose key is one vector a token shared by every
   head; the value is narrower than the query (the flash kernels' two widths).
   No projection is absorbed into another: that is serving's trick.
-- yarn-scaled RoPE (`yarn_inv_freq`, `attention_scale`).
+- yarn-scaled RoPE (`blocks.yarn_inv_freq`, `attention_scale`).
 - leading dense SwiGLU layers, then sparse layers: a sigmoid top-k router
   with a selection bias over ALL routed experts, the routed experts this
   chip HOLDS (`experts_held`, dropless, ops/moe.py) and a shared expert.
@@ -55,14 +55,13 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import moe
 from ..ops.pallas import stream_mix
 from . import stages
 from .blocks import (attention, lm_head_loss, normal, rms_norm, rope,
-                     scan_layers, swiglu)
+                     scan_layers, swiglu, yarn_inv_freq)
 from .trainer import build_adamw_train_step
 
 
@@ -125,27 +124,6 @@ class MlaMoeConfig:
 
 
 # ------------------------------------------------------------------- yarn
-
-def yarn_inv_freq(dim: int, base: float, scaling: Optional[dict]):
-    """Rotary inverse frequencies [dim/2] under yarn (Peng et al. 2023, as
-    DeepSeek-V2 computes them): below `low` a component keeps its frequency,
-    above `high` it is divided by `factor`, between them a linear ramp."""
-    i = np.arange(dim // 2, dtype=np.float64)
-    extra = base ** (-2.0 * i / dim)
-    if not scaling:
-        return extra.astype(np.float32)
-    factor = scaling["factor"]
-    original = scaling["original_max_position_embeddings"]
-
-    def corr(rotations):
-        return dim * math.log(original / (2 * math.pi * rotations)) \
-            / (2 * math.log(base))
-
-    low = max(math.floor(corr(scaling["beta_fast"])), 0)
-    high = min(math.ceil(corr(scaling["beta_slow"])), dim - 1)
-    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
-    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
-
 
 def _mscale(factor: float, a: float) -> float:
     return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
@@ -371,7 +349,7 @@ def _sparse_ffn(y, blk, c: MlaMoeConfig):
             c.routed_scaling_factor)
     with jax.named_scope(stages.EXPERTS):
         routed = moe.held_experts_ffn(flat, ids, weights, blk["experts"],
-                                      c.held)
+                                      c.held, c.n_routed_experts)
     with jax.named_scope(stages.MLP):
         return shared + routed.reshape(b, s, h), ids
 

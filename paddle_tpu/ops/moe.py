@@ -12,8 +12,10 @@ static-shaped and lands on the MXU; tokens over capacity are dropped; under
 GSPMD an 'ep'-sharded expert dim lowers the dispatch einsums to the same
 all-to-all the reference issues by hand. Top-1 and top-2 only.
 
-**Dropless path** (`sigmoid_topk_route`, `held_experts_ffn`): what the
-compiled trainer's sparse families use (models/mla_moe.py). Any k, no
+**Dropless path** (`sigmoid_topk_route`, `softmax_topk_route`,
+`held_experts_ffn`): what the compiled trainer's sparse families use
+(models/mla_moe.py: sigmoid scores with a selection bias; models/llama.py:
+softmax probabilities, renormalised, with a balance term). Any k, no
 capacity, no dropped pair, no [S, E, C] tensor: the (token, expert) pairs
 are sorted by expert and run through grouped products
 (`jax.lax.ragged_dot`, a Mosaic grouped matmul on a TPU). The layer is told
@@ -182,7 +184,52 @@ def sigmoid_topk_route(x, w_r, b, k: int, scale: float):
     return ids.astype(jnp.int32), w
 
 
-CHUNKS = 4      # the sorted pairs are run in this many chunks of rows
+def softmax_topk_route(x, w_r, k: int):
+    """Softmax top-k routing with renormalised weights (`norm_topk_prob`),
+    in float32: no bias, no scaling.
+
+    x [T, M], w_r [M, E] -> (ids [T, k] int32, weights [T, k] float32,
+    probs [T, E] float32). p = softmax(x w_r) over ALL the experts; the k
+    largest are chosen; the weights are the chosen p over their sum.
+    `probs` is what a balance term reads (`balance_term`). The product is
+    a true float32 one, HIGHEST, for `sigmoid_topk_route`'s reason."""
+    probs = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                                   w_r.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST), -1)
+    w, ids = jax.lax.top_k(probs, k)
+    return ids.astype(jnp.int32), w / w.sum(-1, keepdims=True), probs
+
+
+def pairs_drawn(ids, num_experts: int):
+    """Choices [T, k] -> the (token, expert) pairs each of ALL the experts
+    drew, int32 [num_experts]."""
+    return (ids[..., None] == jnp.arange(num_experts)).sum((0, 1)).astype(
+        jnp.int32)
+
+
+def balance_term(ids, probs):
+    """The auxiliary load-balance term of a softmax top-k router (Switch
+    Transformer's, at k choices a token): E * sum_e F_e P_e with F_e the
+    share of the T tokens that chose expert e (sums to k; a count, so no
+    gradient) and P_e the mean over tokens of p_e. k at perfect balance.
+    ids [T, k], probs [T, E] -> (float32 scalar, the pairs drawn [E])."""
+    t, e = probs.shape
+    drawn = pairs_drawn(ids, e)
+    share = jax.lax.stop_gradient(drawn.astype(jnp.float32) / t)
+    return e * jnp.sum(share * probs.mean(0)), drawn
+
+
+def chunk_count(held: int, num_experts: int, pairs: int) -> int:
+    """Chunks the sorted pairs are run in: as many as leave balanced
+    routing's held pairs (pairs * held / num_experts) half of the first
+    chunk, num_experts / (2 held): 4 where an eighth of the experts is
+    held, 2 at a quarter, 1 from a half on (and where the pairs do not cut
+    into chunks of whole sublanes). At a quarter held, 4 chunks would end
+    the balanced load ON the first chunk's edge, and every seed a little
+    over it would run a second chunk's gather and elementwise pass for a
+    handful of pairs."""
+    chunks = max(num_experts // (2 * held), 1)
+    return chunks if pairs % (chunks * 8) == 0 else 1
 
 
 def _gather_sum(rows, pos, live, weights=None):
@@ -269,20 +316,18 @@ def _chunk(x, weights, experts, order, where, starts, ends, lo, rows):
                     live)
 
 
-def _chunk_starts(order):
-    pairs = order.shape[0]
-    chunks = CHUNKS if pairs % (CHUNKS * 8) == 0 else 1
-    rows = pairs // chunks
+def _chunk_starts(order, chunks: int):
+    rows = order.shape[0] // chunks
     return jnp.arange(chunks, dtype=jnp.int32) * rows, rows
 
 
-@jax.custom_vjp
-def _chunks(x, weights, experts, order, where, starts, ends):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunks(chunks, x, weights, experts, order, where, starts, ends):
     """Every chunk that holds a held pair, summed: float32 [T, M]. The
     backward is written out (one chunk at a time, recomputed in the branch
     that runs it): differentiating the `cond` would hand each branch's
     operands, the expert matrices among them, back once a chunk."""
-    los, rows = _chunk_starts(order)
+    los, rows = _chunk_starts(order, chunks)
 
     def body(out, lo):
         return jax.lax.cond(
@@ -294,13 +339,13 @@ def _chunks(x, weights, experts, order, where, starts, ends):
     return jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32), los)[0]
 
 
-def _chunks_fwd(*args):
-    return _chunks(*args), args
+def _chunks_fwd(chunks, *args):
+    return _chunks(chunks, *args), args
 
 
-def _chunks_bwd(res, d_out):
+def _chunks_bwd(chunks, res, d_out):
     x, weights, experts, order, where, starts, ends = res
-    los, rows = _chunk_starts(order)
+    los, rows = _chunk_starts(order, chunks)
     add = functools.partial(jax.tree_util.tree_map, jnp.add)
 
     def body(grads, lo):
@@ -322,29 +367,30 @@ def _chunks_bwd(res, d_out):
 _chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 
-def held_experts_ffn(x, ids, weights, experts, held):
+def held_experts_ffn(x, ids, weights, experts, held, num_experts=None):
     """The part of a routed-experts layer that the experts held here give.
 
-    x [T, M]; ids, weights [T, k] from a router over ALL experts;
-    `experts` the SwiGLU matrices of the held ones, stacked: gate_w, up_w
-    [n, M, F], down_w [n, F, M]; `held` = (first, count): experts first ..
-    first + count - 1 are here. Returns sum over the pairs that chose a
+    x [T, M]; ids, weights [T, k] from a router over ALL `num_experts`
+    experts (None: the held ones are all there are); `experts` the SwiGLU
+    matrices of the held ones, stacked: gate_w, up_w [n, M, F], down_w
+    [n, F, M]; `held` = (first, count): experts first .. first + count - 1
+    are here. Returns sum over the pairs that chose a
     held expert of weight * down(silu(gate x) * up x), [T, M]; what the
     experts held elsewhere would add is left out (expert parallelism adds
     it in its exchange, which this function does not stand in for).
 
     Dropless with static shapes, and no capacity. The T*k pairs are sorted
     so that the pairs of held experts come first, grouped by expert, and
-    the rest last; the sorted order is cut into `CHUNKS` chunks of
-    T*k / CHUNKS rows, and a chunk runs (gather, three grouped products
-    over its part of each expert's group, weighted combine) only if a held
-    pair lies in it. Balanced routing to an eighth of the experts fills
-    half of the first chunk and the other three cost a branch not taken;
-    a router that sends every token to held experts fills all of them, and
-    no pair is dropped either way. Buffers have a chunk's rows. Within a
-    running chunk a pair that chose no held expert costs no product (the
-    grouped products stop at the held pairs) but does cost its row of the
-    gather and the elementwise pass. Gradients reach x, the weights and
+    the rest last; the sorted order is cut into `chunk_count` chunks of
+    equal rows (4 where an eighth of the experts is held, 2 at a quarter),
+    and a chunk runs (gather, three grouped products over its part of each
+    expert's group, weighted combine) only if a held pair lies in it.
+    Balanced routing fills half of the first chunk and the others cost a
+    branch not taken; a router that sends every token to held experts
+    fills all of them, and no pair is dropped either way. Buffers have a
+    chunk's rows. Within a running chunk a pair that chose no held expert
+    costs no product (the grouped products stop at the held pairs) but does
+    cost its row of the gather and the elementwise pass. Gradients reach x, the weights and
     the expert matrices; ids are integers."""
     first, count = held
     t, k = ids.shape
@@ -355,8 +401,9 @@ def held_experts_ffn(x, ids, weights, experts, held):
     ends = jnp.cumsum((key[:, None] == jnp.arange(count)).sum(0)).astype(
         jnp.int32)                              # each held group's end
     starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
-    return _chunks(x, weights.astype(jnp.float32), experts, order, where,
-                   starts, ends).astype(x.dtype)
+    chunks = chunk_count(count, num_experts or count, t * k)
+    return _chunks(chunks, x, weights.astype(jnp.float32), experts, order,
+                   where, starts, ends).astype(x.dtype)
 
 
 # -------------------------------------------------- eager op registration

@@ -18,6 +18,7 @@ from benchmarks.cells import load_cell
 from benchmarks.layer_metrics import _stages
 from benchmarks.reference import mla_moe as ref
 from benchmarks.runners import mla_moe as runner
+from paddle_tpu.models import blocks
 from paddle_tpu.models import mla_moe as m
 from paddle_tpu.models import stages
 from paddle_tpu.ops import moe
@@ -458,7 +459,7 @@ def test_a_width_that_cannot_be_tiled_is_a_named_error(monkeypatch):
 def test_yarn_against_the_closed_forms():
     scaling = SHARE_CONFIG["rope_scaling"]
     dim, base = 64, 10000.0
-    got = m.yarn_inv_freq(dim, base, scaling)
+    got = blocks.yarn_inv_freq(dim, base, scaling)
     plain = [base ** (-2 * i / dim) for i in range(dim // 2)]
 
     def corr(r):
@@ -475,7 +476,8 @@ def test_yarn_against_the_closed_forms():
     np.testing.assert_allclose(
         got, ref.yarn_inv_freq({"qk_rope_head_dim": dim, "rope_theta": base,
                                 "rope_scaling": scaling}), rtol=1e-6)
-    np.testing.assert_allclose(m.yarn_inv_freq(dim, base, None), plain,
+    np.testing.assert_allclose(blocks.yarn_inv_freq(dim, base, None),
+                               plain,
                                rtol=1e-6)
     c = m.MlaMoeConfig(rope_scaling=scaling)
     want = 192 ** -0.5 * (0.1 * math.log(64) + 1) ** 2

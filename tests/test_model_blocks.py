@@ -53,7 +53,8 @@ def test_attention_is_each_familys_former_formula(family):
     d, d_v = (24, 16) if family == "mla_moe" else (32, 32)
     q, k, v = _qkv(48, 4, d, d_v, kv_heads=2 if family == "llama_gqa"
                    else None)
-    if family == "llama_gqa":       # the repeat stays the family's
+    few = (k, v)
+    if family == "llama_gqa":       # the former formula repeated K and V
         k, v = (jnp.repeat(a, 2, axis=2) for a in (k, v))
     mask = None
     if family == "bert_masked":
@@ -66,10 +67,30 @@ def test_attention_is_each_familys_former_formula(family):
     else:       # bert and llama divided by the root
         scale = 1.0 / math.sqrt(d)
         want = _former(q, k, v, lambda s: s / math.sqrt(d), causal, mask)
-    got = blocks.attention(q, k, v, causal=causal, scale=scale, flash=False,
+    got = blocks.attention(q, *few, causal=causal, scale=scale, flash=False,
                            mask=mask)
     assert got.shape == (2, 48, 4 * d_v)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2, 1])
+@pytest.mark.parametrize("window", [1, 7, 48, 100])
+def test_attention_einsum_branch_with_a_window_and_fewer_kv_heads(kv_heads,
+                                                                  window):
+    """The second diagonal, and K/V heads shared without a repeat, against
+    the former formula on repeated heads with the mask written from i, j."""
+    q, k, v = _qkv(48, 4, 32, 32, kv_heads=kv_heads, seed=3)
+    scale = 1.0 / math.sqrt(32)
+    i, j = jnp.arange(48)[:, None], jnp.arange(48)[None, :]
+    hidden = jnp.where((j <= i) & (i - j < window), 0.0, -1e30)
+    want = _former(q, *(jnp.repeat(a, 4 // kv_heads, axis=2) for a in (k, v)),
+                   lambda s: s * scale, False, hidden[None, None])
+    got = blocks.attention(q, k, v, causal=True, scale=scale, flash=False,
+                           window=window)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    if window >= 48:        # a window longer than the sequence is no window
+        np.testing.assert_allclose(got, blocks.attention(
+            q, k, v, causal=True, scale=scale, flash=False), rtol=1e-6)
 
 
 @pytest.mark.parametrize("seq", [128, 256])

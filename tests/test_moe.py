@@ -125,3 +125,76 @@ def test_moe_ffn_expert_parallel_pjit():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
     np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
+
+
+# ------------------------------------------- the dropless path's softmax router
+
+def test_softmax_topk_route_is_softmax_then_top_k_renormalised():
+    x = jax.random.normal(jax.random.PRNGKey(0), (37, 24), jnp.float32)
+    w_r = jax.random.normal(jax.random.PRNGKey(1), (24, 16), jnp.float32)
+    ids, weights, probs = moe_ops.softmax_topk_route(x, w_r, 8)
+    want = jax.nn.softmax(jnp.dot(x, w_r, precision="highest"), -1)
+    top, top_ids = jax.lax.top_k(want, 8)
+    np.testing.assert_allclose(probs, want, rtol=1e-6)
+    np.testing.assert_array_equal(ids, top_ids)
+    np.testing.assert_allclose(weights, top / top.sum(-1, keepdims=True),
+                               rtol=1e-6)
+    np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-6)
+    assert ids.dtype == jnp.int32 and weights.dtype == jnp.float32
+    # bfloat16 activations meet a float32 router in float32
+    low, _, _ = moe_ops.softmax_topk_route(x.astype(jnp.bfloat16), w_r, 8)
+    assert low.shape == ids.shape
+
+
+def test_balance_term_is_k_at_perfect_balance_and_grows_with_skew():
+    e, k, t = 16, 4, 64
+    even = jnp.stack([(jnp.arange(k) + i * k) % e for i in range(t)])
+    uniform = jnp.full((t, e), 1.0 / e)
+    value, drawn = moe_ops.balance_term(even, uniform)
+    np.testing.assert_allclose(value, k, rtol=1e-6)
+    np.testing.assert_array_equal(drawn, np.full(e, t * k // e))
+    np.testing.assert_array_equal(moe_ops.pairs_drawn(even, e), drawn)
+    # every token on the same k experts, and probabilities that follow
+    skewed = jnp.tile(jnp.arange(k), (t, 1))
+    peaked = jnp.zeros((t, e)).at[:, :k].set(1.0 / k)
+    assert float(moe_ops.balance_term(skewed, peaked)[0]) == pytest.approx(e)
+    # the counts carry no gradient, the probabilities do
+    grad = jax.grad(lambda p: moe_ops.balance_term(skewed, p)[0])(uniform)
+    np.testing.assert_allclose(grad[:, :k], e / t, rtol=1e-6)
+    np.testing.assert_allclose(grad[:, k:], 0.0)
+
+
+@pytest.mark.parametrize("held, experts, pairs, want", [
+    (8, 64, 4 * 16384, 4),      # an eighth held: the two older sparse cells
+    (16, 64, 8 * 16384, 2),     # a quarter: the balanced load ends mid-chunk
+    (32, 64, 4096, 1), (64, 64, 4096, 1), (4, 16, 4096, 2),
+    (8, 64, 100, 1),            # rows that do not cut into whole sublanes
+])
+def test_chunk_count_follows_the_held_share(held, experts, pairs, want):
+    assert moe_ops.chunk_count(held, experts, pairs) == want
+
+
+@pytest.mark.parametrize("experts", [None, 16, 32, 64],
+                         ids=["all_held", "quarter", "eighth", "sixteenth"])
+def test_held_experts_ffn_is_the_same_in_any_number_of_chunks(experts):
+    """The chunk count is bookkeeping: whatever the router's width says of
+    the held share, the held experts' part is the loop's."""
+    t, hidden, width, k, held = 64, 32, 16, 2, (0, 4)
+    keys = jax.random.split(jax.random.PRNGKey(4), 5)
+    x = jax.random.normal(keys[0], (t, hidden), jnp.float32)
+    ids = jax.random.randint(keys[1], (t, k), 0, 6).astype(jnp.int32)
+    weights = jax.random.uniform(keys[2], (t, k), jnp.float32)
+    stack = {"gate_w": jax.random.normal(keys[3], (4, hidden, width),
+                                         jnp.float32) * 0.2,
+             "up_w": jax.random.normal(keys[4], (4, hidden, width),
+                                       jnp.float32) * 0.2,
+             "down_w": jax.random.normal(keys[3], (4, width, hidden),
+                                         jnp.float32) * 0.2}
+    got = moe_ops.held_experts_ffn(x, ids, weights, stack, held, experts)
+    want = 0
+    for i in range(4):
+        mine = (weights * (ids == i)).sum(-1, keepdims=True)
+        gate = x @ stack["gate_w"][i]
+        want = want + mine * ((jax.nn.silu(gate) * (x @ stack["up_w"][i]))
+                              @ stack["down_w"][i])
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)

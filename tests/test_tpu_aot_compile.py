@@ -327,6 +327,36 @@ def test_past_the_grouped_fit_the_head_major_kernels_take_over(v5e):
         _compile(v5e, gradient, *[((4, cap + 512, 1024), jnp.bfloat16)] * 3)
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_grouped_query_kernels_compile_at_the_mellum_cells_shape(v5e,
+                                                                 window):
+    """32 query heads on 4 K/V heads of 128 at 4 x 4,096, a window layer and
+    a full one: q [4, 4096, 4096] and k, v [4, 4096, 512] go to the two
+    Mosaic calls as the projections wrote them, dK and dV come back with 4
+    heads, and nothing of 32 K/V heads' size other than q, dO, the output
+    and dQ exists."""
+    b, s, heads, kv, d = 4, 4096, 32, 4, 128
+    assert fa.head_group(heads, d, d, s, s, jnp.bfloat16, kv) == 1
+
+    def gradient(q, k, v):
+        return jax.grad(lambda q, k, v: _sum32(fa.mha_seq_major(
+            q, k, v, heads, causal=True, kv_heads=kv, window=window)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    shapes = [((b, s, heads * d), jnp.bfloat16)] \
+        + [((b, s, kv * d), jnp.bfloat16)] * 2
+    compiled = _compile(v5e, gradient, *shapes)
+    text = compiled.as_text()
+    wide, narrow = b * s * heads * d, b * s * kv * d
+    assert sorted(_mosaic_calls(text, narrow)) == [(2, 0), (2, 2)]
+    assert sorted(_mosaic_calls(text, wide)) == [(1, 1), (2, 1)]
+    assert not _of_size(text, wide, "copy", "transpose")
+    dq, dk, dv = jax.eval_shape(gradient, *[
+        jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes])
+    assert (dq.shape, dk.shape, dv.shape) == (
+        (b, s, heads * d), (b, s, kv * d), (b, s, kv * d))
+
+
 def test_gpt2_medium_block_reaches_the_kernels_without_a_copy(v5e):
     """One checkpointed gpt2-medium layer and its gradient at the cell's
     batch: the three Mosaic calls (forward, its remat, backward) take q, k
