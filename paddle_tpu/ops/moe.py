@@ -18,7 +18,9 @@ all-to-all the reference issues by hand. Top-1 and top-2 only.
 softmax probabilities, renormalised, with a balance term). Any k, no
 capacity, no dropped pair, no [S, E, C] tensor: the (token, expert) pairs
 are sorted by expert and run through grouped products
-(`jax.lax.ragged_dot`, a Mosaic grouped matmul on a TPU). The layer is told
+(`ops/pallas/grouped_matmul.grouped_dot`: the repo's own Mosaic kernels,
+forward and both gradients, which visit only the row tiles that hold a
+group's rows). The layer is told
 which experts it holds, as expert parallelism tells it: it routes over all
 E and computes the part of the result its own experts give.
 
@@ -34,6 +36,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from .pallas.grouped_matmul import grouped_dot
 
 
 # ------------------------------------------------------------------ gating
@@ -300,17 +304,18 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 def _chunk(x, weights, experts, order, where, starts, ends, lo, rows):
     """The chunk of `rows` sorted pairs from `lo` through the held experts:
     float32 [T, M], the weighted sum of its live rows' outputs at their
-    tokens."""
+    tokens. The grouped products leave the rows past the last group
+    unwritten (`grouped_matmul`): everything here that reads such a row
+    selects it away (`valid`, `live`), and `silu(gate) * up` of one feeds
+    only a row the down product skips."""
     pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
     pos = where - lo
     live = (pos >= 0) & (pos < rows) & (where < ends[-1])
     pos = jnp.clip(pos, 0, rows - 1)
     sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
     mine = _dispatch(x, pair // weights.shape[1], pos, live)    # [C, M]
-    gate = jax.lax.ragged_dot(mine, experts["gate_w"], sizes)
-    up = jax.lax.ragged_dot(mine, experts["up_w"], sizes)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["down_w"],
-                             sizes)
+    gate, up = grouped_dot(mine, (experts["gate_w"], experts["up_w"]), sizes)
+    out = grouped_dot(jax.nn.silu(gate) * up, experts["down_w"], sizes)
     valid = jnp.arange(rows) < sizes.sum()
     return _combine(jnp.where(valid[:, None], out, 0), weights, pair, pos,
                     live)
@@ -389,9 +394,11 @@ def held_experts_ffn(x, ids, weights, experts, held, num_experts=None):
     branch not taken; a router that sends every token to held experts
     fills all of them, and no pair is dropped either way. Buffers have a
     chunk's rows. Within a running chunk a pair that chose no held expert
-    costs no product (the grouped products stop at the held pairs) but does
-    cost its row of the gather and the elementwise pass. Gradients reach x, the weights and
-    the expert matrices; ids are integers."""
+    costs no product (`grouped_dot`'s kernels visit the row tiles of the
+    held pairs and no other, in all nine products of a pass, and leave the
+    rows past them unwritten: `_chunk` selects those away) but does cost
+    its row of the gather and the elementwise pass. Gradients reach x, the
+    weights and the expert matrices; ids are integers."""
     first, count = held
     t, k = ids.shape
     local = ids.reshape(-1) - first

@@ -401,3 +401,88 @@ def test_train_step_with_flash_lowers_on_pp2_mp2_mesh(v5e):
     text = step.trace(state, batch, batch).lower(
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------- grouped products (PR 34)
+
+gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
+# a chunk's rows, hidden, expert width, experts held: the Mellum, GLM and
+# Xing cells
+GROUPED = {"mellum": (65536, 2304, 896, 16), "glm": (16384, 2048, 1536, 8),
+           "xing": (4096, 3584, 1024, 8)}
+
+
+@pytest.mark.parametrize("kernel", ["forward", "dgrad", "wgrad"])
+@pytest.mark.parametrize("cell", list(GROUPED))
+def test_grouped_products_compile_at_the_sparse_cells_shapes(v5e, cell,
+                                                             kernel):
+    """A group's whole matrix resident (over the 16 MiB default scoped
+    VMEM: the calls raise the limit), a grid whose bound is read on the
+    device, the contraction of the ragged rows, a gradient added into the
+    buffer of an earlier one; at the tile the shape rule gives."""
+    rows, k, n, held = GROUPED[cell]
+    bf16, sizes = jnp.bfloat16, ((held,), jnp.int32)
+    tile = gm.row_tile(rows, held)
+    fn, shapes = {
+        "forward": (lambda l, w, s: gm.gmm(l, w, s, tile),
+                    (((rows, k), bf16), ((held, k, n), bf16), sizes)),
+        "dgrad": (lambda d, w, s, first: gm.gmm(d, w.swapaxes(1, 2), s, tile,
+                                                add_to=first),
+                  (((rows, n), bf16), ((held, k, n), bf16), sizes,
+                   ((rows, k), bf16))),
+        "wgrad": (lambda l, d, s: gm.tgmm(l, d, s, tile),
+                  (((rows, k), bf16), ((rows, n), bf16), sizes))}[kernel]
+    text = _compile(v5e, fn, *shapes).as_text()
+    # no float32 copy of the rows reaches HBM
+    assert f"f32[{rows}," not in text
+
+
+def _routed_layers_gradient(layers):
+    """`jax.grad` of `layers` routed feed-forwards side by side, each with
+    matrices of its own, at the Xing cell's shapes."""
+    from paddle_tpu.ops import moe
+    t, hidden, width, k, experts, held = 4096, 3584, 1024, 4, 64, 8
+    bf16 = jnp.bfloat16
+
+    def loss(x, weights, ids, *stacks):
+        return sum(_sum32(moe.held_experts_ffn(
+            x, ids, weights, {"gate_w": gate_w, "up_w": up_w,
+                              "down_w": down_w}, (0, held), experts))
+            for gate_w, up_w, down_w in zip(*[iter(stacks)] * 3))
+
+    shapes = [((t, hidden), bf16), ((t, k), jnp.float32), ((t, k), jnp.int32)]
+    shapes += [((held, hidden, width), bf16), ((held, hidden, width), bf16),
+               ((held, width, hidden), bf16)] * layers
+    return (jax.grad(loss, argnums=(0, 1) + tuple(range(3, 3 + 3 * layers))),
+            shapes)
+
+
+def test_held_experts_ffn_gradient_compiles_with_the_kernels_in_its_loop(
+        v5e):
+    """The kernels inside the `lax.cond` inside the `lax.scan` of
+    `ops/moe._chunks`, under `jax.grad`: the backward's three recomputed
+    products and six gradients are nine Mosaic calls and no grouped matmul
+    of XLA's is left."""
+    fn, shapes = _routed_layers_gradient(1)
+    text = _compile(v5e, fn, *shapes).as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 9
+    assert "ragged-dot" not in text
+
+
+def test_a_second_routed_layer_lowers_no_further_mosaic_body(v5e):
+    """The guard on the set-up cost (about 0.15 s of Python a lowered body
+    a program, PERF.md section 6, PR 34): the jitted entries make the sites
+    of one shape call one `func.func`, so a second layer of the same
+    shapes adds calls and no Mosaic body to the lowered module."""
+    def bodies(layers):
+        fn, shapes = _routed_layers_gradient(layers)
+        sh = SingleDeviceSharding(v5e[0])
+        text = jax.jit(fn).trace(*[jax.ShapeDtypeStruct(
+            s, d, sharding=sh) for s, d in shapes]).lower(
+                lowering_platforms=("tpu",)).as_text()
+        assert "ragged_dot" not in text
+        return text.count("stablehlo.custom_call @tpu_custom_call")
+
+    one = bodies(1)
+    assert 5 <= one <= 9          # five are distinct; twelve sites a layer
+    assert bodies(2) == one
