@@ -71,26 +71,15 @@ class Sizes:
                    dry_run=True)
 
 
-class CacheCounter:
-    """Persistent-compile-cache traffic, from JAX's own monitoring events."""
-
-    def __init__(self):
-        import jax
-        self.requests = self.hits = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def snapshot(self):
-        return self.requests, self.hits
-
-    def since(self, snap):
-        r, h = (a - b for a, b in zip(self.snapshot(), snap))
-        return {"cache_requests": r, "cache_hits": h, "cache_misses": r - h}
+def cache_traffic() -> tuple:
+    """(hits, misses) of JAX's persistent compile cache so far: the
+    counters the program recorder keeps in the registry
+    (`paddle_tpu/observability/programs.py`, registered by
+    `enable_compile_cache()`; the one listener on JAX's events)."""
+    from paddle_tpu import observability
+    counters = observability.stats()["counters"]
+    return (counters.get("programs.cache_hits", 0),
+            counters.get("programs.cache_misses", 0))
 
 
 def _peak_bytes(dev=None):
@@ -407,11 +396,12 @@ PHASES = (("train", phase_train), ("flash", phase_flash),
           ("kernels", phase_kernels), ("eager", phase_eager))
 
 
-def run_phases(phases, sz: Sizes, counter=None) -> list:
-    """Run EVERY phase, a failure included; one 'PHASE {json}' line each."""
+def run_phases(phases, sz: Sizes, traffic=None) -> list:
+    """Run EVERY phase, a failure included; one 'PHASE {json}' line each,
+    with the phase's share of `traffic()` (`cache_traffic`) where given."""
     results = []
     for name, fn in phases:
-        snap = counter.snapshot() if counter else None
+        before = traffic() if traffic else None
         t0 = time.perf_counter()
         row = {"phase": name, "ok": True}
         try:
@@ -421,8 +411,10 @@ def run_phases(phases, sz: Sizes, counter=None) -> list:
             row.update(ok=False, error=f"{type(e).__name__}: {e}"[:2000])
         row["seconds"] = round(time.perf_counter() - t0, 2)
         row["peak_bytes_in_use"] = _peak_bytes()
-        if counter:
-            row.update(counter.since(snap))
+        if traffic:
+            hits, misses = (a - b for a, b in zip(traffic(), before))
+            row.update(cache_requests=hits + misses, cache_hits=hits,
+                       cache_misses=misses)
         print("PHASE " + json.dumps(row), flush=True)
         results.append(row)
     return results
@@ -456,7 +448,6 @@ def main(argv=None) -> int:
 
     from paddle_tpu._core.device import enable_compile_cache
     print(f"compile cache: {enable_compile_cache()}", flush=True)
-    counter = CacheCounter()
 
     phases = list(PHASES)
     if device["count"] >= 4:
@@ -466,7 +457,7 @@ def main(argv=None) -> int:
               f"process has {device['count']})")
     if args.phases:
         phases = [p for p in phases if p[0] in args.phases.split(",")]
-    results = run_phases(phases, sz, counter)
+    results = run_phases(phases, sz, cache_traffic)
 
     failed = [r["phase"] for r in results if not r["ok"]]
     summary = {"ok": not failed, "device": device}

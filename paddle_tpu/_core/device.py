@@ -231,7 +231,14 @@ def enable_compile_cache() -> str:
     `op_name` of a compiled instruction carries the stage the program
     gave it (`models/stages.py`) and a device trace is read by it, so an
     executable cached from a program with other scopes is another
-    program, though its computation is the same."""
+    program, though its computation is the same.
+
+    It also registers the recorder of program-building
+    (`observability/programs.py`): from here on every trace, lowering,
+    compile and cache load of the process is a span, and
+    `observability.stats()["programs"]` says which program missed."""
+    from ..observability import programs
+    programs.register()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -26,12 +26,24 @@ instrumentation point (`observability._state.ACTIVE`), zero registry
 work — asserted by tests/test_observability.py
 (`test_off_mode_zero_registry_work`).
 
-    python -m paddle_tpu.observability        # demo workload + stats
+Program building is the one part with no flag (`programs.py`): once an
+entry point has called `_core.device.enable_compile_cache()` (or
+`enable()` here), every program the process traces, lowers, compiles or
+loads from JAX's persistent cache is a span, and `stats()["programs"]` is
+the operator's table for "why did this job take five minutes to its first
+step, and which program missed the cache": one row a program (trace self /
+trace of children / lower / compile or load, hit or miss), the totals, the
+functions traced twice for one shape, the recorder's own cost.
+`benchmarks/setup_table.py` lays the same spans beside a benchmark cell's
+set-up phases.
+
+    python -m paddle_tpu.observability        # demo workload + stats,
+                                              # the programs' table under it
 """
 from __future__ import annotations
 
 from .._core import flags as _flags
-from . import _state, flight, metrics, spans
+from . import _state, flight, metrics, programs, spans
 from .metrics import counter, gauge, histogram
 from .spans import span
 
@@ -104,7 +116,9 @@ _flags.watch_flag("FLAGS_monitor", _on_monitor_flag)
 
 
 def enable(flight_recorder: bool = None):
-    """Turn on metrics collection (and optionally the flight recorder)."""
+    """Turn on metrics collection (and optionally the flight recorder),
+    and the recorder of program-building, which then stays."""
+    programs.register()
     f = {"FLAGS_observability": True}
     if flight_recorder is not None:
         f["FLAGS_flight_recorder"] = bool(flight_recorder)
@@ -120,10 +134,11 @@ def enabled() -> bool:
 
 
 def reset():
-    """Zero every metric and drop the flight ring (counter snapshots
-    restart from a clean baseline)."""
+    """Zero every metric, drop the flight ring and the programs' spans
+    (counter snapshots restart from a clean baseline)."""
     metrics.reset()
     flight.reset()
+    programs.reset()
 
 
 def _derived(counters: dict) -> dict:
@@ -159,8 +174,14 @@ def stats(reset_after: bool = False) -> dict:
     - ``step_cache_hit_rate``: the fused fwd+vjp "step cache" alone —
       THE steady-state train-step health signal.
     """
+    # the programs the process built, a row each (no flag: it is there once
+    # an entry point asked for the compile cache); first, because reading
+    # them brings their counters in the registry up to date
+    built = programs.summary() if programs.registered() else None
     snap = metrics.snapshot()
     snap.update(_derived(snap["counters"]))
+    if built is not None:
+        snap["programs"] = built
     if _state.MEM:
         # byte-domain headline (census watermark + cached per-
         # executable memory analysis) rides along whenever the memory

@@ -494,6 +494,9 @@ def _render(snap: dict) -> str:
             lines.append(
                 f"    {k:<40} n={h['count']} avg={h['avg']:.1f} "
                 f"min={h['min']:.1f} max={h['max']:.1f}")
+    if "programs" in snap:
+        from paddle_tpu.observability import programs
+        lines.append(programs.render(snap["programs"]))
     return "\n".join(lines)
 
 

@@ -69,17 +69,29 @@ class Histogram:
         self.max = None
         self.buckets = [0] * (len(_BOUNDS) + 1)
 
+    def _take(self, v: float):
+        self.count += 1
+        self.total += v
+        if self.min is None or v < self.min:
+            self.min = v
+        if self.max is None or v > self.max:
+            self.max = v
+        self.buckets[bisect.bisect_left(_BOUNDS, v)] += 1
+
     def observe(self, v: float):
         global MUTATIONS
         with _LOCK:
-            self.count += 1
-            self.total += v
-            if self.min is None or v < self.min:
-                self.min = v
-            if self.max is None or v > self.max:
-                self.max = v
-            self.buckets[bisect.bisect_left(_BOUNDS, v)] += 1
+            self._take(v)
             MUTATIONS += 1
+
+    def observe_all(self, values):
+        """`observe` each of `values` under one taking of the lock (the
+        program recorder's traces, thousands to a model)."""
+        global MUTATIONS
+        with _LOCK:
+            for v in values:
+                self._take(v)
+            MUTATIONS += len(values)
 
     def summary(self) -> dict:
         out = {"count": self.count, "total": self.total,

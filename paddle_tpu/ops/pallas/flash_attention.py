@@ -76,6 +76,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as _P
 
 from ..._core.device import pallas_interpret
+from ...observability.programs import mosaic_site
 
 NEG_INF = -1e30
 
@@ -487,7 +488,7 @@ def _fwd(q, k, v, heads, group, causal, scale, block_q, block_k, q_offset,
     gd, gdv = hd // heads * group, v.shape[2] // kv_heads * group
     nqb = sq // block_q
     kv = _kv_index(heads, rep)
-    with _no_x64():
+    with mosaic_site(_fwd_kernel, q, k, v), _no_x64():
         return pl.pallas_call(
             functools.partial(_fwd_kernel, group=group, causal=causal,
                               scale=scale, block_k=block_k,
@@ -672,7 +673,7 @@ def _bwd(q, k, v, out, lse, do, heads, group, causal, scale, block_q,
                     pltpu.VMEM((sk, group * dv), jnp.float32)]
         semantics = tuple("arbitrary" if axis >= rep_axis else "parallel"
                           for axis in range(3))
-    with _no_x64():
+    with mosaic_site(_bwd_kernel, q, k, v, do), _no_x64():
         return pl.pallas_call(
             functools.partial(_bwd_kernel, group=group, causal=causal,
                               scale=scale, block_q=block_q,

@@ -63,6 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..._core.device import pallas_interpret
+from ...observability.programs import mosaic_site
 from .flash_attention import SCOPED_VMEM_BYTES, _no_x64
 
 LANES = 128
@@ -271,18 +272,20 @@ def _call(kernel, vmem, columns, tables, in_specs, out_spec, out_shape,
     of `tables` (`_steps`), which are prefetched as scalars and bound the
     grid, with `vmem` bytes (and the margin) of scoped VMEM, never under
     Mosaic's default. `aliases` counts operands from the first table."""
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(columns, tables[4]),
-            in_specs=in_specs, out_specs=out_spec, scratch_shapes=scratch),
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=max(vmem + VMEM_MARGIN_BYTES,
-                                 SCOPED_VMEM_BYTES)),
-        input_output_aliases=aliases or {},
-        interpret=interpret)(*tables[:4], *args)
+    with mosaic_site(kernel, *args):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(columns, tables[4]),
+                in_specs=in_specs, out_specs=out_spec,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=max(vmem + VMEM_MARGIN_BYTES,
+                                     SCOPED_VMEM_BYTES)),
+            input_output_aliases=aliases or {},
+            interpret=interpret)(*tables[:4], *args)
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))

@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..._core.device import pallas_interpret
+from ...observability.programs import mosaic_site
 from .flash_attention import _no_x64
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -328,7 +329,7 @@ def _read_in_bwd_kernel(dhin_ref, x_ref, part_ref, g_ref, praw_ref, rinv_ref,
 # --------------------------------------------------------------- the calls
 
 def _call(kernel, args, in_specs, out_specs, out_shape, grid, aliases=None):
-    with _no_x64():
+    with mosaic_site(kernel, *args), _no_x64():
         return pl.pallas_call(
             kernel, grid=(grid,), in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, input_output_aliases=aliases or {},

@@ -133,3 +133,32 @@ def with_flag(name, value):
         def __exit__(self, *a):
             set_flags({name: self.old})
     return _Ctx()
+
+
+def unregister_program_recorder():
+    """Take JAX's listeners back from the recorder of program-building
+    (`paddle_tpu/observability/programs.py`) and empty it. The recorder has
+    no switch; a test that registers it undoes that by hand, so that the
+    tests after it in this process see none (import as `from conftest
+    import unregister_program_recorder`)."""
+    from jax._src import monitoring
+    from paddle_tpu.observability import programs
+    if programs.registered():
+        monitoring.unregister_event_listener(programs.RECORDER.on_event)
+        monitoring.unregister_event_duration_listener(
+            programs.RECORDER.on_duration)
+        monitoring.unregister_event_time_span_listener(
+            programs.RECORDER.on_time_span)
+        programs._REGISTERED = False
+    programs.reset()
+
+
+@pytest.fixture
+def recorder():
+    """The process's recorder of program-building, registered and empty
+    for one test."""
+    from paddle_tpu.observability import programs
+    unregister_program_recorder()
+    programs.register()
+    yield programs.RECORDER
+    unregister_program_recorder()
