@@ -114,20 +114,41 @@ def wd_mask(config: BertConfig) -> Dict:
     }
 
 
-def _block(x, blk, config: BertConfig, attn_mask=None):
+def _block(x, blk, config: BertConfig, attn_mask=None,
+           mesh: Optional[Mesh] = None):
     """Post-norm encoder block (BERT convention): x [B, S, H] -> (x, None);
-    attn_mask [B, 1, 1, S] additive or None."""
+    attn_mask [B, 1, 1, S] additive or None. `blocks.attention` picks the
+    branch: without a mask, on a TPU, at a sequence of 256 tokens or more
+    that 128 divides, the flash kernels (no diagonal: every tile whole); at
+    128 tokens, with a padding mask (the kernels take none) or on a CPU the
+    einsum path. Off an `mp` mesh q, k and v are three products on column
+    blocks of `qkv_w`: three [B, S, h] arrays the seq-major kernels index
+    as they are, where slices of one [B, S, 3h] product are three copies in
+    front of every call (forward, remat, backward), and cost the einsum
+    path its copies too. On a `mesh` the kernels run manual over its axes
+    (`mha_sharded`: Mosaic calls are not partitioned automatically), the
+    batch over `dp` and the heads over `mp`, which must divide them. The
+    stored parameters are the same everywhere."""
     c = config
-    b, s, _ = x.shape
+    b, s, h = x.shape
     with jax.named_scope(stages.ATTN_QKV):
-        qkv = jnp.einsum("bsh,hk->bsk", x, blk["qkv_w"]) + blk["qkv_b"]
+        if mesh is None or mesh.shape.get("mp", 1) == 1:
+            q, k, v = (jnp.einsum("bsh,hk->bsk", x,
+                                  blk["qkv_w"][:, i * h:(i + 1) * h])
+                       + blk["qkv_b"][i * h:(i + 1) * h] for i in range(3))
+        else:
+            # qkv_w's columns are sharded over mp as one [h, 3h] matrix: a
+            # column block of it lives on other chips than its heads, so
+            # the one product is split, by heads (as `gpt._block` does)
+            qkv = jnp.einsum("bsh,hk->bsk", x, blk["qkv_w"]) + blk["qkv_b"]
+            qkv = qkv.reshape(b, s, 3, h)
+            q, k, v = (qkv[:, :, i] for i in range(3))
     with jax.named_scope(stages.ATTN_CORE):
-        qkv = qkv.reshape(b, s, 3, c.num_heads, c.head_dim)
-        # flash=False: no kernel serves this family yet. ROADMAP A2 turns
-        # it on here (the two bert cells judge it).
-        attn = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                         causal=False, scale=1.0 / math.sqrt(c.head_dim),
-                         flash=False, mask=attn_mask)
+        q, k, v = (a.reshape(b, s, c.num_heads, c.head_dim)
+                   for a in (q, k, v))
+        attn = attention(q, k, v, causal=False,
+                         scale=1.0 / math.sqrt(c.head_dim), flash=True,
+                         mesh=mesh, mask=attn_mask)
     with jax.named_scope(stages.ATTN_OUT):
         attn = jnp.einsum("bsh,hk->bsk", attn, blk["proj_w"]) \
             + blk["proj_b"]
@@ -140,7 +161,10 @@ def _block(x, blk, config: BertConfig, attn_mask=None):
 
 
 def bert_encode(params, tokens, token_type_ids=None, attention_mask=None,
-                config: BertConfig = None, remat=True):
+                config: BertConfig = None, remat=True,
+                mesh: Optional[Mesh] = None):
+    """tokens [B, S] int32 -> hidden [B, S, H]. Under jit on more than one
+    device pass the `mesh` (see `_block`)."""
     b, s = tokens.shape
     c = config
     with jax.named_scope(stages.EMBED):
@@ -157,8 +181,8 @@ def bert_encode(params, tokens, token_type_ids=None, attention_mask=None,
             add_mask = (1.0 - attention_mask[:, None, None, :].astype(
                 jnp.float32)) * -1e30
     x, _ = scan_layers(
-        functools.partial(_block, config=c, attn_mask=add_mask), x,
-        params["blocks"], remat)
+        functools.partial(_block, config=c, attn_mask=add_mask, mesh=mesh),
+        x, params["blocks"], remat)
     return x
 
 
@@ -171,16 +195,18 @@ def _mlm_transform(params, x, config: BertConfig):
 
 
 def bert_mlm_logits(params, tokens, config: BertConfig, remat=True,
-                    attention_mask=None):
-    x = bert_encode(params, tokens, None, attention_mask, config, remat)
+                    attention_mask=None, mesh: Optional[Mesh] = None):
+    x = bert_encode(params, tokens, None, attention_mask, config, remat,
+                    mesh)
     with jax.named_scope(stages.LOSS_HEAD):
         return jnp.einsum("bsh,vh->bsv", _mlm_transform(params, x, config),
                           params["wte"])
 
 
-def bert_mlm_loss(params, tokens, labels, config: BertConfig, remat=True):
+def bert_mlm_loss(params, tokens, labels, config: BertConfig, remat=True,
+                  mesh: Optional[Mesh] = None):
     """labels: -100 for unmasked positions (ignored), else target id."""
-    x = bert_encode(params, tokens, config=config, remat=remat)
+    x = bert_encode(params, tokens, config=config, remat=remat, mesh=mesh)
     with jax.named_scope(stages.LOSS_HEAD):
         return lm_head_loss(_mlm_transform(params, x, config),
                             params["wte"], labels, ignore_negative=True)
@@ -189,6 +215,7 @@ def bert_mlm_loss(params, tokens, labels, config: BertConfig, remat=True):
 def build_train_step(config: BertConfig, mesh: Optional[Mesh] = None, *,
                      remat: bool = True, lr: float = 1e-4, **adamw):
     return build_adamw_train_step(
-        functools.partial(bert_mlm_loss, config=config, remat=remat),
+        functools.partial(bert_mlm_loss, config=config, remat=remat,
+                          mesh=mesh),
         functools.partial(init_bert_params, config),
         param_specs(config), wd_mask(config), mesh=mesh, lr=lr, **adamw)

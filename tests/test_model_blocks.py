@@ -204,6 +204,103 @@ def test_gpt_block_projects_q_k_v_apart_from_the_one_qkv_matrix():
     np.testing.assert_allclose(apart, whole, rtol=1e-5, atol=1e-6)
 
 
+def _tiny_bert(seq):
+    """Two layers, two heads of 64 (a pair a grid step), float32."""
+    config = bert.BertConfig(vocab_size=96, hidden_size=128, num_layers=2,
+                             num_heads=2, intermediate_size=256,
+                             max_position_embeddings=seq, dtype="float32")
+    params = bert.init_bert_params(config, 5)
+    params["blocks"]["qkv_b"] = jnp.tile(
+        jnp.linspace(-0.5, 0.5, 384, dtype=jnp.float32), (2, 1))
+    rng = np.random.RandomState(seq)
+    tokens = jnp.asarray(rng.randint(0, 96, (2, seq)), jnp.int32)
+    labels = jnp.where(jnp.asarray(rng.rand(2, seq)) < 0.15, tokens, -100)
+    return config, params, tokens, labels
+
+
+def test_bert_loss_and_gradients_through_the_kernels_equal_the_einsum_path():
+    """256 tokens and no padding mask: with the interpreter's flag bert's
+    layers take the kernel branch (three qkv products, no diagonal), and
+    the masked-LM loss and every parameter's gradient are the einsum
+    path's."""
+    config, params, tokens, labels = _tiny_bert(256)
+    grad = jax.value_and_grad(lambda p: bert.bert_mlm_loss(
+        p, tokens, labels, config))
+    want, want_grads = grad(params)
+    with with_flag("FLAGS_flash_interpret", True):
+        assert "pallas_call" in str(jax.make_jaxpr(grad)(params))
+        got, got_grads = grad(params)
+    np.testing.assert_allclose(got, want, rtol=2e-4)
+    flat, _ = jax.tree_util.tree_flatten_with_path(want_grads)
+    for (path, b), a in zip(flat, jax.tree_util.tree_leaves(got_grads)):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("seq, masked, interpret, kernels", [
+    (256, False, True, True), (256, True, True, False),
+    (256, False, False, False), (200, False, True, False)],
+    ids=["kernels", "padding_mask", "cpu", "ragged"])
+def test_bert_block_takes_the_kernels_only_where_they_serve_it(
+        seq, masked, interpret, kernels):
+    """`bert._block` always asks for the kernels and `blocks.attention`
+    decides: no mask, a sequence they tile, a TPU or the interpreter's
+    flag. With a padding mask, on a CPU or at a ragged length the einsum
+    branch stays. Whichever branch runs, the block's value is that of the
+    one [h, 3h] product on the stored `qkv_w`, split by thirds, through
+    softmax attention written out here."""
+    config, params, _, _ = _tiny_bert(256)
+    blk = jax.tree_util.tree_map(lambda a: a[0], params["blocks"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, 128), jnp.float32)
+    mask = None
+    if masked:
+        keep = jnp.arange(seq)[None, :] < jnp.array([[seq], [seq - 57]])
+        mask = (1.0 - keep[:, None, None, :].astype(jnp.float32)) * -1e30
+
+    def block(x):
+        return bert._block(x, blk, config, mask)[0]
+
+    q, k, v = ((x @ blk["qkv_w"] + blk["qkv_b"]).reshape(2, seq, 3, 2, 64)
+               .transpose(2, 0, 3, 1, 4))                   # [B, H, S, D]
+    logits = q @ k.swapaxes(-1, -2) / 8.0 + (0.0 if mask is None else mask)
+    attn = (jax.nn.softmax(logits, -1) @ v).swapaxes(1, 2).reshape(
+        2, seq, 128)
+    y = blocks.layer_norm(x + attn @ blk["proj_w"] + blk["proj_b"],
+                          blk["ln1_g"], blk["ln1_b"], config.layer_norm_eps)
+    want = blocks.layer_norm(
+        y + blocks.gelu_mlp(y, blk["fc_w"], blk["fc_b"], blk["fo_w"],
+                            blk["fo_b"]),
+        blk["ln2_g"], blk["ln2_b"], config.layer_norm_eps)
+    with with_flag("FLAGS_flash_interpret", interpret):
+        assert ("pallas_call" in str(jax.make_jaxpr(block)(x))) is kernels
+        got = block(x)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_bert_block_on_an_mp_mesh_keeps_the_one_product_and_the_value():
+    """`qkv_w`'s columns are sharded over `mp` as one matrix, so on an
+    `mp` mesh the block makes the one product and splits it by heads (as
+    `gpt._block`), and the kernels run inside `mha_sharded`'s shard_map;
+    on a `dp` mesh the three products stay. The value is the meshless
+    block's either way."""
+    config, params, _, _ = _tiny_bert(256)
+    blk = jax.tree_util.tree_map(lambda a: a[0], params["blocks"])
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 256, 128), jnp.float32)
+    want = bert._block(x, blk, config)[0]
+    devs = np.asarray(jax.devices()[:4]).reshape(2, 2)
+    for mesh, first in ((Mesh(devs, ("dp", "mp")), (2, 256, 384)),
+                        (Mesh(devs[:, 0], ("dp",)), (2, 256, 128))):
+        def block(x):
+            return bert._block(x, blk, config, mesh=mesh)[0]
+        with with_flag("FLAGS_flash_interpret", True):
+            jaxpr = jax.make_jaxpr(block)(x)
+            got = jax.jit(block)(x)
+        assert [e.outvars[0].aval.shape for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "dot_general"][0] == first
+        assert "shard_map" in str(jaxpr) and "pallas_call" in str(jaxpr)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
 @pytest.mark.parametrize("flash, seq, tpu, interpret, want", [
     (True, 1024, True, False, True), (False, 1024, True, False, False),
     (True, 128, True, False, False), (True, 1000, True, False, False),

@@ -43,12 +43,13 @@ def tpu_gates_open(monkeypatch):
     assert not device.pallas_interpret()
 
 
-def _compile(devs, fn, *shapes):
-    """Trace on v5e compile-only device 0, lower for TPU, run Mosaic + XLA."""
+def _compile(devs, fn, *shapes, kernels=True):
+    """Trace on v5e compile-only device 0, lower for TPU, run Mosaic + XLA.
+    `kernels`: whether the lowered program holds a Mosaic call."""
     sh = SingleDeviceSharding(devs[0])
     args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
-    assert "tpu_custom_call" in lowered.as_text()
+    assert ("tpu_custom_call" in lowered.as_text()) is kernels
     return lowered.compile()
 
 
@@ -384,6 +385,57 @@ def test_gpt2_medium_block_reaches_the_kernels_without_a_copy(v5e):
     assert sorted(_mosaic_calls(text, size)) == [(3, 1), (3, 1), (4, 3)]
 
 
+def _arrays_of(text, size):
+    """Shapes in compiled HLO that hold `size` elements."""
+    return {dims for dims in re.findall(r"\w+\[([\d,]+)\]", text)
+            if math.prod(map(int, dims.split(","))) == size}
+
+
+def _bert_large_layer_gradient(v5e, batch, seq, kernels):
+    """Compiled HLO of one checkpointed bert-large layer and its gradient
+    at [batch, seq, 1024] bfloat16, with no padding mask."""
+    from paddle_tpu.models import bert, blocks
+    config = bert.BertConfig(hidden_size=1024, num_layers=1, num_heads=16,
+                             intermediate_size=4096)
+    params = jax.eval_shape(
+        lambda: bert.init_bert_params(config, 0))["blocks"]
+
+    def loss(x, *leaves):
+        stacked = dict(zip(params, leaves))
+        out, _ = blocks.scan_layers(
+            lambda x, blk: bert._block(x, blk, config), x, stacked, True)
+        return _sum32(out)
+
+    return _compile(v5e, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    ((batch, seq, 1024), jnp.bfloat16),
+                    *[(a.shape, a.dtype) for a in params.values()],
+                    kernels=kernels).as_text()
+
+
+def test_bert_large_block_reaches_the_kernels_without_a_copy(v5e):
+    """The bert twin of the gpt2-medium case, at `bertl-mlm-s512`'s batch:
+    the three Mosaic calls (forward, its remat, backward) take q, k and v
+    as the three qkv products wrote them, in pairs of heads and not
+    head-major; no `copy` or `transpose` of q's element count stands under
+    `attn_core`, and no array of the scores' size exists."""
+    from paddle_tpu.models import stages
+    assert fa.head_group(16, 64, 64, 512, 512, jnp.bfloat16) == 2
+    text = _bert_large_layer_gradient(v5e, 32, 512, kernels=True)
+    size = 32 * 512 * 1024
+    moved = [line for line in _of_size(text, size, "copy", "transpose")
+             if stages.ATTN_CORE in line]
+    assert not moved, moved
+    assert sorted(_mosaic_calls(text, size)) == [(3, 1), (3, 1), (4, 3)]
+    assert not _arrays_of(text, 32 * 16 * 512 * 512)
+
+
+def test_bert_large_block_at_128_tokens_holds_no_kernel(v5e):
+    """`bertl-mlm-s128`'s layer: 128 keys are under the kernels' 256, so
+    the einsum path and the one qkv product stay, and no Mosaic call."""
+    text = _bert_large_layer_gradient(v5e, 128, 128, kernels=False)
+    assert _arrays_of(text, 128 * 16 * 128 * 128)
+
+
 def test_train_step_with_flash_lowers_on_pp2_mp2_mesh(v5e):
     """Lowering only: Mosaic kernels cannot be auto-partitioned, so flash in
     a mesh program must sit in a shard_map over every axis GSPMD still owns,
@@ -401,6 +453,31 @@ def test_train_step_with_flash_lowers_on_pp2_mp2_mesh(v5e):
     text = step.trace(state, batch, batch).lower(
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("axes", [{"dp": 2, "mp": 2}, {"dp": 4}],
+                         ids=["dp2mp2", "dp4"])
+def test_bert_train_step_reaches_the_kernels_on_a_mesh(v5e, axes):
+    """`bert.build_train_step(config, mesh)` at 512 tokens with no padding
+    mask: the mesh reaches `bert._block`, so the kernels sit in
+    `mha_sharded`'s shard_map (batch over dp, heads over mp) and the step
+    lowers and compiles for four v5e chips. Without the mesh there the
+    Mosaic call meets GSPMD and the lowering raises."""
+    from paddle_tpu.models import bert
+    config = bert.BertConfig(vocab_size=1024, hidden_size=256, num_layers=2,
+                             num_heads=4, intermediate_size=512)
+    mesh = Mesh(np.asarray(v5e).reshape(tuple(axes.values())), tuple(axes))
+    init_fn, step = bert.build_train_step(config, mesh)
+    state = jax.eval_shape(lambda: init_fn(0))
+    batch = jax.ShapeDtypeStruct((8, 512), jnp.int32)
+    lowered = step.trace(state, batch, batch).lower(
+        lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    text = lowered.compile().as_text()
+    # forward, its remat and the backward in the scanned layer, a chip's
+    # share of the scores nowhere
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 3
+    assert not _arrays_of(text, 8 * 4 * 512 * 512 // 4)
 
 
 # ------------------------------------------------- grouped products (PR 34)
