@@ -222,18 +222,38 @@ def scan_layers(block_fn: Callable, x, stacked, remat: bool):
 def scan_periods(block_fns: Sequence[Callable], x, stacked, remat: bool):
     """`scan_layers` for a model whose layers repeat in a PERIOD of several
     kinds (three window layers to each full one): `block_fns[i](x, layer)
-    -> (x, y)` is the i-th layer of a period, `stacked` the parameters of
-    all the layers on a leading axis, in order, so a period's are
-    consecutive. The scan is over periods and its body is one period, its
-    kinds each compiled once, so compile time is O(1) in depth. `remat`
-    checkpoints each layer of the body (a period's backward then holds one
-    layer's internals and a period's layer inputs, not four layers'
-    internals). -> (x, ys), ys stacked over all the layers."""
+    -> (x, y)` is the i-th layer of a period. The scan is over periods and
+    its body is one period, its kinds each compiled once, so compile time
+    is O(1) in depth. `remat` checkpoints each layer of the body (a
+    period's backward then holds one layer's internals and a period's layer
+    inputs, not four layers' internals).
+
+    `stacked` is the parameters in one of two forms. Where the kinds share
+    one parameter tree (a window layer and a full one): that tree, all the
+    layers on a leading axis, in order, so a period's are consecutive ->
+    (x, ys), ys stacked over all the layers. Where each position of the
+    period has a tree of its own (a linear-attention layer beside a softmax
+    one): a list of them, one a position, each stacked over the PERIODS ->
+    (x, a tuple of each position's ys, stacked over the periods)."""
     size = len(block_fns)
-    if size == 1:
+    own_trees = isinstance(stacked, (list, tuple))
+    if size == 1 and not own_trees:
         return scan_layers(block_fns[0], x, stacked, remat)
     if remat:
         block_fns = [jax.checkpoint(fn) for fn in block_fns]
+    if own_trees:
+        if len(stacked) != size:
+            raise ValueError(f"scan_periods: {size} layers in a period and "
+                             f"{len(stacked)} parameter trees")
+
+        def period(x, layers):
+            ys = []
+            for fn, layer in zip(block_fns, layers):
+                x, y = fn(x, layer)
+                ys.append(y)
+            return x, tuple(ys)
+
+        return jax.lax.scan(period, x, tuple(stacked))
 
     def period(x, layers):
         ys = []

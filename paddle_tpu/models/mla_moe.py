@@ -17,6 +17,22 @@ manifold-constrained hyper-connections, arXiv:2512.24880):
   iterations make doubly stochastic (ops/pallas/stream_mix.py: four
   kernels, each one pass over the streams).
 
+- layers of two kinds in one trunk (`layer_types`): beside the latent
+  softmax layer (FULL) a linear-attention layer (LINEAR, Kimi Delta
+  Attention, arXiv:2510.26692): q, k and v through a short causal depthwise
+  convolution and SiLU, q and k normalised a head, a log-decay per head AND
+  key channel from a low-rank projection, a write strength a head, the gated
+  delta rule in its chunked form (ops/linear_attention.py) and a gated
+  RMSNorm a head before the output projection. The feed-forward half of a
+  layer is the same under both. The trunk is then the leading dense layers,
+  `blocks.scan_periods` over the whole periods of the sparse layers'
+  pattern and a tail of what is left, each position of a period on a
+  parameter tree of its own.
+
+`q_lora_rank` None is a query projection without a latent; `mla_use_nope`
+takes the rotary table away (the key columns every head shares stay,
+unrotated), as a model does whose linear layers carry the positions.
+
 Three mechanisms are optional, and a configuration that leaves one out
 compiles none of it:
 
@@ -58,11 +74,16 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import moe
+from ..ops.linear_attention import chunk_gated_delta_rule
 from ..ops.pallas import stream_mix
 from . import stages
 from .blocks import (attention, lm_head_loss, normal, rms_norm, rope,
-                     scan_layers, swiglu, yarn_inv_freq)
+                     scan_layers, scan_periods, swiglu, yarn_inv_freq)
 from .trainer import build_adamw_train_step
+
+
+FULL, LINEAR = "full", "linear"        # the kinds of attention layer
+QK_NORM_EPS = 1e-6      # under the root of a linear layer's q and k norms
 
 
 @dataclasses.dataclass
@@ -73,7 +94,7 @@ class MlaMoeConfig:
     first_k_dense: int = 2                    # leading dense layers
     num_heads: int = 32                       # published
     heads_held: Optional[int] = None          # on this chip; None = all
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768          # None: no query latent
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -93,6 +114,12 @@ class MlaMoeConfig:
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     rope_scaling: Optional[dict] = None       # yarn's keys, as published
+    mla_use_nope: bool = False                # no rotary table at all
+    layer_types: Optional[Tuple[str, ...]] = None   # FULL | LINEAR a layer;
+    #                                           None = every layer FULL
+    linear_heads: int = 32                    # a LINEAR layer's heads,
+    linear_head_dim: int = 128                # their width (keys, values),
+    linear_conv_size: int = 4                 # the short convolution's taps
     initializer_range: float = 0.02
     router_bias_update_rate: float = 0.0      # gamma; 0 = the bias is held
     mtp_layers: int = 0                       # prediction modules: 0 or 1
@@ -105,6 +132,13 @@ class MlaMoeConfig:
             raise NotImplementedError(
                 "multi-token prediction of depth 1 only: each further "
                 "module feeds on the one before it")
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            if len(self.layer_types) != self.num_layers or set(
+                    self.layer_types) - {FULL, LINEAR}:
+                raise ValueError(
+                    f"layer_types names {FULL!r} or {LINEAR!r} for each of "
+                    f"the {self.num_layers} layers, got {self.layer_types}")
 
     @property
     def heads(self) -> int:
@@ -121,6 +155,28 @@ class MlaMoeConfig:
     @property
     def sparse_layers(self) -> int:
         return self.num_layers - self.first_k_dense
+
+    @property
+    def segments(self):
+        """The trunk in order, as (group of the parameters, kinds of one
+        period, sparse, periods). Without `layer_types`: the dense layers
+        and the sparse ones, each a stack of like layers. With them: the
+        leading dense layers once, the shortest run the sparse layers
+        repeat at least twice (or all of them once) as often as it is
+        whole, and under "tail" what is left, once."""
+        dense, sparse = self.first_k_dense, self.sparse_layers
+        if self.layer_types is None:
+            return [("dense", (FULL,), False, dense),
+                    ("sparse", (FULL,), True, sparse)]
+        kinds = self.layer_types[dense:]
+        size = next((p for p in range(1, sparse // 2 + 1) if all(
+            kinds[i] == kinds[i % p] for i in range(sparse // p * p))),
+            sparse)
+        whole = sparse // size * size
+        found = [("dense", self.layer_types[:dense], False, 1),
+                 ("sparse", kinds[:size], True, sparse // size),
+                 ("tail", kinds[whole:], True, 1)]
+        return [segment for segment in found if segment[1]]
 
 
 # ------------------------------------------------------------------- yarn
@@ -168,7 +224,8 @@ def _init_hc(key, layers: int, c: MlaMoeConfig):
     }
 
 
-def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
+def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool,
+                 kind: str = FULL):
     h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
     out_std = std / math.sqrt(2 * c.num_layers)
     heads, dn, dr, dv = (c.heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
@@ -178,17 +235,23 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
     def norm(shape, scale=std, dtype=dt):
         return normal(next(ks), (layers,) + shape, scale, dtype)
 
-    p = {
-        "ln1_g": jnp.ones((layers, h), dt),
-        "q_a_w": norm((h, c.q_lora_rank)),
-        "q_a_ln": jnp.ones((layers, c.q_lora_rank), dt),
-        "q_b_w": norm((c.q_lora_rank, heads * (dn + dr))),
-        "kv_a_w": norm((h, c.kv_lora_rank + dr)),
-        "kv_a_ln": jnp.ones((layers, c.kv_lora_rank), dt),
-        "kv_b_w": norm((c.kv_lora_rank, heads * (dn + dv))),
-        "o_w": norm((heads * dv, h), out_std),
-        "ln2_g": jnp.ones((layers, h), dt),
-    }
+    p = {"ln1_g": jnp.ones((layers, h), dt)}
+    if kind == LINEAR:
+        p.update(_init_linear(jax.random.fold_in(key, 1), layers, c))
+        width = c.linear_heads * c.linear_head_dim
+    else:
+        if c.q_lora_rank is None:
+            p["q_w"] = norm((h, heads * (dn + dr)))
+        else:
+            p.update(q_a_w=norm((h, c.q_lora_rank)),
+                     q_a_ln=jnp.ones((layers, c.q_lora_rank), dt),
+                     q_b_w=norm((c.q_lora_rank, heads * (dn + dr))))
+        p.update(kv_a_w=norm((h, c.kv_lora_rank + dr)),
+                 kv_a_ln=jnp.ones((layers, c.kv_lora_rank), dt),
+                 kv_b_w=norm((c.kv_lora_rank, heads * (dn + dv))))
+        width = heads * dv
+    p.update(o_w=norm((width, h), out_std),
+             ln2_g=jnp.ones((layers, h), dt))
     if c.hc_mult is not None:
         p.update(hc_attn=_init_hc(next(ks), layers, c),
                  hc_ffn=_init_hc(next(ks), layers, c))
@@ -212,12 +275,50 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool):
     return p
 
 
+def _init_linear(key, layers: int, c: MlaMoeConfig):
+    """A LINEAR layer's own parameters, from keys of their own. The
+    projections at `initializer_range`; the convolutions' taps uniform in
+    +-1/sqrt(taps) (a depthwise Conv1d's default); `decay_log` the log of
+    uniform(1, 16) a head and `dt_bias` the inverse softplus of a step
+    log-uniform in [1e-3, 1e-1] a channel, as the delta-rule family seeds
+    its gate, both float32 like the router."""
+    h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
+    heads, d, taps = c.linear_heads, c.linear_head_dim, c.linear_conv_size
+    ks = iter(jax.random.split(key, 16))
+
+    def norm(shape):
+        return normal(next(ks), (layers,) + shape, std, dt)
+
+    def taps_w():
+        return jax.random.uniform(
+            next(ks), (layers, taps, heads * d), jnp.float32,
+            -taps ** -0.5, taps ** -0.5).astype(dt)
+
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (layers, heads * d), jnp.float32, math.log(1e-3),
+        math.log(1e-1)))
+    return {
+        "q_w": norm((h, heads * d)), "k_w": norm((h, heads * d)),
+        "v_w": norm((h, heads * d)),
+        "q_conv_w": taps_w(), "k_conv_w": taps_w(), "v_conv_w": taps_w(),
+        "decay_a_w": norm((h, d)), "decay_b_w": norm((d, heads * d)),
+        "decay_log": jnp.log(jax.random.uniform(
+            next(ks), (layers, heads), jnp.float32, 1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "beta_w": norm((h, heads)),
+        "gate_a_w": norm((h, d)), "gate_b_w": norm((d, heads * d)),
+        "o_ln": jnp.ones((layers, d), dt)}
+
+
 def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
     """Parameters as a pytree: the leading dense layers stacked under
     "dense", the sparse layers under "sparse" (the scan layouts), an
     untied embedding and head; with a prediction module, under "mtp" its
     two norms, the projection of [hidden ; embedding], its one sparse
-    layer (stacked, a stack of one) and its final norm."""
+    layer (stacked, a stack of one) and its final norm. With `layer_types`
+    a group ("dense", "sparse", "tail": `MlaMoeConfig.segments`) is a LIST
+    of trees, one for each position of its period, each stacked over the
+    periods."""
     c = config
     h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
     root = jax.random.PRNGKey(seed)
@@ -225,11 +326,17 @@ def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
 
     params = {
         "wte": normal(k[0], (c.vocab_size, h), std, dt),
-        "dense": _init_layers(k[1], c.first_k_dense, c, sparse=False),
-        "sparse": _init_layers(k[2], c.sparse_layers, c, sparse=True),
         "lnf_g": jnp.ones((h,), dt),
         "lm_head": normal(k[3], (c.vocab_size, h), std, dt),
     }
+    keys = {"dense": k[1], "sparse": k[2], "tail": jax.random.fold_in(root, 5)}
+    for group, kinds, sparse, periods in c.segments:
+        if c.layer_types is None:
+            params[group] = _init_layers(keys[group], periods, c, sparse)
+        else:
+            params[group] = [
+                _init_layers(jax.random.fold_in(keys[group], i), periods, c,
+                             sparse, kind) for i, kind in enumerate(kinds)]
     if c.mtp_layers:
         # keys of its own, so that the trunk's do not move with the module
         k = jax.random.split(jax.random.fold_in(root, 4), 2)
@@ -243,7 +350,8 @@ def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
 
 def wd_mask(params) -> Dict:
     """Weight decay on the matrices (`*_w`, `phi`) and the embeddings; none
-    on norm gains, the router's bias, or the mixing's scalars and biases."""
+    on norm gains, the router's bias, the mixing's scalars and biases, or
+    a linear layer's `decay_log` and `dt_bias`."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: path[-1].key in ("wte", "lm_head", "phi")
         or path[-1].key.endswith("_w"), params)
@@ -257,11 +365,18 @@ def count_params(config: MlaMoeConfig) -> Dict[str, int]:
         return sum(math.prod(a.shape)
                    for a in jax.tree_util.tree_leaves(tree))
 
+    def experts(tree):
+        return sum(math.prod(a.shape) for path, a in
+                   jax.tree_util.tree_leaves_with_path(tree)
+                   if any(getattr(k, "key", None) == "experts"
+                          for k in path))
+
+    sparse = [shapes[g] for g in ("sparse", "tail") if g in shapes]
     out = {"embedding_and_head": size(shapes["wte"])
            + size(shapes["lm_head"]),
-           "dense_layers": size(shapes["dense"]),
-           "sparse_layers": size(shapes["sparse"]),
-           "routed_experts": size(shapes["sparse"]["experts"])}
+           "dense_layers": size(shapes.get("dense", ())),
+           "sparse_layers": size(sparse),
+           "routed_experts": experts(sparse)}
     if "mtp" in shapes:
         out["mtp_module"] = size(shapes["mtp"])
     out["total"] = size(shapes)
@@ -300,9 +415,12 @@ def _attention(y, blk, c: MlaMoeConfig):
     eps = c.rms_norm_eps
     with jax.named_scope(stages.ATTN_QKV):
         y = rms_norm(y, blk["ln1_g"], eps)
-        c_q = rms_norm(jnp.einsum("bsh,hr->bsr", y, blk["q_a_w"]),
-                       blk["q_a_ln"], eps)
-        q = jnp.einsum("bsr,rk->bsk", c_q, blk["q_b_w"])
+        if c.q_lora_rank is None:
+            q = jnp.einsum("bsh,hk->bsk", y, blk["q_w"])
+        else:
+            c_q = rms_norm(jnp.einsum("bsh,hr->bsr", y, blk["q_a_w"]),
+                           blk["q_a_ln"], eps)
+            q = jnp.einsum("bsr,rk->bsk", c_q, blk["q_b_w"])
         kv_a = jnp.einsum("bsh,hr->bsr", y, blk["kv_a_w"])
         c_kv = rms_norm(kv_a[..., :c.kv_lora_rank], blk["kv_a_ln"], eps)
         k_rope = kv_a[..., c.kv_lora_rank:]
@@ -314,11 +432,14 @@ def _attention(y, blk, c: MlaMoeConfig):
         v = jnp.einsum("bsr,rk->bsk", c_kv,
                        kv_w[..., dn:].reshape(-1, heads * dv))
     with jax.named_scope(stages.ATTN_CORE):
-        inv_freq = yarn_inv_freq(dr, c.rope_theta, c.rope_scaling)
         q = q.reshape(b, s, heads, dn + dr)
-        q = jnp.concatenate(
-            [q[..., :dn], rope(q[..., dn:], c.rope_theta, inv_freq)], -1)
-        k_rope = rope(k_rope[:, :, None, :], c.rope_theta, inv_freq)
+        if not c.mla_use_nope:
+            inv_freq = yarn_inv_freq(dr, c.rope_theta, c.rope_scaling)
+            q = jnp.concatenate(
+                [q[..., :dn], rope(q[..., dn:], c.rope_theta, inv_freq)], -1)
+            k_rope = rope(k_rope[:, :, None, :], c.rope_theta, inv_freq)
+        else:
+            k_rope = k_rope[:, :, None, :]
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (b, s, heads, dr))], -1)
         attn = attention(q, k, v.reshape(b, s, heads, dv), causal=True,
@@ -326,6 +447,97 @@ def _attention(y, blk, c: MlaMoeConfig):
                          flash=c.use_flash_attention)
     with jax.named_scope(stages.ATTN_OUT):
         return jnp.einsum("bsk,kh->bsh", attn, blk["o_w"]), None
+
+
+@functools.partial(jax.checkpoint, static_argnums=(2, 3))
+def _conv_heads(x, taps, heads: int, norm):
+    """x [B, S, H d] through the causal depthwise convolution with taps
+    [K, H d] and SiLU, silu(sum_i taps[i] * x[t - (K - 1) + i]) with zero
+    before the row's start, then split into heads [B, S, H, d]; with
+    `norm`, each head over its L2 norm, times `norm`. Float32 inside, and
+    checkpointed: the backward pass keeps x alone."""
+    size, (b, seq, _) = taps.shape[0], x.shape
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (size - 1, 0), (0, 0)))
+    taps = taps.astype(jnp.float32)
+    out = jax.nn.silu(sum(taps[i] * padded[:, i:i + seq]
+                          for i in range(size))).reshape(b, seq, heads, -1)
+    if norm:
+        out = out * (norm * jax.lax.rsqrt(
+            (out * out).sum(-1, keepdims=True) + QK_NORM_EPS))
+    return out.astype(x.dtype)
+
+
+@jax.checkpoint
+def _log_decay(a, decay_log, dt_bias):
+    """a [B, S, H d] -> the log-decay a head and key channel, float32
+    [B, S, H, d]: -exp(A_h) * softplus(a + b_dt) < 0."""
+    heads = decay_log.shape[0]
+    rate = jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+    return -jnp.exp(decay_log)[:, None] * rate.reshape(
+        a.shape[:2] + (heads, -1))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def _gated_norm(out, gain, gate, eps: float):
+    """out [B, S, H, d], gate [B, S, H d] -> RMSNorm_d(out; gain) *
+    sigmoid(gate), [B, S, H d]."""
+    b, s, heads, d = out.shape
+    gate = jax.nn.sigmoid(gate.astype(jnp.float32)).reshape(out.shape)
+    return (rms_norm(out, gain, eps) * gate.astype(out.dtype)).reshape(
+        b, s, heads * d)
+
+
+def _rule_operands(y, blk, c: MlaMoeConfig):
+    """A linear-attention layer on y [B, S, h] (its norm is here) up to the
+    rule: ((q, k, v, the log-decays, the write strengths) as the rule takes
+    them, the output gate's projection [B, S, H d]). Six projections under
+    ATTN_QKV; the convolutions, norms and gates' activations under
+    LINEAR_ATTN, in pieces that the backward pass computes again from the
+    projections' outputs."""
+    heads, d = c.linear_heads, c.linear_head_dim
+    with jax.named_scope(stages.ATTN_QKV):
+        y = rms_norm(y, blk["ln1_g"], c.rms_norm_eps)
+        q, k, v = (jnp.einsum("bsh,hk->bsk", y, blk[name])
+                   for name in ("q_w", "k_w", "v_w"))
+        decay, gate = (
+            jnp.einsum("bsr,rk->bsk",
+                       jnp.einsum("bsh,hr->bsr", y, blk[f"{name}_a_w"]),
+                       blk[f"{name}_b_w"]) for name in ("decay", "gate"))
+        beta = jnp.einsum("bsh,hn->bsn", y, blk["beta_w"])
+    with jax.named_scope(stages.LINEAR_ATTN):
+        return (_conv_heads(q, blk["q_conv_w"], heads, d ** -0.5),
+                _conv_heads(k, blk["k_conv_w"], heads, 1.0),
+                _conv_heads(v, blk["v_conv_w"], heads, None),
+                _log_decay(decay, blk["decay_log"], blk["dt_bias"]),
+                jax.nn.sigmoid(beta.astype(jnp.float32))), gate
+
+
+def _linear_attention(y, blk, c: MlaMoeConfig):
+    """The linear-attention layer on y [B, S, h] -> its output projection,
+    [B, S, h]: `_rule_operands`, the chunked rule and the gated head norm
+    under LINEAR_ATTN, the output's projection under ATTN_OUT."""
+    operands, gate = _rule_operands(y, blk, c)
+    with jax.named_scope(stages.LINEAR_ATTN):
+        out = chunk_gated_delta_rule(*operands)
+        out = _gated_norm(out, blk["o_ln"], gate, c.rms_norm_eps)
+    with jax.named_scope(stages.ATTN_OUT):
+        return jnp.einsum("bsk,kh->bsh", out, blk["o_w"]), None
+
+
+def first_rule(params, x, config: MlaMoeConfig):
+    """The chunked rule as the model's FIRST layer runs it on that layer's
+    input x [B, S, h] (the embedding's rows), which must be a
+    linear-attention layer: the rule's output [B, S, H, d]. For a check of
+    the rule alone, forward and backward, against the recurrence it stands
+    for. Jit it."""
+    c = config
+    if c.layer_types is None or c.layer_types[0] != LINEAR:
+        raise ValueError("the model's first layer is no linear-attention "
+                         "layer")
+    group = next(g for g in ("dense", "sparse", "tail") if params.get(g))
+    blk = jax.tree_util.tree_map(lambda a: a[0], params[group][0])
+    operands, _ = _rule_operands(x.astype(jnp.dtype(c.dtype)), blk, c)
+    return chunk_gated_delta_rule(*operands)
 
 
 def _dense_ffn(y, blk, c: MlaMoeConfig):
@@ -354,23 +566,26 @@ def _sparse_ffn(y, blk, c: MlaMoeConfig):
         return shared + routed.reshape(b, s, h), ids
 
 
-def _block(x, blk, c: MlaMoeConfig, sparse: bool, want_ids: bool):
+def _block(x, blk, c: MlaMoeConfig, sparse: bool, want_ids: bool,
+           kind: str = FULL):
     """One layer -> (x', router choices where `want_ids` and the layer is
-    sparse, else None): an attention sub-layer and a feed-forward one. On
-    the streams x [n, B, S, h] each has its own stream mixing; without
-    streams x is [B, S, h] and each is the plain pre-norm residual."""
+    sparse, else None): an attention sub-layer of `kind` and a feed-forward
+    one. On the streams x [n, B, S, h] each has its own stream mixing;
+    without streams x is [B, S, h] and each is the plain pre-norm
+    residual."""
     ffn = functools.partial(_sparse_ffn if sparse else _dense_ffn, blk=blk,
                             c=c)
+    attend = functools.partial(
+        _linear_attention if kind == LINEAR else _attention, blk=blk, c=c)
     if c.hc_mult is None:
-        y, _ = _attention(x, blk, c)
+        y, _ = attend(x)
         with jax.named_scope(stages.ATTN_OUT):
             x = x + y
         y, ids = ffn(x)
         with jax.named_scope(stages.MLP):
             x = x + y
     else:
-        x, _ = _sublayer(x, blk["hc_attn"],
-                         functools.partial(_attention, blk=blk, c=c), c)
+        x, _ = _sublayer(x, blk["hc_attn"], attend, c)
         x, ids = _sublayer(x, blk["hc_ffn"], ffn, c)
     return x, ids if want_ids else None
 
@@ -390,18 +605,36 @@ def _gather(x, c: MlaMoeConfig):
 def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
     """tokens [B, S] -> (the last layer's output [B, S, h], before the final
     norm; choices [L_sparse, T, k] or None): the embedding (copied to the
-    streams where there are any), the dense layers, the sparse layers (the
-    streams summed)."""
+    streams where there are any), the trunk's segments in order (the dense
+    layers, the sparse layers' periods, their tail; the streams summed)."""
     with jax.named_scope(stages.EMBED):
         x = _spread(params["wte"][tokens].astype(jnp.dtype(c.dtype)), c)
-    x, _ = scan_layers(
-        functools.partial(_block, c=c, sparse=False, want_ids=False), x,
-        params["dense"], remat)
-    x, ids = scan_layers(
-        functools.partial(_block, c=c, sparse=True, want_ids=want_ids), x,
-        params["sparse"], remat)
+    ids = []
+    for group, kinds, sparse, _ in c.segments:
+        x, ys = scan_periods(
+            [functools.partial(_block, c=c, sparse=sparse,
+                               want_ids=want_ids and sparse, kind=kind)
+             for kind in kinds], x, params[group], remat)
+        if sparse:
+            ids.append(_in_layer_order(ys))
     with jax.named_scope(stages.LOSS_HEAD):
-        return _gather(x, c), ids
+        if not want_ids:
+            return _gather(x, c), None
+        return _gather(x, c), \
+            ids[0] if len(ids) == 1 else jnp.concatenate(ids)
+
+
+def _in_layer_order(found):
+    """What a group's layers gave or hold, [layers, ...] in layer order:
+    a stack of like layers as it is; of a list with one entry a position
+    of the period, each [periods, ...], the periods' positions
+    interleaved."""
+    if not isinstance(found, (list, tuple)):
+        return found
+    if found[0] is None:
+        return None
+    stacked = jnp.stack(found, 1)
+    return stacked.reshape((-1,) + stacked.shape[2:])
 
 
 def _mtp_loss(params, hidden, labels, c: MlaMoeConfig, remat: bool,
@@ -492,9 +725,17 @@ def step_facts(params, tokens, labels, config: MlaMoeConfig):
     return facts
 
 
+def _router_groups(params):
+    """The groups of the trunk that hold routers, in layer order."""
+    return [group for group in ("sparse", "tail") if group in params]
+
+
 def _router_biases(params):
     """[L_sparse (+ 1), n_routed_experts], in the order of the choices."""
-    found = [params["sparse"]["router_b"]]
+    found = [_in_layer_order(
+        layers["router_b"] if isinstance(layers, dict)
+        else [layer["router_b"] for layer in layers])
+        for layers in map(params.get, _router_groups(params))]
     if "mtp" in params:
         found.append(params["mtp"]["layer"]["router_b"])
     return jnp.concatenate(found)
@@ -511,11 +752,22 @@ def _move_router_biases(master, ids, config: MlaMoeConfig):
     biases = _router_biases(master)
     biases = biases + config.router_bias_update_rate * jnp.sign(
         drawn.mean(-1, keepdims=True) - drawn)
-    layers = config.sparse_layers
-    master = dict(master, sparse=dict(master["sparse"],
-                                      router_b=biases[:layers]))
+    at = 0
+    for group in _router_groups(master):
+        layers = master[group]
+        if isinstance(layers, dict):
+            count = layers["router_b"].shape[0]
+            moved = dict(layers, router_b=biases[at:at + count])
+        else:       # a list, a position of the period each: interleaved
+            periods, count = layers[0]["router_b"].shape[0], len(layers)
+            mine = biases[at:at + periods * count].reshape(
+                periods, count, -1)
+            moved = [dict(layer, router_b=mine[:, i])
+                     for i, layer in enumerate(layers)]
+            count *= periods
+        master, at = dict(master, **{group: moved}), at + count
     if "mtp" in master:
-        layer = dict(master["mtp"]["layer"], router_b=biases[layers:])
+        layer = dict(master["mtp"]["layer"], router_b=biases[at:])
         master["mtp"] = dict(master["mtp"], layer=layer)
     return master
 

@@ -9,7 +9,8 @@ benchmark's readers (`benchmarks/layer_metrics/_stages.py`) import them.
 Flat, never nested, with one exception (MTP, below). A dense block is
 ATTN_QKV + ATTN_CORE + ATTN_OUT + MLP and nothing else; a sparse family
 (models/mla_moe.py) adds ROUTER and EXPERTS beside MLP, which is then its
-dense MLP and its shared expert, and RESIDUAL_MIX around every sub-layer.
+dense MLP and its shared expert, and RESIDUAL_MIX around every sub-layer;
+a linear-attention layer has LINEAR_ATTN where a softmax layer has ATTN_CORE.
 The same name opened twice is one stage. MTP is the exception: one scope
 around the whole multi-token-prediction module, opened OUTSIDE the stages
 its layer and its head pass open themselves, so a path reads
@@ -54,6 +55,14 @@ MTP = "mtp"                 # the multi-token-prediction module, whole: its
 #                             stages inside this one), its final norm, its
 #                             pass through the shared head and its loss
 
+LINEAR_ATTN = "linear_attn"     # a linear-attention layer between its
+#                             projections and its output projection: the
+#                             short convolutions, SiLU, the head norms of q
+#                             and k, softplus and the log-decays, the
+#                             chunked delta rule, the gated head norm. A
+#                             softmax layer of the same model keeps
+#                             ATTN_CORE, so a trace tells the two apart
+
 BLOCK = (ATTN_QKV, ATTN_CORE, ATTN_OUT, MLP)
 ALL = (EMBED,) + BLOCK + (LOSS_HEAD, OPTIMIZER) \
-    + (ROUTER, EXPERTS, RESIDUAL_MIX) + (MTP,)
+    + (ROUTER, EXPERTS, RESIDUAL_MIX) + (MTP,) + (LINEAR_ATTN,)

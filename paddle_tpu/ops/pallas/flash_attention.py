@@ -60,7 +60,9 @@ with the backward: 7,168 at head_dim 64, 6,144 at 128, 3,584 at 192 / 128,
 2,560 at 256 / 256; README "Flash attention sequence limit") are the
 head-major entry's; a group's blocks are g heads wide, so the seq-major
 kernels end earlier (5,632 at 64, 2,048 at 192 / 128) and the head-major
-ones take over. Several query heads on a K/V head add the float32 dK, dV
+ones take over: 32 heads of 192 / 128 at 2,048 tokens, the latent layer of
+the hybrid linear-attention cell (PR 37), run the seq-major pairs exactly
+at their cap. Several query heads on a K/V head add the float32 dK, dV
 sums to the backward (4,096 at 128 with 256-key blocks, whatever their
 number); a window changes no cap, since K/V stay resident all the same.
 """

@@ -641,8 +641,9 @@ def test_the_scopes_change_no_computation(monkeypatch):
 def test_the_new_stages_are_appended_and_the_dense_block_keeps_its_four():
     assert stages.ALL[:7] == ("embed", "attn_qkv", "attn_core", "attn_out",
                               "mlp", "loss_head", "optimizer")
-    assert stages.ALL[7:] == (stages.ROUTER, stages.EXPERTS,
-                              stages.RESIDUAL_MIX, stages.MTP)
+    assert stages.ALL[7:11] == (stages.ROUTER, stages.EXPERTS,
+                                stages.RESIDUAL_MIX, stages.MTP)
+    assert stages.ALL[11:] == (stages.LINEAR_ATTN,)
     assert len(stages.BLOCK) == 4 and not set(stages.BLOCK) & set(
         stages.ALL[7:])
 

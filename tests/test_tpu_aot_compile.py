@@ -563,3 +563,35 @@ def test_a_second_routed_layer_lowers_no_further_mosaic_body(v5e):
     one = bodies(1)
     assert 5 <= one <= 9          # five are distinct; twelve sites a layer
     assert bodies(2) == one
+
+
+def test_chunked_delta_rule_compiles_at_the_kimi_cells_shape(v5e):
+    """`ops/linear_attention.chunk_gated_delta_rule` and its gradient at the
+    hybrid cell's widths (32 heads of 128, 2,048 tokens, bfloat16 q, k, v,
+    float32 log-decays), two rows: XLA's own products, no Mosaic call;
+    rows are taken one at a time (`GROUP_TOKENS`), the scan over chunks
+    carries a float32 [1, 32, 128, 128] state, the pairwise [.., 16, 16,
+    128] tensor of a sub-block's decays is never an array of its own, and
+    no integer division reaches the device (the masks are constants)."""
+    la = importlib.import_module("paddle_tpu.ops.linear_attention")
+    wide, heads = (2, 2048, 32, 128), (2, 2048, 32)
+
+    def loss(q, k, v, g, beta):
+        return _sum32(la.chunk_gated_delta_rule(q, k, v, g, beta))
+
+    compiled = _compile(
+        v5e, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        *[(wide, jnp.bfloat16)] * 3, (wide, jnp.float32),
+        (heads, jnp.float32), kernels=False)
+    text = compiled.as_text()
+    assert "f32[1,32,128,128]" in text
+    # what a fusion keeps in registers is no array: look outside the fused
+    # computations, at what instructions write
+    written = "\n".join(
+        block for block in text.split("\n\n")
+        if not block.lstrip().startswith("%fused_computation"))
+    assert _arrays_of(written, 2048 * 32 * 128)
+    assert not _arrays_of(written, 32 * 32 * 4 * 16 * 16 * 128)
+    assert not re.findall(r" (?:divide|remainder)\([^)]*\), .*s32\[", text)
+    assert "/rem\"" not in text and "floor_divide" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -153,6 +153,106 @@ def test_scan_periods_is_the_loop_over_the_layers():
         one_x, blocks.scan_layers(fns[0], x0, stacked, True)[0])
 
 
+def _scan_periods_before(block_fns, x, stacked, remat):
+    """`blocks.scan_periods` as it stood before a period's positions could
+    carry parameter trees of their own (PR 37), word for word."""
+    size = len(block_fns)
+    if size == 1:
+        return blocks.scan_layers(block_fns[0], x, stacked, remat)
+    if remat:
+        block_fns = [jax.checkpoint(fn) for fn in block_fns]
+
+    def period(x, layers):
+        ys = []
+        for i, fn in enumerate(block_fns):
+            x, y = fn(x, jax.tree_util.tree_map(lambda a: a[i], layers))
+            ys.append(y)
+        return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
+
+    x, ys = jax.lax.scan(period, x, jax.tree_util.tree_map(
+        lambda a: a.reshape((-1, size) + a.shape[1:]), stacked))
+    return x, jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_period_of_like_layers_gives_what_it_gave(tiny, remat):
+    """The family that shares `scan_periods` with the layers of two kinds:
+    on its tiny configuration (three window layers to a full one, one
+    stacked tree) the values, the routers' records and every gradient are
+    the former function's, bit for bit, and so is the lowered text."""
+    _, c, master, _, tiled = tiny
+    x0 = master["wte"][jnp.asarray(tiled[0])]
+    fns = [functools.partial(llama._block, config=c, kind=kind)
+           for kind in c.period]
+
+    def run(scan, blocks_, x):
+        x, records = scan(fns, x, blocks_, remat)
+        return (x * x).mean() + records["balance"].sum(), (x, records)
+
+    got, want = (jax.value_and_grad(functools.partial(run, scan),
+                                    argnums=(0, 1), has_aux=True)
+                 for scan in (blocks.scan_periods, _scan_periods_before))
+    assert jax.jit(got).lower(master["blocks"], x0).as_text() \
+        == jax.jit(want).lower(master["blocks"], x0).as_text().replace(
+            "_scan_periods_before", "scan_periods")
+    (g_loss, g_aux), g_grads = got(master["blocks"], x0)
+    (w_loss, w_aux), w_grads = want(master["blocks"], x0)
+    for a, b in zip(jax.tree_util.tree_leaves((g_loss, g_aux, g_grads)),
+                    jax.tree_util.tree_leaves((w_loss, w_aux, w_grads))):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_period_whose_positions_have_trees_of_their_own():
+    """Two periods of (a, b) where a and b take DIFFERENT parameters: a
+    list with one tree a position, each stacked over the periods, gives
+    what the plain loop over the four layers gives, values and gradients,
+    with each position's outputs apart; and a list that is not one tree a
+    position is refused."""
+    def a(x, layer):
+        return x * layer["w"], x.sum()
+
+    def b(x, layer):
+        return x + layer["u"] @ layer["v"], {"norm": (x * x).sum()}
+
+    trees = [{"w": jnp.asarray([[1.5, 2.0, 0.5], [0.25, 1.0, 3.0]])},
+             {"u": jnp.arange(12.0).reshape(2, 3, 2) / 7,
+              "v": jnp.asarray([[1.0, -1.0], [0.5, 2.0]])}]
+    x0 = jnp.asarray([1.0, 2.0, 3.0])
+
+    def loop(trees, x):
+        ys = ([], [])
+        for n in range(2):
+            for i, fn in enumerate((a, b)):
+                x, y = fn(x, jax.tree_util.tree_map(lambda t: t[n],
+                                                    trees[i]))
+                ys[i].append(y)
+        return (x * x).sum(), (x, ys)
+
+    (_, (want_x, want_ys)), want_g = jax.value_and_grad(
+        loop, has_aux=True)(trees, x0)
+    for remat in (False, True):
+        def scanned(trees, x):
+            x, ys = blocks.scan_periods([a, b], x, trees, remat)
+            return (x * x).sum(), (x, ys)
+        (_, (x, ys)), grads = jax.value_and_grad(scanned, has_aux=True)(
+            trees, x0)
+        np.testing.assert_allclose(x, want_x, rtol=1e-6)
+        np.testing.assert_allclose(ys[0], jnp.stack(want_ys[0]), rtol=1e-6)
+        np.testing.assert_allclose(
+            ys[1]["norm"], jnp.stack([y["norm"] for y in want_ys[1]]),
+            rtol=1e-6)
+        for g, w in zip(jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(want_g)):
+            np.testing.assert_allclose(g, w, rtol=1e-6)
+    with pytest.raises(ValueError, match="2 layers in a period and 1"):
+        blocks.scan_periods([a, b], x0, trees[:1], False)
+    # one position with a tree of its own is still a list
+    x, (ys,) = blocks.scan_periods([a], x0, trees[:1], True)
+    np.testing.assert_allclose(x, x0 * trees[0]["w"][0] * trees[0]["w"][1])
+    assert ys.shape == (2,)
+
+
 def test_the_period_is_the_shortest_run_the_stack_repeats():
     full, window = llama.FULL, llama.WINDOW
     def cfg(kinds):
