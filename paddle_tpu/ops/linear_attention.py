@@ -43,16 +43,38 @@ _inverse`, on [sub, sub, batch] arrays so that the batch and not a width of
 [-Q^-1 R P^-1, Q^-1]] above it, which on the whole chunk is T <- T - T R T
 with R the level's blocks of A.
 
-Precision: the log-decays, their sums, every exp, T and the state are
-float32; the products' operands are rounded to q's dtype (bfloat16 in the
-trainer) and accumulate in float32. The backward pass is JAX's transpose of
-this function, which keeps the inputs and computes the rest again: rows of
-the batch are taken in groups of at most `GROUP_TOKENS` tokens, one after
-another, each under `jax.checkpoint`, so that what a group's backward pass
-holds (`_chunk_terms`' float32 intermediates, the size of several q, and a
-state a trip of the scan) does not grow with the batch. One row of 2,048
-tokens a group was the fastest of 1, 2, 4 and 8 on a v5e and the only one
-that left 8 rows of the Kimi cell under 15 GB (PERF.md section 6, PR 37).
+Two ways to compute the chunk terms, one algorithm, chosen by the operands'
+shapes alone (`ops/pallas/delta_rule.taken`): head widths that are
+multiples of 128 lanes at the chunk of `CHUNK` = 4 `SUB` take the Mosaic
+kernels of `ops/pallas/delta_rule.py`, which read q, k, v and g where the
+layer wrote them, `[B, S, H d]`, hold everything above in VMEM and write the
+six terms once, the chunk axis leading; any other shape (narrow heads,
+chunks of 8 to 32) takes `_chunk_terms`, XLA's own passes, which is also
+what the kernels are tested against. The scan over chunks is the same
+`lax.scan` behind both.
+
+Precision, in both: the log-decays, their sums, every exp, T and the state
+are float32; the products' operands are rounded to q's dtype (bfloat16 in
+the trainer) and accumulate in float32.
+
+The backward pass keeps a group's five inputs and computes the rest again:
+rows of the batch are taken in groups of at most `GROUP_TOKENS` tokens, one
+after another, each under `jax.checkpoint`. Behind the kernels a group's
+backward pass runs the forward kernel and the scan again (the scan's
+transpose, JAX's, reads the six terms and a state a trip), then the
+backward kernel, which forms G, the pairwise exponentials, T, W and U once
+more in VMEM from the five inputs and writes the five gradients once: no
+float32 intermediate of the chunk terms is an array in either direction.
+Behind the XLA form the backward pass is JAX's transpose of `_chunk_terms`,
+whose float32 intermediates, the size of several q, are what the groups
+bound there. One row of 2,048 tokens a group is still the choice behind the
+kernels: what a group's backward pass holds is now the six terms and the
+states of the scan's transpose, and on a v5e the rule at 8 rows of the Kimi
+cell's widths ran 17.5 / 47.0 ms forward / gradient with one row a group
+and 18.0 / 48.9 with two, whose step compiles to the same 13.96 GB; four
+rows a group compile to 15.34 GB and eight to 17.14 (PERF.md section 6,
+PR 38; with the XLA form one row was the fastest of 1, 2, 4 and 8 and the
+only one under 15 GB, PR 37).
 """
 from __future__ import annotations
 
@@ -62,9 +84,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .pallas import delta_rule
+
 CHUNK = 64      # tokens a trip of the scan over chunks: the family's
 SUB = 16        # the pairwise sub-block; a chunk is SUB times a power of two
 GROUP_TOKENS = 2048     # rows of the batch taken together: this many tokens
+                        # (measured, the docstring's last paragraph)
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -172,21 +197,30 @@ def _chunk_terms(q, k, v, g, beta, sub: int):
             jnp.exp(last[..., 0, :]), B)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(5, 6))
-def _rule(q, k, v, g, beta, chunk: int, sub: int):
-    """`chunk_gated_delta_rule` on a group of rows. Checkpointed: the
-    backward pass keeps a group's inputs and computes the rest again, one
-    group at a time."""
+@functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
+def _rule(q, k, v, g, beta, chunk: int, sub: int, kernels: bool):
+    """`chunk_gated_delta_rule` on a group of rows, the chunk terms by the
+    Mosaic kernels where `kernels`, else by `_chunk_terms`. Checkpointed:
+    the backward pass keeps a group's inputs and computes the rest again,
+    one group at a time. (Behind the kernels that is one more forward
+    kernel: the scan's transpose reads the six terms and a state a trip,
+    140 MB a row of 2,048 tokens at 32 heads of 128, and eight rows of
+    them kept across a layer's backward pass compiled the Kimi cell's step
+    to 16.87 GB against 13.96, sandbox compile, PR 38.)"""
     b, s, h, dk = q.shape
     dv, dtype, f32 = v.shape[-1], q.dtype, jnp.float32
+    g, beta = g.astype(f32), beta.astype(f32)
 
     def chunks(x):          # [B, S, H, ...] -> [B, H, N, C, ...]
         x = x.reshape((b, s // chunk, chunk) + x.shape[2:])
         return jnp.moveaxis(x, 3, 1)
 
-    terms = _chunk_terms(chunks(q), chunks(k), chunks(v),
-                         chunks(g.astype(f32)), chunks(beta.astype(f32)),
-                         sub)
+    if kernels:     # q, k, v, g as [B, S, H d]; the chunk axis comes leading
+        terms = delta_rule.chunk_terms(
+            *(x.reshape(b, s, -1) for x in (q, k, v, g)), beta, h)
+    else:
+        terms = tuple(jnp.moveaxis(x, 2, 0) for x in _chunk_terms(
+            chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta), sub))
 
     def step(state, xs):
         W, U, q_in, k_out, keep, B = xs
@@ -201,8 +235,7 @@ def _rule(q, k, v, g, beta, chunk: int, sub: int):
             "bhic,bhiv->bhcv", k_out, u, preferred_element_type=f32)
         return state, o.astype(dtype)
 
-    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
-                        tuple(jnp.moveaxis(x, 2, 0) for x in terms))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32), terms)
     # [N, B, H, C, d_v] -> [B, S, H, d_v]
     return jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, s, h, dv)
 
@@ -225,10 +258,11 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
             f"two, got {chunk}")
     rows = max(n for n in range(1, b + 1)
                if b % n == 0 and (n == 1 or n * s <= GROUP_TOKENS))
+    kernels = delta_rule.taken(q.shape[-1], v.shape[-1], chunk, sub, q.dtype)
     if rows == b:
-        return _rule(q, k, v, g, beta, chunk, sub)
+        return _rule(q, k, v, g, beta, chunk, sub, kernels)
     groups = jax.lax.map(
-        lambda x: _rule(*x, chunk, sub),
+        lambda x: _rule(*x, chunk, sub, kernels),
         tuple(x.reshape((b // rows, rows) + x.shape[1:])
               for x in (q, k, v, g, beta)))
     return groups.reshape((b,) + groups.shape[2:])

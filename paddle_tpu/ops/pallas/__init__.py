@@ -12,7 +12,9 @@ pallas_interpret`), which keeps them testable on the CPU mesh, and
 tests/test_tpu_aot_compile.py runs the real TPU compiler on them in tier-1.
 `stream_mix` (the mixing of several residual streams, models/mla_moe.py) is
 imported as a module: `read_in`, `write_back`; so is `grouped_matmul` (the
-grouped products of the dropless experts path, ops/moe.py): `grouped_dot`.
+grouped products of the dropless experts path, ops/moe.py): `grouped_dot`;
+and `delta_rule` (the chunk terms of the gated delta rule, forward and
+backward, ops/linear_attention.py): `chunk_terms`, `taken`.
 """
 from .flash_attention import flash_attention, mha_forward, mha_seq_major
 from .fused import rms_norm, swiglu, fused_rotary_position_embedding

@@ -1,12 +1,18 @@
 """The chunked gated delta rule (ops/linear_attention.py) against the
 recurrence it stands for, token by token, written out here: outputs and all
 five gradients at three chunk sizes, log-decays strong enough to overflow a
-form that multiplies exp(G) by exp(-G), and the shapes it refuses."""
+form that multiplies exp(G) by exp(-G), and the shapes it refuses. At head
+widths of 128 and the chunk of 64 the chunk terms are the Mosaic kernels of
+ops/pallas/delta_rule.py (here in the interpreter): the same comparisons,
+and against the XLA form on the same operands."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.ops import linear_attention
 from paddle_tpu.ops.linear_attention import SUB, chunk_gated_delta_rule
 
 NAMES = ("q", "k", "v", "g", "beta")
@@ -160,3 +166,83 @@ def test_a_chunk_is_the_sub_block_times_a_power_of_two():
         np.testing.assert_allclose(
             chunk_gated_delta_rule(*x, chunk=8), recurrence(*x),
             rtol=2e-5, atol=2e-6)
+
+
+# ------------------------------------------- shapes the Mosaic kernels take
+
+KERNEL_CASES = [(128, 1, "float32"), (256, 2, "float32"),
+                (128, 2, "bfloat16"), (256, 1, "bfloat16")]
+
+
+def off(got, want) -> float:
+    """|got - want|_2 / |want|_2 in float32."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.sqrt(((got - want) ** 2).sum() / (want ** 2).sum()))
+
+
+@functools.lru_cache(maxsize=None)
+def kernel_case(seq: int, batch: int, dtype: str, decay: float = 0.1):
+    """(the recurrence's, the kernels', the XLA form's) output and five
+    gradients at 2 heads of 128: q, k, v in `dtype`, the recurrence on
+    their float32 values."""
+    x = inputs(decay, seq=seq, batch=batch, dk=128, dv=128)
+    given = tuple(a.astype(dtype) for a in x[:3]) + x[3:]
+    x = tuple(a.astype(jnp.float32) for a in given)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
+
+    def both(rule, operands):
+        # jitted: eagerly the XLA form alone takes 14 s here
+        return jax.jit(lambda *a: (rule(*a), jax.grad(
+            lambda *a: (rule(*a).astype(jnp.float32) * weight).sum(),
+            argnums=range(5))(*a)))(*operands)
+
+    with jax.default_matmul_precision("highest"):
+        return (both(recurrence, x), both(chunk_gated_delta_rule, given),
+                both(lambda *a: linear_attention._rule(*a, 64, SUB, False),
+                     given))
+
+
+@pytest.mark.parametrize("what", ("o",) + NAMES)
+@pytest.mark.parametrize("seq, batch, dtype", KERNEL_CASES)
+def test_the_kernels_are_the_recurrence_and_the_xla_form(seq, batch, dtype,
+                                                         what):
+    (want, want_g), (got, got_g), (xla, xla_g) = kernel_case(seq, batch,
+                                                             dtype)
+    if what != "o":
+        i = NAMES.index(what)
+        want, got, xla = want_g[i], got_g[i], xla_g[i]
+    assert got.shape == xla.shape and got.dtype == xla.dtype
+    if dtype == "float32":
+        scale = float(jnp.abs(want).max())
+        for other in (want, xla):
+            np.testing.assert_allclose(got, other, rtol=1e-4,
+                                       atol=1e-5 * scale)
+    else:
+        # bfloat16 operands: the kernels round where the XLA form rounds
+        assert off(xla, want) < 8e-3
+        assert off(got, want) < 8e-3 and off(got, xla) < 6e-3
+
+
+def test_strong_decay_through_the_kernels_is_finite_and_the_recurrences():
+    """A chunk of 64 sums to about -320 a channel: the kernels' exponents
+    are differences of summed logs that are <= 0, forward and backward."""
+    (want, want_g), (got, got_g), _ = kernel_case(128, 1, "float32", 5.0)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    for g, w in zip(got_g, want_g):
+        assert bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(
+            g, w, rtol=1e-4, atol=1e-5 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("dk, dv, chunk, kernels", [
+    (128, 128, 64, True), (16, 8, 64, False), (128, 128, 32, False),
+    (128, 64, 64, False)])
+def test_the_shapes_alone_choose_the_kernels(dk, dv, chunk, kernels):
+    """Head widths that are multiples of 128 at the chunk of 64 take the
+    Mosaic kernels, forward and backward; any other shape lowers the XLA
+    form with no `pallas_call`."""
+    x = inputs(0.1, seq=64, batch=1, dk=dk, dv=dv)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: chunk_gated_delta_rule(*a, chunk=chunk).sum(),
+        argnums=range(5)))(*x))
+    assert ("pallas_call" in text) is kernels

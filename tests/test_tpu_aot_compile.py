@@ -565,33 +565,76 @@ def test_a_second_routed_layer_lowers_no_further_mosaic_body(v5e):
     assert bodies(2) == one
 
 
-def test_chunked_delta_rule_compiles_at_the_kimi_cells_shape(v5e):
-    """`ops/linear_attention.chunk_gated_delta_rule` and its gradient at the
-    hybrid cell's widths (32 heads of 128, 2,048 tokens, bfloat16 q, k, v,
-    float32 log-decays), two rows: XLA's own products, no Mosaic call;
-    rows are taken one at a time (`GROUP_TOKENS`), the scan over chunks
-    carries a float32 [1, 32, 128, 128] state, the pairwise [.., 16, 16,
-    128] tensor of a sub-block's decays is never an array of its own, and
-    no integer division reaches the device (the masks are constants)."""
+def _delta_rule_layers(layers, backward=True):
+    """`layers` chunked rules side by side, each on operands of its own, at
+    the hybrid cell's widths (32 heads of 128, 2,048 tokens, bfloat16 q,
+    k, v, float32 log-decays and write strengths), two rows: the sum of
+    their outputs or its gradient, and the operands' shapes."""
     la = importlib.import_module("paddle_tpu.ops.linear_attention")
     wide, heads = (2, 2048, 32, 128), (2, 2048, 32)
 
-    def loss(q, k, v, g, beta):
-        return _sum32(la.chunk_gated_delta_rule(q, k, v, g, beta))
+    def loss(*operands):
+        return sum(_sum32(la.chunk_gated_delta_rule(*x))
+                   for x in zip(*[iter(operands)] * 5))
 
-    compiled = _compile(
-        v5e, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-        *[(wide, jnp.bfloat16)] * 3, (wide, jnp.float32),
-        (heads, jnp.float32), kernels=False)
+    shapes = ([(wide, jnp.bfloat16)] * 3 + [(wide, jnp.float32),
+                                            (heads, jnp.float32)]) * layers
+    return (jax.grad(loss, argnums=tuple(range(5 * layers))) if backward
+            else loss), shapes
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_chunked_delta_rule_compiles_at_the_kimi_cells_shape(v5e, backward):
+    """`ops/linear_attention.chunk_gated_delta_rule` and its gradient at the
+    hybrid cell's widths, two rows: the chunk terms are the Mosaic kernels
+    of `ops/pallas/delta_rule.py` (the forward; under `jax.grad` the
+    forward and the backward), the scan over chunks is still a
+    `while` loop that carries a float32 [rows, 32, 128, 128] state, rows
+    taken one at a time (`GROUP_TOKENS`); outside the kernels nothing
+    writes the pairwise [.., 16, 16, 128] tensor of a sub-block's decays
+    or a float32 [.., 64, 64] array a chunk and head (a level of the
+    halving, T, B before its rounding), and no integer division reaches
+    the device."""
+    fn, shapes = _delta_rule_layers(1, backward)
+    compiled = _compile(v5e, fn, *shapes, kernels=True)
     text = compiled.as_text()
-    assert "f32[1,32,128,128]" in text
+    calls = len(re.findall(r'custom_call_target="tpu_custom_call"', text))
+    # the gradient alone: the forward's own pass is dead code, what is left
+    # is the backward's repeat of the forward kernel and the backward kernel
+    assert calls == (2 if backward else 1)
+    state = [rows for line in text.splitlines() if " while(" in line
+             for rows in re.findall(r"f32\[(\d+),32,128,128\]",
+                                    line.split(" while(")[0])]
+    assert state and set(state) == {"1"}
     # what a fusion keeps in registers is no array: look outside the fused
     # computations, at what instructions write
     written = "\n".join(
         block for block in text.split("\n\n")
         if not block.lstrip().startswith("%fused_computation"))
     assert _arrays_of(written, 2048 * 32 * 128)
-    assert not _arrays_of(written, 32 * 32 * 4 * 16 * 16 * 128)
+    chunk_heads = 32 * 32           # a row's: 2,048 / 64 chunks of 32 heads
+    for rows in (1, 2):
+        assert not _arrays_of(written, rows * chunk_heads * 4 * 16 * 16 * 128)
+    # (a trip of the scan's transpose writes its own [32, 64, 64])
+    assert all(math.prod(map(int, dims.split(","))) < chunk_heads * 64 * 64
+               for dims in re.findall(r"f32\[([\d,]*\b64,64)\]", written))
     assert not re.findall(r" (?:divide|remainder)\([^)]*\), .*s32\[", text)
     assert "/rem\"" not in text and "floor_divide" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_a_second_delta_rule_layer_lowers_no_further_mosaic_body(v5e):
+    """The guard on the set-up cost, as for the grouped products: the
+    kernels' entries are jitted, so a second layer of the same shapes (and
+    the backward's repeat of the forward) adds calls and no Mosaic body to
+    the lowered module, one for each of the two kernels."""
+    def bodies(layers):
+        fn, shapes = _delta_rule_layers(layers)
+        sh = SingleDeviceSharding(v5e[0])
+        return jax.jit(fn).trace(*[jax.ShapeDtypeStruct(
+            s, d, sharding=sh) for s, d in shapes]).lower(
+                lowering_platforms=("tpu",)).as_text().count(
+                    "stablehlo.custom_call @tpu_custom_call")
+
+    assert bodies(1) == 2
+    assert bodies(2) == 2
