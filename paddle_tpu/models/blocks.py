@@ -25,6 +25,7 @@ from .._core import device
 from .._core.flags import flag_value
 from ..distributed.fleet.mp_ops import vocab_parallel_softmax_cross_entropy
 from ..distributed.pipeline_compiled import pipelined_trunk
+from ..ops import moe
 from ..ops.pallas.flash_attention import mha_seq_major, mha_sharded
 
 
@@ -72,14 +73,18 @@ def yarn_inv_freq(dim: int, base: float, scaling: Optional[dict]):
     return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
 
 
-def rope(x, theta: float, inv_freq=None, factor: Optional[float] = None):
-    """x [B, S, H, D] -> rotated. Half-split convention. `inv_freq` [D/2]
-    replaces theta's plain frequencies (a scaled RoPE such as yarn);
-    `factor` multiplies cos and sin (yarn's `attention_factor` where a
-    model applies it there: q and k both carry it, the scores its
-    square)."""
+def rope(x, theta: float, inv_freq=None, factor: Optional[float] = None,
+         rotary_dim: Optional[int] = None):
+    """x [B, S, H, D] -> rotated. Half-split convention. `inv_freq`
+    [rotary_dim/2] replaces theta's plain frequencies (a scaled RoPE such as
+    yarn); `factor` multiplies cos and sin (yarn's `attention_factor` where
+    a model applies it there: q and k both carry it, the scores its
+    square). `rotary_dim` (None: D) rotates the first components of a head
+    alone, halves paired within them; the rest pass through (partial
+    rotary)."""
     b, s, h, d = x.shape
-    half = d // 2
+    rot = d if rotary_dim is None else rotary_dim
+    half = rot // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half) \
         if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
@@ -87,12 +92,13 @@ def rope(x, theta: float, inv_freq=None, factor: Optional[float] = None):
     sin = jnp.sin(ang)[None, :, None, :]
     if factor is not None:
         cos, sin = cos * factor, sin * factor
-    x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = x[..., :half], x[..., half:rot]
     xf1 = x1.astype(jnp.float32)
     xf2 = x2.astype(jnp.float32)
-    return jnp.concatenate(
+    out = jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
         axis=-1).astype(x.dtype)
+    return out if rot == d else jnp.concatenate([out, x[..., rot:]], -1)
 
 
 def swiglu(y, gate_w, up_w, down_w):
@@ -266,6 +272,117 @@ def scan_periods(block_fns: Sequence[Callable], x, stacked, remat: bool):
         lambda a: a.reshape((-1, size) + a.shape[1:]), stacked))
     return x, jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+
+
+def segments(kinds: Sequence[str], dense: int):
+    """The trunk of a model whose first `dense` layers are dense and the
+    rest routed, its layers of `kinds` (one name a layer), in order, as
+    (group of the parameters, kinds of one period, sparse, periods): the
+    leading dense layers once ("dense"), the shortest run the sparse
+    layers repeat at least twice (or all of them once) as often as it is
+    whole ("sparse"), and what is left, once ("tail"); a group without a
+    layer is left out. Each position of a group's period has a parameter
+    tree of its own, stacked over the group's periods (`scan_periods`)."""
+    sparse = len(kinds) - dense
+    rest = kinds[dense:]
+    size = next((p for p in range(1, sparse // 2 + 1) if all(
+        rest[i] == rest[i % p] for i in range(sparse // p * p))), sparse)
+    whole = sparse // size * size
+    found = [("dense", kinds[:dense], False, 1),
+             ("sparse", rest[:size], True, sparse // size),
+             ("tail", rest[whole:], True, 1)]
+    return [segment for segment in found if segment[1]]
+
+
+def scan_segments(block_of: Callable, trunk, x, params, remat: bool):
+    """`scan_periods` over the segments of a trunk in order (`segments`'
+    tuples; `params[group]` each group's trees), `block_of(kind, sparse)`
+    the block function of a layer -> (x, each sparse group's ys in layer
+    order, `in_layer_order`)."""
+    found = []
+    for group, kinds, sparse, _ in trunk:
+        x, ys = scan_periods([block_of(kind, sparse) for kind in kinds], x,
+                             params[group], remat)
+        if sparse:
+            found.append(in_layer_order(ys))
+    return x, found
+
+
+def in_layer_order(found):
+    """What a group's layers gave or hold, [layers, ...] in layer order:
+    a stack of like layers as it is; of a list with one entry a position
+    of the period, each [periods, ...] (or a tree of such), the periods'
+    positions interleaved."""
+    if not isinstance(found, (list, tuple)):
+        return found
+    return jax.tree_util.tree_map(
+        lambda *a: jnp.stack(a, 1).reshape((-1,) + a[0].shape[1:]), *found)
+
+
+def layer_leaves(params, groups: Sequence[str], key: str) -> list:
+    """The leaf `key` of every layer of `params[group]` for each of the
+    `groups`, one array [layers, ...] a group in layer order
+    (`in_layer_order`)."""
+    return [in_layer_order(layers[key] if isinstance(layers, dict)
+                           else [layer[key] for layer in layers])
+            for layers in map(params.get, groups)]
+
+
+def put_layer_leaves(params, groups: Sequence[str], key: str, values):
+    """`layer_leaves`' inverse: `params` with the leaf `key` of every layer
+    of the groups taken from `values` [layers, ...], the groups' layers
+    one after the other in layer order -> (params, rows of values
+    taken)."""
+    at = 0
+    for group in groups:
+        layers = params[group]
+        if isinstance(layers, dict):
+            count = layers[key].shape[0]
+            moved = dict(layers, **{key: values[at:at + count]})
+        else:       # a list, a position of the period each: interleaved
+            periods, count = layers[0][key].shape[0], len(layers)
+            mine = values[at:at + periods * count].reshape(
+                (periods, count) + values.shape[1:])
+            moved = [dict(layer, **{key: mine[:, i]})
+                     for i, layer in enumerate(layers)]
+            count *= periods
+        params, at = dict(params, **{group: moved}), at + count
+    return params, at
+
+
+def router_groups(params) -> list:
+    """The groups of a trunk's parameters that hold routers, in layer
+    order."""
+    return [group for group in ("blocks", "sparse", "tail")
+            if group in params]
+
+
+def move_router_biases(params, ids, num_experts: int, rate: float,
+                       more=()):
+    """The trainer's `state_update` of a sigmoid router: `params` (master
+    weights) with the selection bias of every router of the trunk moved by
+    the load of the step that has just run (`moe.moved_biases`: the pairs
+    that the choices ids [routers, T, k] drew over ALL the experts,
+    counted over this chip's tokens), the trunk's routers in layer order
+    and then `more`, biases [E] of routers outside it -> (params, those of
+    `more` moved)."""
+    drawn = moe.pairs_drawn(ids, num_experts).astype(jnp.float32)
+    groups = router_groups(params)
+    biases = moe.moved_biases(jnp.concatenate(
+        layer_leaves(params, groups, "router_b") + list(more)), drawn, rate)
+    params, at = put_layer_leaves(params, groups, "router_b", biases)
+    return params, biases[at:] if len(more) else None
+
+
+def move_biases_only(state, ids, move: Callable):
+    """The train state after the biases' move of one step WITHOUT the step:
+    `move(tree, ids)` on master and parameters alike, ids the choices of a
+    forward pass on a batch; no gradient, no AdamW, the step count as it
+    was. What a deployment's thousands of steps do to the load (the rule
+    balances it, the weights hardly moving meanwhile) a benchmark can
+    reach in a few hundred of these before it times real steps."""
+    return dict(state, **{name: move(state[name], ids)
+                          for name in ("params", "master")})
 
 
 def layer_trunk(block_fn: Callable, mesh: Optional[Mesh], num_layers: int,

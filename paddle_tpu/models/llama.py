@@ -10,8 +10,8 @@ machinery as gpt.py: layer-stacked params scanned (or pipelined over a 'pp'
 mesh axis), Megatron TP specs on the mp axis, ZeRO-1 over dp, bf16 compute
 + fp32 master.
 
-Three mechanisms are optional, and a configuration that leaves one out
-compiles none of it (the dense presets below leave out all three):
+These mechanisms are optional, and a configuration that leaves one out
+compiles none of it (the dense presets below leave out all of them):
 
 - an explicit head width (`head_dim`; None = hidden / heads), so that
   heads * head_dim need not be the hidden size;
@@ -28,7 +28,25 @@ compiles none of it (the dense presets below leave out all three):
   a softmax top-k router over ALL the experts with renormalised weights
   and no shared expert (ops/moe.py), the routed experts this chip HOLDS
   (`experts_held`, dropless), and a balance term in the loss,
-  `router_aux_loss_coef` * mean over layers of E * sum_e F_e P_e.
+  `router_aux_loss_coef` * mean over layers of E * sum_e F_e P_e;
+- in place of that router (`router` "sigmoid"): sigmoid scores with a
+  selection bias that only steers the choice, the chosen scores
+  renormalised and times `moe_routed_scaling_factor`
+  (`moe.sigmoid_topk_route`), no balance term, and the bias moved after
+  each step by the load (`router_bias_update_rate`, `moe.moved_biases`);
+- a shared expert beside the routed ones (`shared_expert_intermediate_size`),
+  added ungated;
+- leading dense layers in a routed model (`first_k_dense`) and query heads
+  by layer kind (`heads_by_kind`): the kinds' parameter trees then differ,
+  and the trunk is segments (`blocks.segments`: the dense layers, whole
+  periods of the rest, a tail), each position of a period on a tree of its
+  own;
+- an output gate on attention (`gating`): sigmoid(y W_g) times the core's
+  output, elementwise over the heads' H * head_dim, y the block's normed
+  input, under the stage `attn_gate`;
+- rotary on part of a head: a kind's `partial_rotary_factor` in
+  `rope_parameters` rotates that share of each head's components (yarn's
+  frequencies over it) and passes the rest through.
 
 `experts_held` is a chip's share of a layer that several chips divide by
 expert parallelism: the routed output is then a partial sum over the held
@@ -47,8 +65,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import moe
 from . import stages
-from .blocks import (attention, layer_trunk, lm_head_loss, normal, rms_norm,
-                     rope, scan_periods, swiglu, yarn_inv_freq)
+from .blocks import (attention, layer_leaves, layer_trunk, lm_head_loss,
+                     move_router_biases, normal, rms_norm, rope,
+                     router_groups, scan_periods, scan_segments, segments,
+                     swiglu, yarn_inv_freq)
+from .blocks import move_biases_only as blocks_move_biases_only
 from .trainer import build_adamw_train_step
 
 FULL, WINDOW = "full_attention", "sliding_attention"
@@ -80,6 +101,14 @@ class LlamaConfig:
     initializer_range: float = 0.02
     dtype: str = "bfloat16"
     tie_embeddings: bool = False
+    heads_by_kind: Optional[Dict[str, int]] = None  # query heads of a layer
+    #                                           kind; None = num_heads in all
+    gating: bool = False                      # a sigmoid gate on attention
+    first_k_dense: int = 0                    # leading dense layers (routed)
+    shared_expert_intermediate_size: int = 0  # 0 = no shared expert
+    router: str = "softmax"                   # | "sigmoid", with a bias
+    moe_routed_scaling_factor: float = 1.0    # the sigmoid router's weights
+    router_bias_update_rate: float = 0.0      # gamma; 0 = the bias is held
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -94,10 +123,22 @@ class LlamaConfig:
             if WINDOW in self.layer_types and not self.sliding_window:
                 raise ValueError("a sliding_attention layer needs a "
                                  "sliding_window")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"router {self.router!r}: softmax or sigmoid")
+        if self.first_k_dense and not self.sparse:
+            raise ValueError("leading dense layers need a routed "
+                             "feed-forward after them")
+        if any(n % self.kv_heads for n in (self.heads_by_kind or {}).values()):
+            raise ValueError(f"{self.kv_heads} K/V heads do not divide "
+                             f"every kind's query heads: {self.heads_by_kind}")
 
     @property
     def kv_heads(self):
         return self.num_kv_heads or self.num_heads
+
+    def heads(self, kind: str = FULL) -> int:
+        """Query heads of a layer of `kind`."""
+        return (self.heads_by_kind or {}).get(kind, self.num_heads)
 
     @property
     def period(self) -> Tuple[str, ...]:
@@ -116,6 +157,20 @@ class LlamaConfig:
     def held(self) -> Tuple[int, int]:
         return self.experts_held or (0, self.num_experts)
 
+    @property
+    def segments(self):
+        """None where every layer shares one parameter tree, stacked over
+        all the layers under "blocks" (`period` is then the scan's); with
+        leading dense layers or heads by kind the trunk in order, as
+        `blocks.segments` gives it (group, kinds of one period, sparse,
+        periods), each group a list of trees, one a position."""
+        if not self.first_k_dense and self.heads_by_kind is None:
+            return None
+        return [(group, kinds, sparse and self.sparse, periods)
+                for group, kinds, sparse, periods in segments(
+                    self.layer_types or (FULL,) * self.num_layers,
+                    self.first_k_dense)]
+
 
 LLAMA_CONFIGS = {
     "llama-tiny": LlamaConfig(vocab_size=1024, hidden_size=128,
@@ -128,15 +183,16 @@ LLAMA_CONFIGS = {
 }
 
 
-def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
-    key = jax.random.PRNGKey(seed)
-    c = config
-    h, f, L = c.hidden_size, c.intermediate_size, c.num_layers
-    qh, kvh = c.num_heads * c.head_dim, c.kv_heads * c.head_dim
+def _layers(ks, key, L: int, c: LlamaConfig, heads: int, sparse: bool):
+    """`L` like layers of `heads` query heads, stacked on a leading axis:
+    the matrices from the split keys `ks` and the router from `key` folded
+    with 9, as the stacked layout has always drawn them, and what the
+    options add from `key` folded with 10 and up."""
+    h, f = c.hidden_size, c.intermediate_size
+    qh, kvh = heads * c.head_dim, c.kv_heads * c.head_dim
     dt = jnp.dtype(c.dtype)
     std = c.initializer_range
-    out_std = std / math.sqrt(2 * L)
-    ks = jax.random.split(key, 9)
+    out_std = std / math.sqrt(2 * c.num_layers)
     blocks = {
         "ln1_g": jnp.ones((L, h), dt),
         "q_w": normal(ks[1], (L, h, qh), std, dt),
@@ -145,7 +201,10 @@ def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
         "o_w": normal(ks[4], (L, qh, h), out_std, dt),
         "ln2_g": jnp.ones((L, h), dt),
     }
-    if c.sparse:
+    if c.gating:
+        blocks["attn_gate_w"] = normal(jax.random.fold_in(key, 10),
+                                       (L, h, qh), std, dt)
+    if sparse:
         f, n = c.moe_intermediate_size, c.held[1]
         blocks.update(
             # the router is float32, as sparse checkpoints keep the gate
@@ -154,15 +213,44 @@ def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
             experts={"gate_w": normal(ks[5], (L, n, h, f), std, dt),
                      "up_w": normal(ks[6], (L, n, h, f), std, dt),
                      "down_w": normal(ks[7], (L, n, f, h), out_std, dt)})
+        if c.router == "sigmoid":
+            # no gradient reaches it: `_move_router_biases` moves it
+            blocks["router_b"] = jnp.zeros((L, c.num_experts), jnp.float32)
+        fs = c.shared_expert_intermediate_size
+        if fs:
+            k = jax.random.split(jax.random.fold_in(key, 11), 3)
+            blocks.update(shared_gate_w=normal(k[0], (L, h, fs), std, dt),
+                          shared_up_w=normal(k[1], (L, h, fs), std, dt),
+                          shared_down_w=normal(k[2], (L, fs, h), out_std,
+                                               dt))
     else:
         blocks.update(gate_w=normal(ks[5], (L, h, f), std, dt),
                       up_w=normal(ks[6], (L, h, f), std, dt),
                       down_w=normal(ks[7], (L, f, h), out_std, dt))
-    params = {
-        "wte": normal(ks[0], (c.vocab_size, h), std, dt),
-        "blocks": blocks,
-        "lnf_g": jnp.ones((h,), dt),
-    }
+    return blocks
+
+
+def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
+    """The embedding, the head and the layers: stacked under "blocks", or
+    with `segments` a list of trees under each group ("dense", "sparse",
+    "tail"), one a position of its period, each stacked over the periods
+    and drawn from keys of its own."""
+    key = jax.random.PRNGKey(seed)
+    c = config
+    h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
+    ks = jax.random.split(key, 9)
+    params = {"wte": normal(ks[0], (c.vocab_size, h), std, dt)}
+    if c.segments is None:
+        params["blocks"] = _layers(ks, key, c.num_layers, c, c.num_heads,
+                                   c.sparse)
+    for n, (group, kinds, sparse, periods) in enumerate(c.segments or ()):
+        params[group] = []
+        for i, kind in enumerate(kinds):
+            mine = jax.random.fold_in(jax.random.fold_in(key, 20 + n), i)
+            params[group].append(_layers(
+                jax.random.split(mine, 9), mine, periods, c, c.heads(kind),
+                sparse))
+    params["lnf_g"] = jnp.ones((h,), dt)
     if not c.tie_embeddings:
         params["lm_head"] = normal(ks[8], (c.vocab_size, h), std, dt)
     return params
@@ -171,16 +259,27 @@ def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
 def param_specs(config: LlamaConfig, pp: Optional[str] = None) -> Dict:
     """Megatron TP layout: q/k/v/gate/up column-split, o/down row-split.
     The routed feed-forward runs one chip's share (`build_train_step`):
-    its leaves are replicated."""
+    its leaves are replicated, and so is every leaf of a trunk in
+    `segments`, which runs on one chip alone."""
+    if config.segments is not None:
+        return jax.tree_util.tree_map(lambda _: P(), jax.eval_shape(
+            lambda: init_llama_params(config, 0)))
     blocks = {
         "ln1_g": P(pp, None),
         "q_w": P(pp, None, "mp"), "k_w": P(pp, None, "mp"),
         "v_w": P(pp, None, "mp"), "o_w": P(pp, "mp", None),
         "ln2_g": P(pp, None),
     }
+    if config.gating:
+        blocks["attn_gate_w"] = P(pp, None, "mp")
     if config.sparse:
         blocks.update(router_w=P(), experts={
             "gate_w": P(), "up_w": P(), "down_w": P()})
+        if config.router == "sigmoid":
+            blocks["router_b"] = P()
+        if config.shared_expert_intermediate_size:
+            blocks.update(shared_gate_w=P(), shared_up_w=P(),
+                          shared_down_w=P())
     else:
         blocks.update(gate_w=P(pp, None, "mp"), up_w=P(pp, None, "mp"),
                       down_w=P(pp, "mp", None))
@@ -192,9 +291,10 @@ def param_specs(config: LlamaConfig, pp: Optional[str] = None) -> Dict:
 
 def wd_mask(config: LlamaConfig) -> Dict:
     """Weight decay on the matrices and the embeddings, none on the norm
-    gains."""
+    gains or a router's selection bias."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: not path[-1].key.startswith("ln"),
+        lambda path, _: not path[-1].key.startswith("ln")
+        and path[-1].key != "router_b",
         param_specs(config), is_leaf=lambda x: isinstance(x, P))
 
 
@@ -206,11 +306,16 @@ def count_params(config: LlamaConfig) -> Dict[str, int]:
         return sum(math.prod(a.shape)
                    for a in jax.tree_util.tree_leaves(tree))
 
+    layers = [shapes[g] for g in ("blocks", "dense", "sparse", "tail")
+              if g in shapes]
     out = {"embedding_and_head": size(shapes["wte"])
            + size(shapes.get("lm_head", ())),
-           "layers": size(shapes["blocks"]), "total": size(shapes)}
+           "layers": size(layers), "total": size(shapes)}
     if config.sparse:
-        out["routed_experts"] = size(shapes["blocks"]["experts"])
+        out["routed_experts"] = sum(
+            math.prod(a.shape) for path, a in
+            jax.tree_util.tree_leaves_with_path(layers)
+            if any(getattr(k, "key", None) == "experts" for k in path))
     return out
 
 
@@ -227,33 +332,53 @@ def _rotary(config: LlamaConfig, kind: str):
     if p["rope_type"] != "yarn":
         raise NotImplementedError(f"rope_type {p['rope_type']!r}")
     factor = p.get("attention_factor") or 0.1 * math.log(p["factor"]) + 1.0
-    return p["rope_theta"], yarn_inv_freq(config.head_dim, p["rope_theta"],
-                                          p), factor
+    return p["rope_theta"], yarn_inv_freq(
+        _rotary_dim(config, kind) or config.head_dim, p["rope_theta"],
+        p), factor
+
+
+def _rotary_dim(config: LlamaConfig, kind: str) -> Optional[int]:
+    """Components of a head that a layer of `kind` rotates, its
+    `partial_rotary_factor` of `head_dim`; None where it is all of them."""
+    if config.rope_parameters is None:
+        return None
+    share = config.rope_parameters[kind].get("partial_rotary_factor", 1)
+    rot = round(config.head_dim * share)
+    return None if rot == config.head_dim else rot
 
 
 def _routed_ffn(y, blk, c: LlamaConfig):
     """The held routed experts' part on y [B, S, h], already normed ->
-    (that, {"balance": the layer's E * sum_e F_e P_e, "pairs": the pairs
-    each of ALL the experts drew [E]})."""
+    (that, the router's record): the softmax router's {"balance": the
+    layer's E * sum_e F_e P_e, "pairs": the pairs each of ALL the experts
+    drew [E]}, the sigmoid router's choices [B*S, k]."""
     b, s, h = y.shape
     flat = y.reshape(b * s, h)
     with jax.named_scope(stages.ROUTER):
-        ids, weights, probs = moe.softmax_topk_route(
-            flat, blk["router_w"], c.num_experts_per_tok)
-        balance, drawn = moe.balance_term(ids, probs)
+        if c.router == "sigmoid":
+            ids, weights = moe.sigmoid_topk_route(
+                flat, blk["router_w"], blk["router_b"],
+                c.num_experts_per_tok, c.moe_routed_scaling_factor)
+            record = ids
+        else:
+            ids, weights, probs = moe.softmax_topk_route(
+                flat, blk["router_w"], c.num_experts_per_tok)
+            balance, drawn = moe.balance_term(ids, probs)
+            record = {"balance": balance, "pairs": drawn}
     with jax.named_scope(stages.EXPERTS):
         routed = moe.held_experts_ffn(flat, ids, weights, blk["experts"],
                                       c.held, c.num_experts)
-    return routed.reshape(b, s, h), {"balance": balance, "pairs": drawn}
+    return routed.reshape(b, s, h), record
 
 
-def _block(x, blk, config: LlamaConfig, kind: str = FULL):
-    """Pre-norm decoder block of `kind`: x [B, S, H] -> (x, None, or the
-    router's record where the feed-forward is routed)."""
-    c = config
+def _attention(x, blk, c: LlamaConfig, kind: str):
+    """The attention sub-layer of `kind` on x [B, S, H], the block's input:
+    its output before W_o, gated where the configuration gates,
+    [B, S, heads * head_dim]."""
     b, s, _ = x.shape
-    nh, nkv, d = c.num_heads, c.kv_heads, c.head_dim
+    nh, nkv, d = c.heads(kind), c.kv_heads, c.head_dim
     theta, inv_freq, factor = _rotary(c, kind)
+    rot = _rotary_dim(c, kind)
 
     with jax.named_scope(stages.ATTN_QKV):
         y = rms_norm(x, blk["ln1_g"], c.rms_norm_eps)
@@ -261,22 +386,71 @@ def _block(x, blk, config: LlamaConfig, kind: str = FULL):
         k = jnp.einsum("bsh,hk->bsk", y, blk["k_w"])
         v = jnp.einsum("bsh,hk->bsk", y, blk["v_w"])
     with jax.named_scope(stages.ATTN_CORE):
-        q = rope(q.reshape(b, s, nh, d), theta, inv_freq, factor)
-        k = rope(k.reshape(b, s, nkv, d), theta, inv_freq, factor)
+        q = rope(q.reshape(b, s, nh, d), theta, inv_freq, factor, rot)
+        k = rope(k.reshape(b, s, nkv, d), theta, inv_freq, factor, rot)
         attn = attention(q, k, v.reshape(b, s, nkv, d), causal=True,
                          scale=1.0 / math.sqrt(d),
                          flash=True,
                          window=c.sliding_window if kind == WINDOW else None)
+    if c.gating:
+        with jax.named_scope(stages.ATTN_GATE):
+            gate = jnp.einsum("bsh,hk->bsk", y, blk["attn_gate_w"])
+            attn = attn * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(attn.dtype)
+    return attn
+
+
+def _block(x, blk, config: LlamaConfig, kind: str = FULL,
+           sparse: Optional[bool] = None):
+    """Pre-norm decoder block of `kind`: x [B, S, H] -> (x, None, or the
+    router's record where the feed-forward is routed: `sparse`, None =
+    the configuration's)."""
+    c = config
+    sparse = c.sparse if sparse is None else sparse
+    attn = _attention(x, blk, c, kind)
     with jax.named_scope(stages.ATTN_OUT):
         x = x + jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
     with jax.named_scope(stages.MLP):
         y = rms_norm(x, blk["ln2_g"], c.rms_norm_eps)
-        if not c.sparse:
+        if not sparse:
             return x + swiglu(y, blk["gate_w"], blk["up_w"],
                               blk["down_w"]), None
+        shared = swiglu(y, blk["shared_gate_w"], blk["shared_up_w"],
+                        blk["shared_down_w"]) \
+            if c.shared_expert_intermediate_size else None
     y, record = _routed_ffn(y, blk, c)
     with jax.named_scope(stages.MLP):
-        return x + y, record
+        return x + (y if shared is None else shared + y), record
+
+
+def layer_attention(params, x, n: int, config: LlamaConfig):
+    """Layer n's attention sub-layer as the trunk runs it, on x [B, S, H]
+    handed in as that layer's input: its output before W_o, gated (the
+    kernels, the partial rotary and the gate of the timed step), for a
+    check of the layer against a reference forward and backward. The
+    trunk must be segments (`config.segments`). Jit it."""
+    c, at = config, 0
+    for group, kinds, _, periods in c.segments:
+        if n < at + len(kinds) * periods:
+            period, position = divmod(n - at, len(kinds))
+            blk = jax.tree_util.tree_map(lambda a: a[period],
+                                         params[group][position])
+            return _attention(x.astype(jnp.dtype(c.dtype)), blk, c,
+                              kinds[position])
+        at += len(kinds) * periods
+    raise ValueError(f"the trunk has {at} layers, no layer {n}")
+
+
+def _trunk(params, x, config: LlamaConfig, remat):
+    """x [B, S, H] through the segments of the trunk in order ->
+    (x, the routed layers' records in layer order or None)."""
+    x, found = scan_segments(
+        lambda kind, sparse: functools.partial(
+            _block, config=config, kind=kind, sparse=sparse),
+        config.segments, x, params, remat)
+    if not found:
+        return x, None
+    return x, jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *found)
 
 
 def _hidden(params, tokens, config: LlamaConfig, remat, pp_trunk):
@@ -287,6 +461,8 @@ def _hidden(params, tokens, config: LlamaConfig, remat, pp_trunk):
     records = None
     if pp_trunk is not None:
         x = pp_trunk(params["blocks"], x)
+    elif config.segments is not None:
+        x, records = _trunk(params, x, config, remat)
     else:
         x, records = scan_periods(
             [functools.partial(_block, config=config, kind=kind)
@@ -307,15 +483,19 @@ def llama_forward(params, tokens, config: LlamaConfig, remat=True,
 def loss_parts(params, tokens, labels, config: LlamaConfig, remat=True,
                pp_trunk=None):
     """(L, aux): the step's scalar, and what it is made of. Dense: L is
-    the mean float32 cross-entropy and aux None. Routed: L = L_lm + alpha *
-    balance with aux {"lm": L_lm, "balance": the mean over the layers of
-    E * sum_e F_e P_e (k at perfect balance), "pairs": the pairs each of
-    ALL the experts drew in each layer [L, E]}, over this chip's tokens."""
+    the mean float32 cross-entropy and aux None. Routed by softmax: L =
+    L_lm + alpha * balance with aux {"lm": L_lm, "balance": the mean over
+    the layers of E * sum_e F_e P_e (k at perfect balance), "pairs": the
+    pairs each of ALL the experts drew in each layer [L, E]}, over this
+    chip's tokens. Routed by sigmoid: L = L_lm with aux {"lm": L_lm,
+    "ids": every routed layer's choices [L, T, k]}."""
     x, head, records = _hidden(params, tokens, config, remat, pp_trunk)
     with jax.named_scope(stages.LOSS_HEAD):
         lm = lm_head_loss(x, head, labels)
     if records is None:
         return lm, None
+    if config.router == "sigmoid":
+        return lm, {"lm": lm, "ids": records}
     with jax.named_scope(stages.ROUTER):
         balance = records["balance"].mean()
         loss = lm + config.router_aux_loss_coef * balance
@@ -323,16 +503,40 @@ def loss_parts(params, tokens, labels, config: LlamaConfig, remat=True,
 
 
 def llama_loss(params, tokens, labels, config: LlamaConfig, remat=True,
-               pp_trunk=None):
-    return loss_parts(params, tokens, labels, config, remat, pp_trunk)[0]
+               pp_trunk=None, want_ids=False):
+    """The step's scalar; with `want_ids` (a sigmoid router), (that, every
+    routed layer's choices) for `state_update`."""
+    loss, aux = loss_parts(params, tokens, labels, config, remat, pp_trunk)
+    return (loss, aux["ids"]) if want_ids else loss
 
 
 def step_facts(params, tokens, labels, config: LlamaConfig):
     """What a routed step computes besides its scalar, from one forward
-    pass: `loss`, `lm` and `balance` apart and `pairs` [L, num_experts].
+    pass: `loss` and `lm`, `pairs` [L, num_experts] and, by softmax,
+    `balance` apart, by sigmoid the selection `biases` [L, num_experts].
     Jit it."""
     loss, aux = loss_parts(params, tokens, labels, config, remat=False)
+    if config.router == "sigmoid":
+        ids = aux.pop("ids")
+        return dict(aux, loss=loss,
+                    pairs=moe.pairs_drawn(ids, config.num_experts),
+                    biases=jnp.concatenate(layer_leaves(
+                        params, router_groups(params), "router_b")))
     return dict(aux, loss=loss)
+
+
+def _move_router_biases(master, ids, config: LlamaConfig):
+    """The trainer's `state_update` (`blocks.move_router_biases`)."""
+    return move_router_biases(master, ids, config.num_experts,
+                              config.router_bias_update_rate)[0]
+
+
+def move_biases_only(state, tokens, labels, config: LlamaConfig):
+    """`blocks.move_biases_only` after a forward pass on the batch. Jit it
+    with the state donated."""
+    _, aux = loss_parts(state["params"], tokens, labels, config, remat=False)
+    return blocks_move_biases_only(state, aux["ids"], functools.partial(
+        _move_router_biases, config=config))
 
 
 def move_routers_only(state, tokens, labels, config: LlamaConfig,
@@ -375,16 +579,22 @@ def build_train_step(config: LlamaConfig, mesh: Optional[Mesh] = None, *,
     several chips they need an `ep` axis (and a pipeline whose stage holds
     a period), which the trainer does not have yet."""
     several = mesh is not None and mesh.size > 1
-    if several and (config.sparse or len(config.period) > 1):
+    if several and (config.sparse or len(config.period) > 1
+                    or config.segments is not None):
         raise NotImplementedError(
             "llama.build_train_step runs a routed feed-forward or mixed "
             "layer kinds as one chip's share; experts over an 'ep' mesh "
             "axis are not implemented")
     pp_trunk = layer_trunk(functools.partial(_block, config=config), mesh,
                            config.num_layers, remat, pp_microbatches)
+    moves = config.sparse and config.router == "sigmoid" \
+        and bool(config.router_bias_update_rate)
     return build_adamw_train_step(
         functools.partial(llama_loss, config=config, remat=remat,
-                          pp_trunk=pp_trunk),
+                          pp_trunk=pp_trunk, want_ids=moves),
         functools.partial(init_llama_params, config),
         param_specs(config, pp=None if pp_trunk is None else "pp"),
-        wd_mask(config), mesh=mesh, **adamw)
+        wd_mask(config), mesh=mesh,
+        state_update=functools.partial(
+            _move_router_biases, config=config) if moves else None,
+        **adamw)

@@ -77,8 +77,11 @@ from ..ops import moe
 from ..ops.linear_attention import chunk_gated_delta_rule
 from ..ops.pallas import stream_mix
 from . import stages
-from .blocks import (attention, lm_head_loss, normal, rms_norm, rope,
-                     scan_layers, scan_periods, swiglu, yarn_inv_freq)
+from .blocks import (attention, layer_leaves, lm_head_loss,
+                     move_router_biases, normal, rms_norm, rope,
+                     router_groups, scan_layers, scan_segments, segments,
+                     swiglu, yarn_inv_freq)
+from .blocks import move_biases_only as blocks_move_biases_only
 from .trainer import build_adamw_train_step
 
 
@@ -163,20 +166,11 @@ class MlaMoeConfig:
         and the sparse ones, each a stack of like layers. With them: the
         leading dense layers once, the shortest run the sparse layers
         repeat at least twice (or all of them once) as often as it is
-        whole, and under "tail" what is left, once."""
-        dense, sparse = self.first_k_dense, self.sparse_layers
+        whole, and under "tail" what is left, once (`blocks.segments`)."""
         if self.layer_types is None:
-            return [("dense", (FULL,), False, dense),
-                    ("sparse", (FULL,), True, sparse)]
-        kinds = self.layer_types[dense:]
-        size = next((p for p in range(1, sparse // 2 + 1) if all(
-            kinds[i] == kinds[i % p] for i in range(sparse // p * p))),
-            sparse)
-        whole = sparse // size * size
-        found = [("dense", self.layer_types[:dense], False, 1),
-                 ("sparse", kinds[:size], True, sparse // size),
-                 ("tail", kinds[whole:], True, 1)]
-        return [segment for segment in found if segment[1]]
+            return [("dense", (FULL,), False, self.first_k_dense),
+                    ("sparse", (FULL,), True, self.sparse_layers)]
+        return segments(self.layer_types, self.first_k_dense)
 
 
 # ------------------------------------------------------------------- yarn
@@ -609,32 +603,15 @@ def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
     layers, the sparse layers' periods, their tail; the streams summed)."""
     with jax.named_scope(stages.EMBED):
         x = _spread(params["wte"][tokens].astype(jnp.dtype(c.dtype)), c)
-    ids = []
-    for group, kinds, sparse, _ in c.segments:
-        x, ys = scan_periods(
-            [functools.partial(_block, c=c, sparse=sparse,
-                               want_ids=want_ids and sparse, kind=kind)
-             for kind in kinds], x, params[group], remat)
-        if sparse:
-            ids.append(_in_layer_order(ys))
+    x, ids = scan_segments(
+        lambda kind, sparse: functools.partial(
+            _block, c=c, sparse=sparse, want_ids=want_ids and sparse,
+            kind=kind), c.segments, x, params, remat)
     with jax.named_scope(stages.LOSS_HEAD):
         if not want_ids:
             return _gather(x, c), None
         return _gather(x, c), \
             ids[0] if len(ids) == 1 else jnp.concatenate(ids)
-
-
-def _in_layer_order(found):
-    """What a group's layers gave or hold, [layers, ...] in layer order:
-    a stack of like layers as it is; of a list with one entry a position
-    of the period, each [periods, ...], the periods' positions
-    interleaved."""
-    if not isinstance(found, (list, tuple)):
-        return found
-    if found[0] is None:
-        return None
-    stacked = jnp.stack(found, 1)
-    return stacked.reshape((-1,) + stacked.shape[2:])
 
 
 def _mtp_loss(params, hidden, labels, c: MlaMoeConfig, remat: bool,
@@ -699,8 +676,7 @@ def mla_moe_loss(params, tokens, labels, config: MlaMoeConfig, remat=True,
 def _pairs_drawn(ids, config: MlaMoeConfig):
     """Choices [L, T, k] -> the pairs each of ALL the routed experts drew,
     int32 [L, n_routed_experts]."""
-    return (ids[..., None] == jnp.arange(config.n_routed_experts)).sum(
-        (1, 2)).astype(jnp.int32)
+    return moe.pairs_drawn(ids, config.n_routed_experts)
 
 
 def routing_stats(params, tokens, config: MlaMoeConfig):
@@ -725,66 +701,34 @@ def step_facts(params, tokens, labels, config: MlaMoeConfig):
     return facts
 
 
-def _router_groups(params):
-    """The groups of the trunk that hold routers, in layer order."""
-    return [group for group in ("sparse", "tail") if group in params]
-
-
 def _router_biases(params):
     """[L_sparse (+ 1), n_routed_experts], in the order of the choices."""
-    found = [_in_layer_order(
-        layers["router_b"] if isinstance(layers, dict)
-        else [layer["router_b"] for layer in layers])
-        for layers in map(params.get, _router_groups(params))]
+    found = layer_leaves(params, router_groups(params), "router_b")
     if "mtp" in params:
         found.append(params["mtp"]["layer"]["router_b"])
     return jnp.concatenate(found)
 
 
 def _move_router_biases(master, ids, config: MlaMoeConfig):
-    """The trainer's `state_update`: master weights -> the same with every
-    router's selection bias moved by the load of the step that has just
-    run, b_e + gamma * sign(mean(c) - c_e), c_e the pairs that chose expert
-    e in the step's batch over ALL the experts (on one chip this chip's
-    tokens'; a deployment sums the counts over its chips first, an
-    exchange nothing here stands in for)."""
-    drawn = _pairs_drawn(ids, config).astype(jnp.float32)
-    biases = _router_biases(master)
-    biases = biases + config.router_bias_update_rate * jnp.sign(
-        drawn.mean(-1, keepdims=True) - drawn)
-    at = 0
-    for group in _router_groups(master):
-        layers = master[group]
-        if isinstance(layers, dict):
-            count = layers["router_b"].shape[0]
-            moved = dict(layers, router_b=biases[at:at + count])
-        else:       # a list, a position of the period each: interleaved
-            periods, count = layers[0]["router_b"].shape[0], len(layers)
-            mine = biases[at:at + periods * count].reshape(
-                periods, count, -1)
-            moved = [dict(layer, router_b=mine[:, i])
-                     for i, layer in enumerate(layers)]
-            count *= periods
-        master, at = dict(master, **{group: moved}), at + count
-    if "mtp" in master:
-        layer = dict(master["mtp"]["layer"], router_b=biases[at:])
+    """The trainer's `state_update` (`blocks.move_router_biases`), the
+    module's router after the trunk's."""
+    mtp = "mtp" in master
+    master, moved = move_router_biases(
+        master, ids, config.n_routed_experts, config.router_bias_update_rate,
+        [master["mtp"]["layer"]["router_b"]] if mtp else [])
+    if mtp:
+        layer = dict(master["mtp"]["layer"], router_b=moved)
         master["mtp"] = dict(master["mtp"], layer=layer)
     return master
 
 
 def move_biases_only(state, tokens, labels, config: MlaMoeConfig):
-    """The train state after the biases' move of one step WITHOUT the step:
-    a forward pass on the batch, the load it shows, `_move_router_biases`
-    on master and parameters alike; no gradient, no AdamW, the step count
-    as it was. What a deployment's thousands of steps do to the load (the
-    rule balances it, the weights hardly moving meanwhile) a benchmark can
-    reach in a few hundred of these before it times real steps. Jit it
+    """`blocks.move_biases_only` after a forward pass on the batch. Jit it
     with the state donated."""
     _, ids = mla_moe_loss(state["params"], tokens, labels, config,
                           remat=False, want_ids=True)
-    return dict(state, **{
-        name: _move_router_biases(state[name], ids, config)
-        for name in ("params", "master")})
+    return blocks_move_biases_only(state, ids, functools.partial(
+        _move_router_biases, config=config))
 
 
 def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None, *,

@@ -10,7 +10,8 @@ Flat, never nested, with one exception (MTP, below). A dense block is
 ATTN_QKV + ATTN_CORE + ATTN_OUT + MLP and nothing else; a sparse family
 (models/mla_moe.py) adds ROUTER and EXPERTS beside MLP, which is then its
 dense MLP and its shared expert, and RESIDUAL_MIX around every sub-layer;
-a linear-attention layer has LINEAR_ATTN where a softmax layer has ATTN_CORE.
+a linear-attention layer has LINEAR_ATTN where a softmax layer has ATTN_CORE;
+a gated softmax layer adds ATTN_GATE between ATTN_CORE and ATTN_OUT.
 The same name opened twice is one stage. MTP is the exception: one scope
 around the whole multi-token-prediction module, opened OUTSIDE the stages
 its layer and its head pass open themselves, so a path reads
@@ -63,6 +64,12 @@ LINEAR_ATTN = "linear_attn"     # a linear-attention layer between its
 #                             softmax layer of the same model keeps
 #                             ATTN_CORE, so a trace tells the two apart
 
+ATTN_GATE = "attn_gate"     # a gated softmax layer's output gate, between
+#                             the attention core and the output projection:
+#                             the gate's product from the block's normed
+#                             input, its sigmoid and the multiply
+
 BLOCK = (ATTN_QKV, ATTN_CORE, ATTN_OUT, MLP)
 ALL = (EMBED,) + BLOCK + (LOSS_HEAD, OPTIMIZER) \
-    + (ROUTER, EXPERTS, RESIDUAL_MIX) + (MTP,) + (LINEAR_ATTN,)
+    + (ROUTER, EXPERTS, RESIDUAL_MIX) + (MTP,) + (LINEAR_ATTN,) \
+    + (ATTN_GATE,)

@@ -205,10 +205,22 @@ def softmax_topk_route(x, w_r, k: int):
 
 
 def pairs_drawn(ids, num_experts: int):
-    """Choices [T, k] -> the (token, expert) pairs each of ALL the experts
-    drew, int32 [num_experts]."""
-    return (ids[..., None] == jnp.arange(num_experts)).sum((0, 1)).astype(
+    """Choices [..., T, k] -> the (token, expert) pairs each of ALL the
+    experts drew, int32 [..., num_experts] (a router's, or each of a stack
+    of routers')."""
+    return (ids[..., None] == jnp.arange(num_experts)).sum((-3, -2)).astype(
         jnp.int32)
+
+
+def moved_biases(biases, drawn, rate: float):
+    """The load-driven move of a sigmoid router's selection bias (Wang et
+    al. 2024, arXiv:2408.15664; DeepSeek-V3 section 2.1.2), after a step:
+    b_e + rate * sign(mean(c) - c_e), c_e the pairs that chose expert e in
+    the step's batch over ALL the experts (on one chip this chip's tokens';
+    a deployment sums the counts over its chips first, an exchange nothing
+    here stands in for). biases, drawn float32 [routers, E]. No gradient,
+    no AdamW: a trainer's `state_update` calls it."""
+    return biases + rate * jnp.sign(drawn.mean(-1, keepdims=True) - drawn)
 
 
 def balance_term(ids, probs):

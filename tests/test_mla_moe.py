@@ -643,7 +643,7 @@ def test_the_new_stages_are_appended_and_the_dense_block_keeps_its_four():
                               "mlp", "loss_head", "optimizer")
     assert stages.ALL[7:11] == (stages.ROUTER, stages.EXPERTS,
                                 stages.RESIDUAL_MIX, stages.MTP)
-    assert stages.ALL[11:] == (stages.LINEAR_ATTN,)
+    assert stages.ALL[11:] == (stages.LINEAR_ATTN, stages.ATTN_GATE)
     assert len(stages.BLOCK) == 4 and not set(stages.BLOCK) & set(
         stages.ALL[7:])
 

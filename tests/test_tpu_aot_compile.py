@@ -482,11 +482,37 @@ def test_bert_train_step_reaches_the_kernels_on_a_mesh(v5e, axes):
 
 # ------------------------------------------------- grouped products (PR 34)
 
+@pytest.mark.parametrize("heads, window", [(48, None), (64, 512)],
+                         ids=["full_48_on_8", "window_64_on_8"])
+def test_grouped_query_kernels_compile_at_the_laguna_cells_shapes(
+        v5e, heads, window):
+    """The Laguna cell's two kinds at 2 x 4,096, forward and backward: 48
+    query heads on 8 K/V heads of 128 in a full layer (6 a K/V head), 64 on
+    8 with a 512-key window in a window layer (8 a K/V head, a window one
+    tile wide); q [2, 4096, H 128] and k, v [2, 4096, 1024] go to the two
+    Mosaic calls as the projections wrote them, dK and dV come back with
+    8 heads."""
+    b, s, kv, d = 2, 4096, 8, 128
+
+    def gradient(q, k, v):
+        return jax.grad(lambda q, k, v: _sum32(fa.mha_seq_major(
+            q, k, v, heads, causal=True, kv_heads=kv, window=window)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    shapes = [((b, s, heads * d), jnp.bfloat16)] \
+        + [((b, s, kv * d), jnp.bfloat16)] * 2
+    text = _compile(v5e, gradient, *shapes).as_text()
+    wide, narrow = b * s * heads * d, b * s * kv * d
+    assert sorted(_mosaic_calls(text, narrow)) == [(2, 0), (2, 2)]
+    assert sorted(_mosaic_calls(text, wide)) == [(1, 1), (2, 1)]
+    assert not _of_size(text, wide, "copy", "transpose")
+
+
 gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
-# a chunk's rows, hidden, expert width, experts held: the Mellum, GLM and
-# Xing cells
+# a chunk's rows, hidden, expert width, experts held: the Mellum, GLM,
+# Xing and Laguna cells
 GROUPED = {"mellum": (65536, 2304, 896, 16), "glm": (16384, 2048, 1536, 8),
-           "xing": (4096, 3584, 1024, 8)}
+           "xing": (4096, 3584, 1024, 8), "laguna": (16384, 2048, 512, 32)}
 
 
 @pytest.mark.parametrize("kernel", ["forward", "dgrad", "wgrad"])

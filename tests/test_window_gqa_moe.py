@@ -370,3 +370,80 @@ def test_every_instruction_of_the_routed_step_says_its_stage():
     balance = [p for p in placed if p.endswith("/mul")
                and f"({stages.ROUTER})" in p]
     assert balance
+
+
+# ------------------------------------- the options ISSUE 39 added, left out
+
+def test_a_configuration_without_the_new_keys_builds_the_parents_step():
+    """`heads_by_kind`, `gating`, `first_k_dense`, a shared expert and the
+    sigmoid router left out: the parameter tree is the one stacked tree,
+    and the jaxpr of the Mellum cell's tiny step and of the dense preset's
+    loss are the parent's (06f4fa5) to the character, by their sha256."""
+    import hashlib
+    cell = load_cell(CELL, tiny=True)
+    c = cell.runner.program_config(cell.config)
+    assert c.segments is None and not c.gating and c.router == "softmax"
+    init_fn, step, _ = cell.runner.build(cell.config, None, {})
+    state = jax.eval_shape(lambda: init_fn(0))
+    assert set(state["params"]) == {"wte", "blocks", "lnf_g", "lm_head"}
+    assert set(state["params"]["blocks"]) == {
+        "ln1_g", "q_w", "k_w", "v_w", "o_w", "ln2_g", "router_w", "experts"}
+    tokens = jax.ShapeDtypeStruct(
+        (cell.traffic["batch"], cell.traffic["seq"]), jnp.int32)
+    text = str(jax.make_jaxpr(step)(state, tokens, tokens))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "8b73aeb3ccf23ee7"
+    dense = dataclasses.replace(llama.LLAMA_CONFIGS["llama-tiny"],
+                                num_layers=2)
+    params = jax.eval_shape(lambda: llama.init_llama_params(dense, 0))
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    text = str(jax.make_jaxpr(functools.partial(
+        llama.llama_loss, config=dense))(params, tokens, tokens))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "ca35969d877eadde"
+
+
+def test_partial_rotary_turns_the_first_components_and_keeps_the_rest():
+    """`blocks.rope` with a rotary width under the head's: the first
+    `rotary_dim` components rotated, halves paired within them, as a head
+    of that width would be; the rest passed through."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 2, 32))
+    inv = blocks.yarn_inv_freq(16, 500000.0, {
+        "factor": 64, "original_max_position_embeddings": 4096,
+        "beta_fast": 64, "beta_slow": 1})
+    got = blocks.rope(x, 500000.0, inv, 1.4, rotary_dim=16)
+    np.testing.assert_array_equal(got[..., 16:], x[..., 16:])
+    np.testing.assert_allclose(
+        got[..., :16], blocks.rope(x[..., :16], 500000.0, inv, 1.4),
+        rtol=1e-6)
+    np.testing.assert_array_equal(blocks.rope(x, 10.0, rotary_dim=32),
+                                  blocks.rope(x, 10.0))
+
+
+def test_leading_dense_layers_and_heads_by_kind_make_segments():
+    full, window = llama.FULL, llama.WINDOW
+    kinds = (full,) + (window, window, window, full) * 2 + (window,)
+    c = llama.LlamaConfig(
+        num_layers=10, layer_types=kinds, sliding_window=8, num_heads=8,
+        num_kv_heads=2, heads_by_kind={window: 12}, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=16, first_k_dense=1,
+        router="sigmoid")
+    assert (c.heads(full), c.heads(window)) == (8, 12)
+    assert c.segments == [("dense", (full,), False, 1),
+                          ("sparse", (window,) * 3 + (full,), True, 2),
+                          ("tail", (window,), True, 1)]
+    params = jax.eval_shape(lambda: llama.init_llama_params(c, 0))
+    assert [len(params[g]) for g in ("dense", "sparse", "tail")] == [1, 4, 1]
+    d = c.head_dim
+    assert params["sparse"][0]["q_w"].shape[1:] == (c.hidden_size, 12 * d)
+    assert params["sparse"][3]["q_w"].shape == (2, c.hidden_size, 8 * d)
+    assert "router_b" in params["tail"][0] and "gate_w" in params["dense"][0]
+    mask = llama.wd_mask(c)
+    assert mask["sparse"][0]["router_w"] and not mask["sparse"][0]["router_b"]
+    with pytest.raises(ValueError, match="router"):
+        llama.LlamaConfig(router="softplus")
+    with pytest.raises(ValueError, match="leading dense"):
+        llama.LlamaConfig(first_k_dense=1)
+    with pytest.raises(ValueError, match="K/V heads"):
+        llama.LlamaConfig(num_heads=8, num_kv_heads=2,
+                          heads_by_kind={full: 7})
