@@ -75,7 +75,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import moe
 from ..ops.linear_attention import chunk_gated_delta_rule
-from ..ops.pallas import stream_mix
+from ..ops.pallas import kda_elementwise, stream_mix
 from . import stages
 from .blocks import (attention, layer_leaves, lm_head_loss,
                      move_router_biases, normal, rms_norm, rope,
@@ -481,39 +481,119 @@ def _gated_norm(out, gain, gate, eps: float):
         b, s, heads * d)
 
 
-def _rule_operands(y, blk, c: MlaMoeConfig):
-    """A linear-attention layer on y [B, S, h] (its norm is here) up to the
-    rule: ((q, k, v, the log-decays, the write strengths) as the rule takes
-    them, the output gate's projection [B, S, H d]). Six projections under
-    ATTN_QKV; the convolutions, norms and gates' activations under
-    LINEAR_ATTN, in pieces that the backward pass computes again from the
-    projections' outputs."""
-    heads, d = c.linear_heads, c.linear_head_dim
+# a linear layer's parameters that the activations before the rule read
+_ACTIVATED = ("q_conv_w", "k_conv_w", "v_conv_w", "decay_log", "dt_bias")
+
+
+@jax.custom_vjp
+def _wide_product(y, w):
+    """y [B, S, h] @ w [h, k] -> float32 [B, S, k]. XLA fuses the product
+    into the XLA form's convolutions, which read its float32 accumulator;
+    the kernels read this, so that the rule's operands are as precise
+    (rounded to bfloat16 first, the first layer's rule read 6.0e-3 off its
+    float32 recurrence on a v5e, where the XLA form reads 5.4e-3). The
+    backward pass takes the cotangent in y's dtype, as the XLA form's
+    does."""
+    return jnp.einsum("bsh,hk->bsk", y, w, preferred_element_type=jnp.float32)
+
+
+def _wide_product_fwd(y, w):
+    return _wide_product(y, w), (y, w)
+
+
+def _wide_product_bwd(saved, cotangent):
+    y, w = saved
+    _, pull = jax.vjp(lambda y, w: jnp.einsum("bsh,hk->bsk", y, w), y, w)
+    return pull(cotangent.astype(y.dtype))
+
+
+_wide_product.defvjp(_wide_product_fwd, _wide_product_bwd)
+
+
+def _kernels(y, blk, c: MlaMoeConfig) -> bool:
+    """Whether the one-pass Mosaic kernels of `ops/pallas/kda_elementwise.py`
+    serve this layer's activations and its gated norm
+    (`kda_elementwise.taken`: its head width, sequence and operands
+    alone)."""
+    return kda_elementwise.taken(c.linear_head_dim, y.shape[1], y,
+                                 *jax.tree_util.tree_leaves(blk))
+
+
+def _projections(y, blk, c: MlaMoeConfig, kernels: bool):
+    """A linear-attention layer's norm and six projections of y [B, S, h],
+    under ATTN_QKV: ((q, k, v, the decay's, the write strength's), the
+    output gate's [B, S, H d]). Where `kernels`, q's, k's and v's products
+    are float32 (`_wide_product`)."""
     with jax.named_scope(stages.ATTN_QKV):
         y = rms_norm(y, blk["ln1_g"], c.rms_norm_eps)
-        q, k, v = (jnp.einsum("bsh,hk->bsk", y, blk[name])
+        q, k, v = (_wide_product(y, blk[name]) if kernels
+                   else jnp.einsum("bsh,hk->bsk", y, blk[name])
                    for name in ("q_w", "k_w", "v_w"))
         decay, gate = (
             jnp.einsum("bsr,rk->bsk",
                        jnp.einsum("bsh,hr->bsr", y, blk[f"{name}_a_w"]),
                        blk[f"{name}_b_w"]) for name in ("decay", "gate"))
         beta = jnp.einsum("bsh,hn->bsn", y, blk["beta_w"])
+    return (q, k, v, decay, beta), gate
+
+
+def _activated(projected, params, c: MlaMoeConfig, kernels: bool):
+    """The projections as the rule takes them: (q, k, v) through the short
+    convolutions with SiLU, q's and k's heads normed, in the configuration's
+    dtype, the log-decays and the write strengths. The kernels where
+    `kernels`, else the checkpointed XLA functions above."""
+    heads, d = c.linear_heads, c.linear_head_dim
+    q, k, v, decay, beta = projected
+    if kernels:
+        conv = functools.partial(kda_elementwise.conv_heads, eps=QK_NORM_EPS,
+                                 dtype=jnp.dtype(c.dtype))
+        decays = kda_elementwise.log_decay
+    else:
+        conv, decays = _conv_heads, _log_decay
+    return (conv(q, params["q_conv_w"], heads, d ** -0.5),
+            conv(k, params["k_conv_w"], heads, 1.0),
+            conv(v, params["v_conv_w"], heads, None),
+            decays(decay, params["decay_log"], params["dt_bias"]),
+            jax.nn.sigmoid(beta.astype(jnp.float32)))
+
+
+def _rule_operands(y, blk, c: MlaMoeConfig):
+    """A linear-attention layer on y [B, S, h] (its norm is here) up to the
+    rule: ((q, k, v, the log-decays, the write strengths) as the rule takes
+    them, the output gate's projection [B, S, H d]): `_projections` under
+    ATTN_QKV, `_activated` under LINEAR_ATTN."""
+    kernels = _kernels(y, blk, c)
+    projected, gate = _projections(y, blk, c, kernels)
     with jax.named_scope(stages.LINEAR_ATTN):
-        return (_conv_heads(q, blk["q_conv_w"], heads, d ** -0.5),
-                _conv_heads(k, blk["k_conv_w"], heads, 1.0),
-                _conv_heads(v, blk["v_conv_w"], heads, None),
-                _log_decay(decay, blk["decay_log"], blk["dt_bias"]),
-                jax.nn.sigmoid(beta.astype(jnp.float32))), gate
+        return _activated(projected, blk, c, kernels), gate
 
 
 def _linear_attention(y, blk, c: MlaMoeConfig):
     """The linear-attention layer on y [B, S, h] -> its output projection,
-    [B, S, h]: `_rule_operands`, the chunked rule and the gated head norm
-    under LINEAR_ATTN, the output's projection under ATTN_OUT."""
-    operands, gate = _rule_operands(y, blk, c)
+    [B, S, h]: the projections under ATTN_QKV; the activations, the chunked
+    rule and the gated head norm under LINEAR_ATTN; the output's projection
+    under ATTN_OUT. Where the kernels serve the layer, the activations
+    and the rule are checkpointed together: the layer's backward pass keeps
+    the projections, which the kernels' backward reads anyway, and forms
+    the rule's operands from them once more (one more run of the forward
+    kernels), so that neither those operands nor the float32 log-decays
+    are kept across the rest of the layer's backward pass. Kept, they
+    compiled the Kimi cell's step for a v5e to about 15.4 GB, against
+    13.85 checkpointed together and 13.96 for the XLA form."""
+    kernels = _kernels(y, blk, c)
+    projected, gate = _projections(y, blk, c, kernels)
     with jax.named_scope(stages.LINEAR_ATTN):
-        out = chunk_gated_delta_rule(*operands)
-        out = _gated_norm(out, blk["o_ln"], gate, c.rms_norm_eps)
+        if kernels:
+            out = jax.checkpoint(lambda projected, params: (
+                chunk_gated_delta_rule(*_activated(projected, params, c,
+                                                   True))))(
+                projected, {name: blk[name] for name in _ACTIVATED})
+            out = kda_elementwise.gated_norm(out, blk["o_ln"], gate,
+                                             c.rms_norm_eps)
+        else:
+            out = chunk_gated_delta_rule(*_activated(projected, blk, c,
+                                                     False))
+            out = _gated_norm(out, blk["o_ln"], gate, c.rms_norm_eps)
     with jax.named_scope(stages.ATTN_OUT):
         return jnp.einsum("bsk,kh->bsh", out, blk["o_w"]), None
 

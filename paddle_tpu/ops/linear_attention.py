@@ -65,6 +65,12 @@ transpose, JAX's, reads the six terms and a state a trip), then the
 backward kernel, which forms G, the pairwise exponentials, T, W and U once
 more in VMEM from the five inputs and writes the five gradients once: no
 float32 intermediate of the chunk terms is an array in either direction.
+Where a layer forms the five inputs with the Mosaic kernels of
+`ops/pallas/kda_elementwise.py`, `models/mla_moe._linear_attention`
+checkpoints those kernels and the rule together, so what the layer's
+backward pass keeps is the projections the inputs are formed from; the
+inputs are formed once more and held only while the rule's gradient is
+taken.
 Behind the XLA form the backward pass is JAX's transpose of `_chunk_terms`,
 whose float32 intermediates, the size of several q, are what the groups
 bound there. One row of 2,048 tokens a group is still the choice behind the

@@ -664,3 +664,72 @@ def test_a_second_delta_rule_layer_lowers_no_further_mosaic_body(v5e):
 
     assert bodies(1) == 2
     assert bodies(2) == 2
+
+
+def _kda_elementwise(kernel, backward):
+    """One of `ops/pallas/kda_elementwise.py`'s passes at the hybrid cell's
+    widths (32 heads of 128, 2,048 tokens, bfloat16 but for q's float32
+    product; two rows): the sum of its output or its gradient to every
+    operand, and the operands' shapes."""
+    ke = importlib.import_module("paddle_tpu.ops.pallas.kda_elementwise")
+    heads, d, wide = 32, 128, (2, 2048, 32 * 128)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    fn, shapes = {
+        # as the model hands them: a float32 product in, bfloat16 out
+        "conv_qk": (lambda x, t: ke.conv_heads(x, t, heads, d ** -0.5, 1e-6,
+                                               dtype=bf),
+                    [(wide, f32), ((4, heads * d), bf)]),
+        "conv_v": (lambda x, t: ke.conv_heads(x, t, heads, None, 1e-6),
+                   [(wide, bf), ((4, heads * d), bf)]),
+        "decay": (ke.log_decay,
+                  [(wide, bf), ((heads,), f32), ((heads * d,), f32)]),
+        "norm": (lambda o, w, g: ke.gated_norm(o, w, g, 1e-5),
+                 [((2, 2048, heads, d), bf), ((d,), bf), (wide, bf)]),
+    }[kernel]
+
+    def loss(*operands):
+        return _sum32(fn(*operands))
+
+    return (jax.grad(loss, argnums=tuple(range(len(shapes)))) if backward
+            else loss), shapes
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("kernel", ["conv_qk", "conv_v", "decay", "norm"])
+def test_kda_elementwise_kernels_compile_at_the_kimi_cells_shape(
+        v5e, kernel, backward):
+    """The KDA layer's short convolution (normed and not), log-decays and
+    gated norm at the hybrid cell's widths: one Mosaic call each, the
+    forward kernel, or under `jax.grad` the backward kernel alone (each
+    `custom_vjp` keeps its inputs, so the forward's own pass is dead
+    code), and outside it no array the size of an operand."""
+    fn, shapes = _kda_elementwise(kernel, backward)
+    compiled = _compile(v5e, fn, *shapes, kernels=True)
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2048 * 4096
+
+
+def test_a_second_convolution_site_lowers_no_further_mosaic_body(v5e):
+    """The guard on the set-up cost: the kernels' entries are jitted, so a
+    second convolution of the same shapes and mode (a layer's q and k take
+    one mode each, a later layer repeats them) adds calls and no Mosaic
+    body to the lowered module: one forward body and one backward."""
+    ke = importlib.import_module("paddle_tpu.ops.pallas.kda_elementwise")
+    wide, taps = (2, 2048, 4096), (4, 4096)
+
+    def bodies(sites):
+        def loss(*operands):
+            return sum(_sum32(ke.conv_heads(x, t, 32, 1.0, 1e-6))
+                       for x, t in zip(operands[::2], operands[1::2]))
+        sh = SingleDeviceSharding(v5e[0])
+        shapes = [wide, taps] * sites
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(2 * sites)))).trace(
+            *[jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sh)
+              for s in shapes]).lower(lowering_platforms=("tpu",)).as_text(
+                  ).count("stablehlo.custom_call @tpu_custom_call")
+
+    assert bodies(1) == 2
+    assert bodies(2) == 2
