@@ -47,11 +47,12 @@ Two ways to compute the chunk terms, one algorithm, chosen by the operands'
 shapes alone (`ops/pallas/delta_rule.taken`): head widths that are
 multiples of 128 lanes at the chunk of `CHUNK` = 4 `SUB` take the Mosaic
 kernels of `ops/pallas/delta_rule.py`, which read q, k, v and g where the
-layer wrote them, `[B, S, H d]`, hold everything above in VMEM and write the
-six terms once, the chunk axis leading; any other shape (narrow heads,
-chunks of 8 to 32) takes `_chunk_terms`, XLA's own passes, which is also
-what the kernels are tested against. The scan over chunks is the same
-`lax.scan` behind both.
+layer wrote them, `[B, S, H d]`, hold everything above in VMEM (there T
+comes from all the levels' blocks at once, in three dependent products)
+and write the six terms once, the chunk axis leading; any other shape
+(narrow heads, chunks of 8 to 32) takes `_chunk_terms`, XLA's own passes,
+which is also what the kernels are tested against. The scan over chunks is
+the same `lax.scan` behind both.
 
 Precision, in both: the log-decays, their sums, every exp, T and the state
 are float32; the products' operands are rounded to q's dtype (bfloat16 in

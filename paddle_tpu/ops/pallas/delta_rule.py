@@ -10,11 +10,13 @@ what does not know the state,
 with T = (I + A)^-1, A[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j) (j < i)
 and G the log-decays summed from the chunk's first token.
 
-**Forward.** A grid step takes one chunk of `heads_step` heads: q, k, g
-`[CHUNK, d_k]`, v `[CHUNK, d_v]` and beta are read where the projections'
-successors wrote them, `[B, S, H d]` in `(1, CHUNK, heads_step d)` blocks
-indexed by (row, chunk, head group), and the six results are written once,
-the chunk axis leading and the heads apart (`[N, B, H, CHUNK, d]`), as
+**Forward.** A grid step takes one chunk of `heads_step` heads
+(`HEADS_STEP`, halved until it divides the heads: their chains are
+independent, for the scheduler to overlap): q, k, g `[CHUNK, d_k]`, v
+`[CHUNK, d_v]` and beta are read where the projections' successors wrote
+them, `[B, S, H d]` in `(1, CHUNK, heads_step d)` blocks indexed by (row,
+chunk, head group), and the six results are written once, the chunk axis
+leading and the heads apart (`[N, B, H, CHUNK, d]`), as
 `lax.scan`'s `xs` want them: no transposed copy stands on either side.
 Nothing else reaches HBM. Inside, per head:
 
@@ -30,7 +32,12 @@ Nothing else reaches HBM. Inside, per head:
 * Below the diagonal the chunk is halved as in the XLA form: a level's
   later halves meet its earlier halves through the summed log at the later
   half's first row, every exponent <= 0, as two products of operands
-  rounded to q's dtype; then T <- T - T R T in float32 (`HIGHEST`).
+  rounded to q's dtype.
+* T from all the levels at once (`_inverse`): their blocks are L, A below
+  the diagonal sub-blocks, and with D^-1 the sub-blocks' inverses N =
+  D^-1 L is strictly lower by sub-blocks, N^4 = 0, so T = (I - N + N^2 -
+  N^3) D^-1 = (I + N^2) P with P = D^-1 - N D^-1: float32 products
+  (`HIGHEST`) three deep, N, then N N and N D^-1 apart, then N^2 P.
 
 **Backward.** Keeps the five inputs and nothing else. It takes the six
 cotangents, forms G, the pairwise exponentials, T, W and U again in VMEM and
@@ -77,8 +84,8 @@ CHUNK = 64
 SUB = 16
 LANES = 128
 # heads a grid step takes: independent chains for the scheduler to
-# interleave and fewer steps, against code size
-HEADS_STEP = 2
+# interleave and fewer steps, against code size and compile time
+HEADS_STEP = 8
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -192,7 +199,7 @@ def _inverse_and_b(q, k, kb, G, dtype, want_b: bool):
         # common: each is rounded where it is made
         Bt = Bt4.reshape(CHUNK, CHUNK).astype(dtype)
         B = _dot(_eye(dtype), Bt, _NT)
-    factors = []
+    factors, lower = [], 0.0
     for size in _levels():
         rows, cols = _through(G, size)
         factors.append((size, rows, cols))
@@ -204,8 +211,20 @@ def _inverse_and_b(q, k, kb, G, dtype, want_b: bool):
                 [_same_run(size)] * (lhs.shape[0] // CHUNK)), below, 0.0)
         if want_b:
             B = B + below[:CHUNK]
-        T = T - _dot(T, _dot(below[-CHUNK:], T))
-    return T, (None if B is None else B.astype(dtype)), factors
+        # the levels' blocks have no element in common
+        lower = lower + below[-CHUNK:]
+    return (_inverse(T, lower), (None if B is None else B.astype(dtype)),
+            factors)
+
+
+def _inverse(diagonal, lower):
+    """T = (I + A)^-1 from D^-1, the diagonal sub-blocks' inverses, and L,
+    A below them: N = D^-1 L is strictly lower by sub-blocks, so N^4 = 0
+    and T = (I - N + N^2 - N^3) D^-1 = (I + N^2) P with P = D^-1 - N D^-1.
+    Three dependent float32 products (`HIGHEST`), the middle two apart."""
+    n = _dot(diagonal, lower)
+    p = diagonal - _dot(n, diagonal)
+    return p + _dot(_dot(n, n), p)
 
 
 def _head_operands(refs, h: int, head, widths):
@@ -428,7 +447,11 @@ def _backward(q, k, v, g, beta, d_terms, *, heads, heads_step, interpret):
 
 
 def _heads_step(heads: int) -> int:
-    return HEADS_STEP if heads % HEADS_STEP == 0 else 1
+    """`HEADS_STEP`, halved until it divides `heads`."""
+    step = HEADS_STEP
+    while heads % step:
+        step //= 2
+    return step
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))

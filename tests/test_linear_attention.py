@@ -14,6 +14,7 @@ import pytest
 
 from paddle_tpu.ops import linear_attention
 from paddle_tpu.ops.linear_attention import SUB, chunk_gated_delta_rule
+from paddle_tpu.ops.pallas import delta_rule
 
 NAMES = ("q", "k", "v", "g", "beta")
 
@@ -170,8 +171,14 @@ def test_a_chunk_is_the_sub_block_times_a_power_of_two():
 
 # ------------------------------------------- shapes the Mosaic kernels take
 
-KERNEL_CASES = [(128, 1, "float32"), (256, 2, "float32"),
-                (128, 2, "bfloat16"), (256, 1, "bfloat16")]
+# (seq, batch, dtype, heads): two heads, then a count the heads a grid
+# step takes divides and one it does not (the step falls back to a divisor)
+KERNEL_CASES = [pytest.param(*case, 2, id="-".join(map(str, case)))
+                for case in [(128, 1, "float32"), (256, 2, "float32"),
+                             (128, 2, "bfloat16"), (256, 1, "bfloat16")]] + [
+    pytest.param(128, 1, "float32", delta_rule.HEADS_STEP, id="divided"),
+    pytest.param(128, 1, "bfloat16", 3 * delta_rule.HEADS_STEP // 2,
+                 id="fallback")]
 
 
 def off(got, want) -> float:
@@ -181,11 +188,12 @@ def off(got, want) -> float:
 
 
 @functools.lru_cache(maxsize=None)
-def kernel_case(seq: int, batch: int, dtype: str, decay: float = 0.1):
+def kernel_case(seq: int, batch: int, dtype: str, decay: float = 0.1,
+                heads: int = 2):
     """(the recurrence's, the kernels', the XLA form's) output and five
-    gradients at 2 heads of 128: q, k, v in `dtype`, the recurrence on
-    their float32 values."""
-    x = inputs(decay, seq=seq, batch=batch, dk=128, dv=128)
+    gradients at `heads` heads of 128: q, k, v in `dtype`, the recurrence
+    on their float32 values."""
+    x = inputs(decay, seq=seq, batch=batch, heads=heads, dk=128, dv=128)
     given = tuple(a.astype(dtype) for a in x[:3]) + x[3:]
     x = tuple(a.astype(jnp.float32) for a in given)
     weight = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
@@ -203,11 +211,15 @@ def kernel_case(seq: int, batch: int, dtype: str, decay: float = 0.1):
 
 
 @pytest.mark.parametrize("what", ("o",) + NAMES)
-@pytest.mark.parametrize("seq, batch, dtype", KERNEL_CASES)
+@pytest.mark.parametrize("seq, batch, dtype, heads", KERNEL_CASES)
 def test_the_kernels_are_the_recurrence_and_the_xla_form(seq, batch, dtype,
-                                                         what):
-    (want, want_g), (got, got_g), (xla, xla_g) = kernel_case(seq, batch,
-                                                             dtype)
+                                                         heads, what):
+    step = delta_rule._heads_step(heads)
+    assert heads % step == 0
+    assert (step == delta_rule.HEADS_STEP) is (
+        heads % delta_rule.HEADS_STEP == 0)
+    (want, want_g), (got, got_g), (xla, xla_g) = kernel_case(
+        seq, batch, dtype, heads=heads)
     if what != "o":
         i = NAMES.index(what)
         want, got, xla = want_g[i], got_g[i], xla_g[i]
@@ -221,6 +233,32 @@ def test_the_kernels_are_the_recurrence_and_the_xla_form(seq, batch, dtype,
         # bfloat16 operands: the kernels round where the XLA form rounds
         assert off(xla, want) < 8e-3
         assert off(got, want) < 8e-3 and off(got, xla) < 6e-3
+
+
+@pytest.mark.parametrize("decay, beta", [(0.1, None), (0.1, 0.99),
+                                         (5.0, 0.99)])
+def test_the_kernels_inverse_is_the_inverse(decay, beta):
+    """T from the kernels' chain (the diagonal sub-blocks' inverses, then
+    three products) against I + A formed in float64 from the same float32
+    operands: T (I + A) = I to float32's rounding, on each head of a chunk
+    of 64 at width 128, with write strengths as drawn or all near 1."""
+    q, k, _, g, b = (np.asarray(x[0]) for x in inputs(
+        decay, seq=delta_rule.CHUNK, batch=1, dk=128, dv=128))
+    if beta is not None:
+        b = np.full_like(b, beta) + 0.01 * np.abs(b)
+    inverse = jax.jit(lambda *x: delta_rule._inverse_and_b(
+        *x, jnp.float32, False)[0])
+    with delta_rule._no_x64():
+        for h in range(q.shape[1]):
+            G = np.cumsum(g[:, h], 0, dtype=np.float32)
+            kb = k[:, h] * b[:, h, None]
+            T = np.asarray(inverse(q[:, h], k[:, h], kb, G), np.float64)
+            decays = np.exp(G[:, None, :].astype(np.float64) - G[None])
+            A = np.tril(np.einsum("ic,jc,ijc->ij", kb, k[:, h], decays), -1)
+            eye = np.eye(len(A))
+            assert np.abs(T @ (eye + A) - eye).max() < 1e-6
+            np.testing.assert_allclose(T, np.linalg.inv(eye + A),
+                                       rtol=0, atol=1e-6)
 
 
 def test_strong_decay_through_the_kernels_is_finite_and_the_recurrences():

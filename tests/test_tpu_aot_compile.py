@@ -653,17 +653,21 @@ def test_a_second_delta_rule_layer_lowers_no_further_mosaic_body(v5e):
     """The guard on the set-up cost, as for the grouped products: the
     kernels' entries are jitted, so a second layer of the same shapes (and
     the backward's repeat of the forward) adds calls and no Mosaic body to
-    the lowered module, one for each of the two kernels."""
+    the lowered module: one forward body and one backward body, whatever
+    the heads a grid step takes."""
     def bodies(layers):
         fn, shapes = _delta_rule_layers(layers)
         sh = SingleDeviceSharding(v5e[0])
-        return jax.jit(fn).trace(*[jax.ShapeDtypeStruct(
+        text = jax.jit(fn).trace(*[jax.ShapeDtypeStruct(
             s, d, sharding=sh) for s, d in shapes]).lower(
-                lowering_platforms=("tpu",)).as_text().count(
-                    "stablehlo.custom_call @tpu_custom_call")
+                lowering_platforms=("tpu",)).as_text()
+        calls = [line for line in text.splitlines()
+                 if "stablehlo.custom_call @tpu_custom_call" in line]
+        return sorted(name for line in calls
+                      for name in re.findall(r'kernel_name = "(\w+)"', line))
 
-    assert bodies(1) == 2
-    assert bodies(2) == 2
+    assert bodies(1) == ["_terms_bwd", "_terms_fwd"]
+    assert bodies(2) == ["_terms_bwd", "_terms_fwd"]
 
 
 def _kda_elementwise(kernel, backward):
