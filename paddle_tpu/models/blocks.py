@@ -9,7 +9,8 @@ vocabulary-parallel or dense logits in `lm_head_loss`, checkpoint-then-scan
 in `scan_layers`, the pipeline over a `pp` axis in `layer_trunk`.
 
 No function here opens a `stages` scope: the caller stands in the stage
-its call belongs to (models/stages.py), so the scopes stay flat.
+its call belongs to (models/stages.py), so the scopes stay flat. The routed
+feed-forward of the sparse families, which opens its own, is routed.py's.
 """
 from __future__ import annotations
 
@@ -25,7 +26,6 @@ from .._core import device
 from .._core.flags import flag_value
 from ..distributed.fleet.mp_ops import vocab_parallel_softmax_cross_entropy
 from ..distributed.pipeline_compiled import pipelined_trunk
-from ..ops import moe
 from ..ops.pallas.flash_attention import mha_seq_major, mha_sharded
 
 
@@ -297,15 +297,18 @@ def segments(kinds: Sequence[str], dense: int):
 def scan_segments(block_of: Callable, trunk, x, params, remat: bool):
     """`scan_periods` over the segments of a trunk in order (`segments`'
     tuples; `params[group]` each group's trees), `block_of(kind, sparse)`
-    the block function of a layer -> (x, each sparse group's ys in layer
-    order, `in_layer_order`)."""
+    the block function of a layer -> (x, what the sparse layers gave, in
+    layer order across the groups (`in_layer_order`), or None where there
+    is no sparse layer)."""
     found = []
     for group, kinds, sparse, _ in trunk:
         x, ys = scan_periods([block_of(kind, sparse) for kind in kinds], x,
                              params[group], remat)
         if sparse:
             found.append(in_layer_order(ys))
-    return x, found
+    if not found:
+        return x, None
+    return x, jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *found)
 
 
 def in_layer_order(found):
@@ -317,72 +320,6 @@ def in_layer_order(found):
         return found
     return jax.tree_util.tree_map(
         lambda *a: jnp.stack(a, 1).reshape((-1,) + a[0].shape[1:]), *found)
-
-
-def layer_leaves(params, groups: Sequence[str], key: str) -> list:
-    """The leaf `key` of every layer of `params[group]` for each of the
-    `groups`, one array [layers, ...] a group in layer order
-    (`in_layer_order`)."""
-    return [in_layer_order(layers[key] if isinstance(layers, dict)
-                           else [layer[key] for layer in layers])
-            for layers in map(params.get, groups)]
-
-
-def put_layer_leaves(params, groups: Sequence[str], key: str, values):
-    """`layer_leaves`' inverse: `params` with the leaf `key` of every layer
-    of the groups taken from `values` [layers, ...], the groups' layers
-    one after the other in layer order -> (params, rows of values
-    taken)."""
-    at = 0
-    for group in groups:
-        layers = params[group]
-        if isinstance(layers, dict):
-            count = layers[key].shape[0]
-            moved = dict(layers, **{key: values[at:at + count]})
-        else:       # a list, a position of the period each: interleaved
-            periods, count = layers[0][key].shape[0], len(layers)
-            mine = values[at:at + periods * count].reshape(
-                (periods, count) + values.shape[1:])
-            moved = [dict(layer, **{key: mine[:, i]})
-                     for i, layer in enumerate(layers)]
-            count *= periods
-        params, at = dict(params, **{group: moved}), at + count
-    return params, at
-
-
-def router_groups(params) -> list:
-    """The groups of a trunk's parameters that hold routers, in layer
-    order."""
-    return [group for group in ("blocks", "sparse", "tail")
-            if group in params]
-
-
-def move_router_biases(params, ids, num_experts: int, rate: float,
-                       more=()):
-    """The trainer's `state_update` of a sigmoid router: `params` (master
-    weights) with the selection bias of every router of the trunk moved by
-    the load of the step that has just run (`moe.moved_biases`: the pairs
-    that the choices ids [routers, T, k] drew over ALL the experts,
-    counted over this chip's tokens), the trunk's routers in layer order
-    and then `more`, biases [E] of routers outside it -> (params, those of
-    `more` moved)."""
-    drawn = moe.pairs_drawn(ids, num_experts).astype(jnp.float32)
-    groups = router_groups(params)
-    biases = moe.moved_biases(jnp.concatenate(
-        layer_leaves(params, groups, "router_b") + list(more)), drawn, rate)
-    params, at = put_layer_leaves(params, groups, "router_b", biases)
-    return params, biases[at:] if len(more) else None
-
-
-def move_biases_only(state, ids, move: Callable):
-    """The train state after the biases' move of one step WITHOUT the step:
-    `move(tree, ids)` on master and parameters alike, ids the choices of a
-    forward pass on a batch; no gradient, no AdamW, the step count as it
-    was. What a deployment's thousands of steps do to the load (the rule
-    balances it, the weights hardly moving meanwhile) a benchmark can
-    reach in a few hundred of these before it times real steps."""
-    return dict(state, **{name: move(state[name], ids)
-                          for name in ("params", "master")})
 
 
 def layer_trunk(block_fn: Callable, mesh: Optional[Mesh], num_layers: int,

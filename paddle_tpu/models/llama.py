@@ -33,7 +33,8 @@ compiles none of it (the dense presets below leave out all of them):
   selection bias that only steers the choice, the chosen scores
   renormalised and times `moe_routed_scaling_factor`
   (`moe.sigmoid_topk_route`), no balance term, and the bias moved after
-  each step by the load (`router_bias_update_rate`, `moe.moved_biases`);
+  each step by the load (`router_bias_update_rate`,
+  `routed.move_router_biases`);
 - a shared expert beside the routed ones (`shared_expert_intermediate_size`),
   added ungated;
 - leading dense layers in a routed model (`first_k_dense`) and query heads
@@ -51,6 +52,8 @@ compiles none of it (the dense presets below leave out all of them):
 `experts_held` is a chip's share of a layer that several chips divide by
 expert parallelism: the routed output is then a partial sum over the held
 experts, and nothing here stands in for the absent chips. None means all.
+The routed feed-forward, the selection biases' move and the one-chip step
+are routed.py's, which the latent family (mla_moe.py) shares.
 """
 from __future__ import annotations
 
@@ -63,14 +66,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops import moe
-from . import stages
-from .blocks import (attention, layer_leaves, layer_trunk, lm_head_loss,
-                     move_router_biases, normal, rms_norm, rope,
-                     router_groups, scan_periods, scan_segments, segments,
-                     swiglu, yarn_inv_freq)
-from .blocks import move_biases_only as blocks_move_biases_only
-from .trainer import build_adamw_train_step
+from . import routed, stages
+from .blocks import (attention, layer_trunk, lm_head_loss, normal, rms_norm,
+                     rope, scan_periods, scan_segments, segments, swiglu,
+                     yarn_inv_freq)
 
 FULL, WINDOW = "full_attention", "sliding_attention"
 
@@ -214,7 +213,7 @@ def _layers(ks, key, L: int, c: LlamaConfig, heads: int, sparse: bool):
                      "up_w": normal(ks[6], (L, n, h, f), std, dt),
                      "down_w": normal(ks[7], (L, n, f, h), out_std, dt)})
         if c.router == "sigmoid":
-            # no gradient reaches it: `_move_router_biases` moves it
+            # no gradient reaches it: `routed.move_router_biases` moves it
             blocks["router_b"] = jnp.zeros((L, c.num_experts), jnp.float32)
         fs = c.shared_expert_intermediate_size
         if fs:
@@ -258,10 +257,9 @@ def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
 
 def param_specs(config: LlamaConfig, pp: Optional[str] = None) -> Dict:
     """Megatron TP layout: q/k/v/gate/up column-split, o/down row-split.
-    The routed feed-forward runs one chip's share (`build_train_step`):
-    its leaves are replicated, and so is every leaf of a trunk in
-    `segments`, which runs on one chip alone."""
-    if config.segments is not None:
+    A routed feed-forward or a trunk in `segments` runs one chip's share
+    (`routed.build_train_step`): every leaf replicated."""
+    if config.sparse or config.segments is not None:
         return jax.tree_util.tree_map(lambda _: P(), jax.eval_shape(
             lambda: init_llama_params(config, 0)))
     blocks = {
@@ -269,20 +267,11 @@ def param_specs(config: LlamaConfig, pp: Optional[str] = None) -> Dict:
         "q_w": P(pp, None, "mp"), "k_w": P(pp, None, "mp"),
         "v_w": P(pp, None, "mp"), "o_w": P(pp, "mp", None),
         "ln2_g": P(pp, None),
+        "gate_w": P(pp, None, "mp"), "up_w": P(pp, None, "mp"),
+        "down_w": P(pp, "mp", None),
     }
     if config.gating:
         blocks["attn_gate_w"] = P(pp, None, "mp")
-    if config.sparse:
-        blocks.update(router_w=P(), experts={
-            "gate_w": P(), "up_w": P(), "down_w": P()})
-        if config.router == "sigmoid":
-            blocks["router_b"] = P()
-        if config.shared_expert_intermediate_size:
-            blocks.update(shared_gate_w=P(), shared_up_w=P(),
-                          shared_down_w=P())
-    else:
-        blocks.update(gate_w=P(pp, None, "mp"), up_w=P(pp, None, "mp"),
-                      down_w=P(pp, "mp", None))
     specs = {"wte": P("mp", None), "blocks": blocks, "lnf_g": P(None)}
     if not config.tie_embeddings:
         specs["lm_head"] = P("mp", None)
@@ -347,28 +336,18 @@ def _rotary_dim(config: LlamaConfig, kind: str) -> Optional[int]:
     return None if rot == config.head_dim else rot
 
 
+def _routing(c: LlamaConfig) -> dict:
+    """The arguments of `routed.routed_part` from the configuration."""
+    return dict(k=c.num_experts_per_tok, scale=c.moe_routed_scaling_factor,
+                held=c.held, experts=c.num_experts)
+
+
 def _routed_ffn(y, blk, c: LlamaConfig):
     """The held routed experts' part on y [B, S, h], already normed ->
-    (that, the router's record): the softmax router's {"balance": the
-    layer's E * sum_e F_e P_e, "pairs": the pairs each of ALL the experts
-    drew [E]}, the sigmoid router's choices [B*S, k]."""
+    (that, the router's record; `routed.routed_part`)."""
     b, s, h = y.shape
-    flat = y.reshape(b * s, h)
-    with jax.named_scope(stages.ROUTER):
-        if c.router == "sigmoid":
-            ids, weights = moe.sigmoid_topk_route(
-                flat, blk["router_w"], blk["router_b"],
-                c.num_experts_per_tok, c.moe_routed_scaling_factor)
-            record = ids
-        else:
-            ids, weights, probs = moe.softmax_topk_route(
-                flat, blk["router_w"], c.num_experts_per_tok)
-            balance, drawn = moe.balance_term(ids, probs)
-            record = {"balance": balance, "pairs": drawn}
-    with jax.named_scope(stages.EXPERTS):
-        routed = moe.held_experts_ffn(flat, ids, weights, blk["experts"],
-                                      c.held, c.num_experts)
-    return routed.reshape(b, s, h), record
+    out, record = routed.routed_part(y.reshape(b * s, h), blk, **_routing(c))
+    return out.reshape(b, s, h), record
 
 
 def _attention(x, blk, c: LlamaConfig, kind: str):
@@ -410,17 +389,13 @@ def _block(x, blk, config: LlamaConfig, kind: str = FULL,
     attn = _attention(x, blk, c, kind)
     with jax.named_scope(stages.ATTN_OUT):
         x = x + jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
+    if sparse:
+        y, record = routed.feed_forward(x, blk, c.rms_norm_eps, **_routing(c))
+        with jax.named_scope(stages.MLP):
+            return x + y, record
     with jax.named_scope(stages.MLP):
         y = rms_norm(x, blk["ln2_g"], c.rms_norm_eps)
-        if not sparse:
-            return x + swiglu(y, blk["gate_w"], blk["up_w"],
-                              blk["down_w"]), None
-        shared = swiglu(y, blk["shared_gate_w"], blk["shared_up_w"],
-                        blk["shared_down_w"]) \
-            if c.shared_expert_intermediate_size else None
-    y, record = _routed_ffn(y, blk, c)
-    with jax.named_scope(stages.MLP):
-        return x + (y if shared is None else shared + y), record
+        return x + swiglu(y, blk["gate_w"], blk["up_w"], blk["down_w"]), None
 
 
 def layer_attention(params, x, n: int, config: LlamaConfig):
@@ -441,18 +416,6 @@ def layer_attention(params, x, n: int, config: LlamaConfig):
     raise ValueError(f"the trunk has {at} layers, no layer {n}")
 
 
-def _trunk(params, x, config: LlamaConfig, remat):
-    """x [B, S, H] through the segments of the trunk in order ->
-    (x, the routed layers' records in layer order or None)."""
-    x, found = scan_segments(
-        lambda kind, sparse: functools.partial(
-            _block, config=config, kind=kind, sparse=sparse),
-        config.segments, x, params, remat)
-    if not found:
-        return x, None
-    return x, jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *found)
-
-
 def _hidden(params, tokens, config: LlamaConfig, remat, pp_trunk):
     """tokens [B, S] -> (the final norm's output [B, S, H], the head, the
     routers' records stacked over the layers or None)."""
@@ -462,7 +425,10 @@ def _hidden(params, tokens, config: LlamaConfig, remat, pp_trunk):
     if pp_trunk is not None:
         x = pp_trunk(params["blocks"], x)
     elif config.segments is not None:
-        x, records = _trunk(params, x, config, remat)
+        x, records = scan_segments(
+            lambda kind, sparse: functools.partial(
+                _block, config=config, kind=kind, sparse=sparse),
+            config.segments, x, params, remat)
     else:
         x, records = scan_periods(
             [functools.partial(_block, config=config, kind=kind)
@@ -519,24 +485,16 @@ def step_facts(params, tokens, labels, config: LlamaConfig):
     if config.router == "sigmoid":
         ids = aux.pop("ids")
         return dict(aux, loss=loss,
-                    pairs=moe.pairs_drawn(ids, config.num_experts),
-                    biases=jnp.concatenate(layer_leaves(
-                        params, router_groups(params), "router_b")))
+                    **routed.facts(params, ids, config.num_experts))
     return dict(aux, loss=loss)
 
 
-def _move_router_biases(master, ids, config: LlamaConfig):
-    """The trainer's `state_update` (`blocks.move_router_biases`)."""
-    return move_router_biases(master, ids, config.num_experts,
-                              config.router_bias_update_rate)[0]
-
-
 def move_biases_only(state, tokens, labels, config: LlamaConfig):
-    """`blocks.move_biases_only` after a forward pass on the batch. Jit it
+    """`routed.move_biases_only` after a forward pass on the batch. Jit it
     with the state donated."""
     _, aux = loss_parts(state["params"], tokens, labels, config, remat=False)
-    return blocks_move_biases_only(state, aux["ids"], functools.partial(
-        _move_router_biases, config=config))
+    return routed.move_biases_only(state, aux["ids"], config.num_experts,
+                                   config.router_bias_update_rate)
 
 
 def move_routers_only(state, tokens, labels, config: LlamaConfig,
@@ -574,27 +532,19 @@ def build_train_step(config: LlamaConfig, mesh: Optional[Mesh] = None, *,
                      remat: bool = True,
                      pp_microbatches: Optional[int] = None, **adamw):
     """(init_fn, step): ONE compiled XLA program a step through
-    `trainer.build_adamw_train_step`. A routed feed-forward or layers of
-    two kinds run one chip's share without its exchange; on a mesh of
-    several chips they need an `ep` axis (and a pipeline whose stage holds
-    a period), which the trainer does not have yet."""
-    several = mesh is not None and mesh.size > 1
-    if several and (config.sparse or len(config.period) > 1
-                    or config.segments is not None):
-        raise NotImplementedError(
-            "llama.build_train_step runs a routed feed-forward or mixed "
-            "layer kinds as one chip's share; experts over an 'ep' mesh "
-            "axis are not implemented")
+    `routed.build_train_step`, which runs a routed feed-forward or layers
+    of two kinds as one chip's share and refuses a mesh of several chips
+    for them; a dense trunk of one kind runs on any mesh, pipelined over a
+    `pp` axis."""
     pp_trunk = layer_trunk(functools.partial(_block, config=config), mesh,
                            config.num_layers, remat, pp_microbatches)
-    moves = config.sparse and config.router == "sigmoid" \
-        and bool(config.router_bias_update_rate)
-    return build_adamw_train_step(
+    return routed.build_train_step(
         functools.partial(llama_loss, config=config, remat=remat,
-                          pp_trunk=pp_trunk, want_ids=moves),
+                          pp_trunk=pp_trunk),
         functools.partial(init_llama_params, config),
         param_specs(config, pp=None if pp_trunk is None else "pp"),
-        wd_mask(config), mesh=mesh,
-        state_update=functools.partial(
-            _move_router_biases, config=config) if moves else None,
+        wd_mask(config), mesh,
+        share=config.sparse or len(config.period) > 1
+        or config.segments is not None,
+        experts=config.num_experts, rate=config.router_bias_update_rate,
         **adamw)

@@ -42,8 +42,8 @@ compiles none of it:
   2024, arXiv:2408.15664; DeepSeek-V3 section 2.1.2): after each step an
   expert that drew more than the mean of the step's pairs has its bias
   lowered by the rate, one that drew fewer has it raised. No gradient, no
-  AdamW: `trainer.build_adamw_train_step`'s `state_update`. 0 holds the
-  bias where it is;
+  AdamW: `trainer.build_adamw_train_step`'s `state_update`
+  (`routed.move_router_biases`). 0 holds the bias where it is;
 - a multi-token-prediction module of depth 1 (`mtp_layers` 1; DeepSeek-V3
   section 2.2): one more sparse layer behind the last, on parameters of its
   own, fed the projection of [norm(hidden) ; norm(embedding of the next
@@ -60,7 +60,9 @@ Same compiled-trainer machinery as gpt.py / llama.py: parameters of like
 layers stacked on a leading axis and scanned under whole-block
 `jax.checkpoint`, bf16 compute with fp32 master weights
 (`trainer.build_adamw_train_step`); RoPE, RMSNorm, SwiGLU, the attention
-core, the loss head and the layer scan are blocks.py's.
+core, the loss head and the layer scan are blocks.py's; the routed
+feed-forward, the router's facts, the biases' move and the one-chip step
+are routed.py's, which the decoder family (llama.py) shares.
 """
 from __future__ import annotations
 
@@ -73,16 +75,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops import moe
 from ..ops.linear_attention import chunk_gated_delta_rule
 from ..ops.pallas import kda_elementwise, stream_mix
-from . import stages
-from .blocks import (attention, layer_leaves, lm_head_loss,
-                     move_router_biases, normal, rms_norm, rope,
-                     router_groups, scan_layers, scan_segments, segments,
-                     swiglu, yarn_inv_freq)
-from .blocks import move_biases_only as blocks_move_biases_only
-from .trainer import build_adamw_train_step
+from . import routed, stages
+from .blocks import (attention, lm_head_loss, normal, rms_norm, rope,
+                     scan_layers, scan_segments, segments, swiglu,
+                     yarn_inv_freq)
 
 
 FULL, LINEAR = "full", "linear"        # the kinds of attention layer
@@ -259,7 +257,7 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool,
     p.update(
         # the router is float32, as the family's checkpoints keep it; its
         # selection bias starts at zero and no gradient reaches it (what
-        # moves it, if anything does, is `_move_router_biases`)
+        # moves it, if anything does, is `routed.move_router_biases`)
         router_w=norm((h, c.n_routed_experts), dtype=jnp.float32),
         router_b=jnp.zeros((layers, c.n_routed_experts), jnp.float32),
         shared_gate_w=norm((h, fs)), shared_up_w=norm((h, fs)),
@@ -621,23 +619,13 @@ def _dense_ffn(y, blk, c: MlaMoeConfig):
 
 
 def _sparse_ffn(y, blk, c: MlaMoeConfig):
-    """Shared expert + the held routed experts' part; aux = the router's
-    choices [B*S, k], over all the experts."""
-    b, s, h = y.shape
-    with jax.named_scope(stages.MLP):
-        y = rms_norm(y, blk["ln2_g"], c.rms_norm_eps)
-        shared = swiglu(y, blk["shared_gate_w"], blk["shared_up_w"],
-                        blk["shared_down_w"])
-    flat = y.reshape(b * s, h)
-    with jax.named_scope(stages.ROUTER):
-        ids, weights = moe.sigmoid_topk_route(
-            flat, blk["router_w"], blk["router_b"], c.num_experts_per_tok,
-            c.routed_scaling_factor)
-    with jax.named_scope(stages.EXPERTS):
-        routed = moe.held_experts_ffn(flat, ids, weights, blk["experts"],
-                                      c.held, c.n_routed_experts)
-    with jax.named_scope(stages.MLP):
-        return shared + routed.reshape(b, s, h), ids
+    """Shared expert + the held routed experts' part
+    (`routed.feed_forward`); aux = the router's choices [B*S, k], over all
+    the experts."""
+    return routed.feed_forward(
+        y, blk, c.rms_norm_eps, k=c.num_experts_per_tok,
+        scale=c.routed_scaling_factor, held=c.held,
+        experts=c.n_routed_experts)
 
 
 def _block(x, blk, c: MlaMoeConfig, sparse: bool, want_ids: bool,
@@ -688,10 +676,7 @@ def _trunk(params, tokens, c: MlaMoeConfig, remat: bool, want_ids: bool):
             _block, c=c, sparse=sparse, want_ids=want_ids and sparse,
             kind=kind), c.segments, x, params, remat)
     with jax.named_scope(stages.LOSS_HEAD):
-        if not want_ids:
-            return _gather(x, c), None
-        return _gather(x, c), \
-            ids[0] if len(ids) == 1 else jnp.concatenate(ids)
+        return _gather(x, c), ids
 
 
 def _mtp_loss(params, hidden, labels, c: MlaMoeConfig, remat: bool,
@@ -716,14 +701,6 @@ def _mtp_loss(params, hidden, labels, c: MlaMoeConfig, remat: bool,
             [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], 1)
         return lm_head_loss(x, params["lm_head"], after_next,
                             ignore_negative=True), ids
-
-
-def mla_moe_forward(params, tokens, config: MlaMoeConfig, remat=True):
-    """tokens [B, S] int32 -> logits [B, S, V] over the held rows."""
-    x, _ = _trunk(params, tokens, config, remat, want_ids=False)
-    with jax.named_scope(stages.LOSS_HEAD):
-        x = rms_norm(x, params["lnf_g"], config.rms_norm_eps)
-        return jnp.einsum("bsh,vh->bsv", x, params["lm_head"])
 
 
 def loss_parts(params, tokens, labels, config: MlaMoeConfig, remat=True,
@@ -753,18 +730,12 @@ def mla_moe_loss(params, tokens, labels, config: MlaMoeConfig, remat=True,
     return (loss, ids) if want_ids else loss
 
 
-def _pairs_drawn(ids, config: MlaMoeConfig):
-    """Choices [L, T, k] -> the pairs each of ALL the routed experts drew,
-    int32 [L, n_routed_experts]."""
-    return moe.pairs_drawn(ids, config.n_routed_experts)
-
-
 def routing_stats(params, tokens, config: MlaMoeConfig):
     """Per sparse layer of the trunk, the (token, expert) pairs each of ALL
     the routed experts drew on this batch: int32 [L_sparse,
     n_routed_experts]. Jit it; it runs the forward pass."""
     _, ids = _trunk(params, tokens, config, remat=False, want_ids=True)
-    return _pairs_drawn(ids, config)
+    return routed.facts(params, ids, config.n_routed_experts)["pairs"]
 
 
 def step_facts(params, tokens, labels, config: MlaMoeConfig):
@@ -774,41 +745,24 @@ def step_facts(params, tokens, labels, config: MlaMoeConfig):
     (+ 1 for the module's, last), n_routed_experts]. Jit it."""
     main, mtp, ids = loss_parts(params, tokens, labels, config, remat=False,
                                 want_ids=True)
-    facts = {"loss_main": main, "pairs": _pairs_drawn(ids, config),
-             "biases": _router_biases(params)}
+    facts = dict(loss_main=main,
+                 **routed.facts(params, ids, config.n_routed_experts))
     if mtp is not None:
         facts["loss_mtp"] = mtp
     return facts
 
 
-def _router_biases(params):
-    """[L_sparse (+ 1), n_routed_experts], in the order of the choices."""
-    found = layer_leaves(params, router_groups(params), "router_b")
-    if "mtp" in params:
-        found.append(params["mtp"]["layer"]["router_b"])
-    return jnp.concatenate(found)
-
-
-def _move_router_biases(master, ids, config: MlaMoeConfig):
-    """The trainer's `state_update` (`blocks.move_router_biases`), the
-    module's router after the trunk's."""
-    mtp = "mtp" in master
-    master, moved = move_router_biases(
-        master, ids, config.n_routed_experts, config.router_bias_update_rate,
-        [master["mtp"]["layer"]["router_b"]] if mtp else [])
-    if mtp:
-        layer = dict(master["mtp"]["layer"], router_b=moved)
-        master["mtp"] = dict(master["mtp"], layer=layer)
-    return master
+# [L_sparse (+ 1), n_routed_experts], in the order of the choices
+_router_biases = routed.router_biases
 
 
 def move_biases_only(state, tokens, labels, config: MlaMoeConfig):
-    """`blocks.move_biases_only` after a forward pass on the batch. Jit it
+    """`routed.move_biases_only` after a forward pass on the batch. Jit it
     with the state donated."""
     _, ids = mla_moe_loss(state["params"], tokens, labels, config,
                           remat=False, want_ids=True)
-    return blocks_move_biases_only(state, ids, functools.partial(
-        _move_router_biases, config=config))
+    return routed.move_biases_only(state, ids, config.n_routed_experts,
+                                   config.router_bias_update_rate)
 
 
 def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None, *,
@@ -816,20 +770,12 @@ def build_train_step(config: MlaMoeConfig, mesh: Optional[Mesh] = None, *,
     """(init_fn, step): step(state, tokens, labels) -> (state, loss) is ONE
     compiled XLA program (forward, backward through the rematted scans,
     AdamW, and where the configuration has a rate the selection biases'
-    move), through `trainer.build_adamw_train_step` as gpt.py's. One chip's
-    share runs without its exchange; a mesh of several chips needs an `ep`
-    axis and the all-to-all, which the trainer does not have yet."""
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(
-            "mla_moe.build_train_step runs one chip's share; experts over "
-            "an 'ep' mesh axis are not implemented")
+    move), through `routed.build_train_step`: one chip's share, without
+    its exchange."""
     init_params = functools.partial(init_mla_moe_params, config)
     shapes = jax.eval_shape(lambda: init_params(0))
-    specs = jax.tree_util.tree_map(lambda _: P(), shapes)
-    moves = bool(config.router_bias_update_rate)
-    return build_adamw_train_step(
-        functools.partial(mla_moe_loss, config=config, remat=remat,
-                          want_ids=moves),
-        init_params, specs, wd_mask(shapes), mesh=mesh,
-        state_update=functools.partial(
-            _move_router_biases, config=config) if moves else None, **adamw)
+    return routed.build_train_step(
+        functools.partial(mla_moe_loss, config=config, remat=remat),
+        init_params, jax.tree_util.tree_map(lambda _: P(), shapes),
+        wd_mask(shapes), mesh, share=True, experts=config.n_routed_experts,
+        rate=config.router_bias_update_rate, **adamw)

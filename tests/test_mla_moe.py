@@ -18,7 +18,7 @@ from benchmarks.cells import load_cell
 from benchmarks.layer_metrics import _stages
 from benchmarks.reference import mla_moe as ref
 from benchmarks.runners import mla_moe as runner
-from paddle_tpu.models import blocks
+from paddle_tpu.models import blocks, llama
 from paddle_tpu.models import mla_moe as m
 from paddle_tpu.models import stages
 from paddle_tpu.ops import moe
@@ -669,11 +669,18 @@ def test_the_step_trains_and_counts_its_routing(tiny, batch):
     assert (counts.sum(1) == BATCH * SEQ * c.num_experts_per_tok).all()
 
 
-def test_a_mesh_of_several_chips_is_refused(tiny):
+@pytest.mark.parametrize("family, cell", [
+    (m, "xing4-ep8share-pretrain-s2048"),
+    (llama, "mellum2-ep4share-pretrain-s4096")], ids=["latent", "decoder"])
+def test_a_mesh_of_several_chips_is_refused(family, cell):
+    """Both sparse families run a routed share on one chip, and refuse a
+    mesh of several in one place (`routed.build_train_step`)."""
     from jax.sharding import Mesh
-    mesh = Mesh(np.asarray(jax.devices()[:2]), ("dp",))
-    with pytest.raises(NotImplementedError, match="ep"):
-        m.build_train_step(tiny[1], mesh)
+    cell = load_cell(cell, tiny=True)
+    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(2, 1), ("dp", "mp"))
+    with pytest.raises(NotImplementedError, match="'ep' mesh axis"):
+        family.build_train_step(cell.runner.program_config(cell.config),
+                                mesh)
 
 
 def test_count_params_of_the_published_share():

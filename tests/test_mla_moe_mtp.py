@@ -20,8 +20,9 @@ from benchmarks.layer_metrics import _stages
 from benchmarks.reference import mla_moe_mtp as ref
 from benchmarks.runners import mla_moe as streams_runner
 from benchmarks.runners import mla_moe_mtp as runner
+from paddle_tpu.models import llama
 from paddle_tpu.models import mla_moe as m
-from paddle_tpu.models import stages, trainer
+from paddle_tpu.models import routed, stages, trainer
 
 CELL = "glm47f-ep8share-pretrain-s2048"
 BATCH, SEQ = 2, 64
@@ -180,22 +181,47 @@ def test_biases_after_one_step_agree_with_the_reference(tiny, stepped):
         assert not np.asarray(tree["mtp"]["layer"]["router_b"]).any()
 
 
-def test_the_bias_moves_against_the_load(tiny, batch):
-    _, c = tiny
-    params = m.init_mla_moe_params(c, 5)
-    facts = jax.jit(functools.partial(m.step_facts, config=c))(
+# the sigmoid-routed families that share `models/routed.py`: this module's
+# cell, and the decoder family's with a shared expert and a leading dense
+# layer (its trunk in segments, a list of trees a group)
+FAMILIES = {
+    "latent": (m, m.init_mla_moe_params, m.mla_moe_loss, CELL,
+               "n_routed_experts"),
+    "decoder": (llama, llama.init_llama_params, llama.llama_loss,
+                "laguna-ep8share-pretrain-s4096", "num_experts")}
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def family(request):
+    """(module, init, loss, program config, experts) of a family's tiny
+    sigmoid-routed configuration."""
+    module, init, loss, cell, experts = FAMILIES[request.param]
+    cell = load_cell(cell, tiny=True)
+    c = cell.runner.program_config(cell.config)
+    assert c.router_bias_update_rate and cell.config["vocab_size"] == 512
+    return module, init, loss, c, getattr(c, experts)
+
+
+def test_the_bias_moves_against_the_load(family, batch):
+    module, init, loss, c, experts = family
+    params = init(c, 5)
+    facts = jax.jit(functools.partial(module.step_facts, config=c))(
         params, *batch)
     pairs = np.asarray(facts["pairs"])
+    assert pairs.shape[1] == experts
     assert (pairs.sum(1) == BATCH * SEQ * c.num_experts_per_tok).all()
-    _, ids = m.mla_moe_loss(params, *batch, c, want_ids=True)
-    moved = m._move_router_biases(params, ids, c)
-    delta = np.asarray(m._router_biases(moved))
+    _, ids = loss(params, *batch, c, want_ids=True)
+    moved = routed.move_router_biases(params, ids, experts,
+                                      c.router_bias_update_rate)
+    delta = np.asarray(routed.router_biases(moved))
     mean = pairs.mean(1, keepdims=True)
     np.testing.assert_array_equal(
         delta, np.float32(c.router_bias_update_rate) * np.sign(mean - pairs))
     # only the biases were replaced
-    assert moved["wte"] is params["wte"]
-    assert moved["sparse"]["router_w"] is params["sparse"]["router_w"]
+    for (path, was), now in zip(
+            jax.tree_util.tree_leaves_with_path(params),
+            jax.tree_util.tree_leaves(moved)):
+        assert (path[-1].key == "router_b") == (now is not was), path
 
 
 def test_bias_only_moves_balance_the_load(tiny, batch):
@@ -225,19 +251,21 @@ def test_bias_only_moves_balance_the_load(tiny, batch):
     assert float(jnp.abs(m._router_biases(state["params"])).max()) > 0.01
 
 
-def test_rate_zero_leaves_the_biases_bit_equal(tiny, batch):
-    _, c = tiny
+def test_rate_zero_leaves_the_biases_bit_equal(family, batch):
+    module, _, _, c, _ = family
     held = dataclasses.replace(c, router_bias_update_rate=0.0)
-    init_fn, step = m.build_train_step(held, lr=1e-2)
+    init_fn, step = module.build_train_step(held, lr=1e-2)
     state = init_fn(1)
-    start = np.asarray(m._router_biases(state["params"])) + 0.25
-    for tree in (state["params"], state["master"]):
-        tree["sparse"]["router_b"] = jnp.asarray(start[:-1])
-        tree["mtp"]["layer"]["router_b"] = jnp.asarray(start[-1:])
+    for name in ("params", "master"):
+        state[name] = jax.tree_util.tree_map_with_path(
+            lambda path, a: a + 0.25 if path[-1].key == "router_b" else a,
+            state[name])
+    start = np.asarray(routed.router_biases(state["params"]))
+    assert start.shape[0] > 1 and (start == 0.25).all()
     for _ in range(3):
         state, loss = step(state, *batch)
     for tree in (state["params"], state["master"]):
-        np.testing.assert_array_equal(m._router_biases(tree), start)
+        np.testing.assert_array_equal(routed.router_biases(tree), start)
 
 
 def test_more_than_one_module_is_refused(tiny):

@@ -308,14 +308,6 @@ def test_moves_of_the_routers_alone_lower_the_balance_term(tiny):
         [changed["m"], changed["v"], changed["step"]]))
 
 
-def test_a_mesh_of_several_chips_is_refused_for_the_routed_share(tiny):
-    from jax.sharding import Mesh
-    _, c, *_ = tiny
-    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(2, 1), ("dp", "mp"))
-    with pytest.raises(NotImplementedError, match="'ep' mesh axis"):
-        llama.build_train_step(c, mesh)
-
-
 def test_the_dense_presets_compile_none_of_it():
     """No router, no window and one rotary table in a dense preset's step:
     its jaxpr names neither stage of the routed feed-forward."""
