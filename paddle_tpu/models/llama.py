@@ -47,7 +47,21 @@ compiles none of it (the dense presets below leave out all of them):
   input, under the stage `attn_gate`;
 - rotary on part of a head: a kind's `partial_rotary_factor` in
   `rope_parameters` rotates that share of each head's components (yarn's
-  frequencies over it) and passes the rest through.
+  frequencies over it) and passes the rest through;
+- no rotary at all (`rope_theta` None), as a hybrid whose linear layers
+  carry the positions;
+- linear-attention layers (`"linear_attention"` in `layer_types`): the
+  gated delta rule of `models/delta_layer.py` in its Gated DeltaNet form
+  (`linear_form`: a log-decay a head, a SiLU output gate, a write strength
+  up to 2 where `linear_allow_neg_eigval`), `linear_num_heads` heads of
+  `linear_key_head_dim` keys and `linear_value_head_dim` values, in the
+  trunk's segments beside the softmax layers, each position of a period on
+  a tree of its own;
+- Olmo's post-norm block (`post_norm`): x + RMSNorm(mixer(x)), then x +
+  RMSNorm(SwiGLU(x)), the mixer and the MLP reading the residual stream
+  itself; `ln1_g` and `ln2_g` are then the two norms after them;
+- a q/k norm (`qk_norm`): RMSNorm over the whole q projection and the whole
+  k projection (Olmo's), with gains `ln_q_g`, `ln_k_g`.
 
 `experts_held` is a chip's share of a layer that several chips divide by
 expert parallelism: the routed output is then a partial sum over the held
@@ -66,12 +80,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import routed, stages
+from ..ops.linear_attention import chunk_gated_delta_rule
+from . import delta_layer, routed, stages
 from .blocks import (attention, layer_trunk, lm_head_loss, normal, rms_norm,
                      rope, scan_periods, scan_segments, segments, swiglu,
                      yarn_inv_freq)
 
-FULL, WINDOW = "full_attention", "sliding_attention"
+FULL, WINDOW, LINEAR = ("full_attention", "sliding_attention",
+                         "linear_attention")
 
 
 @dataclasses.dataclass
@@ -85,7 +101,7 @@ class LlamaConfig:
     head_dim: Optional[int] = None            # None = hidden / heads
     max_position_embeddings: int = 2048
     rms_norm_eps: float = 1e-6
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0     # None = no rotary at all
     layer_types: Optional[Tuple[str, ...]] = None   # a name a layer; None
     #                                           = every layer full
     sliding_window: Optional[int] = None      # keys a window layer sees
@@ -108,6 +124,14 @@ class LlamaConfig:
     router: str = "softmax"                   # | "sigmoid", with a bias
     moe_routed_scaling_factor: float = 1.0    # the sigmoid router's weights
     router_bias_update_rate: float = 0.0      # gamma; 0 = the bias is held
+    linear_num_heads: int = 0                 # a linear_attention layer's
+    linear_key_head_dim: int = 0              # heads, their keys' and
+    linear_value_head_dim: int = 0            # values' widths,
+    linear_conv_kernel_dim: int = 4           # the short convolutions' taps
+    linear_allow_neg_eigval: bool = False     # beta up to 2
+    post_norm: bool = False                   # Olmo's block
+    qk_norm: bool = False                     # RMSNorm over q's and k's
+    #                                           whole projections
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -115,10 +139,16 @@ class LlamaConfig:
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             if len(self.layer_types) != self.num_layers \
-                    or set(self.layer_types) - {FULL, WINDOW}:
+                    or set(self.layer_types) - {FULL, WINDOW, LINEAR}:
                 raise ValueError(
                     f"layer_types must name each of the {self.num_layers} "
-                    f"layers {FULL!r} or {WINDOW!r}: {self.layer_types}")
+                    f"layers {FULL!r}, {WINDOW!r} or {LINEAR!r}: "
+                    f"{self.layer_types}")
+            if LINEAR in self.layer_types and not (
+                    self.linear_num_heads and self.linear_key_head_dim
+                    and self.linear_value_head_dim):
+                raise ValueError("a linear_attention layer needs its heads "
+                                 "and their key and value widths")
             if WINDOW in self.layer_types and not self.sliding_window:
                 raise ValueError("a sliding_attention layer needs a "
                                  "sliding_window")
@@ -127,6 +157,9 @@ class LlamaConfig:
         if self.first_k_dense and not self.sparse:
             raise ValueError("leading dense layers need a routed "
                              "feed-forward after them")
+        if self.post_norm and self.sparse:
+            raise NotImplementedError("the post-norm block has a dense "
+                                      "feed-forward")
         if any(n % self.kv_heads for n in (self.heads_by_kind or {}).values()):
             raise ValueError(f"{self.kv_heads} K/V heads do not divide "
                              f"every kind's query heads: {self.heads_by_kind}")
@@ -160,10 +193,12 @@ class LlamaConfig:
     def segments(self):
         """None where every layer shares one parameter tree, stacked over
         all the layers under "blocks" (`period` is then the scan's); with
-        leading dense layers or heads by kind the trunk in order, as
-        `blocks.segments` gives it (group, kinds of one period, sparse,
-        periods), each group a list of trees, one a position."""
-        if not self.first_k_dense and self.heads_by_kind is None:
+        leading dense layers, heads by kind or linear-attention layers the
+        trunk in order, as `blocks.segments` gives it (group, kinds of one
+        period, sparse, periods), each group a list of trees, one a
+        position."""
+        if not self.first_k_dense and self.heads_by_kind is None \
+                and LINEAR not in (self.layer_types or ()):
             return None
         return [(group, kinds, sparse and self.sparse, periods)
                 for group, kinds, sparse, periods in segments(
@@ -182,25 +217,40 @@ LLAMA_CONFIGS = {
 }
 
 
-def _layers(ks, key, L: int, c: LlamaConfig, heads: int, sparse: bool):
+def _layers(ks, key, L: int, c: LlamaConfig, heads: int, sparse: bool,
+            kind: str = FULL):
     """`L` like layers of `heads` query heads, stacked on a leading axis:
     the matrices from the split keys `ks` and the router from `key` folded
     with 9, as the stacked layout has always drawn them, and what the
-    options add from `key` folded with 10 and up."""
+    options add from `key` folded with 10 and up. A linear-attention
+    layer's own parameters are `delta_layer.init_params`', from `key`
+    folded with 12, beside an output projection from its H d_v."""
     h, f = c.hidden_size, c.intermediate_size
     qh, kvh = heads * c.head_dim, c.kv_heads * c.head_dim
     dt = jnp.dtype(c.dtype)
     std = c.initializer_range
     out_std = std / math.sqrt(2 * c.num_layers)
-    blocks = {
-        "ln1_g": jnp.ones((L, h), dt),
-        "q_w": normal(ks[1], (L, h, qh), std, dt),
-        "k_w": normal(ks[2], (L, h, kvh), std, dt),
-        "v_w": normal(ks[3], (L, h, kvh), std, dt),
-        "o_w": normal(ks[4], (L, qh, h), out_std, dt),
-        "ln2_g": jnp.ones((L, h), dt),
-    }
-    if c.gating:
+    if kind == LINEAR:
+        form = linear_form(c)
+        blocks = {"ln1_g": jnp.ones((L, h), dt),
+                  **delta_layer.init_params(jax.random.fold_in(key, 12), L,
+                                            form, h, std, dt),
+                  "o_w": normal(ks[4], (L, form.heads * form.value_dim, h),
+                                out_std, dt),
+                  "ln2_g": jnp.ones((L, h), dt)}
+    else:
+        blocks = {
+            "ln1_g": jnp.ones((L, h), dt),
+            "q_w": normal(ks[1], (L, h, qh), std, dt),
+            "k_w": normal(ks[2], (L, h, kvh), std, dt),
+            "v_w": normal(ks[3], (L, h, kvh), std, dt),
+            "o_w": normal(ks[4], (L, qh, h), out_std, dt),
+            "ln2_g": jnp.ones((L, h), dt),
+        }
+        if c.qk_norm:
+            blocks.update(ln_q_g=jnp.ones((L, qh), dt),
+                          ln_k_g=jnp.ones((L, kvh), dt))
+    if c.gating and kind != LINEAR:
         blocks["attn_gate_w"] = normal(jax.random.fold_in(key, 10),
                                        (L, h, qh), std, dt)
     if sparse:
@@ -248,7 +298,7 @@ def init_llama_params(config: LlamaConfig, seed: int = 0) -> Dict:
             mine = jax.random.fold_in(jax.random.fold_in(key, 20 + n), i)
             params[group].append(_layers(
                 jax.random.split(mine, 9), mine, periods, c, c.heads(kind),
-                sparse))
+                sparse, kind))
     params["lnf_g"] = jnp.ones((h,), dt)
     if not c.tie_embeddings:
         params["lm_head"] = normal(ks[8], (c.vocab_size, h), std, dt)
@@ -272,6 +322,8 @@ def param_specs(config: LlamaConfig, pp: Optional[str] = None) -> Dict:
     }
     if config.gating:
         blocks["attn_gate_w"] = P(pp, None, "mp")
+    if config.qk_norm:
+        blocks.update(ln_q_g=P(pp, None), ln_k_g=P(pp, None))
     specs = {"wte": P("mp", None), "blocks": blocks, "lnf_g": P(None)}
     if not config.tie_embeddings:
         specs["lm_head"] = P("mp", None)
@@ -279,11 +331,12 @@ def param_specs(config: LlamaConfig, pp: Optional[str] = None) -> Dict:
 
 
 def wd_mask(config: LlamaConfig) -> Dict:
-    """Weight decay on the matrices and the embeddings, none on the norm
-    gains or a router's selection bias."""
+    """Weight decay on the matrices (`*_w`) and the embeddings; none on the
+    norm gains, a router's selection bias or a linear layer's `decay_log`
+    and `dt_bias`."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: not path[-1].key.startswith("ln")
-        and path[-1].key != "router_b",
+        lambda path, _: path[-1].key in ("wte", "lm_head")
+        or path[-1].key.endswith("_w"),
         param_specs(config), is_leaf=lambda x: isinstance(x, P))
 
 
@@ -336,6 +389,18 @@ def _rotary_dim(config: LlamaConfig, kind: str) -> Optional[int]:
     return None if rot == config.head_dim else rot
 
 
+def linear_form(c: LlamaConfig) -> delta_layer.DeltaForm:
+    """A linear_attention layer's form (`models/delta_layer.py`): Gated
+    DeltaNet, a log-decay a head, a SiLU output gate from one product,
+    write strengths up to 2 where `linear_allow_neg_eigval`."""
+    return delta_layer.DeltaForm(
+        heads=c.linear_num_heads, key_dim=c.linear_key_head_dim,
+        value_dim=c.linear_value_head_dim, taps=c.linear_conv_kernel_dim,
+        norm_eps=c.rms_norm_eps, decay=delta_layer.HEAD,
+        gate=delta_layer.SILU,
+        beta_scale=2.0 if c.linear_allow_neg_eigval else 1.0)
+
+
 def _routing(c: LlamaConfig) -> dict:
     """The arguments of `routed.routed_part` from the configuration."""
     return dict(k=c.num_experts_per_tok, scale=c.moe_routed_scaling_factor,
@@ -351,22 +416,29 @@ def _routed_ffn(y, blk, c: LlamaConfig):
 
 
 def _attention(x, blk, c: LlamaConfig, kind: str):
-    """The attention sub-layer of `kind` on x [B, S, H], the block's input:
-    its output before W_o, gated where the configuration gates,
-    [B, S, heads * head_dim]."""
+    """The attention sub-layer of `kind` on x [B, S, H], the block's input
+    (normed here, but in the post-norm block): its output before W_o,
+    gated where the configuration gates, [B, S, heads * head_dim]."""
     b, s, _ = x.shape
     nh, nkv, d = c.heads(kind), c.kv_heads, c.head_dim
     theta, inv_freq, factor = _rotary(c, kind)
     rot = _rotary_dim(c, kind)
 
     with jax.named_scope(stages.ATTN_QKV):
-        y = rms_norm(x, blk["ln1_g"], c.rms_norm_eps)
+        y = x if c.post_norm else rms_norm(x, blk["ln1_g"], c.rms_norm_eps)
         q = jnp.einsum("bsh,hk->bsk", y, blk["q_w"])
         k = jnp.einsum("bsh,hk->bsk", y, blk["k_w"])
         v = jnp.einsum("bsh,hk->bsk", y, blk["v_w"])
+        if c.qk_norm:
+            q = rms_norm(q, blk["ln_q_g"], c.rms_norm_eps)
+            k = rms_norm(k, blk["ln_k_g"], c.rms_norm_eps)
     with jax.named_scope(stages.ATTN_CORE):
-        q = rope(q.reshape(b, s, nh, d), theta, inv_freq, factor, rot)
-        k = rope(k.reshape(b, s, nkv, d), theta, inv_freq, factor, rot)
+        def rotated(x, heads):
+            x = x.reshape(b, s, heads, d)
+            return x if theta is None else rope(x, theta, inv_freq, factor,
+                                                rot)
+
+        q, k = rotated(q, nh), rotated(k, nkv)
         attn = attention(q, k, v.reshape(b, s, nkv, d), causal=True,
                          scale=1.0 / math.sqrt(d),
                          flash=True,
@@ -381,21 +453,45 @@ def _attention(x, blk, c: LlamaConfig, kind: str):
 
 def _block(x, blk, config: LlamaConfig, kind: str = FULL,
            sparse: Optional[bool] = None):
-    """Pre-norm decoder block of `kind`: x [B, S, H] -> (x, None, or the
-    router's record where the feed-forward is routed: `sparse`, None =
-    the configuration's)."""
+    """Decoder block of `kind`: x [B, S, H] -> (x, None, or the router's
+    record where the feed-forward is routed: `sparse`, None = the
+    configuration's). Pre-norm, or Olmo's post-norm (`post_norm`)."""
     c = config
     sparse = c.sparse if sparse is None else sparse
-    attn = _attention(x, blk, c, kind)
+    eps = c.rms_norm_eps
+    if kind == LINEAR:
+        out = delta_layer.linear_attention(x, blk, linear_form(c), c.dtype,
+                                           pre_norm=not c.post_norm)
+    else:
+        attn = _attention(x, blk, c, kind)
     with jax.named_scope(stages.ATTN_OUT):
-        x = x + jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
+        if kind != LINEAR:
+            out = jnp.einsum("bsh,hk->bsk", attn, blk["o_w"])
+        x = x + (rms_norm(out, blk["ln1_g"], eps) if c.post_norm else out)
     if sparse:
-        y, record = routed.feed_forward(x, blk, c.rms_norm_eps, **_routing(c))
+        y, record = routed.feed_forward(x, blk, eps, **_routing(c))
         with jax.named_scope(stages.MLP):
             return x + y, record
     with jax.named_scope(stages.MLP):
-        y = rms_norm(x, blk["ln2_g"], c.rms_norm_eps)
+        if c.post_norm:
+            return x + rms_norm(swiglu(x, blk["gate_w"], blk["up_w"],
+                                       blk["down_w"]), blk["ln2_g"], eps), \
+                None
+        y = rms_norm(x, blk["ln2_g"], eps)
         return x + swiglu(y, blk["gate_w"], blk["up_w"], blk["down_w"]), None
+
+
+def _layer(params, n: int, config: LlamaConfig):
+    """(layer n's parameters, its kind) in a trunk of segments."""
+    at = 0
+    for group, kinds, _, periods in config.segments:
+        if n < at + len(kinds) * periods:
+            period, position = divmod(n - at, len(kinds))
+            return jax.tree_util.tree_map(
+                lambda a: a[period], params[group][position]), \
+                kinds[position]
+        at += len(kinds) * periods
+    raise ValueError(f"the trunk has {at} layers, no layer {n}")
 
 
 def layer_attention(params, x, n: int, config: LlamaConfig):
@@ -404,16 +500,25 @@ def layer_attention(params, x, n: int, config: LlamaConfig):
     kernels, the partial rotary and the gate of the timed step), for a
     check of the layer against a reference forward and backward. The
     trunk must be segments (`config.segments`). Jit it."""
-    c, at = config, 0
-    for group, kinds, _, periods in c.segments:
-        if n < at + len(kinds) * periods:
-            period, position = divmod(n - at, len(kinds))
-            blk = jax.tree_util.tree_map(lambda a: a[period],
-                                         params[group][position])
-            return _attention(x.astype(jnp.dtype(c.dtype)), blk, c,
-                              kinds[position])
-        at += len(kinds) * periods
-    raise ValueError(f"the trunk has {at} layers, no layer {n}")
+    c = config
+    blk, kind = _layer(params, n, c)
+    return _attention(x.astype(jnp.dtype(c.dtype)), blk, c, kind)
+
+
+def layer_rule(params, x, n: int, config: LlamaConfig):
+    """The chunked rule as layer n, a linear-attention layer, runs it on x
+    [B, S, H] handed in as that layer's input: the rule's output [B, S, H,
+    d_v], from the layer's own projections, convolutions, norms and
+    decays, for a check of the rule alone, forward and backward, against
+    the recurrence it stands for. Jit it."""
+    c = config
+    blk, kind = _layer(params, n, c)
+    if kind != LINEAR:
+        raise ValueError(f"layer {n} is a {kind} layer")
+    operands, _ = delta_layer.rule_operands(
+        x.astype(jnp.dtype(c.dtype)), blk, linear_form(c), c.dtype,
+        pre_norm=not c.post_norm)
+    return chunk_gated_delta_rule(*operands)
 
 
 def _hidden(params, tokens, config: LlamaConfig, remat, pp_trunk):

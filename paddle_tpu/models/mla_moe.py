@@ -23,7 +23,9 @@ manifold-constrained hyper-connections, arXiv:2512.24880):
   convolution and SiLU, q and k normalised a head, a log-decay per head AND
   key channel from a low-rank projection, a write strength a head, the gated
   delta rule in its chunked form (ops/linear_attention.py) and a gated
-  RMSNorm a head before the output projection. The feed-forward half of a
+  RMSNorm a head before the output projection; the layer is
+  `models/delta_layer.py`'s, which the decoder family (llama.py) shares, in
+  its KDA form (`linear_form`). The feed-forward half of a
   layer is the same under both. The trunk is then the leading dense layers,
   `blocks.scan_periods` over the whole periods of the sparse layers'
   pattern and a tail of what is left, each position of a period on a
@@ -76,15 +78,14 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.linear_attention import chunk_gated_delta_rule
-from ..ops.pallas import kda_elementwise, stream_mix
-from . import routed, stages
+from ..ops.pallas import stream_mix
+from . import delta_layer, routed, stages
 from .blocks import (attention, lm_head_loss, normal, rms_norm, rope,
                      scan_layers, scan_segments, segments, swiglu,
                      yarn_inv_freq)
 
 
 FULL, LINEAR = "full", "linear"        # the kinds of attention layer
-QK_NORM_EPS = 1e-6      # under the root of a linear layer's q and k norms
 
 
 @dataclasses.dataclass
@@ -229,7 +230,8 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool,
 
     p = {"ln1_g": jnp.ones((layers, h), dt)}
     if kind == LINEAR:
-        p.update(_init_linear(jax.random.fold_in(key, 1), layers, c))
+        p.update(delta_layer.init_params(jax.random.fold_in(key, 1), layers,
+                                         linear_form(c), h, std, dt))
         width = c.linear_heads * c.linear_head_dim
     else:
         if c.q_lora_rank is None:
@@ -267,39 +269,14 @@ def _init_layers(key, layers: int, c: MlaMoeConfig, sparse: bool,
     return p
 
 
-def _init_linear(key, layers: int, c: MlaMoeConfig):
-    """A LINEAR layer's own parameters, from keys of their own. The
-    projections at `initializer_range`; the convolutions' taps uniform in
-    +-1/sqrt(taps) (a depthwise Conv1d's default); `decay_log` the log of
-    uniform(1, 16) a head and `dt_bias` the inverse softplus of a step
-    log-uniform in [1e-3, 1e-1] a channel, as the delta-rule family seeds
-    its gate, both float32 like the router."""
-    h, dt, std = c.hidden_size, jnp.dtype(c.dtype), c.initializer_range
-    heads, d, taps = c.linear_heads, c.linear_head_dim, c.linear_conv_size
-    ks = iter(jax.random.split(key, 16))
-
-    def norm(shape):
-        return normal(next(ks), (layers,) + shape, std, dt)
-
-    def taps_w():
-        return jax.random.uniform(
-            next(ks), (layers, taps, heads * d), jnp.float32,
-            -taps ** -0.5, taps ** -0.5).astype(dt)
-
-    step = jnp.exp(jax.random.uniform(
-        next(ks), (layers, heads * d), jnp.float32, math.log(1e-3),
-        math.log(1e-1)))
-    return {
-        "q_w": norm((h, heads * d)), "k_w": norm((h, heads * d)),
-        "v_w": norm((h, heads * d)),
-        "q_conv_w": taps_w(), "k_conv_w": taps_w(), "v_conv_w": taps_w(),
-        "decay_a_w": norm((h, d)), "decay_b_w": norm((d, heads * d)),
-        "decay_log": jnp.log(jax.random.uniform(
-            next(ks), (layers, heads), jnp.float32, 1.0, 16.0)),
-        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-        "beta_w": norm((h, heads)),
-        "gate_a_w": norm((h, d)), "gate_b_w": norm((d, heads * d)),
-        "o_ln": jnp.ones((layers, d), dt)}
+def linear_form(c: MlaMoeConfig) -> delta_layer.DeltaForm:
+    """A LINEAR layer's form (`models/delta_layer.py`): Kimi Delta
+    Attention, a log-decay a head and key channel, a low-rank sigmoid gate
+    and a write strength up to 1, keys and values of one width."""
+    return delta_layer.DeltaForm(
+        heads=c.linear_heads, key_dim=c.linear_head_dim,
+        value_dim=c.linear_head_dim, taps=c.linear_conv_size,
+        norm_eps=c.rms_norm_eps)
 
 
 def init_mla_moe_params(config: MlaMoeConfig, seed: int = 0) -> Dict:
@@ -441,159 +418,11 @@ def _attention(y, blk, c: MlaMoeConfig):
         return jnp.einsum("bsk,kh->bsh", attn, blk["o_w"]), None
 
 
-@functools.partial(jax.checkpoint, static_argnums=(2, 3))
-def _conv_heads(x, taps, heads: int, norm):
-    """x [B, S, H d] through the causal depthwise convolution with taps
-    [K, H d] and SiLU, silu(sum_i taps[i] * x[t - (K - 1) + i]) with zero
-    before the row's start, then split into heads [B, S, H, d]; with
-    `norm`, each head over its L2 norm, times `norm`. Float32 inside, and
-    checkpointed: the backward pass keeps x alone."""
-    size, (b, seq, _) = taps.shape[0], x.shape
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (size - 1, 0), (0, 0)))
-    taps = taps.astype(jnp.float32)
-    out = jax.nn.silu(sum(taps[i] * padded[:, i:i + seq]
-                          for i in range(size))).reshape(b, seq, heads, -1)
-    if norm:
-        out = out * (norm * jax.lax.rsqrt(
-            (out * out).sum(-1, keepdims=True) + QK_NORM_EPS))
-    return out.astype(x.dtype)
-
-
-@jax.checkpoint
-def _log_decay(a, decay_log, dt_bias):
-    """a [B, S, H d] -> the log-decay a head and key channel, float32
-    [B, S, H, d]: -exp(A_h) * softplus(a + b_dt) < 0."""
-    heads = decay_log.shape[0]
-    rate = jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
-    return -jnp.exp(decay_log)[:, None] * rate.reshape(
-        a.shape[:2] + (heads, -1))
-
-
-@functools.partial(jax.checkpoint, static_argnums=(3,))
-def _gated_norm(out, gain, gate, eps: float):
-    """out [B, S, H, d], gate [B, S, H d] -> RMSNorm_d(out; gain) *
-    sigmoid(gate), [B, S, H d]."""
-    b, s, heads, d = out.shape
-    gate = jax.nn.sigmoid(gate.astype(jnp.float32)).reshape(out.shape)
-    return (rms_norm(out, gain, eps) * gate.astype(out.dtype)).reshape(
-        b, s, heads * d)
-
-
-# a linear layer's parameters that the activations before the rule read
-_ACTIVATED = ("q_conv_w", "k_conv_w", "v_conv_w", "decay_log", "dt_bias")
-
-
-@jax.custom_vjp
-def _wide_product(y, w):
-    """y [B, S, h] @ w [h, k] -> float32 [B, S, k]. XLA fuses the product
-    into the XLA form's convolutions, which read its float32 accumulator;
-    the kernels read this, so that the rule's operands are as precise
-    (rounded to bfloat16 first, the first layer's rule read 6.0e-3 off its
-    float32 recurrence on a v5e, where the XLA form reads 5.4e-3). The
-    backward pass takes the cotangent in y's dtype, as the XLA form's
-    does."""
-    return jnp.einsum("bsh,hk->bsk", y, w, preferred_element_type=jnp.float32)
-
-
-def _wide_product_fwd(y, w):
-    return _wide_product(y, w), (y, w)
-
-
-def _wide_product_bwd(saved, cotangent):
-    y, w = saved
-    _, pull = jax.vjp(lambda y, w: jnp.einsum("bsh,hk->bsk", y, w), y, w)
-    return pull(cotangent.astype(y.dtype))
-
-
-_wide_product.defvjp(_wide_product_fwd, _wide_product_bwd)
-
-
-def _kernels(y, blk, c: MlaMoeConfig) -> bool:
-    """Whether the one-pass Mosaic kernels of `ops/pallas/kda_elementwise.py`
-    serve this layer's activations and its gated norm
-    (`kda_elementwise.taken`: its head width, sequence and operands
-    alone)."""
-    return kda_elementwise.taken(c.linear_head_dim, y.shape[1], y,
-                                 *jax.tree_util.tree_leaves(blk))
-
-
-def _projections(y, blk, c: MlaMoeConfig, kernels: bool):
-    """A linear-attention layer's norm and six projections of y [B, S, h],
-    under ATTN_QKV: ((q, k, v, the decay's, the write strength's), the
-    output gate's [B, S, H d]). Where `kernels`, q's, k's and v's products
-    are float32 (`_wide_product`)."""
-    with jax.named_scope(stages.ATTN_QKV):
-        y = rms_norm(y, blk["ln1_g"], c.rms_norm_eps)
-        q, k, v = (_wide_product(y, blk[name]) if kernels
-                   else jnp.einsum("bsh,hk->bsk", y, blk[name])
-                   for name in ("q_w", "k_w", "v_w"))
-        decay, gate = (
-            jnp.einsum("bsr,rk->bsk",
-                       jnp.einsum("bsh,hr->bsr", y, blk[f"{name}_a_w"]),
-                       blk[f"{name}_b_w"]) for name in ("decay", "gate"))
-        beta = jnp.einsum("bsh,hn->bsn", y, blk["beta_w"])
-    return (q, k, v, decay, beta), gate
-
-
-def _activated(projected, params, c: MlaMoeConfig, kernels: bool):
-    """The projections as the rule takes them: (q, k, v) through the short
-    convolutions with SiLU, q's and k's heads normed, in the configuration's
-    dtype, the log-decays and the write strengths. The kernels where
-    `kernels`, else the checkpointed XLA functions above."""
-    heads, d = c.linear_heads, c.linear_head_dim
-    q, k, v, decay, beta = projected
-    if kernels:
-        conv = functools.partial(kda_elementwise.conv_heads, eps=QK_NORM_EPS,
-                                 dtype=jnp.dtype(c.dtype))
-        decays = kda_elementwise.log_decay
-    else:
-        conv, decays = _conv_heads, _log_decay
-    return (conv(q, params["q_conv_w"], heads, d ** -0.5),
-            conv(k, params["k_conv_w"], heads, 1.0),
-            conv(v, params["v_conv_w"], heads, None),
-            decays(decay, params["decay_log"], params["dt_bias"]),
-            jax.nn.sigmoid(beta.astype(jnp.float32)))
-
-
-def _rule_operands(y, blk, c: MlaMoeConfig):
-    """A linear-attention layer on y [B, S, h] (its norm is here) up to the
-    rule: ((q, k, v, the log-decays, the write strengths) as the rule takes
-    them, the output gate's projection [B, S, H d]): `_projections` under
-    ATTN_QKV, `_activated` under LINEAR_ATTN."""
-    kernels = _kernels(y, blk, c)
-    projected, gate = _projections(y, blk, c, kernels)
-    with jax.named_scope(stages.LINEAR_ATTN):
-        return _activated(projected, blk, c, kernels), gate
-
-
 def _linear_attention(y, blk, c: MlaMoeConfig):
-    """The linear-attention layer on y [B, S, h] -> its output projection,
-    [B, S, h]: the projections under ATTN_QKV; the activations, the chunked
-    rule and the gated head norm under LINEAR_ATTN; the output's projection
-    under ATTN_OUT. Where the kernels serve the layer, the activations
-    and the rule are checkpointed together: the layer's backward pass keeps
-    the projections, which the kernels' backward reads anyway, and forms
-    the rule's operands from them once more (one more run of the forward
-    kernels), so that neither those operands nor the float32 log-decays
-    are kept across the rest of the layer's backward pass. Kept, they
-    compiled the Kimi cell's step for a v5e to about 15.4 GB, against
-    13.85 checkpointed together and 13.96 for the XLA form."""
-    kernels = _kernels(y, blk, c)
-    projected, gate = _projections(y, blk, c, kernels)
-    with jax.named_scope(stages.LINEAR_ATTN):
-        if kernels:
-            out = jax.checkpoint(lambda projected, params: (
-                chunk_gated_delta_rule(*_activated(projected, params, c,
-                                                   True))))(
-                projected, {name: blk[name] for name in _ACTIVATED})
-            out = kda_elementwise.gated_norm(out, blk["o_ln"], gate,
-                                             c.rms_norm_eps)
-        else:
-            out = chunk_gated_delta_rule(*_activated(projected, blk, c,
-                                                     False))
-            out = _gated_norm(out, blk["o_ln"], gate, c.rms_norm_eps)
-    with jax.named_scope(stages.ATTN_OUT):
-        return jnp.einsum("bsk,kh->bsh", out, blk["o_w"]), None
+    """The linear-attention layer on y [B, S, h] (its norm is there) -> its
+    output projection, [B, S, h] (`delta_layer.linear_attention`)."""
+    return delta_layer.linear_attention(y, blk, linear_form(c), c.dtype), \
+        None
 
 
 def first_rule(params, x, config: MlaMoeConfig):
@@ -608,7 +437,8 @@ def first_rule(params, x, config: MlaMoeConfig):
                          "layer")
     group = next(g for g in ("dense", "sparse", "tail") if params.get(g))
     blk = jax.tree_util.tree_map(lambda a: a[0], params[group][0])
-    operands, _ = _rule_operands(x.astype(jnp.dtype(c.dtype)), blk, c)
+    operands, _ = delta_layer.rule_operands(
+        x.astype(jnp.dtype(c.dtype)), blk, linear_form(c), c.dtype)
     return chunk_gated_delta_rule(*operands)
 
 

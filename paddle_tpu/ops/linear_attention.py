@@ -44,19 +44,35 @@ _inverse`, on [sub, sub, batch] arrays so that the batch and not a width of
 with R the level's blocks of A.
 
 Two ways to compute the chunk terms, one algorithm, chosen by the operands'
-shapes alone (`ops/pallas/delta_rule.taken`): head widths that are
-multiples of 128 lanes at the chunk of `CHUNK` = 4 `SUB` take the Mosaic
-kernels of `ops/pallas/delta_rule.py`, which read q, k, v and g where the
-layer wrote them, `[B, S, H d]`, hold everything above in VMEM (there T
-comes from all the levels' blocks at once, in three dependent products)
-and write the six terms once, the chunk axis leading; any other shape
-(narrow heads, chunks of 8 to 32) takes `_chunk_terms`, XLA's own passes,
-which is also what the kernels are tested against. The scan over chunks is
-the same `lax.scan` behind both.
+shapes alone (`ops/pallas/delta_rule.taken` on `lane_widths`): head widths
+that are multiples of 128 lanes, or that become such by a zero padding of
+at most a third of the padded width (`lane_widths`: d_k 96 -> 128 and d_v
+192 -> 256 for Gated DeltaNet's heads), at the chunk of `CHUNK` = 4 `SUB`
+take the Mosaic kernels of `ops/pallas/delta_rule.py`, which read q, k, v
+and g where the layer wrote them, `[B, S, H d]`, hold everything above in
+VMEM (there T comes from all the levels' blocks at once, in three
+dependent products) and write the six terms once, the chunk axis leading;
+any other shape (narrow heads, chunks of 8 to 32) takes `_chunk_terms`,
+XLA's own passes, which is also what the kernels are tested against. The
+scan over chunks is the same `lax.scan` behind both.
 
 Precision, in both: the log-decays, their sums, every exp, T and the state
 are float32; the products' operands are rounded to q's dtype (bfloat16 in
 the trainer) and accumulate in float32.
+
+The padding is exact and is done inside the checkpointed group, so neither
+direction keeps a padded array: zero key channels add nothing to k.k or
+q.k, so A, B and the writes are unchanged, the padded rows and columns of S
+start at 0 and stay 0 (their k and v are 0), and the output's padded
+columns are sliced away. The scan then carries the padded state.
+
+The log-decay is a head AND key channel's, or one a head (Gated DeltaNet,
+Yang et al. 2024, arXiv:2412.06464): g [B, S, H] is the same value in every
+channel, broadcast over d_k inside the group; equal channels give the
+scalar form exactly. Nothing here bounds beta: write strengths up to 2 (I -
+beta k k^T with a negative eigenvalue, Grazzi et al. 2024, arXiv:2411.12537)
+go through the same algebra, T = (I + A)^-1 being unit lower triangular
+whatever A holds.
 
 The backward pass keeps a group's five inputs and computes the rest again:
 rows of the batch are taken in groups of at most `GROUP_TOKENS` tokens, one
@@ -67,7 +83,7 @@ backward kernel, which forms G, the pairwise exponentials, T, W and U once
 more in VMEM from the five inputs and writes the five gradients once: no
 float32 intermediate of the chunk terms is an array in either direction.
 Where a layer forms the five inputs with the Mosaic kernels of
-`ops/pallas/kda_elementwise.py`, `models/mla_moe._linear_attention`
+`ops/pallas/kda_elementwise.py`, `models/delta_layer.linear_attention`
 checkpoints those kernels and the rule together, so what the layer's
 backward pass keeps is the projections the inputs are formed from; the
 inputs are formed once more and held only while the rule's gradient is
@@ -98,6 +114,17 @@ SUB = 16        # the pairwise sub-block; a chunk is SUB times a power of two
 GROUP_TOKENS = 2048     # rows of the batch taken together: this many tokens
                         # (measured, the docstring's last paragraph)
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def lane_widths(d_k: int, d_v: int):
+    """(d_k, d_v) as the kernels would take them: each rounded up to whole
+    lane tiles of `delta_rule.LANES` where the zeros are at most a third of
+    the rounded width, else as it is (a narrow head is no kernel's)."""
+    def rounded(d):
+        up = -(-d // delta_rule.LANES) * delta_rule.LANES
+        return up if 2 * up <= 3 * d else d
+
+    return rounded(d_k), rounded(d_v)
 
 
 @jax.checkpoint
@@ -217,6 +244,15 @@ def _rule(q, k, v, g, beta, chunk: int, sub: int, kernels: bool):
     b, s, h, dk = q.shape
     dv, dtype, f32 = v.shape[-1], q.dtype, jnp.float32
     g, beta = g.astype(f32), beta.astype(f32)
+    width = dv
+    if kernels:
+        dk, dv = lane_widths(dk, dv)
+        q, k = (_widened(x, dk) for x in (q, k))
+        v = _widened(v, dv)
+    if g.ndim == 3:         # one log-decay a head, the same in every channel
+        g = jnp.broadcast_to(g[..., None], (b, s, h, dk))
+    else:
+        g = _widened(g, dk)
 
     def chunks(x):          # [B, S, H, ...] -> [B, H, N, C, ...]
         x = x.reshape((b, s // chunk, chunk) + x.shape[2:])
@@ -244,15 +280,24 @@ def _rule(q, k, v, g, beta, chunk: int, sub: int, kernels: bool):
 
     _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32), terms)
     # [N, B, H, C, d_v] -> [B, S, H, d_v]
-    return jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, s, h, dv)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, s, h, dv)
+    return o if width == dv else o[..., :width]
+
+
+def _widened(x, width: int):
+    """x [..., d] with zeros appended to `width` channels (as it is where
+    it has them)."""
+    extra = width - x.shape[-1]
+    return x if not extra else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
     """q, k [B, S, H, d_k], v [B, S, H, d_v], g [B, S, H, d_k] the
-    log-decays (<= 0), beta [B, S, H] -> o [B, S, H, d_v] in q's dtype: the
-    recurrence of the module docstring from S_0 = 0 in every row of the
-    batch, in chunks of `chunk` tokens. q and k come as the rule takes them
-    (normalised, q scaled)."""
+    log-decays (<= 0) or [B, S, H], one a head, beta [B, S, H] -> o [B, S,
+    H, d_v] in q's dtype: the recurrence of the module docstring from S_0 =
+    0 in every row of the batch, in chunks of `chunk` tokens. q and k come
+    as the rule takes them (normalised, q scaled)."""
     b, s = q.shape[:2]
     sub = min(SUB, chunk)
     if s % chunk:
@@ -265,7 +310,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
             f"two, got {chunk}")
     rows = max(n for n in range(1, b + 1)
                if b % n == 0 and (n == 1 or n * s <= GROUP_TOKENS))
-    kernels = delta_rule.taken(q.shape[-1], v.shape[-1], chunk, sub, q.dtype)
+    kernels = delta_rule.taken(*lane_widths(q.shape[-1], v.shape[-1]), chunk,
+                               sub, q.dtype)
     if rows == b:
         return _rule(q, k, v, g, beta, chunk, sub, kernels)
     groups = jax.lax.map(

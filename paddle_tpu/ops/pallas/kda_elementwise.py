@@ -1,6 +1,6 @@
 """The elementwise passes of a Kimi-Delta-Attention layer around the chunked
-rule (`models/mla_moe.py`: the short convolutions with their head norms, the
-log-decays, the gated head norm) as Pallas TPU kernels under three
+rule (`models/delta_layer.py`: the short convolutions with their head norms,
+the log-decays, the gated head norm) as Pallas TPU kernels under three
 `jax.custom_vjp`s. Each reads its operands once where the projections wrote
 them, `[B, S, H d]`, keeps everything it forms in VMEM in float32, and writes
 its result once:
@@ -39,7 +39,7 @@ in float32.
 `taken(head_dim, seq, *operands)` says from the operands alone whether the
 kernels serve a call: head widths that are multiples of 128 lanes, a
 sequence of whole `HALO`s and no 64-bit operand. Anything else keeps the
-XLA functions of `models/mla_moe.py`, which the kernels are tested against.
+XLA functions of `models/delta_layer.py`, which the kernels are tested against.
 The entries are module-level `jax.jit` functions with static tiles and
 mode, so that the sites of one shape share one trace of a kernel body and
 one Mosaic body a lowered program (as `grouped_matmul.py`'s and
@@ -411,7 +411,7 @@ def _norm_backward(o, gate, gain, dy, *, eps, interpret):
 
 def conv_heads(x, taps, heads: int, norm, eps: float, dtype=None):
     """x [B, S, H d], taps [K, H d] -> [B, S, H, d] in `dtype` (x's if
-    None): `models/mla_moe._conv_heads`'s result, rounded once. Gradients
+    None): `models/delta_layer._conv_heads`'s result, rounded once. Gradients
     reach x and taps, in their own dtypes."""
     b, s, w = x.shape
     return _conv(x, taps, w // heads, norm, eps,
@@ -444,7 +444,7 @@ _conv.defvjp(_conv_fwd, _conv_bwd)
 def log_decay(a, decay_log, dt_bias):
     """a [B, S, H d], decay_log [H], dt_bias [H d] -> the log-decays
     -exp(A_h) softplus(a + b_dt), float32 [B, S, H, d]:
-    `models/mla_moe._log_decay`'s result. Gradients reach all three."""
+    `models/delta_layer._log_decay`'s result. Gradients reach all three."""
     b, s, w = a.shape
     heads = decay_log.shape[0]
     coef = jnp.repeat(-jnp.exp(decay_log.astype(_F32)), w // heads)
@@ -474,7 +474,7 @@ _decay.defvjp(_decay_fwd, _decay_bwd)
 
 def gated_norm(o, gain, gate, eps: float):
     """o [B, S, H, d], gain [d], gate [B, S, H d] -> RMSNorm_d(o; gain) *
-    sigmoid(gate), [B, S, H d] in o's dtype: `models/mla_moe._gated_norm`'s
+    sigmoid(gate), [B, S, H d] in o's dtype: `models/delta_layer._gated_norm`'s
     result. Gradients reach all three."""
     b, s, heads, d = o.shape
     return _norm(o.reshape(b, s, heads * d), gate, gain.reshape(1, d), eps)

@@ -1,6 +1,6 @@
 """The KDA layer's elementwise passes around the chunked rule as Mosaic
 kernels (ops/pallas/kda_elementwise.py), here in the interpreter, against
-the XLA functions of models/mla_moe.py that they replace: the short
+the XLA functions of models/delta_layer.py that they replace: the short
 convolutions with SiLU and the head norms, the log-decays and the gated head
 norm, forward and every gradient, on sequences of several token blocks (the
 halo between blocks, the zero history at a row's start, the zero future at
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from benchmarks.cells import load_cell
-from paddle_tpu.models import mla_moe
+from paddle_tpu.models import delta_layer, mla_moe
 from paddle_tpu.ops.pallas import kda_elementwise as ke
 
 HEADS, D, TAPS = 2, 128, 4
@@ -59,8 +59,8 @@ def test_conv_heads_is_the_xla_form_forward_and_backward(seq, norm):
     assert ke.taken(D, seq, x, taps)
     assert ke.tiling(seq, HEADS * D, D)[0] == (16 if seq == 48 else 512)
     got, want = _both(
-        lambda x, t: ke.conv_heads(x, t, HEADS, norm, mla_moe.QK_NORM_EPS),
-        lambda x, t: mla_moe._conv_heads(x, t, HEADS, norm), (x, taps), 1)
+        lambda x, t: ke.conv_heads(x, t, HEADS, norm, delta_layer.QK_NORM_EPS),
+        lambda x, t: delta_layer._conv_heads(x, t, HEADS, norm), (x, taps), 1)
     _close(got, want, 2e-6)
 
 
@@ -91,8 +91,9 @@ def test_a_float32_product_in_and_the_rules_dtype_out():
     taps = _normal(jax.random.PRNGKey(12), (TAPS, HEADS * D), 0.5, bf)
     got, want = _both(
         lambda x, t: ke.conv_heads(x, t, HEADS, D ** -0.5,
-                                   mla_moe.QK_NORM_EPS, dtype=bf),
-        lambda x, t: mla_moe._conv_heads(x, t, HEADS, D ** -0.5).astype(bf),
+                                   delta_layer.QK_NORM_EPS, dtype=bf),
+        lambda x, t: delta_layer._conv_heads(
+            x, t, HEADS, D ** -0.5).astype(bf),
         (x, taps), 13)
     assert got[0].dtype == bf and got[1][0].dtype == jnp.float32
     _close(got[0], want[0], 2e-3)
@@ -100,13 +101,13 @@ def test_a_float32_product_in_and_the_rules_dtype_out():
 
 
 def test_the_wide_product_is_float32_forward_and_bfloat16_backward():
-    """`mla_moe._wide_product`: the q, k, v products unrounded, as XLA's
+    """`delta_layer._wide_product`: the q, k, v products unrounded, as XLA's
     fusions hand them to the XLA form's convolutions, and a backward pass
     that is the bfloat16 product's on the cotangent rounded to bfloat16."""
     bf = jnp.bfloat16
     y = _normal(jax.random.PRNGKey(14), (2, 16, 64), dtype=bf)
     w = _normal(jax.random.PRNGKey(15), (64, 256), 0.1, bf)
-    out, pull = jax.vjp(mla_moe._wide_product, y, w)
+    out, pull = jax.vjp(delta_layer._wide_product, y, w)
     assert out.dtype == jnp.float32
     _close(out, jnp.einsum("bsh,hk->bsk", y.astype(jnp.float32),
                            w.astype(jnp.float32)), 1e-6)
@@ -125,7 +126,7 @@ def test_log_decay_is_the_xla_form_forward_and_backward():
     decay_log = jnp.log(jax.random.uniform(kd, (HEADS,), jnp.float32, 1, 16))
     dt_bias = _normal(kb, (HEADS * D,), 2.0)
     assert ke.taken(D, 48, a, decay_log, dt_bias)
-    got, want = _both(ke.log_decay, mla_moe._log_decay,
+    got, want = _both(ke.log_decay, delta_layer._log_decay,
                       (a, decay_log, dt_bias), 6)
     assert got[0].dtype == jnp.float32 and got[0].shape == (2, 48, HEADS, D)
     _close(got, want, 2e-6)
@@ -140,7 +141,8 @@ def test_gated_norm_is_the_xla_form_forward_and_backward():
     gain = 1.0 + _normal(kw, (D,), 0.3)
     assert ke.taken(D, 48, o, gate, gain)
     got, want = _both(lambda o, w, g: ke.gated_norm(o, w, g, 1e-5),
-                      lambda o, w, g: mla_moe._gated_norm(o, w, g, 1e-5),
+                      lambda o, w, g: delta_layer._gated_norm(
+                          o, w, g, 1e-5, jax.nn.sigmoid),
                       (o, gain, gate), 8)
     _close(got, want, 2e-6)
 
@@ -168,10 +170,12 @@ def test_bfloat16_operands_round_once_where_the_xla_form_rounds_more():
 
     cases = [
         (lambda x, t: ke.conv_heads(x, t, HEADS, D ** -0.5, 1e-6),
-         lambda x, t: mla_moe._conv_heads(x, t, HEADS, D ** -0.5), (x, taps)),
-        (ke.log_decay, mla_moe._log_decay, (a, decay_log, dt_bias)),
+         lambda x, t: delta_layer._conv_heads(x, t, HEADS, D ** -0.5),
+         (x, taps)),
+        (ke.log_decay, delta_layer._log_decay, (a, decay_log, dt_bias)),
         (lambda o, w, g: ke.gated_norm(o, w, g, 1e-5),
-         lambda o, w, g: mla_moe._gated_norm(o, w, g, 1e-5),
+         lambda o, w, g: delta_layer._gated_norm(o, w, g, 1e-5,
+                                                 jax.nn.sigmoid),
          (o, gain, gate))]
     for kernel, xla, args in cases:
         got, same = kernel(*args), xla(*args)

@@ -4,7 +4,10 @@ five gradients at three chunk sizes, log-decays strong enough to overflow a
 form that multiplies exp(G) by exp(-G), and the shapes it refuses. At head
 widths of 128 and the chunk of 64 the chunk terms are the Mosaic kernels of
 ops/pallas/delta_rule.py (here in the interpreter): the same comparisons,
-and against the XLA form on the same operands."""
+and against the XLA form on the same operands. Gated DeltaNet's heads: a
+decay a head, write strengths near 2, keys and values of two widths, and
+at 96 / 192 the kernels through zero padding against the unpadded XLA
+form."""
 import functools
 
 import jax
@@ -274,7 +277,7 @@ def test_strong_decay_through_the_kernels_is_finite_and_the_recurrences():
 
 @pytest.mark.parametrize("dk, dv, chunk, kernels", [
     (128, 128, 64, True), (16, 8, 64, False), (128, 128, 32, False),
-    (128, 64, 64, False)])
+    (128, 64, 64, False), (96, 192, 64, True), (96, 192, 32, False)])
 def test_the_shapes_alone_choose_the_kernels(dk, dv, chunk, kernels):
     """Head widths that are multiples of 128 at the chunk of 64 take the
     Mosaic kernels, forward and backward; any other shape lowers the XLA
@@ -284,3 +287,84 @@ def test_the_shapes_alone_choose_the_kernels(dk, dv, chunk, kernels):
         lambda *a: chunk_gated_delta_rule(*a, chunk=chunk).sum(),
         argnums=range(5)))(*x))
     assert ("pallas_call" in text) is kernels
+
+
+# ------------------------- Gated DeltaNet's heads: a decay a head, beta to 2
+
+def gdn_inputs(seq=64, batch=1, heads=2, dk=24, dv=40, seed=3):
+    """Operands as a Gated DeltaNet layer hands them: one log-decay a head
+    [B, S, H], write strengths between 1.6 and 2 (I - beta k k^T with an
+    eigenvalue near -1), keys and values of two widths that are no
+    multiple of 128."""
+    q, k, v, _, _ = inputs(0.1, seq=seq, batch=batch, heads=heads, dk=dk,
+                           dv=dv, seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+    g = -0.2 * jax.nn.sigmoid(jax.random.normal(ks[0], (batch, seq, heads)))
+    beta = 1.6 + 0.4 * jax.nn.sigmoid(
+        jax.random.normal(ks[1], (batch, seq, heads)))
+    return q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)
+
+
+def broadcast(x):
+    """The per-head decay as the per-channel operand the recurrence takes."""
+    q, k, v, g, beta = x
+    return q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_a_decay_a_head_and_beta_near_two_are_the_recurrences(chunk):
+    """d_k 24 and d_v 40, beta in (1.6, 2), the decay one a head: the
+    output and the five gradients (the decay's summed over its channels)
+    are the recurrence's, token by token."""
+    x = gdn_inputs(seq=128)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
+    assert float(x[4].min()) > 1.6 and float(x[4].max()) < 2.0
+    def both(rule):
+        return jax.jit(lambda *a: (rule(*a), jax.grad(
+            lambda *a: (rule(*a) * weight).sum(), argnums=range(5))(*a)))(*x)
+
+    with jax.default_matmul_precision("highest"):
+        want, want_g = both(lambda *a: recurrence(*broadcast(a)))
+        got, got_g = both(functools.partial(chunk_gated_delta_rule,
+                                            chunk=chunk))
+    assert got.shape == want.shape == (1, 128, 2, 40)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    for g, w in zip(got_g, want_g):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("dk, dv, lanes", [
+    (96, 192, (128, 256)), (128, 128, (128, 128)), (16, 8, (16, 8)),
+    (64, 128, (64, 128)), (86, 171, (128, 256)), (85, 170, (85, 170))])
+def test_lane_widths_pad_a_head_where_a_third_or_less_is_zeros(dk, dv,
+                                                                lanes):
+    assert linear_attention.lane_widths(dk, dv) == lanes
+
+
+def test_the_padded_kernels_are_the_unpadded_xla_form():
+    """Heads of 96 keys and 192 values take the Mosaic kernels (here in
+    the interpreter) through zero padding to 128 and 256, and the output
+    and the five gradients are the XLA form's on the unpadded operands,
+    with the decay one a head and beta near 2."""
+    x = gdn_inputs(dk=96, dv=192)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
+
+    def both(rule):
+        return jax.jit(lambda *a: (rule(*a), jax.grad(
+            lambda *a: (rule(*a) * weight).sum(), argnums=range(5))(*a)))(*x)
+
+    text = str(jax.make_jaxpr(chunk_gated_delta_rule)(*x))
+    assert "pallas_call" in text and "pad" in text
+    with jax.default_matmul_precision("highest"):
+        got, got_g = both(chunk_gated_delta_rule)
+        want, want_g = both(
+            lambda *a: linear_attention._rule(*a, 64, SUB, False))
+    assert got.shape == want.shape == (1, 64, 2, 192)
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    for g, w in zip(got_g, want_g):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
